@@ -6,8 +6,10 @@
 # Run  :  docker run -p 8000:8000 -p 9000:9000 -p 2121:2121 \
 #             -e TPU_MODEL=llama-1b -e TPU_QUANT=int8 gofr-tpu
 #
-# On a TPU VM, base this on a libtpu-enabled image instead and install
-# jax[tpu]; the framework auto-detects the backend via PJRT.
+# The pip line below installs jax's CPU wheel, so this image serves on
+# the CPU. For a TPU VM install "jax[tpu]" instead; nothing else changes,
+# because no platform is pinned here and the engine runs on the backend
+# JAX finds (health reports it: details.tpu.details.platform).
 
 FROM python:3.12-slim
 
@@ -20,7 +22,6 @@ COPY gofr_tpu/ gofr_tpu/
 COPY examples/tpu-http/ examples/tpu-http/
 
 ENV PYTHONPATH=/app \
-    JAX_PLATFORMS=cpu \
     TPU_ENABLED=1 \
     TPU_MODEL=llama-tiny
 

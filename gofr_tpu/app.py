@@ -408,7 +408,7 @@ class App:
             # /debug/* — ops surface on the metrics port (net-new: the
             # closest Go analog is pprof-on-metrics-port, which the
             # reference does not ship; TPU serving makes the equivalents
-            # indispensable: a wedged device relay shows up as a thread
+            # indispensable: a hung device step shows up as a thread
             # parked in a jit dispatch, and device traces answer "where
             # does the step go" without a redeploy).
             if path == "/debug/threads":

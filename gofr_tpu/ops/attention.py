@@ -62,6 +62,11 @@ def _flash_decode_enabled() -> bool:
 
 
 def _interpret() -> bool:
+    """Pallas interpret mode, for every kernel call in this module: never
+    on a TPU backend, always off it. Off-TPU the kernel path is only
+    taken when forced (``GOFR_TPU_FLASH=1`` / ``GOFR_TPU_FLASH_DECODE=1``,
+    or a test passing ``kernel=True``), so serving on a TPU cannot reach
+    the interpreter."""
     return jax.default_backend() != "tpu"
 
 

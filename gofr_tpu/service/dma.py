@@ -10,19 +10,15 @@ socket fetch from the exporting process — the jax-transfer-server
 shape, where control (the ops-port POST) and data (the block bytes)
 travel different paths and the data path is point-to-point.
 
-Two backends share this seam:
-
-* **ICI/DMA (real TPU pods)** — ``jax.experimental.transfer``'s
-  cross-host transfer server, when the installed jax provides it
-  (:func:`jax_transfer_available`). There the staged entry would be
-  device buffers and the fetch an ICI pull that never touches host
-  memory.
-* **Loopback emulation (CI, CPU)** — a thread-per-connection TCP
-  server over the payload's wire bytes. Same handles, same staging
-  TTL, same failure modes (connect-refused, mid-read reset, stale
-  key, checksum mismatch), so the WHOLE failure matrix runs on a
-  laptop: chaos tests ``kill -9`` a real exporting process mid-fetch
-  and watch the ladder descend one rung.
+The one backend is a loopback emulation: a thread-per-connection TCP
+server over the payload's wire bytes, with the handles, staging TTL and
+failure modes (connect-refused, mid-read reset, stale key, checksum
+mismatch) a device-to-device transfer server would have, so the WHOLE
+failure matrix runs on a laptop: chaos tests ``kill -9`` a real
+exporting process mid-fetch and watch the ladder descend one rung.
+The installed jax (0.9.0) does ship ``jax.experimental.transfer``, whose
+cross-host server would stage device buffers and fetch over ICI without
+touching host memory; nothing here uses it yet (ROADMAP D7).
 
 Failure currency is :class:`DmaError` with ``kind`` ∈
 ``connect`` / ``read`` / ``stale`` / ``proto`` — the replica pool maps
@@ -110,19 +106,6 @@ class DmaError(Exception):
         self.kind = kind
 
 
-def jax_transfer_available() -> bool:
-    """Whether the installed jax carries the cross-host transfer-server
-    API (``jax.experimental.transfer``, jax ≥ 0.5). On the CI jax it
-    does not — the loopback emulation below is then the only backend,
-    which is exactly what makes the failure matrix runnable without a
-    pod."""
-    try:
-        import jax.experimental.transfer  # noqa: F401
-    except ImportError:
-        return False
-    return True
-
-
 @dataclass
 class _Staged:
     body: bytes
@@ -188,6 +171,13 @@ class DmaTransferServer:
         self._stopping.set()
         sock, self._sock = self._sock, None
         if sock is not None:
+            # close() alone does not wake a thread blocked in accept() on
+            # Linux: the listener would outlive stop() and serve one more
+            # connection. shutdown() makes that accept() raise.
+            try:
+                sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
             try:
                 sock.close()
             except OSError:

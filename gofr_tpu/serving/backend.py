@@ -8,6 +8,7 @@ so apps that don't serve models never import jax.
 
 from __future__ import annotations
 
+import traceback
 from typing import Any, Optional
 
 
@@ -17,9 +18,13 @@ def new_tpu_from_config(
     model = config.get_or_default("TPU_MODEL", "")
     if not model:
         return None
+    from gofr_tpu.compile_cache import enable_compile_cache
     from gofr_tpu.serving.engine import InferenceEngine
 
     try:
+        # Before the first jit of a serving process: JAX keeps whichever
+        # cache directory its first compile saw.
+        enable_compile_cache()
         # Replica tier (docs/advanced-guide/resilience.md): TPU_REPLICAS
         # > 1 and/or TPU_REPLICA_ADDRS front the engine(s) with a
         # health-aware failover router — container.tpu becomes the POOL
@@ -42,8 +47,15 @@ def new_tpu_from_config(
             logger.infof("TPU backend initialised with model %s", model)
         return engine
     except Exception as exc:
+        # The datasource idiom: a backend that cannot start is logged
+        # and left None, and the app still boots (completions then 400).
+        # An OOM at 7B width or a Mosaic refusal lands HERE, so the line
+        # carries the exception type and the traceback, not only str().
         if logger is not None:
-            logger.errorf("could not initialise TPU backend: %s", exc)
+            logger.errorf(
+                "could not initialise TPU backend: %s: %s\n%s",
+                type(exc).__name__, exc, traceback.format_exc(),
+            )
         return None
 
 
@@ -128,13 +140,19 @@ def _new_tpu_pool_from_config(
                 "blocks"
             )
 
-    # GSPMD pods (TPU_TP > 1 / TPU_MESH_CP > 1): each in-proc replica
-    # becomes ONE sharded pod over its own DISJOINT slice of the device
-    # list — dp across replicas, tp (× cp) within each. Without enough
+    # Device layout: dp across replicas, tp (× cp) within each. Every
+    # in-proc replica gets its own DISJOINT slice of the device list —
+    # a GSPMD pod of tp·cp chips (TPU_TP / TPU_MESH_CP), or ONE chip for
+    # an unsharded engine, which pins its params and cache there (four
+    # 7B engines stacked on chip 0 do not fit 16 GB). Without enough
     # devices to cover every replica disjointly, overflow replicas
     # share the first slice (correct, just without the parallel
-    # speedup) and the shortfall is logged once instead of the operator
-    # chasing a silent perf gap.
+    # speedup, and HBM permitting) and the shortfall is logged once
+    # instead of the operator chasing a silent gap.
+    import jax
+
+    from gofr_tpu.parallel.mesh import partition_devices
+
     tp = int(
         config.get_or_default(
             "TPU_TP", config.get_or_default("TPU_MESH_TP", "1")
@@ -142,33 +160,25 @@ def _new_tpu_pool_from_config(
     )
     cp = int(config.get_or_default("TPU_MESH_CP", "1"))
     pod_size = max(1, tp) * max(1, cp)
-    device_groups: list = [None] * n_replicas
-    if pod_size > 1:
-        import jax
-
-        from gofr_tpu.parallel.mesh import partition_devices
-
-        all_devices = list(jax.devices())
-        if len(all_devices) < pod_size:
-            # Not even ONE pod fits: fail at the seam with the real
-            # arithmetic instead of letting make_mesh crash after a
-            # log line that promised degraded boot.
-            raise ValueError(
-                f"sharded pool: one pod needs tp·cp={pod_size} "
-                f"device(s) but only {len(all_devices)} are visible — "
-                f"lower TPU_TP/TPU_MESH_CP or add devices"
-            )
-        if len(all_devices) < pod_size * n_replicas and logger is not None:
-            logger.warnf(
-                "sharded pool wants %d devices (%d replica(s) × tp·cp="
-                "%d) but only %d are visible: replicas past the last "
-                "full slice share the first slice's devices",
-                pod_size * n_replicas, n_replicas, pod_size,
-                len(all_devices),
-            )
-        device_groups = partition_devices(
-            all_devices, pod_size, n_replicas
+    all_devices = list(jax.devices())
+    if len(all_devices) < pod_size:
+        # Not even ONE pod fits: fail at the seam with the real
+        # arithmetic instead of letting make_mesh crash after a log
+        # line that promised degraded boot.
+        raise ValueError(
+            f"sharded pool: one pod needs tp·cp={pod_size} "
+            f"device(s) but only {len(all_devices)} are visible — "
+            f"lower TPU_TP/TPU_MESH_CP or add devices"
         )
+    if len(all_devices) < pod_size * n_replicas and logger is not None:
+        logger.warnf(
+            "replica pool wants %d devices (%d replica(s) × tp·cp=%d) "
+            "but only %d are visible: replicas past the last full slice "
+            "share the first slice's devices",
+            pod_size * n_replicas, n_replicas, pod_size,
+            len(all_devices),
+        )
+    device_groups = partition_devices(all_devices, pod_size, n_replicas)
 
     replicas: list = []
     for i in range(n_replicas):
@@ -277,33 +287,22 @@ def _new_tpu_pool_from_config(
             # a spawn counter would double-occupy slice 0 while free
             # slices sat idle. Only past the last free slice does a
             # spawn share slice 0, mirroring the boot-time fallback.
-            spawn_devices = None
-            if pod_size > 1:
-                import jax
-
-                from gofr_tpu.parallel.mesh import partition_devices
-
-                all_devices = list(jax.devices())
-                slices = partition_devices(
-                    all_devices, pod_size,
-                    max(1, len(all_devices) // pod_size),
-                )
-                held = set()
-                for replica in pool.replicas:
-                    mesh = getattr(
-                        getattr(replica, "engine", None), "mesh", None
-                    )
-                    if mesh is not None:
-                        held.add(frozenset(
-                            str(d) for d in mesh.devices.flat
-                        ))
-                spawn_devices = next(
-                    (
-                        s for s in slices
-                        if frozenset(str(d) for d in s) not in held
-                    ),
-                    slices[0],
-                )
+            slices = partition_devices(
+                all_devices, pod_size,
+                max(1, len(all_devices) // pod_size),
+            )
+            held = set()
+            for replica in pool.replicas:
+                held_by = getattr(replica, "engine", None)
+                if held_by is not None:
+                    held.add(frozenset(str(d) for d in held_by.devices))
+            spawn_devices = next(
+                (
+                    s for s in slices
+                    if frozenset(str(d) for d in s) not in held
+                ),
+                slices[0],
+            )
             engine = InferenceEngine.from_config(
                 config, logger=logger, metrics=metrics,
                 devices=spawn_devices,
@@ -423,7 +422,10 @@ def new_tpu_embed_from_config(
         return engine
     except Exception as exc:
         if logger is not None:
-            logger.errorf("could not initialise TPU embed backend: %s", exc)
+            logger.errorf(
+                "could not initialise TPU embed backend: %s: %s\n%s",
+                type(exc).__name__, exc, traceback.format_exc(),
+            )
         return None
 
 

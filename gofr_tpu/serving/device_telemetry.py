@@ -273,10 +273,10 @@ class CompileTracker:
         self.total = 0
         self.steady_state_recompiles = 0
         self._warm = False
-        # Persistent-compile-cache provenance (TPU_COMPILE_CACHE_DIR):
-        # set by the engine at boot when the operator points jax's
-        # compilation cache at a directory; rides health details and
-        # /debug/capacity so "did this restart re-trace" is answerable.
+        # Persistent-compile-cache provenance: set by the engine at
+        # boot when jax's compilation cache has a directory
+        # (gofr_tpu/compile_cache.py); rides health details and
+        # /debug/capacity so "did this restart recompile" is answerable.
         self.cache_info: Optional[dict[str, Any]] = None
         # Boot trace context: compiles fire on the scheduler thread
         # (no ambient span there), so the trace that was ambient when

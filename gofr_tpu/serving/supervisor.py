@@ -3,7 +3,7 @@
 PR 2's watchdog turned a wedged device step into a *detected* failure —
 but a detected failure still latched the engine DOWN until an operator
 restarted it. A production jax_graft system serving millions of users
-must survive a hung relay or a crashed scheduler loop without a pager:
+must survive a hung device step or a crashed scheduler loop without a pager:
 GoFr's capability surface implies the FRAMEWORK owns recovery, and the
 north star's ICI-sharded multi-chip serving makes single-replica
 self-healing the prerequisite for any replica-level failover story.

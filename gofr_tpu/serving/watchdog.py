@@ -1,7 +1,7 @@
 """Scheduler watchdog: declare the engine unhealthy when it stalls.
 
-A hung device step (wedged relay, deadlocked collective, runaway
-compile) is indistinguishable from a slow one from inside the
+A hung device step (deadlocked collective, runaway compile, a device
+that stopped answering) is indistinguishable from a slow one from inside the
 scheduler thread — it is *blocked*. The watchdog watches from outside:
 the scheduler **pets** it once per loop iteration (idle iterations pet
 every ≤20 ms, busy ones once per window), and a monitor checks that

@@ -18,7 +18,7 @@ failed=0
 
 if command -v ruff >/dev/null 2>&1; then
   echo "== ruff =="
-  ruff check gofr_tpu/ tests/ examples/ bench.py __graft_entry__.py || failed=1
+  ruff check gofr_tpu/ tests/ examples/ bench.py chip_smoke.py __graft_entry__.py || failed=1
   ruff check --select I gofr_tpu/analysis tests/test_graftlint.py || failed=1
 else
   echo "== ruff == SKIPPED (not installed; pip install ruff)"

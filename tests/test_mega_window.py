@@ -1,8 +1,7 @@
 """Mega-window decode: one dispatch runs many k-step windows on device
-with budget/EOS early-exit (engine.py `mega_window`). Through a
-network-attached relay every dispatch costs a host↔device RTT, so the
-mega loop is the throughput-mode dispatch amortizer; these tests pin its
-correctness contract on CPU: token-for-token parity with the pipelined
+with budget/EOS early-exit (engine.py `mega_window`): one host↔device
+round trip per many windows. These tests pin its correctness contract
+on CPU: token-for-token parity with the pipelined
 per-window path, exact budget delivery, EOS retirement, and composition
 with paged KV and sampling."""
 

@@ -4,15 +4,35 @@ Kernels run in interpret mode (CPU); the dense jnp implementations in
 ``gofr_tpu.ops.attention`` are the oracle. Mirrors the reference's
 fake-backend test idiom (SURVEY §4: miniredis stands in for Redis; here the
 interpreter stands in for the TPU).
+
+``chip_smoke.py``'s kernel phase imports this module on the TPU, sets
+:data:`INTERPRET` to False and runs :func:`serving_kernel_cases` at
+:data:`MISTRAL_7B` geometry — the same cases the CPU suite runs
+interpreted at :data:`TINY`, compiled through Mosaic.
 """
+
+import itertools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gofr_tpu.ops.attention import attention, decode_attention
-from gofr_tpu.ops.pallas import flash_attention, flash_decode
+from gofr_tpu.ops.attention import (
+    attention,
+    cache_chunk_attention,
+    decode_attention,
+)
+from gofr_tpu.ops.kv_cache import paged_view, quantize_kv
+from gofr_tpu.ops.pallas import (
+    flash_attention,
+    flash_cache_attention,
+    flash_decode,
+)
+
+# Every pallas_call in this module takes this one switch.
+INTERPRET = True
 
 
 def _qkv(key, b, s_q, s_kv, n_heads, n_kv, hd, dtype=jnp.float32):
@@ -37,7 +57,7 @@ def test_flash_attention_matches_dense(b, s_q, s_kv, n_heads, n_kv, hd, causal):
     q, k, v = _qkv(jax.random.PRNGKey(0), b, s_q, s_kv, n_heads, n_kv, hd)
     want = attention(q, k, v, causal=causal)
     got = flash_attention(
-        q, k, v, causal=causal, block_q=32, block_k=32, interpret=True
+        q, k, v, causal=causal, block_q=32, block_k=32, interpret=INTERPRET
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
@@ -57,7 +77,7 @@ def test_flash_attention_lengths_matches_dense(b, s, lengths, causal):
     lens = jnp.asarray(lengths, dtype=jnp.int32)
     want = attention(q, k, v, causal=causal, lengths=lens, kernel=False)
     got = flash_attention(
-        q, k, v, lens, causal=causal, block_q=32, block_k=32, interpret=True
+        q, k, v, lens, causal=causal, block_q=32, block_k=32, interpret=INTERPRET
     )
     # Rows at/after a row's own length are padding queries — the kernel
     # emits 0 there while the dense path emits uniform-softmax junk; only
@@ -99,7 +119,7 @@ def test_flash_attention_bf16():
     q, k, v = _qkv(jax.random.PRNGKey(1), 2, 64, 64, 4, 2, 64, jnp.bfloat16)
     want = attention(q, k, v, causal=True).astype(jnp.float32)
     got = flash_attention(
-        q, k, v, causal=True, block_q=32, block_k=32, interpret=True
+        q, k, v, causal=True, block_q=32, block_k=32, interpret=INTERPRET
     ).astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-2, rtol=3e-2)
 
@@ -121,7 +141,7 @@ def test_flash_decode_matches_dense(b, max_len, n_heads, n_kv, hd, lengths):
     lens = jnp.array(lengths, dtype=jnp.int32)
 
     want = decode_attention(q, k_cache, v_cache, lens)
-    got = flash_decode(q, k_cache, v_cache, lens, block_k=64, interpret=True)
+    got = flash_decode(q, k_cache, v_cache, lens, block_k=64, interpret=INTERPRET)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
@@ -164,91 +184,11 @@ def test_split_decode_matches_write_then_attend(
 
     got_kern = flash_decode(
         q, k_cache, v_cache, prev, k_new=k_new, v_new=v_new, block_k=64,
-        interpret=True,
+        interpret=INTERPRET,
     )
     np.testing.assert_allclose(
         np.asarray(got_kern), np.asarray(want), atol=2e-5, rtol=2e-5
     )
-
-
-def test_split_decode_int8_cache_matches_dense(monkeypatch):
-    """The int8-cache + k_new split combination — exactly what int8-KV
-    serving runs on TPU — must match the dense split path (kernel in
-    interpret mode off-TPU)."""
-    from gofr_tpu.ops.kv_cache import quantize_kv
-
-    b, max_len, n_heads, n_kv, hd = 3, 128, 8, 2, 32
-    key = jax.random.PRNGKey(11)
-    kq, kk, kv, kn, vn_key = jax.random.split(key, 5)
-    q = jax.random.normal(kq, (b, n_heads, hd), jnp.bfloat16)
-    k_f = jax.random.normal(kk, (b, n_kv, max_len, hd))
-    v_f = jax.random.normal(kv, (b, n_kv, max_len, hd))
-    k_new = jax.random.normal(kn, (b, n_kv, hd), jnp.bfloat16)
-    v_new = jax.random.normal(vn_key, (b, n_kv, hd), jnp.bfloat16)
-    prev = jnp.array([0, 60, 128], dtype=jnp.int32)
-
-    kq8, ks = quantize_kv(k_f)  # scales [b, n_kv, max_len]
-    vq8, vs = quantize_kv(v_f)
-    rep8 = lambda s: jnp.broadcast_to(  # noqa: E731
-        s[:, :, None, :], (b, n_kv, 8, max_len)
-    ).astype(jnp.float32)
-    ks8, vs8 = rep8(ks), rep8(vs)
-
-    want = decode_attention(
-        q, kq8, vq8, prev, k_new=k_new, v_new=v_new, k_scale=ks8,
-        v_scale=vs8, kernel=False,
-    ).astype(jnp.float32)
-    got = flash_decode(
-        q, kq8, vq8, prev, k_new=k_new, v_new=v_new, k_scale=ks8,
-        v_scale=vs8, block_k=64, interpret=True,
-    ).astype(jnp.float32)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=3e-2, rtol=3e-2
-    )
-
-
-@pytest.mark.parametrize("quant", [False, True])
-def test_paged_flash_decode_matches_dense(quant):
-    """Table-indexed pool kernel == dense over the gathered view, with a
-    scrambled block table, ragged lengths, and the k_new split merge."""
-    from gofr_tpu.ops.kv_cache import paged_view, quantize_kv
-
-    b, n_heads, n_kv, hd, bs, mb = 3, 8, 2, 32, 64, 4
-    n_blocks = 1 + b * mb
-    key = jax.random.PRNGKey(13)
-    kp, kv_, kq, kn, vn_k = jax.random.split(key, 5)
-    pool_k = jax.random.normal(kp, (n_blocks, n_kv, bs, hd))
-    pool_v = jax.random.normal(kv_, (n_blocks, n_kv, bs, hd))
-    q = jax.random.normal(kq, (b, n_heads, hd))
-    k_new = jax.random.normal(kn, (b, n_kv, hd))
-    v_new = jax.random.normal(vn_k, (b, n_kv, hd))
-    # Scrambled, non-contiguous table (pool ids 1..12 permuted).
-    perm = jax.random.permutation(jax.random.PRNGKey(3), n_blocks - 1) + 1
-    table = perm.reshape(b, mb).astype(jnp.int32)
-    prev = jnp.array([0, 100, 256], dtype=jnp.int32)
-
-    ks = vs = pks = pvs = None
-    if quant:
-        pool_k, ksc = quantize_kv(pool_k)  # scales [n_blocks, n_kv, bs]
-        pool_v, vsc = quantize_kv(pool_v)
-        rep8 = lambda s: jnp.broadcast_to(  # noqa: E731
-            s[:, :, None, :], (n_blocks, n_kv, 8, bs)
-        ).astype(jnp.float32)
-        pks, pvs = rep8(ksc), rep8(vsc)
-
-    vk, vv, vks, vvs = paged_view(table, pool_k, pool_v, jnp.arange(b),
-                                  pks, pvs)
-    want = decode_attention(
-        q, vk, vv, prev, k_new=k_new, v_new=v_new, k_scale=vks,
-        v_scale=vvs, kernel=False,
-    ).astype(jnp.float32)
-    got = flash_decode(
-        q, pool_k, pool_v, prev, k_new=k_new, v_new=v_new, k_scale=pks,
-        v_scale=pvs, block_table=table, interpret=True,
-    ).astype(jnp.float32)
-    tol = 3e-2 if quant else 2e-5
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=tol, rtol=tol)
 
 
 def test_dispatch_and_grad(monkeypatch):
@@ -284,7 +224,7 @@ def test_flash_decode_zero_length_slot_is_finite():
     k_cache = jnp.ones((b, n_kv, max_len, hd))
     v_cache = jnp.ones((b, n_kv, max_len, hd))
     lens = jnp.array([0, 10], dtype=jnp.int32)
-    got = flash_decode(q, k_cache, v_cache, lens, block_k=64, interpret=True)
+    got = flash_decode(q, k_cache, v_cache, lens, block_k=64, interpret=INTERPRET)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(np.asarray(got[0]), 0.0)
 
@@ -317,168 +257,6 @@ def test_flash_decode_env_override(monkeypatch):
     )
 
 
-@pytest.mark.parametrize("quant", [False, True])
-def test_paged_flash_cache_attention_matches_dense(quant):
-    """Table-indexed chunked-prefill kernel == dense over the gathered
-    view: scrambled table, ragged starts/lens, GQA, ±int8 scales."""
-    from gofr_tpu.ops.attention import cache_chunk_attention
-    from gofr_tpu.ops.kv_cache import paged_view, quantize_kv
-    from gofr_tpu.ops.pallas import flash_cache_attention
-
-    P, c, n_heads, n_kv, hd, bs, mb = 3, 8, 4, 2, 32, 64, 4
-    S = 4
-    n_blocks = 1 + S * mb
-    key = jax.random.PRNGKey(17)
-    kp, kv_, kq = jax.random.split(key, 3)
-    pool_k = jax.random.normal(kp, (n_blocks, n_kv, bs, hd))
-    pool_v = jax.random.normal(kv_, (n_blocks, n_kv, bs, hd))
-    q = jax.random.normal(kq, (P, c, n_heads, hd))
-    perm = jax.random.permutation(jax.random.PRNGKey(4), n_blocks - 1) + 1
-    table = perm.reshape(S, mb).astype(jnp.int32)
-    slots = jnp.array([0, 3, 1], dtype=jnp.int32)
-    starts = jnp.array([0, 100, 37], dtype=jnp.int32)
-    lens = jnp.array([8, 8, 5], dtype=jnp.int32)
-
-    pks = pvs = None
-    if quant:
-        pool_k, ksc = quantize_kv(pool_k)
-        pool_v, vsc = quantize_kv(pool_v)
-        rep8 = lambda s: jnp.broadcast_to(  # noqa: E731
-            s[:, :, None, :], (n_blocks, n_kv, 8, bs)
-        ).astype(jnp.float32)
-        pks, pvs = rep8(ksc), rep8(vsc)
-
-    vk, vv, vks, vvs = paged_view(table, pool_k, pool_v, slots, pks, pvs)
-    want = cache_chunk_attention(
-        q, vk, vv, jnp.arange(P), starts, lens, k_scale=vks, v_scale=vvs,
-        kernel=False,
-    ).astype(jnp.float32)
-    got = flash_cache_attention(
-        q, pool_k, pool_v, slots, starts, lens, k_scale=pks, v_scale=pvs,
-        block_table=table, interpret=True,
-    ).astype(jnp.float32)
-    tol = 3e-2 if quant else 2e-5
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize("has_new", [False, True])
-def test_windowed_flash_decode_matches_dense(has_new):
-    """Sliding-window decode in-kernel == the dense windowed math, both
-    calling conventions, ragged lengths crossing the window boundary."""
-    b, max_len, n_heads, n_kv, hd, w = 4, 256, 8, 2, 32, 48
-    key = jax.random.PRNGKey(21)
-    kq, kk, kv_, kn, vn_k = jax.random.split(key, 5)
-    q = jax.random.normal(kq, (b, n_heads, hd))
-    k_cache = jax.random.normal(kk, (b, n_kv, max_len, hd))
-    v_cache = jax.random.normal(kv_, (b, n_kv, max_len, hd))
-    lens = jnp.array([1, 40, 100, 255], dtype=jnp.int32)
-    kw = {}
-    if has_new:
-        kw = dict(
-            k_new=jax.random.normal(kn, (b, n_kv, hd)),
-            v_new=jax.random.normal(vn_k, (b, n_kv, hd)),
-        )
-    want = decode_attention(
-        q, k_cache, v_cache, lens, window=w, kernel=False, **kw
-    )
-    got = flash_decode(
-        q, k_cache, v_cache, lens, window=w, block_k=64, interpret=True,
-        **kw,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
-    )
-    # The window must actually bind: full attention differs.
-    full = decode_attention(q, k_cache, v_cache, lens, kernel=False, **kw)
-    assert not np.allclose(np.asarray(full), np.asarray(want), atol=1e-3)
-
-
-def test_windowed_paged_flash_decode_matches_dense():
-    """Window × paged pool in-kernel == dense over the gathered view —
-    the mistral-with-paged-KV serving path stays on the kernel."""
-    from gofr_tpu.ops.kv_cache import paged_view
-
-    b, n_heads, n_kv, hd, bs, mb, w = 3, 8, 2, 32, 64, 4, 80
-    n_blocks = 1 + b * mb
-    key = jax.random.PRNGKey(22)
-    kp, kv_, kq, kn, vn_k = jax.random.split(key, 5)
-    pool_k = jax.random.normal(kp, (n_blocks, n_kv, bs, hd))
-    pool_v = jax.random.normal(kv_, (n_blocks, n_kv, bs, hd))
-    q = jax.random.normal(kq, (b, n_heads, hd))
-    k_new = jax.random.normal(kn, (b, n_kv, hd))
-    v_new = jax.random.normal(vn_k, (b, n_kv, hd))
-    perm = jax.random.permutation(jax.random.PRNGKey(5), n_blocks - 1) + 1
-    table = perm.reshape(b, mb).astype(jnp.int32)
-    prev = jnp.array([0, 100, 250], dtype=jnp.int32)
-
-    vk, vv, _, _ = paged_view(table, pool_k, pool_v, jnp.arange(b))
-    want = decode_attention(
-        q, vk, vv, prev, k_new=k_new, v_new=v_new, window=w, kernel=False,
-    )
-    got = flash_decode(
-        q, pool_k, pool_v, prev, k_new=k_new, v_new=v_new,
-        block_table=table, window=w, interpret=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
-    )
-
-
-@pytest.mark.parametrize("paged", [False, True])
-def test_windowed_flash_cache_attention_matches_dense(paged):
-    """Windowed chunked prefill in-kernel == dense windowed math, with
-    starts straddling the window boundary (contiguous + paged)."""
-    from gofr_tpu.ops.attention import cache_chunk_attention
-    from gofr_tpu.ops.kv_cache import paged_view
-    from gofr_tpu.ops.pallas import flash_cache_attention
-
-    P, c, n_heads, n_kv, hd, w = 3, 8, 4, 2, 32, 48
-    key = jax.random.PRNGKey(23)
-    kq, kk, kv_ = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (P, c, n_heads, hd))
-    slots_arr = jnp.array([0, 3, 1], dtype=jnp.int32)
-    starts = jnp.array([0, 100, 37], dtype=jnp.int32)
-    lens = jnp.array([8, 8, 5], dtype=jnp.int32)
-    if paged:
-        S, bs, mb = 4, 64, 4
-        n_blocks = 1 + S * mb
-        pool_k = jax.random.normal(kk, (n_blocks, n_kv, bs, hd))
-        pool_v = jax.random.normal(kv_, (n_blocks, n_kv, bs, hd))
-        perm = jax.random.permutation(
-            jax.random.PRNGKey(6), n_blocks - 1
-        ) + 1
-        table = perm.reshape(S, mb).astype(jnp.int32)
-        vk, vv, _, _ = paged_view(table, pool_k, pool_v, slots_arr)
-        want = cache_chunk_attention(
-            q, vk, vv, jnp.arange(P), starts, lens, window=w, kernel=False,
-        )
-        got = flash_cache_attention(
-            q, pool_k, pool_v, slots_arr, starts, lens, block_table=table,
-            window=w, interpret=True,
-        )
-    else:
-        S, max_len = 4, 256
-        k_cache = jax.random.normal(kk, (S, n_kv, max_len, hd))
-        v_cache = jax.random.normal(kv_, (S, n_kv, max_len, hd))
-        want = cache_chunk_attention(
-            q, k_cache, v_cache, slots_arr, starts, lens, window=w,
-            kernel=False,
-        )
-        got = flash_cache_attention(
-            q, k_cache, v_cache, slots_arr, starts, lens, block_k=64,
-            window=w, interpret=True,
-        )
-        # The window must bind for the rows past position w.
-        full = cache_chunk_attention(
-            q, k_cache, v_cache, slots_arr, starts, lens, kernel=False,
-        )
-        assert not np.allclose(np.asarray(full), np.asarray(want), atol=1e-3)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
-    )
-
-
 def test_windowed_flash_attention_matches_dense():
     """Windowed full-sequence kernel == dense windowed math: suffix
     queries (s_kv > s_q offset), ragged lengths, and the differentiable
@@ -495,7 +273,7 @@ def test_windowed_flash_attention_matches_dense():
     want = attention(q, k, v, causal=True, window=w, kernel=False)
     got = flash_attention(
         q, k, v, causal=True, window=w, block_q=64, block_k=64,
-        interpret=True,
+        interpret=INTERPRET,
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5
@@ -508,7 +286,7 @@ def test_windowed_flash_attention_matches_dense():
     want_s = attention(qs, k, v, causal=True, window=w, kernel=False)
     got_s = flash_attention(
         qs, k, v, causal=True, window=w, block_q=64, block_k=64,
-        interpret=True,
+        interpret=INTERPRET,
     )
     np.testing.assert_allclose(
         np.asarray(got_s), np.asarray(want_s), atol=2e-5, rtol=2e-5
@@ -525,7 +303,7 @@ def test_windowed_flash_attention_matches_dense():
     ))
     got_l = np.asarray(flash_attention(
         q, k, v, lens, causal=True, window=w, block_q=64, block_k=64,
-        interpret=True,
+        interpret=INTERPRET,
     ))
     for bi, ln in enumerate([50, 192]):
         np.testing.assert_allclose(
@@ -566,3 +344,236 @@ def test_windowed_flash_attention_grad(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(gk), np.asarray(gd), atol=1e-4, rtol=1e-4
     )
+
+
+# ----------------------------------------------------------------------
+# The serving-case matrix: one case list, two geometries
+# ----------------------------------------------------------------------
+
+
+class Geometry(NamedTuple):
+    """Shapes one run of the matrix uses. ``window`` binds inside
+    ``max_len`` in both; both keep mistral's 4 query heads per kv head
+    (the q/out block height Mosaic sees)."""
+
+    n_heads: int
+    n_kv: int
+    hd: int
+    slots: int
+    max_len: int      # cache capacity per slot
+    block: int        # paged pool block (TPU_KV_BLOCK)
+    window: int       # sliding window, < max_len so it binds
+    chunk: int        # prefill chunk length (TPU_PREFILL_CHUNK)
+    attn_sq: int      # flash_attention: windowed suffix queries ...
+    attn_skv: int     # ... against this many keys (> window)
+    attn_s: int       # flash_attention: unwindowed square length
+    dtype: str        # activations (and K/V where not int8)
+
+
+# mistral-7b head geometry at the smoke's paged boot: 32 q / 8 kv heads
+# of 128, window 4096 inside an 8192 cache, pool block 32, chunk 256.
+MISTRAL_7B = Geometry(
+    n_heads=32, n_kv=8, hd=128, slots=4, max_len=8192, block=32,
+    window=4096, chunk=256, attn_sq=512, attn_skv=4608, attn_s=1024,
+    dtype="bfloat16",
+)
+# f32 on the CPU so the kernels' arithmetic is pinned tightly; the chip
+# runs the dtype serving runs.
+TINY = Geometry(
+    n_heads=8, n_kv=2, hd=32, slots=4, max_len=256, block=32,
+    window=96, chunk=8, attn_sq=64, attn_skv=192, attn_s=64,
+    dtype="float32",
+)
+
+
+def serving_tolerance(case: "KernelCase", g: Geometry) -> float:
+    """atol = rtol against the dense path, fixed from the dtypes. With
+    bf16 anywhere (bf16 activations, or the int8 cache's bf16 V and
+    probabilities) the kernel and the dense path round probabilities to
+    bf16 at different points (before vs after normalisation) and outputs
+    are O(1): a few bf16 ulps of 2^-8. All-f32 differs only in summation
+    order."""
+    return 3e-2 if (g.dtype == "bfloat16" or case.int8_kv) else 2e-5
+
+
+class KernelCase(NamedTuple):
+    kernel: str       # "decode" | "prefill_chunk" | "attention"
+    int8_kv: bool
+    paged: bool
+    windowed: bool
+    extra: bool       # decode: k_new/v_new split; attention: lengths
+
+
+def serving_kernel_cases() -> list[KernelCase]:
+    """Every kernel variant the serving path can select: bf16 and
+    int8-KV, contiguous and paged, window binding or not, decode with
+    and without the ``k_new/v_new`` split, full attention with and
+    without ``lengths``."""
+    cases = [
+        KernelCase("decode", q8, paged, win, new)
+        for q8, paged, win, new in itertools.product((False, True), repeat=4)
+    ]
+    cases += [
+        KernelCase("prefill_chunk", q8, paged, win, False)
+        for q8, paged, win in itertools.product((False, True), repeat=3)
+    ]
+    cases += [
+        KernelCase("attention", False, False, win, lens)
+        for win, lens in itertools.product((False, True), repeat=2)
+    ]
+    return cases
+
+
+def _rep8(scale, width):
+    """[rows, KV, width] scales → the sublane-replicated
+    [rows, KV, 8, width] f32 plane the caches store."""
+    return jnp.broadcast_to(
+        scale[:, :, None, :], scale.shape[:2] + (8, width)
+    ).astype(jnp.float32)
+
+
+def _case_cache(key, g: Geometry, case: KernelCase):
+    """A slot cache for ``case``: contiguous ``[S, KV, max_len, hd]`` or
+    a pool ``[1 + S*mb, KV, block, hd]`` behind a scrambled table (block
+    0 parks, as in the engine), bf16 or int8 with scale planes. Returns
+    ``(k, v, kwargs)`` twice: what the kernel takes, and the contiguous
+    per-slot view the dense path takes."""
+    kk, kv_, kt = jax.random.split(key, 3)
+    if case.paged:
+        mb = g.max_len // g.block
+        rows, width = 1 + g.slots * mb, g.block
+        table = (
+            jax.random.permutation(kt, rows - 1) + 1
+        ).reshape(g.slots, mb).astype(jnp.int32)
+    else:
+        rows, width, table = g.slots, g.max_len, None
+    shape = (rows, g.n_kv, width, g.hd)
+    k = jax.random.normal(kk, shape, jnp.float32)
+    v = jax.random.normal(kv_, shape, jnp.float32)
+    ks = vs = None
+    if case.int8_kv:
+        k, ksc = quantize_kv(k)
+        v, vsc = quantize_kv(v)
+        ks, vs = _rep8(ksc, width), _rep8(vsc, width)
+    else:
+        k, v = k.astype(g.dtype), v.astype(g.dtype)
+    kern = (k, v, dict(k_scale=ks, v_scale=vs, block_table=table))
+    if case.paged:
+        k, v, ks, vs = paged_view(
+            table, k, v, jnp.arange(g.slots), ks, vs
+        )
+    return kern, (k, v, dict(k_scale=ks, v_scale=vs))
+
+
+def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
+    """Run one case's kernel (``interpret=INTERPRET``) against the dense
+    path in ``ops/attention.py`` (``kernel=False``); returns the max
+    absolute difference over the rows serving reads, and asserts it is
+    finite and within :func:`serving_tolerance` — and, for a windowed
+    cache case, that the window really binds (the unwindowed dense
+    result differs)."""
+    key = jax.random.PRNGKey(97)
+    kq, kc, kn, vn = jax.random.split(key, 4)
+    w = g.window if case.windowed else 0
+    bf = jnp.dtype(g.dtype)
+    unwindowed = None
+
+    def queries(shape):
+        # 4× unit-normal queries give logits of std 4: the softmax peaks
+        # on a few keys, outputs stay O(1) however long the row, and one
+        # key wrongly seen or missed moves them far past the tolerance.
+        # Unit queries over 8k random keys average everything to ~0.01.
+        return (4 * jax.random.normal(kq, shape, jnp.float32)).astype(bf)
+
+    if case.kernel != "attention":
+        (k_c, v_c, kern), (k_d, v_d, dense) = _case_cache(kc, g, case)
+    if case.kernel == "decode":
+        q = queries((g.slots, g.n_heads, g.hd))
+        # Empty, short, just past the window, and full slots.
+        lens = jnp.array(
+            [0, g.window // 3, g.window + g.block + 5, g.max_len - 1],
+            jnp.int32,
+        )[: g.slots]
+        if not case.extra:
+            lens = jnp.maximum(lens, 1)  # lengths include the query
+        new = {}
+        if case.extra:
+            new = dict(
+                k_new=jax.random.normal(kn, (g.slots, g.n_kv, g.hd), bf),
+                v_new=jax.random.normal(vn, (g.slots, g.n_kv, g.hd), bf),
+            )
+        got = flash_decode(
+            q, k_c, v_c, lens, window=w, interpret=INTERPRET, **kern, **new
+        )
+        want = decode_attention(
+            q, k_d, v_d, lens, window=w, kernel=False, **dense, **new
+        )
+        if w:
+            unwindowed = decode_attention(
+                q, k_d, v_d, lens, kernel=False, **dense, **new
+            )
+        valid = np.ones(got.shape[:1], bool)
+    elif case.kernel == "prefill_chunk":
+        P, c = 3, g.chunk
+        q = queries((P, c, g.n_heads, g.hd))
+        slots = jnp.array([0, 3, 1], jnp.int32)
+        # First chunk, a chunk straddling the window edge, a ragged
+        # last chunk deep in the cache.
+        starts = jnp.array(
+            [0, g.window - c // 2, g.max_len - 2 * c], jnp.int32
+        )
+        lens = jnp.array([c, c, max(1, c // 2 + 1)], jnp.int32)
+        got = flash_cache_attention(
+            q, k_c, v_c, slots, starts, lens, window=w,
+            interpret=INTERPRET, **kern,
+        )
+
+        def dense_chunk(window):
+            return cache_chunk_attention(
+                q, k_d, v_d, slots, starts, lens, window=window,
+                kernel=False, **dense,
+            )
+
+        want = dense_chunk(w)
+        if w:
+            unwindowed = dense_chunk(0)
+        valid = np.ones(got.shape[:2], bool)
+    else:
+        s_q, s_kv = (g.attn_sq, g.attn_skv) if w else (g.attn_s, g.attn_s)
+        b = 2
+        kk, kv_ = jax.random.split(kc)
+        q = queries((b, s_q, g.n_heads, g.hd))
+        k = jax.random.normal(kk, (b, s_kv, g.n_kv, g.hd), bf)
+        v = jax.random.normal(kv_, (b, s_kv, g.n_kv, g.hd), bf)
+        lens_list = [s_kv, s_kv - s_q // 3]
+        lens = jnp.array(lens_list, jnp.int32) if case.extra else None
+        got = flash_attention(
+            q, k, v, lens, causal=True, window=w, interpret=INTERPRET
+        )
+        want = attention(
+            q, k, v, causal=True, window=w, lengths=lens, kernel=False
+        )
+        # Query rows at or past a row's length are padding: the kernel
+        # emits 0 there, the dense path uniform-softmax junk, and
+        # serving reads neither.
+        pos = (s_kv - s_q) + np.arange(s_q)
+        valid = pos[None, :] < np.asarray(
+            lens_list if case.extra else [s_kv, s_kv]
+        )[:, None]
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(want.astype(jnp.float32))
+    assert np.isfinite(got).all(), f"{case}: non-finite kernel output"
+    tol = serving_tolerance(case, g)
+    np.testing.assert_allclose(
+        got[valid], want[valid], atol=tol, rtol=tol, err_msg=str(case)
+    )
+    if unwindowed is not None:
+        assert not np.allclose(
+            np.asarray(unwindowed.astype(jnp.float32)), want, atol=1e-3
+        ), f"{case}: the window does not bind"
+    return float(np.abs(got - want)[valid].max())
+
+
+@pytest.mark.parametrize("case", serving_kernel_cases(), ids=str)
+def test_serving_kernel_matrix(case):
+    run_serving_kernel_case(case, TINY)

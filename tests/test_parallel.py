@@ -76,7 +76,7 @@ def test_graft_entry_contract():
     fn, args = g.entry()
     out = jax.jit(fn)(*args)
     assert out.ndim == 3
-    g.dryrun_multichip(8)
+    g.dryrun_virtual_mesh(8)
 
 
 def test_dcn_init_noop_without_config():

@@ -7,7 +7,7 @@ greedy (and seeded-sampled) streams to an unsharded ``tp=1`` engine —
 including prefix-cache hits, disaggregated-tier KV-block transfers
 between two differently-placed sharded pods, and a mid-stream replica
 failover. This is the trimmed tp-serving subset of the multichip dryrun
-(``__graft_entry__.dryrun_multichip`` step 5), wired as a named CI step
+(``__graft_entry__.dryrun_virtual_mesh`` step 5), wired as a named CI step
 so sharded-serving token-identity regresses loudly.
 
 Also covered: the pod layout (dp across replicas, tp within — the

@@ -445,20 +445,45 @@ def test_health_probe_and_pool_advertisement():
 
 
 # ----------------------------------------------------------------------
-# compile-cache persistence (TPU_COMPILE_CACHE_DIR)
+# compile-cache persistence (gofr_tpu/compile_cache.py)
 # ----------------------------------------------------------------------
 
 
+@pytest.fixture
+def persistent_compile_cache(tmp_path):
+    """JAX's persistent cache at a temp dir, as a process entry point
+    would place it — and persisting sub-second CPU programs, which
+    JAX's default thresholds skip. conftest.py turns the cache off for
+    the rest of the suite."""
+    import jax
+    from jax._src import compilation_cache
+
+    knobs = {
+        "jax_compilation_cache_dir": str(tmp_path / "xla-cache"),
+        "jax_enable_compilation_cache": True,
+        "jax_persistent_cache_min_compile_time_secs": 0.0,
+        "jax_persistent_cache_min_entry_size_bytes": -1,
+    }
+    saved = {k: getattr(jax.config, k) for k in knobs}
+    for knob, value in knobs.items():
+        jax.config.update(knob, value)
+    compilation_cache.reset_cache()
+    yield knobs["jax_compilation_cache_dir"]
+    for knob, value in saved.items():
+        jax.config.update(knob, value)
+    compilation_cache.reset_cache()
+
+
 def test_compile_cache_dir_recorded_and_no_steady_state_regression(
-    tmp_path,
+    persistent_compile_cache,
 ):
     """A second engine boot against a populated cache dir serves with
     zero steady-state recompiles, and the cache's provenance rides
     health and /debug/capacity."""
-    cache_dir = str(tmp_path / "xla-cache")
+    cache_dir = persistent_compile_cache
 
     def boot():
-        eng = make_engine(compile_cache_dir=cache_dir)
+        eng = make_engine()
         eng.generate_sync(
             "cache me", max_new_tokens=4, temperature=0.0,
             stop_on_eos=False, timeout=300,
@@ -468,6 +493,7 @@ def test_compile_cache_dir_recorded_and_no_steady_state_regression(
     eng1 = boot()
     cache1 = eng1.compile_stats()["compile_cache"]
     assert cache1["dir"] == cache_dir
+    assert cache1["enabled"] and cache1["entries"] > 0
     health = eng1.health_check()
     assert (
         health["details"]["compiles"]["compile_cache"]["dir"] == cache_dir

@@ -64,7 +64,6 @@ from gofr_tpu.service.dma import (
     DmaTransferServer,
     dma_fetch,
     get_transfer_server,
-    jax_transfer_available,
     reset_transfer_server,
 )
 from gofr_tpu.service.replica_pool import (
@@ -258,13 +257,6 @@ def test_handle_codec_rejects_malformed():
     # First-4-byte dispatch: a handle is never confusable with an
     # inline body (the import endpoint branches on exactly this).
     assert wire[:4] != WIRE_MAGIC
-
-
-def test_loopback_is_the_ci_backend():
-    """The CI jax has no ``jax.experimental.transfer``; the gate must
-    say so (the dma leg then runs entirely on the loopback emulation —
-    which is the point: the matrix runs without a pod)."""
-    assert jax_transfer_available() is False
 
 
 # ----------------------------------------------------------------------
