@@ -1,0 +1,95 @@
+"""Everything a run is made of, found by the names in ``BENCHMARK.json``.
+
+A cell names a configuration and a traffic mix. The configuration is
+``benchmark/configs/<config>.json`` (or the ``file`` its entry gives), the
+mix ``benchmark/traffic/<traffic>.json``, the mix's kind
+``benchmark/traffic_kinds/<kind>.py``, a per-layer metric
+``benchmark/layer_metrics/<metric>.json`` and its reader
+``benchmark/readers/<reader>.py``. Adding any of them is adding a file and
+one entry; nothing here lists them.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+from typing import Any
+
+from benchmark.harness.server import BENCHMARK, CHECKOUT, BenchFailure
+
+
+def load_json(path: str) -> Any:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def load_file(name: str, path: str) -> Any:
+    """Import a file that is not on a package path."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    assert spec is not None and spec.loader is not None
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_module(kind: str, name: str) -> Any:
+    """``benchmark/<kind>/<name>.py`` as a module."""
+    path = os.path.join(BENCHMARK, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise BenchFailure(f"no {kind[:-1]} named {name!r}: {path} is missing")
+    return load_file(f"benchmark.{kind}.{name}", path)
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict          # the configuration file, with "name" and "path"
+    mix: dict             # the traffic file
+    kind: Any             # the traffic kind's module
+    end_to_end: list      # BENCHMARK.json entries this cell reports
+    per_layer: list
+
+
+def metrics_of(entries: list, cell: str) -> list:
+    return [m for m in entries if cell in m.get("workloads", [cell])]
+
+
+def load_cell(cells_file: str, workload: str) -> Cell:
+    """``cells_file`` is ``BENCHMARK.json`` or a file of the same shape
+    (the tests' rehearsal cells), relative to the checkout."""
+    bench = load_json(os.path.join(CHECKOUT, cells_file))
+    by_name = {w["name"]: w for w in bench["workloads"]}
+    if workload not in by_name:
+        raise BenchFailure(
+            f"no workload {workload!r} in {cells_file}; it has {sorted(by_name)}"
+        )
+    entry = by_name[workload]
+    config_entry = next(
+        c for c in bench["configs"] if c["name"] == entry["config"]
+    )
+    config_path = os.path.join(CHECKOUT, config_entry["file"])
+    config = dict(load_json(config_path))
+    config["name"], config["path"] = entry["config"], config_path
+    # A cell's entry may name its mix only by ``traffic``, so the mix lies
+    # where its configuration does: ``<dir>/configs/x.json`` goes with
+    # ``<dir>/traffic/<traffic>.json`` (benchmark/, or the tests' own).
+    mix = load_json(os.path.join(
+        os.path.dirname(os.path.dirname(config_path)), "traffic",
+        f"{entry['traffic']}.json",
+    ))
+    return Cell(
+        name=workload, chips=int(entry["chips"]), config=config, mix=mix,
+        kind=load_module("traffic_kinds", mix["kind"]),
+        end_to_end=metrics_of(bench["end_to_end"], workload),
+        per_layer=metrics_of(bench["per_layer"], workload),
+    )
+
+
+def layer_metric(name: str) -> dict:
+    path = os.path.join(BENCHMARK, "layer_metrics", f"{name}.json")
+    if not os.path.isfile(path):
+        raise BenchFailure(f"no per-layer metric file {path}")
+    return load_json(path)

@@ -1,0 +1,116 @@
+"""The server child: the app an operator runs, plus what a user of the
+framework could add from outside.
+
+``examples/openai-server/main.py`` builds the app (``App`` +
+``add_openai_routes``, configured from the process environment), as
+``chip_smoke.py``'s ``child_serve`` does. Added here, and nowhere in the
+program:
+
+* before the app is built, the configuration file named by
+  ``BENCH_CONFIG_FILE`` may register its model: ``base`` with
+  ``overrides`` applied, under the configuration's own name;
+* SIGUSR1 arms the engine's warm-up fence (``mark_steady_state``), which
+  has no HTTP surface;
+* ``GET /bench/device``: platform, kind and count as JAX reports them, and
+  the peak memory of the fullest chip;
+* ``POST /bench/reference``: the benchmark's own plain reference
+  (``benchmark/reference``) on this engine's weights.
+
+This is the only process of a run that imports jax.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import json
+import os
+import signal
+import sys
+from typing import Any
+
+# Started as a script: the checkout is not on the path yet.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
+
+from benchmark.harness.cells import load_file  # noqa: E402
+from benchmark.harness.server import ENTRY_POINT  # noqa: E402
+
+
+def register_configuration(config: dict) -> None:
+    """``dataclasses.replace(get_model(base).config, **overrides)`` under the
+    name the file's ``TPU_MODEL`` asks for (the configuration's own)."""
+    from gofr_tpu.models.registry import ModelSpec, get_model, register_model
+
+    if not config.get("overrides"):
+        return
+    base = get_model(config["base"])
+    register_model(ModelSpec(
+        name=config["env"]["TPU_MODEL"], family=base.family,
+        config=dataclasses.replace(base.config, **config["overrides"]),
+        init=base.init, eos_token=base.eos_token, forward=base.forward,
+    ))
+
+
+def engines_of(app: Any) -> list:
+    tpu = app.container.tpu
+    if hasattr(tpu, "replicas"):
+        return [r.engine for r in tpu.replicas if hasattr(r, "engine")]
+    return [tpu]
+
+
+def add_bench_routes(app: Any) -> None:
+    from gofr_tpu.http.response import Raw
+
+    @app.get("/bench/device")
+    async def device(ctx: Any) -> Raw:  # noqa: ARG001
+        import jax
+
+        devices = jax.devices()
+        peaks = [
+            stats.get("peak_bytes_in_use")
+            for d in devices if (stats := d.memory_stats())
+        ]
+        return Raw({
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            "memory_peak_bytes": max(
+                (p for p in peaks if p is not None), default=None
+            ),
+        }, status=200)
+
+    @app.post("/bench/reference")
+    async def reference(ctx: Any) -> Raw:
+        """{"sequences": [[ids]], "n_prompt": n, "ablate": ""} -> the plain
+        reference's teacher-forced log-probability of every token after
+        the first ``n_prompt`` of each sequence."""
+        from benchmark.reference.adapter import reference_logprobs
+
+        body = json.loads(ctx.request.raw.body)
+        engine = engines_of(app)[0]
+        loop = asyncio.get_running_loop()
+        out = await loop.run_in_executor(
+            None, reference_logprobs, engine, body["sequences"],
+            int(body["n_prompt"]), body.get("ablate") or "",
+        )
+        return Raw({"logprobs": out}, status=200)
+
+
+def main() -> None:
+    with open(os.environ["BENCH_CONFIG_FILE"]) as fh:
+        register_configuration(json.load(fh))
+    app = load_file("openai_server", ENTRY_POINT).main()
+    add_bench_routes(app)
+
+    def arm_fence(signum: int, frame: Any) -> None:  # noqa: ARG001
+        for engine in engines_of(app):
+            engine.mark_steady_state()
+
+    signal.signal(signal.SIGUSR1, arm_fence)
+    app.run()
+
+
+if __name__ == "__main__":
+    main()
