@@ -291,8 +291,34 @@ class Container:
             1, 2.5, 5, 10, 30,
         )
         m.new_histogram(
+            "app_tpu_entry_seconds",
+            "HTTP handler took the request up → submit (body parse, "
+            "tokenisation, validation)", lat_buckets,
+        )
+        m.new_histogram(
             "app_tpu_queue_wait_seconds",
             "submit → admission into a KV slot", lat_buckets,
+        )
+        m.new_histogram(
+            "app_tpu_prefill_wait_seconds",
+            "admission → the first prefill chunk step's dispatch",
+            lat_buckets,
+        )
+        m.new_histogram(
+            "app_tpu_prefill_dispatch_seconds",
+            "first prefill chunk step's dispatch → prefill finalize "
+            "(one chunk a pass, a window fetch between them)",
+            lat_buckets,
+        )
+        m.new_histogram(
+            "app_tpu_first_token_wait_seconds",
+            "prefill finalize → first token in hand (the chunk behind "
+            "the in-flight windows, its compute, the emit-flush poll)",
+            lat_buckets,
+        )
+        m.new_histogram(
+            "app_tpu_delivery_seconds",
+            "first token in hand → its SSE chunk written", lat_buckets,
         )
         m.new_histogram(
             "app_tpu_prefill_seconds",
@@ -316,14 +342,17 @@ class Container:
             "app_tpu_batch_occupancy",
             "live decode slots / total slots, set once per window",
         )
-        m.new_gauge(
-            "app_tpu_decode_step_seconds",
-            "decode-step duration (window dispatch→processed over its "
-            "steps; includes pipeline queueing)",
+        ratio_buckets = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
+        m.new_histogram(
+            "app_tpu_window_occupancy",
+            "slots live when a decode window was dispatched / total "
+            "slots, one record per processed window", ratio_buckets,
         )
-        m.new_gauge(
-            "app_tpu_tokens_per_step",
-            "client-visible tokens emitted per decode step, per window",
+        m.new_histogram(
+            "app_tpu_prefill_fill_ratio",
+            "prompt tokens in a prefill chunk step / its TPU_PREFILL_"
+            "BATCH x TPU_PREFILL_CHUNK token rows, one record per step",
+            ratio_buckets,
         )
         # Disaggregated prefill/decode tiers (TPU_REPLICA_ROLES;
         # docs/advanced-guide/resilience.md): cross-tier KV-block
@@ -475,6 +504,17 @@ class Container:
             "ledger|brownout|control|sweep|tier_import|prefill|"
             "emit_flush|dispatch|device_window|idle|other; sums to "
             "pass wall time)",
+        )
+        m.new_counter(
+            "app_tpu_loop_phase_seconds_total",
+            "scheduler-loop wall seconds by phase, summed over closed "
+            "passes (same phase vocabulary; a difference of two scrapes "
+            "is the loop's time over exactly that interval)",
+        )
+        m.new_counter(
+            "app_tpu_gc_pause_seconds_total",
+            "wall seconds the Python collector ran in this process "
+            "(generation=0|1|2)",
         )
         m.new_gauge(
             "app_tpu_loop_utilization",

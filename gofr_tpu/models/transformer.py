@@ -442,6 +442,15 @@ def _norm(x, w, cfg, b=None):
     return rms_norm(x, w, cfg.norm_eps, 1.0 if cfg.norm_offset else 0.0)
 
 
+# The serving steps below run under ``jax.named_scope`` with a fixed
+# vocabulary — embed, attn, kv_commit, ffn, moe_router, moe_experts,
+# lm_head here; sample, draft, verify in serving/programs.py — so that an
+# op in the profiler's trace says which part of the model it belongs to
+# (its ``tf_op`` reads ``jit(spec_window)/…/attn/dot_general``). Compile-
+# time metadata only: no shape, value or fusion depends on it.
+
+
+@jax.named_scope("embed")
 def _embed(params, tokens, cfg, positions=None):
     """Token embedding lookup; Gemma scales by sqrt(d_model) — the scalar
     is cast to the activation dtype first (HF casts the normalizer to the
@@ -457,6 +466,7 @@ def _embed(params, tokens, cfg, positions=None):
     return x
 
 
+@jax.named_scope("ffn")
 def _ffn_dense(x, lp, cfg, aids=None):
     if cfg.ffn == "mlp":
         # Non-gated act(x·W_up + b)·W_down + b (GPT-NeoX/GPT-2 shape).
@@ -482,21 +492,30 @@ def _ffn_moe(x, lp, cfg):
     small expert counts (no ragged dispatch); capacity-based a2a dispatch is
     the scale-out variant (see parallel/moe_dispatch)."""
     b, s, D = x.shape
-    router_logits = _wein("bsd,de->bse", x, lp["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    topk_probs, topk_idx = jax.lax.top_k(probs, cfg.n_experts_active)
-    topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
-    # weights[b,s,E]: zero except the chosen experts.
-    weights = jnp.zeros_like(probs).at[
-        jnp.arange(b)[:, None, None],
-        jnp.arange(s)[None, :, None],
-        topk_idx,
-    ].set(topk_probs)
-    gate = _wein("bsd,edf->bsef", x, lp["w_gate"])
-    up = _wein("bsd,edf->bsef", x, lp["w_up"])
-    hidden = _act(cfg)(gate) * up
-    out = _wein("bsef,efd->bsed", hidden, lp["w_down"])
-    return jnp.einsum("bsed,bse->bsd", out, weights.astype(x.dtype))
+    with jax.named_scope("moe_router"):
+        router_logits = _wein(
+            "bsd,de->bse", x, lp["router"]
+        ).astype(jnp.float32)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        topk_probs, topk_idx = jax.lax.top_k(probs, cfg.n_experts_active)
+        topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+        # weights[b,s,E]: zero except the chosen experts.
+        weights = jnp.zeros_like(probs).at[
+            jnp.arange(b)[:, None, None],
+            jnp.arange(s)[None, :, None],
+            topk_idx,
+        ].set(topk_probs)
+    with jax.named_scope("moe_experts"):
+        gate = _wein("bsd,edf->bsef", x, lp["w_gate"])
+        up = _wein("bsd,edf->bsef", x, lp["w_up"])
+        hidden = _act(cfg)(gate) * up
+        out = _wein("bsef,efd->bsed", hidden, lp["w_down"])
+        return jnp.einsum("bsed,bse->bsd", out, weights.astype(x.dtype))
+
+
+@jax.named_scope("lm_head")
+def _lm_head(eq, x, params):
+    return _wein(eq, x, params["lm_head"]).astype(jnp.float32)
 
 
 def _qkv(h, lp, eq, H, KV, hd, *lead, aids=None):
@@ -599,7 +618,7 @@ def transformer_forward(
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["layers"])
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
-    return _wein("bsd,dv->bsv", x, params["lm_head"]).astype(jnp.float32)
+    return _lm_head("bsd,dv->bsv", x, params)
 
 
 def transformer_prefill(
@@ -659,7 +678,7 @@ def transformer_prefill(
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     last_idx = jnp.maximum(lengths - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    logits = _wein("bd,dv->bv", x_last, params["lm_head"]).astype(jnp.float32)
+    logits = _lm_head("bd,dv->bv", x_last, params)
     return logits, cache
 
 
@@ -731,37 +750,39 @@ def transformer_prefill_chunk(
 
     def body(x, scanned):
         lp, ck, cv, cks, cvs = scanned  # ck/cv: [S, KV, max_len, hd]
-        h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
-        q, k, v = _qkv(h, lp, "pcd,dh->pch", H, KV, hd, P, c, aids=aids)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        with jax.named_scope("attn"):
+            h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
+            q, k, v = _qkv(h, lp, "pcd,dh->pch", H, KV, hd, P, c, aids=aids)
+            if cfg.pos_emb == "rope":
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
         # Write the chunk's K/V into the cache, then attend against the
         # cache in place (kernel reads only blocks up to starts+lens).
-        if cks is not None:
-
-            k, k_sc = quantize_kv(k)  # scales [P, c, KV]
-            v, v_sc = quantize_kv(v)
-            cks = cks.at[s_row, s_kv, s_sub, s_pos].set(
-                k_sc.transpose(0, 2, 1)[:, :, None, :]
+        with jax.named_scope("kv_commit"):
+            if cks is not None:
+                k, k_sc = quantize_kv(k)  # scales [P, c, KV]
+                v, v_sc = quantize_kv(v)
+                cks = cks.at[s_row, s_kv, s_sub, s_pos].set(
+                    k_sc.transpose(0, 2, 1)[:, :, None, :]
+                )
+                cvs = cvs.at[s_row, s_kv, s_sub, s_pos].set(
+                    v_sc.transpose(0, 2, 1)[:, :, None, :]
+                )
+            ck = ck.at[idx_row, idx_kv, idx_pos].set(k.transpose(0, 2, 1, 3))
+            cv = cv.at[idx_row, idx_kv, idx_pos].set(v.transpose(0, 2, 1, 3))
+        with jax.named_scope("attn"):
+            attn = cache_chunk_attention(
+                q, ck, cv, slots, starts, lens, k_scale=cks, v_scale=cvs,
+                block_table=cache.block_table if paged else None,
+                kernel=False if dense_attn else None,
+                window=cfg.sliding_window,
             )
-            cvs = cvs.at[s_row, s_kv, s_sub, s_pos].set(
-                v_sc.transpose(0, 2, 1)[:, :, None, :]
+            ao = attn.reshape(P, c, H * hd)
+            attn_out = (
+                _wein("pch,hd->pcd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
             )
-        ck = ck.at[idx_row, idx_kv, idx_pos].set(k.transpose(0, 2, 1, 3))
-        cv = cv.at[idx_row, idx_kv, idx_pos].set(v.transpose(0, 2, 1, 3))
-        attn = cache_chunk_attention(
-            q, ck, cv, slots, starts, lens, k_scale=cks, v_scale=cvs,
-            block_table=cache.block_table if paged else None,
-            kernel=False if dense_attn else None,
-            window=cfg.sliding_window,
-        )
-        ao = attn.reshape(P, c, H * hd)
-        attn_out = (
-            _wein("pch,hd->pcd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
-        )
-        if "wo_b" in lp:
-            attn_out = attn_out + lp["wo_b"]
+            if "wo_b" in lp:
+                attn_out = attn_out + lp["wo_b"]
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
@@ -778,8 +799,7 @@ def transformer_prefill_chunk(
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     last_idx = jnp.maximum(lens - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    logits = _wein("pd,dv->pv", x_last, params["lm_head"]).astype(jnp.float32)
-    return logits, cache
+    return _lm_head("pd,dv->pv", x_last, params), cache
 
 
 def transformer_decode_step(
@@ -826,30 +846,34 @@ def transformer_decode_step(
 
     def body(x, scanned):
         lp, ck, cv, cks, cvs = scanned  # ck/cv: [S, KV, max_len, hd]
-        h = _norm(
-            x[:, None, :], lp["attn_norm"], cfg, lp.get("attn_norm_b")
-        )[:, 0]
-        q, k, v = _qkv(h, lp, "bd,dh->bh", H, KV, hd, S, aids=aids)
-        pos2 = positions[:, None]  # [S, 1]
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q[:, None], cos, sin, pos2)[:, 0]
-            k = apply_rope(k[:, None], cos, sin, pos2)[:, 0]
-        if cache.quantized:
-            # Attend what the cache will hold: fake-quantize the fresh
-            # K/V so the split path matches a write-then-attend int8
-            # cache bit for bit (commit re-quantizes to the same int8).
-            k, v = fake_quantize_kv(k), fake_quantize_kv(v)
-        attn = decode_attention(
-            q, ck, cv, positions, k_new=k, v_new=v, k_scale=cks,
-            v_scale=cvs,
-            block_table=cache.block_table if paged else None,
-            kernel=False if dense_attn else None,
-            window=cfg.sliding_window,
-        )
-        ao = attn.reshape(S, H * hd)
-        attn_out = _wein("bh,hd->bd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
-        if "wo_b" in lp:
-            attn_out = attn_out + lp["wo_b"]
+        with jax.named_scope("attn"):
+            h = _norm(
+                x[:, None, :], lp["attn_norm"], cfg, lp.get("attn_norm_b")
+            )[:, 0]
+            q, k, v = _qkv(h, lp, "bd,dh->bh", H, KV, hd, S, aids=aids)
+            pos2 = positions[:, None]  # [S, 1]
+            if cfg.pos_emb == "rope":
+                q = apply_rope(q[:, None], cos, sin, pos2)[:, 0]
+                k = apply_rope(k[:, None], cos, sin, pos2)[:, 0]
+            if cache.quantized:
+                # Attend what the cache will hold: fake-quantize the
+                # fresh K/V so the split path matches a write-then-attend
+                # int8 cache bit for bit (commit re-quantizes to the same
+                # int8).
+                k, v = fake_quantize_kv(k), fake_quantize_kv(v)
+            attn = decode_attention(
+                q, ck, cv, positions, k_new=k, v_new=v, k_scale=cks,
+                v_scale=cvs,
+                block_table=cache.block_table if paged else None,
+                kernel=False if dense_attn else None,
+                window=cfg.sliding_window,
+            )
+            ao = attn.reshape(S, H * hd)
+            attn_out = (
+                _wein("bh,hd->bd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
+            )
+            if "wo_b" in lp:
+                attn_out = attn_out + lp["wo_b"]
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(
             mlp_in[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b")
@@ -870,38 +894,40 @@ def transformer_decode_step(
     # [l, s, kv, write_pos[s]] (slot cache) or [l, table[s, p//B], kv,
     # p%B] (paged pool; inactive slots park in block 0) — donation makes
     # this in-place.
-    li = jnp.arange(L)[:, None, None]
-    ki = jnp.arange(KV)[None, None, :]
-    if paged:
-        B = cache.block
-        blk_log = positions // B
-        blk = jnp.take_along_axis(
-            cache.block_table, jnp.minimum(blk_log, cache.block_table.shape[1] - 1)[:, None], axis=1
-        )[:, 0]
-        row = jnp.where(active, blk, 0)[None, :, None]
-        wp = jnp.where(active, positions % B, B - 1)[None, :, None]
-    else:
-        row = slot_idx[None, :, None]
-        wp = write_pos[None, :, None]
-    if cache.quantized:
-        new_k, k_sc = quantize_kv(new_k)  # scales [L, S, KV]
-        new_v, v_sc = quantize_kv(new_v)
-        sidx = (
-            li[..., None], row[..., None], ki[..., None],
-            jnp.arange(8)[None, None, None, :], wp[..., None],
-        )
+    with jax.named_scope("kv_commit"):
+        li = jnp.arange(L)[:, None, None]
+        ki = jnp.arange(KV)[None, None, :]
+        if paged:
+            B = cache.block
+            blk_log = positions // B
+            blk = jnp.take_along_axis(
+                cache.block_table,
+                jnp.minimum(blk_log, cache.block_table.shape[1] - 1)[:, None],
+                axis=1,
+            )[:, 0]
+            row = jnp.where(active, blk, 0)[None, :, None]
+            wp = jnp.where(active, positions % B, B - 1)[None, :, None]
+        else:
+            row = slot_idx[None, :, None]
+            wp = write_pos[None, :, None]
+        if cache.quantized:
+            new_k, k_sc = quantize_kv(new_k)  # scales [L, S, KV]
+            new_v, v_sc = quantize_kv(new_v)
+            sidx = (
+                li[..., None], row[..., None], ki[..., None],
+                jnp.arange(8)[None, None, None, :], wp[..., None],
+            )
+            cache = cache._replace(
+                k_s=cache.k_s.at[sidx].set(k_sc[..., None]),
+                v_s=cache.v_s.at[sidx].set(v_sc[..., None]),
+            )
         cache = cache._replace(
-            k_s=cache.k_s.at[sidx].set(k_sc[..., None]),
-            v_s=cache.v_s.at[sidx].set(v_sc[..., None]),
+            k=cache.k.at[li, row, ki, wp].set(new_k.astype(cache.k.dtype)),
+            v=cache.v.at[li, row, ki, wp].set(new_v.astype(cache.v.dtype)),
+            lengths=cache.lengths + active.astype(jnp.int32),
         )
-    cache = cache._replace(
-        k=cache.k.at[li, row, ki, wp].set(new_k.astype(cache.k.dtype)),
-        v=cache.v.at[li, row, ki, wp].set(new_v.astype(cache.v.dtype)),
-        lengths=cache.lengths + active.astype(jnp.int32),
-    )
     x = _norm(x[:, None, :], params["final_norm"], cfg, params.get("final_norm_b"))[:, 0]
-    logits = _wein("bd,dv->bv", x, params["lm_head"]).astype(jnp.float32)
-    return logits, cache
+    return _lm_head("bd,dv->bv", x, params), cache
 
 
 def transformer_verify_step(
@@ -929,28 +955,32 @@ def transformer_verify_step(
 
     def body(x, scanned):
         lp, ck, cv, cks, cvs = scanned  # read-only cache slices
-        h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
-        q, k, v = _qkv(h, lp, "bcd,dh->bch", H, KV, hd, S, c, aids=aids)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-        if cache.quantized:
-            # Same fake-quant rule as the decode step: the in-chunk K/V
-            # must match what commit_chunk_kv will write, or spec-on
-            # output diverges from spec-off under an int8 cache.
-            k, v = fake_quantize_kv(k), fake_quantize_kv(v)
-        if paged:
-            ck, cv, cks, cvs = paged_view(cache.block_table, ck, cv, rows, cks, cvs)
-        attn = verify_chunk_attention(
-            q, ck, cv, cache.lengths, k, v, k_scale=cks, v_scale=cvs,
-            window=cfg.sliding_window,
-        )
-        ao = attn.reshape(S, c, H * hd)
-        attn_out = (
-            _wein("bch,hd->bcd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
-        )
-        if "wo_b" in lp:
-            attn_out = attn_out + lp["wo_b"]
+        with jax.named_scope("attn"):
+            h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
+            q, k, v = _qkv(h, lp, "bcd,dh->bch", H, KV, hd, S, c, aids=aids)
+            if cfg.pos_emb == "rope":
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
+            if cache.quantized:
+                # Same fake-quant rule as the decode step: the in-chunk
+                # K/V must match what commit_chunk_kv will write, or
+                # spec-on output diverges from spec-off under an int8
+                # cache.
+                k, v = fake_quantize_kv(k), fake_quantize_kv(v)
+            if paged:
+                ck, cv, cks, cvs = paged_view(
+                    cache.block_table, ck, cv, rows, cks, cvs
+                )
+            attn = verify_chunk_attention(
+                q, ck, cv, cache.lengths, k, v, k_scale=cks, v_scale=cvs,
+                window=cfg.sliding_window,
+            )
+            ao = attn.reshape(S, c, H * hd)
+            attn_out = (
+                _wein("bch,hd->bcd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
+            )
+            if "wo_b" in lp:
+                attn_out = attn_out + lp["wo_b"]
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
@@ -963,10 +993,10 @@ def transformer_verify_step(
         body, x, (params["layers"], cache.k, cache.v, cache.k_s, cache.v_s)
     )
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
-    logits = _wein("bcd,dv->bcv", x, params["lm_head"]).astype(jnp.float32)
-    return logits, new_k, new_v
+    return _lm_head("bcd,dv->bcv", x, params), new_k, new_v
 
 
+@jax.named_scope("kv_commit")
 def commit_chunk_kv(
     cache: KVCache,
     new_k: jnp.ndarray,
@@ -1015,6 +1045,7 @@ def commit_chunk_kv(
     )
 
 
+@jax.named_scope("draft")
 def ngram_draft(
     history: jnp.ndarray,
     lengths: jnp.ndarray,

@@ -353,6 +353,7 @@ class CompileTracker:
                 self._note_compile(program, self._clock() - t0, w0)
             return out
 
+        wrapped.__wrapped__ = fn  # type: ignore[attr-defined]  # the jitted program, e.g. to lower it
         return wrapped
 
     def _note_compile(

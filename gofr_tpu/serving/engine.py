@@ -614,8 +614,8 @@ class InferenceEngine(
         # Lives OUTSIDE _init_llm_serving_state like the flight
         # recorder — rolling stats and anomaly rings survive supervisor
         # warm restarts. TPU_LOOP_PROFILE=0 builds no profiler: every
-        # scheduler hook degrades to one `is not None` and the loop is
-        # byte-identical to the pre-profiler scheduler.
+        # scheduler phase runs in one shared no-op context and the loop
+        # is byte-identical to the pre-profiler scheduler.
         if loop_profile is None:
             loop_profile = os.environ.get(
                 "TPU_LOOP_PROFILE", "1"
@@ -640,6 +640,7 @@ class InferenceEngine(
                 capture=trace_capture,
                 metrics=metrics,
                 logger=logger,
+                clock=self._obs.now,
             )
             self._loop_prof.context = self._loop_context
 
@@ -2598,6 +2599,7 @@ class InferenceEngine(
         slo_class: str = "",
         pin_replica: bool = False,
         traceparent: "Optional[str]" = None,
+        received: "Optional[float]" = None,
     ) -> _GenRequest:
         if self.family != "llm":
             raise RuntimeError(f"model {self.model_name} is not a generative LLM")
@@ -2781,7 +2783,7 @@ class InferenceEngine(
         # when the whole layer is off — the scheduler hooks all guard.
         req.timeline = self._obs.begin(
             prompt_tokens=len(ids), traceparent=traceparent,
-            tenant=str(tenant or ""),
+            tenant=str(tenant or ""), received=received,
         )
         try:
             self._enqueue(req)

@@ -40,14 +40,39 @@ know when to look. This module is that answer, always on:
   bounded ``jax.profiler`` capture through the
   :mod:`~gofr_tpu.serving.profiler_capture` singleton, cooldown-gated
   so the storm can't thrash the profiler.
+* **A stalled pass explains itself.** Every pass also takes the
+  scheduler thread's CPU clock, the process's, and the process's
+  collector seconds (one ``gc.callbacks`` hook, module-wide), so an
+  anomaly record says what the host did in a stall: ``cpu_s`` is the
+  thread's CPU seconds in the pass (≈ ``total_s``: it computed; ≈ 0: it
+  was off the CPU), ``proc_cpu_s`` every thread's (≈ 0 too: the whole
+  process was off the CPU; more than the thread's: others ran, and one
+  holding the GIL starves this one), ``gc_s`` the collector's.
+  ``next_pass`` (the following pass's phases, filled in when it closes)
+  then says on which side of the fetch the stall was: two windows are
+  in flight behind the one being fetched, so a following
+  ``device_window`` of ~0 means the device had run on through them —
+  the result was there and the host did not pick it up — and a full
+  window's length means the stalled window itself finished late: the
+  device was busy with work queued ahead of it (``context`` says what
+  was prefilling) or stood still.
+* **Counters the window can difference.**
+  ``app_tpu_loop_phase_seconds_total{phase}`` grows by each closed
+  pass's phase seconds: the difference of two scrapes is the loop's
+  time by phase over exactly the interval between them, which neither
+  the last-pass gauge nor the rolling ratio gives.
+* **The host's phases in the profiler's trace.** :meth:`LoopProfiler.
+  phase` wraps a phase in ``jax.profiler.TraceAnnotation("loop/<phase>")``
+  and laps on exit, so a ``/debug/tpu-trace`` capture carries the
+  scheduler thread's phases on the same clock as the device's ops.
 * **It measures itself.** Summarization/publication work per pass is
   accumulated into ``self_overhead_s`` and reported on ``/debug/loop``
   — the profiler's cost is a number, not a hope. The bench A/B
   (``TPU_LOOP_PROFILE=0``) pins the whole layer's cost.
 
 Off is off: ``TPU_LOOP_PROFILE=0`` builds no profiler — every scheduler
-hook degrades to one ``is not None`` and the loop is byte-identical to
-the pre-profiler scheduler.
+phase runs in one shared no-op context (:func:`loop_phase`) and the loop
+is byte-identical to the pre-profiler scheduler.
 
 Determinism: every mutation takes the timestamp as an argument (the
 caller reads the clock once per boundary), so tests drive exact phase
@@ -57,10 +82,14 @@ math, stall hysteresis, and ring bounds with stated clocks.
 from __future__ import annotations
 
 
+import contextlib
+import gc
 import time
 from collections import deque
 from itertools import islice
-from typing import Any, Callable, Optional
+from typing import Any, Callable, ContextManager, Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 from gofr_tpu.analysis import lockcheck
 
@@ -103,6 +132,69 @@ REL_STALL_FLOOR_S = 0.05
 REL_STALL_MIN_SAMPLES = 16
 
 
+# -- the collector's pauses, process-wide ------------------------------
+#
+# One ``gc.callbacks`` hook for the process (installed with the first
+# profiler): each collection's wall seconds are added to its
+# generation's total. A collection runs under the GIL on whichever
+# thread tripped it and stops every Python thread, so the hook needs no
+# lock of its own; publication to /metrics (below) does.
+_GC_GENERATIONS = 3
+_gc_total = [0.0] * _GC_GENERATIONS      # seconds collected, by generation
+_gc_published = [0.0] * _GC_GENERATIONS  # ... already added to the counter
+_gc_started = 0.0
+_gc_lock = lockcheck.make_lock("loop_profiler._gc_lock")
+
+
+def _on_gc(phase: str, info: dict[str, Any]) -> None:
+    global _gc_started
+    if phase == "start":
+        _gc_started = time.perf_counter()
+    elif _gc_started:
+        _gc_total[info["generation"]] += time.perf_counter() - _gc_started
+        _gc_started = 0.0
+
+
+def gc_pause_seconds() -> float:
+    """Wall seconds the collector has run in this process since the
+    hook was installed."""
+    return sum(_gc_total)
+
+
+def _install_gc_hook() -> None:
+    with _gc_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def _publish_gc(metrics: Any) -> None:
+    """Add what the collector ran since the last publication to
+    ``app_tpu_gc_pause_seconds_total``. The total is the process's, so
+    whichever engine's loop publishes first takes the delta: replicas
+    in one process count each second once."""
+    for gen in range(_GC_GENERATIONS):
+        if _gc_total[gen] <= _gc_published[gen]:
+            continue
+        with _gc_lock:
+            delta = _gc_total[gen] - _gc_published[gen]
+            _gc_published[gen] = _gc_total[gen]
+        if delta > 0.0:
+            metrics.add_counter(
+                "app_tpu_gc_pause_seconds_total", delta,
+                "generation", str(gen),
+            )
+
+
+_NO_PHASE: ContextManager[None] = contextlib.nullcontext()
+
+
+def loop_phase(prof: "Optional[LoopProfiler]", name: str) -> ContextManager[None]:
+    """``prof.phase(name)``, or a shared no-op when no profiler was
+    built (``TPU_LOOP_PROFILE=0``): the scheduler's phases are ``with``
+    blocks either way."""
+    return _NO_PHASE if prof is None else prof.phase(name)
+
+
 def _pctl(sorted_vals: list[float], q: float) -> float:
     """Nearest-rank percentile of an already-sorted list (0.0 empty)."""
     if not sorted_vals:
@@ -132,6 +224,10 @@ class LoopProfiler:
         metrics: Any = None,
         logger: Any = None,
         perf: Callable[[], float] = time.perf_counter,
+        clock: Callable[[], float] = time.monotonic,
+        thread_time: Callable[[], float] = time.thread_time,
+        process_time: Callable[[], float] = time.process_time,
+        gc_seconds: Callable[[], float] = gc_pause_seconds,
     ) -> None:
         self.model_name = model_name
         #: Absolute stall bound (seconds; 0 disables the absolute arm).
@@ -144,6 +240,17 @@ class LoopProfiler:
         self._metrics = metrics
         self._logger = logger
         self._perf = perf
+        #: The clock :meth:`phase` laps with: the one whose readings
+        #: the scheduler hands to ``begin_pass`` / ``lap``.
+        self._clock = clock
+        #: The calling (scheduler) thread's CPU seconds, the process's
+        #: (every thread's), and the process's collector seconds: read
+        #: once per pass.
+        self._thread_time = thread_time
+        self._process_time = process_time
+        self._gc_seconds = gc_seconds
+        if gc_seconds is gc_pause_seconds:
+            _install_gc_hook()
         #: Serving-context callback for anomaly records (queue depth,
         #: occupancy, brownout level, HBM headroom) — installed by the
         #: engine, invoked on the scheduler thread at the stall instant.
@@ -160,6 +267,12 @@ class LoopProfiler:
         self._pass_start: Optional[float] = None
         self._last_stamp = 0.0
         self._acc: dict[str, float] = {}
+        self._cpu_start = 0.0
+        self._proc_start = 0.0
+        self._gc_start = 0.0
+        # The newest anomaly, until the pass after it closes and fills
+        # in its ``next_pass``.
+        self._awaits_next: Optional[dict[str, Any]] = None
         # Rolling state (under the lock).
         window = max(8, int(window))
         self.passes = 0
@@ -207,11 +320,17 @@ class LoopProfiler:
         """Start a pass — and close the previous one (its residual
         since the last stamp lands in ``other``, so per-phase durations
         sum to pass wall time exactly)."""
+        cpu, proc = self._thread_time(), self._process_time()
+        gc_s = self._gc_seconds()
         if self._pass_start is not None:
-            self._close_pass(now)
+            self._close_pass(
+                now, cpu - self._cpu_start, proc - self._proc_start,
+                gc_s - self._gc_start,
+            )
         self._pass_start = now
         self._last_stamp = now
         self._acc = {}
+        self._cpu_start, self._proc_start, self._gc_start = cpu, proc, gc_s
 
     def lap(self, phase: str, now: float) -> None:
         """Attribute the interval since the previous stamp to
@@ -223,9 +342,23 @@ class LoopProfiler:
         )
         self._last_stamp = now
 
+    @contextlib.contextmanager
+    def phase(self, name: str) -> Iterator[None]:
+        """Run a loop phase inside ``TraceAnnotation("loop/<name>")``
+        on the calling thread and lap it on exit: one clock read per
+        boundary, and the profiler's capture shows the phase on a host
+        line beside the device's ops."""
+        with TraceAnnotation("loop/" + name):
+            yield
+            # (Not reached when the body raises: a thread the supervisor
+            # abandoned must not stamp the pass of the one after it.)
+            self.lap(name, self._clock())
+
     # -- pass summarization --------------------------------------------
 
-    def _close_pass(self, now: float) -> None:
+    def _close_pass(
+        self, now: float, cpu_s: float, proc_cpu_s: float, gc_s: float
+    ) -> None:
         o0 = self._perf()
         start = self._pass_start
         assert start is not None
@@ -239,8 +372,14 @@ class LoopProfiler:
         anomaly: Optional[dict[str, Any]] = None
         kind = ""
         threshold = 0.0
+        phases_s = {p: round(acc[p], 6) for p in PHASES if p in acc}
         with self._lock:
             self.passes += 1
+            if self._awaits_next is not None:
+                # This pass followed a stalled one: its phases say
+                # what the device did during the stall.
+                self._awaits_next["next_pass"] = phases_s
+                self._awaits_next = None
             for p in PHASES:
                 v = acc.get(p)
                 if v is None:
@@ -316,10 +455,17 @@ class LoopProfiler:
                     "kind": kind,
                     "total_s": round(total, 6),
                     "threshold_s": round(threshold, 6),
-                    "phases": {
-                        p: round(acc[p], 6) for p in PHASES if p in acc
-                    },
+                    "phases": phases_s,
+                    # CPU seconds in the pass: the scheduler thread's
+                    # (≈ total_s: it computed; ≈ 0: it was off the
+                    # CPU), every thread's of the process (≈ 0 too: the
+                    # whole process was), and the collector's seconds.
+                    "cpu_s": round(max(0.0, cpu_s), 6),
+                    "proc_cpu_s": round(max(0.0, proc_cpu_s), 6),
+                    "gc_s": round(max(0.0, gc_s), 6),
+                    "next_pass": None,  # until the next pass closes
                 }
+                self._awaits_next = anomaly
             elif not kind:
                 self._stall_latched = False
             util = self._utilization_locked()
@@ -366,15 +512,24 @@ class LoopProfiler:
     def _publish(
         self, acc: dict[str, float], util: float, host: float
     ) -> None:
-        """Refresh the loop gauges from the just-closed pass. Every
-        phase publishes (0.0 when absent) so the exported set always
-        sums to the pass wall time."""
+        """Refresh the loop gauges from the just-closed pass (every
+        phase publishes, 0.0 when absent, so the exported set always
+        sums to the pass wall time) and grow the counters by it."""
         m = self._metrics
         for p in PHASES:
+            v = acc.get(p)
             m.set_gauge(
-                "app_tpu_loop_phase_seconds", acc.get(p, 0.0),
+                "app_tpu_loop_phase_seconds", v or 0.0,
                 "model", self.model_name, "phase", p,
             )
+            if v is not None:
+                # The counter twin: two scrapes' difference is the
+                # loop's time by phase over exactly that interval.
+                m.add_counter(
+                    "app_tpu_loop_phase_seconds_total", v,
+                    "model", self.model_name, "phase", p,
+                )
+        _publish_gc(m)
         m.set_gauge(
             "app_tpu_loop_utilization", util, "model", self.model_name
         )

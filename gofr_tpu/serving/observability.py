@@ -23,6 +23,11 @@ through; it owns three things:
   ``record`` per request per phase, computed at retirement from
   host-side timestamps already in hand. Never per token, never a new
   host↔device pull (graftlint GL006/GL010/GL011 stay clean).
+  Time to first token is also split where it is spent (see
+  :meth:`RequestTimeline.phases`): ``entry`` → ``queue_wait`` →
+  ``prefill_wait`` → ``prefill_dispatch`` → ``first_token_wait`` →
+  ``delivery``, six phases that sum to ``first_written − received``
+  exactly, each its own histogram.
 * **Flight recorder** — a fixed-size ring of per-request timelines
   (phase durations, token counts, prefix-cache hit tokens,
   shed/cancel/replay/failover annotations, trace id) served at
@@ -143,9 +148,10 @@ class RequestTimeline:
     """
 
     __slots__ = (
-        "hub", "rid", "trace_id", "parent_span_id", "enqueued",
+        "hub", "rid", "trace_id", "parent_span_id", "received", "enqueued",
         "wall_ns_base", "mono_base", "admitted", "admissions",
-        "prefill_done", "first_token", "done", "outcome", "finish_reason",
+        "prefill_done", "first_token", "first_written", "done", "outcome",
+        "finish_reason",
         "chunks", "annotations", "transfers", "prompt_tokens",
         "output_tokens", "prefix_hit_tokens", "replays", "tenant",
         "_lock", "_finished",
@@ -161,11 +167,16 @@ class RequestTimeline:
         wall_ns_base: int,
         prompt_tokens: int,
         tenant: str = "",
+        received: Optional[float] = None,
     ) -> None:
         self.hub = hub
         self.rid = rid
         self.trace_id = trace_id
         self.parent_span_id = parent_span_id
+        # When the HTTP handler took the request up, before tokenisation
+        # (the hub's clock). None for a request that came in by gRPC,
+        # pubsub or /v1/batches: it has no entry phase.
+        self.received = received
         self.enqueued = enqueued
         # Wall↔monotonic anchor pair: phases are measured monotonic (NTP
         # steps must not skew durations), spans need wall-clock ns.
@@ -175,6 +186,9 @@ class RequestTimeline:
         self.admissions = 0
         self.prefill_done: Optional[float] = None
         self.first_token: Optional[float] = None
+        # After the SSE handler's first token chunk went out (set by
+        # the handler's task, not the scheduler thread).
+        self.first_written: Optional[float] = None
         self.done: Optional[float] = None
         self.outcome = ""
         self.finish_reason = ""
@@ -218,6 +232,13 @@ class RequestTimeline:
     def mark_first_token(self, now: float) -> None:
         if self.first_token is None:
             self.first_token = now
+
+    def mark_first_written(self) -> None:
+        """The streaming handler wrote the first token's chunk. Called
+        from the handler's task once per token; only the first call
+        reads the clock."""
+        if self.first_written is None:
+            self.first_written = self.hub.now()
 
     # -- cross-thread annotations --------------------------------------
 
@@ -301,14 +322,42 @@ class RequestTimeline:
 
     def phases(self) -> dict[str, float]:
         """Durations (seconds) of the completed phases; a phase the
-        request never reached is simply absent."""
+        request never reached is simply absent.
+
+        ``entry_s``, ``queue_wait_s``, ``prefill_wait_s``,
+        ``prefill_dispatch_s``, ``first_token_wait_s`` and
+        ``delivery_s`` are consecutive differences of the marks
+        received → enqueued → admitted → first chunk's dispatch start →
+        prefill_done → first_token → first_written, so together they
+        are ``first_written − received`` exactly, under any clock."""
         out: dict[str, float] = {}
+        if self.received is not None:
+            out["entry_s"] = self.enqueued - self.received
         if self.admitted is not None:
             out["queue_wait_s"] = self.admitted - self.enqueued
+            if self.chunks:
+                first_chunk = self.chunks[0][0]
+                out["prefill_wait_s"] = first_chunk - self.admitted
+                if self.prefill_done is not None:
+                    # One chunk step a pass, a window fetch between
+                    # them: the first step's start to the last one's
+                    # dispatch.
+                    out["prefill_dispatch_s"] = (
+                        self.prefill_done - first_chunk
+                    )
         if self.prefill_done is not None and self.admitted is not None:
             out["prefill_s"] = self.prefill_done - self.admitted
+            if self.first_token is not None:
+                # The last chunk queued behind the in-flight windows,
+                # its own compute, and the wait for the next
+                # _flush_prefill_emits poll.
+                out["first_token_wait_s"] = (
+                    self.first_token - self.prefill_done
+                )
         if self.first_token is not None:
             out["ttft_s"] = self.first_token - self.enqueued
+            if self.first_written is not None:
+                out["delivery_s"] = self.first_written - self.first_token
         if self.done is not None and self.first_token is not None:
             decode_s = self.done - self.first_token
             out["decode_s"] = decode_s
@@ -395,7 +444,12 @@ class FlightRecorder:
 
 #: Histogram names, registered in ``container.register_framework_metrics``.
 PHASE_HISTOGRAMS = {
+    "entry_s": "app_tpu_entry_seconds",
     "queue_wait_s": "app_tpu_queue_wait_seconds",
+    "prefill_wait_s": "app_tpu_prefill_wait_seconds",
+    "prefill_dispatch_s": "app_tpu_prefill_dispatch_seconds",
+    "first_token_wait_s": "app_tpu_first_token_wait_seconds",
+    "delivery_s": "app_tpu_delivery_seconds",
     "prefill_s": "app_tpu_prefill_seconds",
     "ttft_s": "app_tpu_ttft_seconds",
     "inter_token_s": "app_tpu_inter_token_seconds",
@@ -440,6 +494,7 @@ class RequestObservability:
         prompt_tokens: int,
         traceparent: Optional[str] = None,
         tenant: str = "",
+        received: Optional[float] = None,
     ) -> Optional[RequestTimeline]:
         """Mint a timeline for a submitting request, adopting the trace
         context from ``traceparent``, then from the calling task's
@@ -472,6 +527,7 @@ class RequestObservability:
             wall_ns_base=self._wall_ns(),
             prompt_tokens=prompt_tokens,
             tenant=tenant,
+            received=received,
         )
 
     def note_shed(
@@ -554,6 +610,8 @@ class RequestObservability:
                 attributes=attrs,
             )
 
+        if tl.received is not None:
+            child("tpu.entry", tl.received, tl.enqueued)
         if tl.admitted is not None:
             child("tpu.queue_wait", tl.enqueued, tl.admitted)
             child(
@@ -561,6 +619,8 @@ class RequestObservability:
                 outcome="admitted",
                 prefix_hit_tokens=tl.prefix_hit_tokens,
             )
+            if tl.chunks:
+                child("tpu.prefill_wait", tl.admitted, tl.chunks[0][0])
         for i, (start, end, tokens) in enumerate(tl.chunks):
             child(
                 "tpu.prefill.chunk", start, end,
@@ -577,6 +637,8 @@ class RequestObservability:
                 "tpu.transfer", start, end,
                 source=src, target=dst, result=result, leg=leg,
             )
+        if tl.first_token is not None and tl.first_written is not None:
+            child("tpu.delivery", tl.first_token, tl.first_written)
         if tl.first_token is not None:
             child(
                 "tpu.decode", tl.first_token, done,

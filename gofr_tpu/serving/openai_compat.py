@@ -254,6 +254,7 @@ def add_openai_routes(
             sent_tokens = 0  # ids already attached to a yielded chunk
             printed = ""
             reason = "stop"
+            timeline = getattr(req, "timeline", None)
 
             def payload_of(text: str) -> dict:
                 nonlocal sent_tokens
@@ -287,38 +288,36 @@ def add_openai_routes(
                     if tok is None:
                         break
                     emitted_ids.append(tok)
-                    if engine.tokenizer is None:
-                        if include_tokens:
-                            # Token-id wire with no text surface: the
-                            # consumer (a routing tier) decodes itself.
-                            yield _sse(rid, object_name, model, created,
-                                       payload_of(""))
-                        continue
-                    # Cumulative decode: per-token decode would split
-                    # multi-byte UTF-8 / BPE merges.
-                    full = engine.tokenizer.decode(emitted_ids)
-                    at = stop_hit(full)
-                    if at != -1:
-                        full = full[:at]
-                        stopped = True
-                    elif full.endswith("�"):
-                        # Possibly incomplete UTF-8 tail — hold back
-                        # (the ids still flow when the consumer asked
-                        # for them: delivered-prefix accounting must
-                        # not lag the generation).
-                        if include_tokens:
-                            yield _sse(rid, object_name, model, created,
-                                       payload_of(""))
-                        continue
-                    else:
-                        full = full[: max(len(printed), len(full) - hold)]
-                    if len(full) > len(printed):
-                        text, printed = full[len(printed):], full
+                    # What this token sends: None is nothing, "" a chunk
+                    # that carries only token ids.
+                    text: Optional[str] = "" if include_tokens else None
+                    if engine.tokenizer is not None:
+                        # (Without one the wire is token ids alone: the
+                        # consumer, a routing tier, decodes itself.)
+                        # Cumulative decode: per-token decode would split
+                        # multi-byte UTF-8 / BPE merges.
+                        full = engine.tokenizer.decode(emitted_ids)
+                        at = stop_hit(full)
+                        if at != -1:
+                            full = full[:at]
+                            stopped = True
+                        elif full.endswith("�"):
+                            # Possibly incomplete UTF-8 tail — hold back
+                            # (the ids still flow when the consumer asked
+                            # for them: delivered-prefix accounting must
+                            # not lag the generation).
+                            full = printed
+                        else:
+                            full = full[: max(len(printed), len(full) - hold)]
+                        if len(full) > len(printed):
+                            text, printed = full[len(printed):], full
+                    if text is not None:
                         yield _sse(rid, object_name, model, created,
                                    payload_of(text))
-                    elif include_tokens:
-                        yield _sse(rid, object_name, model, created,
-                                   payload_of(""))
+                        # Back from the yield: the chunk is written. The
+                        # first one closes the timeline's delivery phase.
+                        if timeline is not None:
+                            timeline.mark_first_written()
                 brownout_flag = False
                 if stopped:
                     reason = "stop"
@@ -443,11 +442,15 @@ def add_openai_routes(
 
     @app.post("/v1/completions")
     async def completions(ctx: Any) -> Union[Raw, Stream]:
+        received = time.monotonic()  # the timeline's entry mark
         engine = _engine(ctx)
         body = _completion_body(ctx.request.raw.body)
         adapter = _check_model(body, engine)
         prompts = _normalize_prompts(body.get("prompt", ""))
-        params = dict(_params(body), adapter=adapter, **_lifecycle(ctx))
+        params = dict(
+            _params(body), adapter=adapter, received=received,
+            **_lifecycle(ctx),
+        )
         stop_seqs = _stop_list(body)
         streaming = bool(body.get("stream"))
         n = _n_choices(body, streaming)
@@ -535,6 +538,7 @@ def add_openai_routes(
 
     @app.post("/v1/chat/completions")
     async def chat_completions(ctx: Any) -> Union[Raw, Stream]:
+        received = time.monotonic()  # the timeline's entry mark
         engine = _engine(ctx)
         body = _completion_body(ctx.request.raw.body)
         adapter = _check_model(body, engine)
@@ -553,7 +557,10 @@ def add_openai_routes(
                 prompt = template(messages)
         else:
             prompt = template(messages)
-        params = dict(_params(body), adapter=adapter, **_lifecycle(ctx))
+        params = dict(
+            _params(body), adapter=adapter, received=received,
+            **_lifecycle(ctx),
+        )
         stop_seqs = _stop_list(body)
         streaming = bool(body.get("stream"))
         n = _n_choices(body, streaming)

@@ -99,6 +99,7 @@ class LLMProgramsMixin:
         enable_penalties = self.enable_penalties
         top_lp_k = self.top_logprobs
 
+        @jax.named_scope("sample")
         def sample(
             logits: Any, keys: Any, temps: Any, greedy: Any,
             topps: Any, pen: Optional[tuple] = None,
@@ -559,9 +560,12 @@ class LLMProgramsMixin:
                         (nxt, nlp),
                     )
 
-                (cache, _), (chosen_s, chosen_lp_s) = jax.lax.scan(
-                    pos_body, (cache, nsteps), inputs.T
-                )
+                # "verify" in the trace: the G+1 decode-step forwards
+                # that check the draft (their ops read verify/…/attn).
+                with jax.named_scope("verify"):
+                    (cache, _), (chosen_s, chosen_lp_s) = jax.lax.scan(
+                        pos_body, (cache, nsteps), inputs.T
+                    )
                 chosen = chosen_s.T  # [S, G+1] — position j's TRUE token
                 chosen_lp = chosen_lp_s.T
                 # Accept the longest prefix of drafts that match the token

@@ -16,6 +16,7 @@ import numpy as np
 
 from gofr_tpu import faults
 from gofr_tpu.analysis import lockcheck
+from gofr_tpu.serving.loop_profiler import loop_phase
 from gofr_tpu.serving.types import (
     _ActiveSeq,
     _GenRequest,
@@ -194,11 +195,12 @@ class SchedulerMixin:
         from collections import deque
 
         inflight: deque = deque()  # _dispatch_window return tuples
-        # Loop profiler (serving/loop_profiler.py): one clock stamp per
-        # PHASE BOUNDARY per pass (window granularity — GL011's
-        # discipline), attributed into per-phase rolling stats, the
-        # utilization / host-overhead gauges, and the stall detector.
-        # Off (TPU_LOOP_PROFILE=0) = one `is not None` per boundary.
+        # Loop profiler (serving/loop_profiler.py): each phase of a pass
+        # is a `with` block that enters TraceAnnotation("loop/<phase>")
+        # and, on exit, reads the clock ONCE and attributes the time
+        # since the previous stamp to the phase (window granularity —
+        # GL011's discipline). Off (TPU_LOOP_PROFILE=0) = one shared
+        # no-op context per boundary.
         prof = self._loop_prof
         try:
             while self._running and self._epoch == epoch:
@@ -207,37 +209,37 @@ class SchedulerMixin:
                 # per-phase durations sum to pass wall time exactly.
                 if prof is not None:
                     prof.begin_pass(self._obs.now())
-                # Progress heartbeat: the watchdog trips when this loop
-                # stalls (a hung device step) for longer than
-                # its wall-time bound. Idle iterations pet every ≤20 ms.
-                if self._watchdog is not None:
-                    self._watchdog.pet()
-                # Fault seam: a test's armed action here can stall the
-                # whole loop (watchdog coverage) or fail one iteration.
-                faults.fire("scheduler.window", engine=self)
-                self._check_superseded()
-                # Lifecycle reap: cancelled/disconnected/deadline-expired
-                # sequences retire HERE, once per loop iteration, so a
-                # dead stream's KV blocks free within one decode window.
-                self._reap_lifecycle()
-                if prof is not None:
-                    prof.lap("reap", self._obs.now())
+                with loop_phase(prof, "reap"):
+                    # Progress heartbeat: the watchdog trips when this
+                    # loop stalls (a hung device step) for longer than
+                    # its wall-time bound. Idle iterations pet every
+                    # ≤20 ms.
+                    if self._watchdog is not None:
+                        self._watchdog.pet()
+                    # Fault seam: a test's armed action here can stall
+                    # the whole loop (watchdog coverage) or fail one
+                    # iteration.
+                    faults.fire("scheduler.window", engine=self)
+                    self._check_superseded()
+                    # Lifecycle reap: cancelled/disconnected/deadline-
+                    # expired sequences retire HERE, once per loop
+                    # iteration, so a dead stream's KV blocks free
+                    # within one decode window.
+                    self._reap_lifecycle()
                 # Tenant attribution (serving/tenant_ledger.py): one
                 # KV-occupancy integration pass per loop iteration —
                 # one clock read shared by every live slot, never per
                 # token. Off (TPU_TENANT_LEDGER=0) = this one check.
                 if self._tenant_ledger is not None:
-                    self._ledger_tick()
-                    if prof is not None:
-                        prof.lap("ledger", self._obs.now())
+                    with loop_phase(prof, "ledger"):
+                        self._ledger_tick()
                 # Brownout control loop (serving/brownout.py): ONE
                 # evaluation per scheduler pass — the GL011-disciplined
                 # cadence the ladder's sustain windows assume. Off
                 # (TPU_BROWNOUT=0) = this one check.
                 if self._brownout is not None:
-                    self._brownout_tick()
-                    if prof is not None:
-                        prof.lap("brownout", self._obs.now())
+                    with loop_phase(prof, "brownout"):
+                        self._brownout_tick()
                 # Control plane (serving/control_plane.py): ONE guarded
                 # pass over every registered signal + the three closed
                 # loops, right after the sensors it consumes ticked.
@@ -245,54 +247,58 @@ class SchedulerMixin:
                 # never raises (a lying sensor degrades its loop to
                 # observe-only instead of wedging this pass).
                 if self._control is not None:
-                    self._control.evaluate(self._obs.now())
-                    if prof is not None:
-                        prof.lap("control", self._obs.now())
+                    with loop_phase(prof, "control"):
+                        self._control.evaluate(self._obs.now())
                 if self.kv_block:
                     # Proactive prefix-eviction sweep: keep the free
                     # list above the watermark so admission finds free
                     # blocks instead of pre-evicting synchronously.
-                    self._radix_watermark_sweep()
-                    if prof is not None:
-                        prof.lap("sweep", self._obs.now())
-                # One chunk step per iteration, interleaved 1:1 with decode
-                # windows: a long prompt's prefill proceeds in bounded slices
-                # and never freezes active token streams (VERDICT r1 #9).
-                progressed = self._dispatch_prefill_chunk(lap_import=True)
-                # Wave admission: on a cold start or a retirement wave the
-                # 1:1 interleave would refill capacity one chunk per window
-                # — at 64 slots that is ~15 windows of a mostly-idle device
-                # (measured: the 64-slot bench lost ~2 s per wave to it).
-                # While live streams fill under a quarter of the slots, the
-                # marginal inter-token latency of another ~1-4 ms chunk step
-                # is noise next to the idle capacity, so keep draining; past
-                # that, protect the live streams' latency (1:1 again).
-                if progressed:
-                    while (
-                        sum(1 for s in self._slots if s is not None) * 4
-                        < self.n_slots
-                        and self._dispatch_prefill_chunk()
-                    ):
-                        pass
-                if prof is not None:
-                    prof.lap("prefill", self._obs.now())
-                self._flush_prefill_emits()
-                if prof is not None:
-                    prof.lap("emit_flush", self._obs.now())
+                    with loop_phase(prof, "sweep"):
+                        self._radix_watermark_sweep()
+                with loop_phase(prof, "prefill"):
+                    # One chunk step per iteration, interleaved 1:1 with
+                    # decode windows: a long prompt's prefill proceeds in
+                    # bounded slices and never freezes active token
+                    # streams (VERDICT r1 #9).
+                    progressed = self._dispatch_prefill_chunk(
+                        lap_import=True
+                    )
+                    # Wave admission: on a cold start or a retirement
+                    # wave the 1:1 interleave would refill capacity one
+                    # chunk per window — at 64 slots that is ~15 windows
+                    # of a mostly-idle device (measured: the 64-slot
+                    # bench lost ~2 s per wave to it). While live streams
+                    # fill under a quarter of the slots, the marginal
+                    # inter-token latency of another ~1-4 ms chunk step
+                    # is noise next to the idle capacity, so keep
+                    # draining; past that, protect the live streams'
+                    # latency (1:1 again).
+                    if progressed:
+                        while (
+                            sum(1 for s in self._slots if s is not None) * 4
+                            < self.n_slots
+                            and self._dispatch_prefill_chunk()
+                        ):
+                            pass
+                with loop_phase(prof, "emit_flush"):
+                    self._flush_prefill_emits()
                 any_active = any(s is not None for s in self._slots)
                 if not any_active and not inflight:
                     if not progressed and not self._prefill_emits:
-                        # Publish "verifiably idle" under the submit lock:
-                        # the graceful drain trusts this flag, and the
-                        # lock means no submission can race past it.
-                        with self._submit_lock:
-                            if self._pending.empty() and not self._wait_kv:
-                                self._sched_idle = True
-                                self._idle_evt.set()
-                        self._work.wait(timeout=0.02)
-                        self._work.clear()
-                        if prof is not None:
-                            prof.lap("idle", self._obs.now())
+                        with loop_phase(prof, "idle"):
+                            # Publish "verifiably idle" under the submit
+                            # lock: the graceful drain trusts this flag,
+                            # and the lock means no submission can race
+                            # past it.
+                            with self._submit_lock:
+                                if (
+                                    self._pending.empty()
+                                    and not self._wait_kv
+                                ):
+                                    self._sched_idle = True
+                                    self._idle_evt.set()
+                            self._work.wait(timeout=0.02)
+                            self._work.clear()
                     continue
                 with self._submit_lock:
                     self._sched_idle = False
@@ -311,20 +317,18 @@ class SchedulerMixin:
                     for s in self._slots
                 )
                 if wants_more:
-                    inflight.append(self._dispatch_window())
-                    if prof is not None:
-                        prof.lap("dispatch", self._obs.now())
-                processed = False
-                while len(inflight) > (self.pipeline_depth if wants_more else 0):
-                    self._process_window(*inflight.popleft())
-                    processed = True
-                if processed and prof is not None:
+                    with loop_phase(prof, "dispatch"):
+                        inflight.append(self._dispatch_window())
+                keep = self.pipeline_depth if wants_more else 0
+                if len(inflight) > keep:
                     # The designated device-wait seam: the fetch block
                     # inside _process_window is where the loop
                     # legitimately waits on the device — everything
                     # else busy counts as host overhead (GL019 is the
                     # static twin of this attribution).
-                    prof.lap("device_window", self._obs.now())
+                    with loop_phase(prof, "device_window"):
+                        while len(inflight) > keep:
+                            self._process_window(*inflight.popleft())
         except SchedulerSuperseded:
             # The supervisor restarted the engine around this wedged
             # thread: a new scheduler owns every structure, and the
@@ -1212,17 +1216,18 @@ class SchedulerMixin:
         # its request was popped still applies next call — the request
         # just pays a redundant prefill, never a wrong answer).
         if self.kv_block:
-            self._apply_tier_imports()
-            if lap_import and self._loop_prof is not None:
-                # Tier-import apply is its own loop phase: shipped-block
-                # writes are device work that would otherwise hide
-                # inside "prefill" (one stamp per apply, not per block).
-                # Only the PASS-SEAM call laps — re-entries from the
-                # wave-admission loop or _process_window's mega-mode
-                # readiness poll would otherwise attribute prefill work
-                # (or the device-window wait itself) to tier_import and
-                # invert the host-overhead diagnosis.
-                self._loop_prof.lap("tier_import", self._obs.now())
+            # Tier-import apply is its own loop phase: shipped-block
+            # writes are device work that would otherwise hide inside
+            # "prefill" (one stamp per apply, not per block). Only the
+            # PASS-SEAM call laps — re-entries from the wave-admission
+            # loop or _process_window's mega-mode readiness poll would
+            # otherwise attribute prefill work (or the device-window
+            # wait itself) to tier_import and invert the host-overhead
+            # diagnosis.
+            with loop_phase(
+                self._loop_prof if lap_import else None, "tier_import"
+            ):
+                self._apply_tier_imports()
         # Admission is host bookkeeping only — the device work is the
         # chunk steps that follow.
         free = [
@@ -1593,6 +1598,13 @@ class SchedulerMixin:
             self._metrics.record_histogram(
                 "app_tpu_batch_size", len(rows), "batcher", "prefill"
             )
+            # How much of the fixed [P, c] step was prompt: the rest of
+            # its P x c token rows is padding the device computes anyway.
+            self._metrics.record_histogram(
+                "app_tpu_prefill_fill_ratio",
+                float(lens[: len(rows)].sum()) / (P * c),
+                "model", self.model_name,
+            )
 
         emits_started = False
         # One clock read per chunk DISPATCH (window granularity); the
@@ -1759,7 +1771,7 @@ class SchedulerMixin:
         decode, [2, k, S, G+1] plus a [k, S] counts array for speculative
         windows, [2, m*k, S] plus a windows-run scalar for mega windows.
         Returns ``(emitted_dev, counts_dev_or_None, slots_snapshot,
-        t_dispatch, wrun_dev_or_None)`` for _process_window — the snapshot
+        wrun_dev_or_None, etops_dev_or_None)`` for _process_window — the snapshot
         matters because by processing time a retired slot may already hold
         a NEW request admitted in between."""
         # Fault seam: a raise models the device failing a decode window;
@@ -1865,7 +1877,6 @@ class SchedulerMixin:
                     min(cover, int(remaining_host[i])) if mega > 1
                     else self.window_k
                 )
-        t0 = time.time()
         counts = None
         wrun = None
         etops = None
@@ -1946,14 +1957,13 @@ class SchedulerMixin:
         if self._lockstep:
             lockcheck.note_device_sync("lockstep_block_until_ready")
             self._jax.block_until_ready(emitted)
-        return emitted, counts, list(self._slots), t0, wrun, etops
+        return emitted, counts, list(self._slots), wrun, etops
 
     def _process_window(
         self,
         emitted: Any,
         counts: Any,
         snapshot: "list[Optional[_ActiveSeq]]",
-        t0: float,
         wrun: Any = None,
         etops: Any = None,
     ) -> None:
@@ -1979,7 +1989,10 @@ class SchedulerMixin:
         # Decode: [2, k, S] (mega: [2, m*k, S], first wrun*k valid).
         # Spec: [2, k, S, G+1] + counts [k, S].
         lockcheck.note_device_sync("decode_window_fetch")
-        emitted_host = np.asarray(emitted)
+        # Named in the profiler's trace: the one place this thread
+        # blocks on the device, beside the device's own ops.
+        with self._jax.profiler.TraceAnnotation("window_fetch"):
+            emitted_host = np.asarray(emitted)
         # The fetch above is this loop's other blocking point (a hung
         # device step stalls HERE, not only at dispatch): if the supervisor
         # abandoned this thread while it was stuck, the token block in
@@ -1994,21 +2007,14 @@ class SchedulerMixin:
             else int(np.asarray(wrun)) * self.window_k
         )
         if self._metrics is not None:
-            # decode_fetch = host-blocking time (what pipelining hides);
-            # decode_window_pipeline = dispatch→processed incl. D windows
-            # of pipeline queueing (NOT per-window device latency).
-            now_m = time.time()
+            # decode_fetch = host-blocking time (what pipelining hides).
             self._metrics.record_histogram(
-                "app_tpu_infer_latency", now_m - t_fetch, "kind", "decode_fetch"
-            )
-            self._metrics.record_histogram(
-                "app_tpu_infer_latency", now_m - t0,
-                "kind", "decode_window_pipeline",
+                "app_tpu_infer_latency", time.time() - t_fetch,
+                "kind", "decode_fetch",
             )
 
         now = time.time()
         mono_now = self._obs.now()  # shared by every row in this window
-        emitted_n = 0  # client-visible emissions this window (gauge)
         for i, seq in enumerate(snapshot):
             if seq is None:
                 continue
@@ -2082,7 +2088,6 @@ class SchedulerMixin:
                         ]
                     seq.last_token = tok
                     seq.n_generated += 1
-                    emitted_n += 1
                     self._emit_token(seq, tok, float(lp), top)
                     if self._finished(seq):
                         self._retire(i, seq)
@@ -2103,25 +2108,23 @@ class SchedulerMixin:
                     "model", self.model_name,
                 )
         if self._metrics is not None and steps:
-            # Per-WINDOW observability gauges (one set_gauge each per
-            # processed window, from host values already in hand — no
-            # per-token work, no device pulls): how full the batch is,
-            # how long a decode step takes (dispatch→processed over the
-            # window's steps — includes the pipeline's D windows of
-            # queueing, i.e. the number real tokens actually wait), and
-            # how many client-visible tokens a step yields.
+            # Per-WINDOW observability (one record each per processed
+            # window, from host values already in hand — no per-token
+            # work, no device pulls): how full the batch is now (the
+            # gauge), and how full this window ran — the slots live when
+            # it was dispatched — as a histogram whose sum over count
+            # between two scrapes is the mean over exactly the windows
+            # in between.
             in_use = sum(1 for s in self._slots if s is not None)
             self._metrics.set_gauge(
                 "app_tpu_batch_occupancy",
                 in_use / max(1, self.n_slots),
                 "model", self.model_name,
             )
-            self._metrics.set_gauge(
-                "app_tpu_decode_step_seconds", (now - t0) / steps,
-                "model", self.model_name,
-            )
-            self._metrics.set_gauge(
-                "app_tpu_tokens_per_step", emitted_n / steps,
+            self._metrics.record_histogram(
+                "app_tpu_window_occupancy",
+                sum(1 for s in snapshot if s is not None)
+                / max(1, self.n_slots),
                 "model", self.model_name,
             )
         self._update_slot_gauges()

@@ -20,6 +20,15 @@ Covered:
   re-arming only after a clean pass;
 * compile-pass exemption: a pass during which the compile counter grew
   is the compile tracker's to attribute, never a loop stall;
+* a stalled pass explains itself: its record carries the scheduler
+  thread's CPU seconds, the process's, the collector's seconds inside
+  the pass and, once the following pass closes, that pass's phases;
+* ``app_tpu_loop_phase_seconds_total{phase}`` grows by each closed
+  pass's phase seconds, so counter deltas over N passes are those
+  passes' wall time exactly; ``app_tpu_gc_pause_seconds_total`` counts
+  each collected second of the process once;
+* ``phase()`` laps once per boundary on the profiler's own clock, skips
+  the lap when its body raises, and is inert when no profiler is built;
 * the anomaly ring is bounded and absolute-stall records are PINNED —
   they survive a burst of relative anomalies;
 * trace-capture cooldown: a stall storm triggers at most one capture
@@ -40,11 +49,13 @@ import pytest
 
 from gofr_tpu.metrics import Manager
 from gofr_tpu.serving.engine import InferenceEngine
+from gofr_tpu.serving import loop_profiler
 from gofr_tpu.serving.loop_profiler import (
     PHASES,
     REL_STALL_FLOOR_S,
     REL_STALL_MIN_SAMPLES,
     LoopProfiler,
+    loop_phase,
 )
 from gofr_tpu.serving.profiler_capture import ProfilerCapture
 from gofr_tpu.serving.tokenizer import ByteTokenizer
@@ -59,7 +70,12 @@ def loop_metrics() -> Manager:
         "app_tpu_loop_host_overhead_ratio",
     ):
         m.new_gauge(name)
-    m.new_counter("app_tpu_loop_stalls_total")
+    for name in (
+        "app_tpu_loop_stalls_total",
+        "app_tpu_loop_phase_seconds_total",
+        "app_tpu_gc_pause_seconds_total",
+    ):
+        m.new_counter(name)
     return m
 
 
@@ -136,6 +152,39 @@ def test_phase_durations_sum_to_pass_wall_exactly():
     vals = gauge_values(m, "app_tpu_loop_phase_seconds")
     assert len(vals) == len(PHASES)
     assert sum(vals.values()) == pytest.approx(1.0)
+
+
+def test_phase_counter_deltas_are_the_passes_wall_time_exactly():
+    """What a benchmark window does: scrape, wait, scrape, subtract."""
+    m = loop_metrics()
+    prof = make_prof(metrics=m, stall_s=0.0)
+    name = "app_tpu_loop_phase_seconds_total"
+    # Binary fractions: every sum below is exact.
+    drive_pass(prof, 0.0, [("prefill", 0.25), ("idle", 1.0)], 1.0)
+    scrape0 = {p: counter_value(m, name, phase=p) for p in PHASES}
+    assert sum(scrape0.values()) == 1.0  # the pass before the window
+    passes = 5
+    for i in range(passes):
+        t = 1.0 + 0.5 * i  # 0.5 s a pass: 0.125 host, 0.25 device, rest other
+        drive_pass(
+            prof, t,
+            [("reap", t + 0.0625), ("dispatch", t + 0.125),
+             ("device_window", t + 0.375)],
+            t + 0.5,
+        )
+    delta = {p: counter_value(m, name, phase=p) - scrape0[p] for p in PHASES}
+    assert sum(delta.values()) == passes * 0.5  # the window's wall time
+    assert delta["device_window"] == passes * 0.25
+    assert delta["idle"] == 0.0 and delta["prefill"] == 0.0
+    # The window's host share, from counters alone: busy time outside
+    # the device-window seam over busy time.
+    busy = sum(v for p, v in delta.items() if p != "idle")
+    assert (busy - delta["device_window"]) / busy == 0.5
+    # A phase that never ran has no series: a reader sums what is there.
+    inst = [i for i in m.instruments() if i.name == name][0]
+    assert {dict(k)["phase"] for k in inst.collect()} == {
+        "prefill", "idle", "reap", "dispatch", "device_window", "other",
+    }
 
 
 def test_multiple_laps_accumulate_within_a_pass():
@@ -229,6 +278,123 @@ def test_absolute_stall_pins_exactly_one_record_per_incident():
     snap = prof.snapshot()
     assert snap["stalls"] == 2
     assert len(snap["pinned_anomalies"]) == 2
+
+
+def test_stalled_pass_says_what_it_was_and_what_followed():
+    cpu, proc, collected = [0.0], [0.0], [0.0]
+    prof = make_prof(
+        stall_s=1.0, thread_time=lambda: cpu[0], process_time=lambda: proc[0],
+        gc_seconds=lambda: collected[0],
+    )
+
+    def one_pass(t0, laps, t_end, cpu_s, gc_s):
+        if prof._pass_start is None:
+            prof.begin_pass(t0)
+        for phase, at in laps:
+            prof.lap(phase, at)
+        cpu[0] += cpu_s
+        proc[0] += 4 * cpu_s  # three other threads as busy as this one
+        collected[0] += gc_s
+        prof.begin_pass(t_end)
+
+    one_pass(0.0, [("device_window", 0.25)], 0.25, 0.03125, 0.0)
+    # The stalled pass: 4 s waiting on a window's fetch, the thread
+    # blocked (CPU 0.0625 s), the collector idle but for 0.125 s.
+    one_pass(0.25, [("dispatch", 0.5), ("device_window", 4.25)], 4.25,
+             0.0625, 0.125)
+    rec = prof.snapshot()["pinned_anomalies"][0]
+    assert rec["total_s"] == 4.0 and rec["phases"]["device_window"] == 3.75
+    assert rec["cpu_s"] == 0.0625 and rec["gc_s"] == 0.125
+    assert rec["proc_cpu_s"] == 0.25  # every thread's, this one's in it
+    assert rec["next_pass"] is None  # the pass after it is still open
+    # With two windows in flight the next one was dispatched before the
+    # stalled fetch: its own fetch returning at once says the device
+    # worked through the stall.
+    one_pass(4.25, [("dispatch", 4.5), ("device_window", 4.5078125)],
+             4.5078125, 0.25, 0.0)
+    rec = prof.snapshot()["pinned_anomalies"][0]
+    assert rec["next_pass"] == {"dispatch": 0.25, "device_window": 0.007812}
+    # Only the pass right after the stall is recorded, and once.
+    one_pass(4.5078125, [("device_window", 5.0)], 5.0, 0.0, 0.0)
+    assert prof.snapshot()["pinned_anomalies"][0]["next_pass"] == rec["next_pass"]
+    # The same record in a host-bound stall: the CPU clock ran all along.
+    one_pass(5.0, [("prefill", 8.0)], 8.0, 2.875, 1.5)
+    host_bound = prof.snapshot()["pinned_anomalies"][1]
+    assert host_bound["cpu_s"] == 2.875 and host_bound["gc_s"] == 1.5
+
+
+def test_collector_seconds_are_counted_once_for_the_process():
+    import gc
+
+    m = loop_metrics()
+    a, b = make_prof(metrics=m, stall_s=0.0), make_prof(metrics=m, stall_s=0.0)
+    assert gc.callbacks.count(loop_profiler._on_gc) == 1  # one hook, two profilers
+    name = "app_tpu_gc_pause_seconds_total"
+    drive_pass(a, 0.0, [("idle", 1.0)], 1.0)  # publishes what ran so far
+    before = counter_value(m, name)
+    collected0 = loop_profiler.gc_pause_seconds()
+    junk = [[i] for i in range(20000)]
+    junk.append(junk)
+    del junk
+    gc.collect()
+    collected = loop_profiler.gc_pause_seconds() - collected0
+    assert collected > 0.0
+    drive_pass(a, 1.0, [("idle", 2.0)], 2.0)
+    drive_pass(b, 0.0, [("idle", 1.0)], 1.0)  # the second engine's loop
+    drive_pass(a, 2.0, [("idle", 3.0)], 3.0)
+    # Both loops published; each collected second was added once. (More
+    # may have run since: collections of the passes themselves.)
+    grown = counter_value(m, name) - before
+    assert collected <= grown + 1e-12
+    assert grown <= loop_profiler.gc_pause_seconds() - collected0 + 1e-12
+    assert counter_value(m, name, generation="2") > 0.0
+    # ... and a pass sees what ran inside it, on this thread's CPU clock.
+    c = make_prof(stall_s=0.0001, stall_factor=0.0)
+    c.begin_pass(0.0)
+    gc.collect()
+    c.lap("prefill", 1.0)
+    c.begin_pass(1.0)
+    rec = c.snapshot()["pinned_anomalies"][0]
+    assert rec["gc_s"] > 0.0 and rec["proc_cpu_s"] >= rec["cpu_s"] > 0.0
+
+
+def test_phase_context_laps_once_per_boundary_on_its_own_clock():
+    t = [0.0]
+    reads = []
+
+    def clock():
+        reads.append(t[0])
+        return t[0]
+
+    prof = make_prof(stall_s=0.0, clock=clock)
+    prof.begin_pass(0.0)
+    with prof.phase("reap"):
+        t[0] = 0.125
+    with prof.phase("prefill"):
+        with prof.phase("tier_import"):  # nests: the inner laps first
+            t[0] = 0.25
+        t[0] = 0.5
+    with pytest.raises(RuntimeError):
+        with prof.phase("dispatch"):  # a body that raises does not lap
+            t[0] = 0.75
+            raise RuntimeError("superseded")
+    with loop_phase(prof, "device_window"):
+        t[0] = 1.0
+    prof.begin_pass(1.0)
+    assert reads == [0.125, 0.25, 0.5, 1.0]  # one read per closed boundary
+    phases = {p: v["total_s"] for p, v in prof.snapshot()["phases"].items()}
+    assert phases == {
+        "reap": 0.125, "tier_import": 0.125, "prefill": 0.25,
+        "device_window": 0.5,  # took the unlapped dispatch's time too
+    }
+
+
+def test_phase_context_is_inert_without_a_profiler():
+    off = loop_phase(None, "reap")
+    assert off is loop_phase(None, "device_window")  # one shared no-op
+    with off:
+        with loop_phase(None, "prefill"):
+            pass
 
 
 def test_relative_p95_stall_needs_samples_and_floor():

@@ -23,6 +23,12 @@ Covered:
   annotation;
 * ``traceparent`` round-trips through ``HTTPReplica`` so cross-replica
   traces stitch;
+* time to first token split where it is spent: the six phases entry →
+  queue_wait → prefill_wait → prefill_dispatch → first_token_wait →
+  delivery sum EXACTLY to ``first_written − received`` under a stated
+  clock, a request stopped before a mark lacks the later phases, each
+  phase records one histogram sample and one span, and a completion
+  streamed over HTTP carries all six in ``/debug/flight``'s record;
 * shed requests land PINNED in the recorder with the shed outcome;
 * the layer costs nothing when off: ``TPU_FLIGHT_RECORDER=0`` with no
   metrics and no active exporter mints no timeline at all.
@@ -111,12 +117,17 @@ def _fault_hygiene():
     faults.reset()
 
 
-def _hist_count(metrics, name, model="llama-tiny"):
+def _hist_sum_count(metrics, name, model="llama-tiny"):
+    """(count, sum) of a histogram's samples for ``model``."""
     inst = {i.name: i for i in metrics.instruments()}[name]
-    for labels, (_counts, (_total, n)) in inst.collect().items():
+    for labels, (_counts, (total, n)) in inst.collect().items():
         if ("model", model) in labels:
-            return n
-    return 0
+            return n, total
+    return 0, 0.0
+
+
+def _hist_count(metrics, name, model="llama-tiny"):
+    return _hist_sum_count(metrics, name, model)[0]
 
 
 def _gauge(metrics, name):
@@ -175,6 +186,149 @@ def test_timeline_phase_math_with_injected_clock():
     assert len(hub.recorder.snapshot()["records"]) == 1
 
 
+TTFT_SPLIT = (
+    "entry_s", "queue_wait_s", "prefill_wait_s", "prefill_dispatch_s",
+    "first_token_wait_s", "delivery_s",
+)
+# The marks of one streamed request, in order, on a clock of binary
+# fractions so that every difference and their sum are exact.
+MARKS = dict(
+    received=100.0, enqueued=100.125, admitted=100.5, chunk0=100.75,
+    chunk1=101.0, prefill_done=101.5, first_token=101.625,
+    first_written=101.6875,
+)
+
+
+def _timeline_up_to(hub, t, last_mark):
+    """Drive a timeline through MARKS up to and including ``last_mark``
+    (None: every mark), then retire it."""
+    order = list(MARKS)
+    upto = len(order) if last_mark is None else order.index(last_mark) + 1
+    reached = set(order[:upto])
+    t[0] = MARKS["enqueued"]
+    tl = hub.begin(prompt_tokens=300, received=MARKS["received"])
+    if "admitted" in reached:
+        tl.mark_admitted(MARKS["admitted"])
+    if "chunk0" in reached:
+        tl.note_chunk(MARKS["chunk0"], MARKS["chunk0"] + 0.0625, 256)
+    if "prefill_done" in reached:
+        tl.note_chunk(MARKS["chunk1"], MARKS["prefill_done"], 44)
+        tl.mark_prefill_done(MARKS["prefill_done"])
+    if "first_token" in reached:
+        tl.mark_first_token(MARKS["first_token"])
+    if "first_written" in reached:
+        t[0] = MARKS["first_written"]
+        tl.mark_first_written()
+        t[0] += 0.5
+        tl.mark_first_written()  # later tokens: the first mark stands
+    t[0] = 103.0
+    tl.finish("ok" if last_mark is None else "cancelled", "stop",
+              output_tokens=9)
+    return tl
+
+
+def test_ttft_split_sums_exactly_to_first_written_minus_received():
+    t = [0.0]
+    hub = RequestObservability(
+        "m", recorder=FlightRecorder(), clock=lambda: t[0], wall_ns=lambda: 0,
+    )
+    tl = _timeline_up_to(hub, t, None)
+    phases = tl.phases()
+    assert [phases[k] for k in TTFT_SPLIT] == [
+        0.125, 0.375, 0.25, 0.75, 0.125, 0.0625,
+    ]
+    # Exactly, not approximately: consecutive differences of one clock.
+    assert sum(phases[k] for k in TTFT_SPLIT) == (
+        tl.first_written - tl.received
+    ) == 1.6875
+    # What was there keeps its meaning beside the split.
+    assert phases["prefill_s"] == (
+        phases["prefill_wait_s"] + phases["prefill_dispatch_s"]
+    )
+    assert phases["ttft_s"] == sum(phases[k] for k in TTFT_SPLIT[1:5])
+    # /debug/flight carries the split through to_dict with no code of
+    # its own.
+    entry = hub.recorder.snapshot()["records"][-1]
+    assert set(TTFT_SPLIT) <= set(entry["phases"])
+
+
+@pytest.mark.parametrize("last_mark,present", [
+    ("enqueued", ["entry_s"]),
+    ("admitted", ["entry_s", "queue_wait_s"]),
+    ("chunk0", ["entry_s", "queue_wait_s", "prefill_wait_s"]),
+    ("prefill_done", list(TTFT_SPLIT[:4])),
+    ("first_token", list(TTFT_SPLIT[:5])),
+])
+def test_request_stopped_before_a_mark_lacks_the_later_phases(
+    last_mark, present,
+):
+    t = [0.0]
+    hub = RequestObservability(
+        "m", recorder=FlightRecorder(), clock=lambda: t[0], wall_ns=lambda: 0,
+    )
+    phases = _timeline_up_to(hub, t, last_mark).phases()
+    assert [k for k in TTFT_SPLIT if k in phases] == present
+
+
+def test_request_without_an_http_handler_lacks_entry_and_delivery():
+    """gRPC, pubsub and /v1/batches submit with no ``received`` and write
+    no SSE chunk: the four phases in between are all there is."""
+    t = [50.0]
+    hub = RequestObservability(
+        "m", recorder=FlightRecorder(), clock=lambda: t[0], wall_ns=lambda: 0,
+    )
+    tl = hub.begin(prompt_tokens=4)
+    tl.mark_admitted(50.5)
+    tl.note_chunk(50.75, 51.0, 4)
+    tl.mark_prefill_done(51.0)
+    tl.mark_first_token(51.5)
+    t[0] = 52.0
+    tl.finish("ok", "length", output_tokens=3)
+    phases = tl.phases()
+    assert [k for k in TTFT_SPLIT if k in phases] == list(TTFT_SPLIT[1:5])
+    assert sum(phases[k] for k in TTFT_SPLIT[1:5]) == phases["ttft_s"] == 1.5
+
+
+def test_ttft_split_records_one_histogram_sample_and_one_span_per_phase(
+    metrics, capture,
+):
+    from gofr_tpu.serving.observability import PHASE_HISTOGRAMS
+
+    split = [PHASE_HISTOGRAMS[k] for k in TTFT_SPLIT]
+    assert split == [
+        "app_tpu_entry_seconds", "app_tpu_queue_wait_seconds",
+        "app_tpu_prefill_wait_seconds", "app_tpu_prefill_dispatch_seconds",
+        "app_tpu_first_token_wait_seconds", "app_tpu_delivery_seconds",
+    ]
+    t = [0.0]
+    hub = RequestObservability(
+        "split-model", metrics=metrics, clock=lambda: t[0], wall_ns=lambda: 0,
+    )
+    before = {n: _hist_count(metrics, n, "split-model") for n in split}
+    tl = _timeline_up_to(hub, t, None)
+    _timeline_up_to(hub, t, "admitted")  # records the first two only
+    after = {n: _hist_count(metrics, n, "split-model") for n in split}
+    assert [after[n] - before[n] for n in split] == [2, 2, 1, 1, 1, 1]
+    # One child span per phase, under the request's one trace and parent.
+    root = [s for s in capture.by_name("tpu.request")
+            if s.trace_id == tl.trace_id][0]
+    for name, seconds in (
+        ("tpu.entry", 0.125), ("tpu.queue_wait", 0.375),
+        ("tpu.prefill_wait", 0.25), ("tpu.emit_flush", 0.125),
+        ("tpu.delivery", 0.0625),
+    ):
+        spans = [s for s in capture.by_name(name)
+                 if s.trace_id == tl.trace_id]
+        assert len(spans) == 1, name
+        assert spans[0].parent_id == root.span_id
+        assert spans[0].end_ns - spans[0].start_ns == int(seconds * 1e9)
+    # prefill_dispatch is the chunk spans: first one's start to the last
+    # one's end.
+    chunks = [s for s in capture.by_name("tpu.prefill.chunk")
+              if s.trace_id == tl.trace_id]
+    assert chunks[-1].end_ns - chunks[0].start_ns == int(0.75 * 1e9)
+
+
 def test_flight_recorder_evicts_ring_but_pins_survive_burst():
     t = [0.0]
     hub = RequestObservability(
@@ -211,7 +365,9 @@ def test_layer_off_mints_no_timeline():
 
 
 def test_phase_histograms_record_exactly_once_per_request(metrics, engine):
+    ratios = ("app_tpu_window_occupancy", "app_tpu_prefill_fill_ratio")
     before = {name: _hist_count(metrics, name) for name in PHASES}
+    before_ratio = {name: _hist_sum_count(metrics, name) for name in ratios}
     for _ in range(2):
         r = engine.generate_sync(
             "histogram once per phase", max_new_tokens=8,
@@ -221,10 +377,24 @@ def test_phase_histograms_record_exactly_once_per_request(metrics, engine):
     after = {name: _hist_count(metrics, name) for name in PHASES}
     for name in PHASES:
         assert after[name] - before[name] == 2, name
-    # Per-window utilization gauges rode along (host values only).
+    # Per-window and per-step utilization rode along (host values
+    # only): the occupancy gauge, and the two ratios as histograms whose
+    # sum over count between two reads is the mean over exactly the
+    # windows (steps) in between.
     assert _gauge(metrics, "app_tpu_batch_occupancy") is not None
-    assert _gauge(metrics, "app_tpu_tokens_per_step") is not None
-    assert _gauge(metrics, "app_tpu_decode_step_seconds") is not None
+    mean = {}
+    for name in ratios:
+        (n0, sum0), (n1, sum1) = before_ratio[name], _hist_sum_count(metrics, name)
+        assert n1 - n0 >= 2, name  # at least one a request
+        mean[name] = (sum1 - sum0) / (n1 - n0)
+    # One of the four slots was live when each window was dispatched ...
+    assert mean["app_tpu_window_occupancy"] == pytest.approx(0.25)
+    # ... and the prompt was all that was real in each prefill step's
+    # prefill_batch x prefill_chunk token rows.
+    assert mean["app_tpu_prefill_fill_ratio"] == pytest.approx(
+        r.prompt_tokens / (engine.prefill_batch * engine.prefill_chunk)
+    )
+    assert 0 < mean["app_tpu_prefill_fill_ratio"] < 0.125
 
 
 def test_one_trace_per_request_with_phase_parentage(capture, engine):

@@ -575,3 +575,53 @@ def test_completions_echo(oai_app):
     assert text.startswith("hello there")
     assert len(text) > len("hello there")
     c.close()
+
+
+TTFT_SPLIT = (
+    "entry_s", "queue_wait_s", "prefill_wait_s", "prefill_dispatch_s",
+    "first_token_wait_s", "delivery_s",
+)
+
+
+def _newest_flight_record(app) -> dict:
+    flight = app.container.tpu.flight_records()
+    return max(flight["records"] + flight["pinned"], key=lambda e: e["rid"])
+
+
+@pytest.mark.parametrize("path,body", [
+    ("/v1/completions", {"prompt": "split my first token"}),
+    ("/v1/chat/completions", {"messages": [{"role": "user", "content": "go"}]}),
+])
+def test_streamed_request_splits_its_time_to_first_token(oai_app, path, body):
+    """The handler stamps ``received`` before tokenisation and
+    ``first_written`` after the first token's chunk went out: the
+    request's flight record carries the six phases from socket to
+    socket, and they are the whole of that time."""
+    c = _conn(oai_app)
+    c.request("POST", path, body=json.dumps({
+        **body, "max_tokens": 48, "temperature": 0, "stream": True,
+        "stream_options": {"include_tokens": True},
+    }))
+    r = c.getresponse()
+    assert r.status == 200 and r.read().decode().rstrip().endswith("[DONE]")
+    c.close()
+    phases = _newest_flight_record(oai_app)["phases"]
+    assert set(TTFT_SPLIT) <= set(phases)
+    assert all(phases[k] >= 0 for k in TTFT_SPLIT)
+    # entry + (submit → first token) + delivery; rounded to the µs in
+    # the record, so to within a few of them.
+    assert sum(phases[k] for k in TTFT_SPLIT) == pytest.approx(
+        phases["entry_s"] + phases["ttft_s"] + phases["delivery_s"], abs=1e-5
+    )
+    assert phases["delivery_s"] < 1.0 and phases["entry_s"] < 1.0
+
+
+def test_unstreamed_request_has_an_entry_phase_and_no_delivery(oai_app):
+    c = _conn(oai_app)
+    c.request("POST", "/v1/completions", body=json.dumps({
+        "prompt": "no stream", "max_tokens": 4, "temperature": 0,
+    }))
+    assert c.getresponse().status == 200
+    c.close()
+    phases = _newest_flight_record(oai_app)["phases"]
+    assert [k for k in TTFT_SPLIT if k in phases] == list(TTFT_SPLIT[:5])

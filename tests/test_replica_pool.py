@@ -641,11 +641,14 @@ def test_hedged_unary_request_wins_on_second_replica(metrics, engines):
         gate_in, gate_out = threading.Event(), threading.Event()
 
         def stall_a(engine=None, **kw):
-            if engine is eng_a:
+            # The fault point is process-global and B's loop passes it
+            # too: a ``times=1`` budget is B's to spend first as often
+            # as not. The closure parks A once; only A's thread gets here.
+            if engine is eng_a and not gate_in.is_set():
                 gate_in.set()
                 gate_out.wait(timeout=120)
 
-        faults.arm("scheduler.window", action=stall_a, times=1)
+        faults.arm("scheduler.window", action=stall_a)
         assert gate_in.wait(30)  # A's scheduler is parked: requests hang
         result = pool.generate_sync(
             "hedge me", timeout=120, max_new_tokens=8, temperature=0.0,
