@@ -182,7 +182,7 @@ class Container:
         )
         m.new_histogram(
             "app_tpu_spec_tokens_per_step",
-            "speculative decoding: tokens accepted per live step",
+            "tokens emitted per live decode step (plain windows: 1.0)",
             (1, 1.5, 2, 2.5, 3, 4, 5, 6, 8),
         )
         m.new_gauge(

@@ -60,12 +60,6 @@ from gofr_tpu.serving.types import (
 )
 from gofr_tpu.serving.watchdog import Watchdog
 
-# Draft length the TPU_SPEC_TOKENS=auto default resolves to where the
-# bench gate holds (BENCH_SPEC_WORKLOAD: G=2 is the measured knee —
-# longer drafts inflate the per-step decode-forward count faster than
-# n-gram acceptance grows).
-SPEC_AUTO_TOKENS = 2
-
 
 def resolve_spec_tokens(
     raw: str,
@@ -75,29 +69,29 @@ def resolve_spec_tokens(
 ) -> "tuple[int, Optional[str]]":
     """Resolve ``TPU_SPEC_TOKENS`` (``auto``/int) to a draft count.
 
-    ``auto`` — the default — flips speculation ON exactly where the
-    two-metric bench gate holds, and OFF where it measurably does not:
+    ``auto`` — the default — resolves to 0 on every backend: the engine
+    serves the plain decode window.
 
     * The numerics-exact spec window runs the decode-step program once
-      per candidate position, so device compute per emitted token is
-      never below the plain decode window's; speculation's entire win
-      is per-dispatch amortization (an accepted draft means fewer
-      windows — fewer host↔device round trips and scheduler passes —
-      per token). On dispatch/host-overhead-bound TPU serving (the
-      regime ``app_tpu_loop_host_overhead_ratio`` measures) the
-      BENCH_SPEC_WORKLOAD A/B holds: tok/s up, host overhead flat. On
-      compute-bound backends (CPU) the same A/B measures tok/s DOWN —
-      the extra forwards dominate — so ``auto`` resolves to 0 there
-      rather than shipping the gate's own counterexample.
-    * Compile features the spec window's emission block excludes
-      (penalties' evolving count plane, the top_logprobs alternatives
-      plane) win over an *implicit* default: ``auto`` resolves to 0
-      with a boot note instead of refusing to boot. An EXPLICIT
-      ``TPU_SPEC_TOKENS>0`` alongside them still raises in the
-      constructor — that combination is a contradiction the user
+      per candidate position, one after another, so a step costs G + 1
+      decode forwards and emits between 1 and G + 1 tokens: device
+      compute per emitted token is never below the plain window's, and
+      equals it only when every draft of every live slot is accepted.
+      What speculation can save is dispatches, and on an attached chip
+      the host's share of the loop is 2-5%, hidden behind a pipeline
+      two windows deep. Measured on the v5e at G = 2
+      (PERF_LEDGER.jsonl, PR 25; both benchmark cells):
+      ``app_tpu_spec_tokens_per_step`` 1.0 — three forwards a step for
+      one token, two thirds of all decode compute. An explicit integer
+      opts in; the streams are bit-identical either way.
+    * Penalties' evolving count plane and the top_logprobs alternatives
+      plane are per-step planes the spec window's emission block
+      excludes: the note names them, so that an operator about to opt
+      in knows an EXPLICIT ``TPU_SPEC_TOKENS>0`` alongside them raises
+      in the constructor — that combination is a contradiction the user
       typed, not one a default created.
 
-    Returns ``(spec_tokens, note)``; ``note`` explains any auto
+    Returns ``(spec_tokens, note)``; ``note`` explains the auto
     resolution so boots are attributable in logs.
     """
     val = (raw or "auto").strip().lower()
@@ -110,26 +104,21 @@ def resolve_spec_tokens(
             )
             if on
         ]
-        if conflicts:
-            return 0, (
-                "speculative decoding default-on skipped: "
-                + "/".join(conflicts)
-                + " needs per-step planes the spec window's emission "
-                "block excludes (set TPU_SPEC_TOKENS explicitly to "
-                "choose the other way)"
-            )
-        if backend != "tpu":
-            return 0, (
-                f"speculative decoding stays off on backend={backend!r}: "
-                "the exact verify pays one decode forward per emitted "
-                "token, and the BENCH_SPEC_WORKLOAD gate (tok/s up AND "
-                "host_overhead_ratio flat) only holds on dispatch-bound "
-                "TPU serving (set TPU_SPEC_TOKENS>0 to force)"
-            )
-        return SPEC_AUTO_TOKENS, (
-            f"speculative decoding ON by default (G={SPEC_AUTO_TOKENS}, "
-            "numerics-exact verify; TPU_SPEC_TOKENS=0 disables)"
+        note = (
+            f"speculative decoding off by default (TPU_SPEC_TOKENS=auto "
+            f"-> 0, backend={backend!r}): the exact verify runs G+1 "
+            "decode forwards a step one after another, so it can tie "
+            "the plain decode window in device time and never beat it "
+            "(measured on the v5e at G=2: 1.0 tokens a step, three "
+            "forwards a step, on both benchmark cells); set "
+            "TPU_SPEC_TOKENS to an integer to opt in"
         )
+        if conflicts:
+            note += (
+                " (not alongside " + "/".join(conflicts) + ": the spec "
+                "window's emission block excludes their per-step planes)"
+            )
+        return 0, note
     try:
         n = int(val)
     except ValueError:
@@ -1015,18 +1004,15 @@ class InferenceEngine(
         model_name = config.get_or_default("TPU_MODEL", "llama-tiny")
         ckpt = config.get_or_default("TPU_CHECKPOINT", "")
         quant_cfg = config.get_or_default("TPU_QUANT", "")
-        # Speculative decoding defaults ON where the bench gate holds
-        # (see resolve_spec_tokens): resolve before the constructor so
-        # an implicit default can yield to explicitly-enabled features
-        # instead of raising the constructor's explicit-conflict error.
+        # TPU_SPEC_TOKENS=auto serves the plain decode window (see
+        # resolve_spec_tokens); only an explicit integer reaches the
+        # constructor's explicit-conflict error.
         top_logprobs_cfg = int(
             config.get_or_default("TPU_TOP_LOGPROBS", "0")
         )
         penalties_cfg = config.get_or_default(
             "TPU_PENALTIES", "false"
         ).lower() in ("1", "true", "yes")
-        # No fallback: a chip another process holds must fail the boot,
-        # not read as "this is a CPU box" and flip the default.
         import jax
 
         spec_tokens_cfg, spec_note = resolve_spec_tokens(

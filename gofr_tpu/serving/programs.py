@@ -255,10 +255,15 @@ class LLMProgramsMixin:
             return (cache, all_tokens, all_logps, rep(first), rep(first_lp),
                     pcounts, nsteps, topi, topl, None, None)
 
-        prefill_chunk_step = partial(
+        @partial(
             jax.jit, donate_argnums=(1, 12, 13, 14, 15, 18, 19),
             static_argnames=("use_bias",),
-        )(_prefill_core)
+        )
+        def prefill_chunk_step(*operands: Any, use_bias: bool = False) -> tuple:
+            """The plain prefill step (speculation off), a function of
+            its own so that the profiler's trace names its module
+            ``jit_prefill_chunk_step`` as it names the ``_hist`` one."""
+            return _prefill_core(*operands, use_bias)
 
         def _multi_chunk_core(
             params: Any, cache: Any, tokens3: Any, slots: Any,

@@ -2097,36 +2097,45 @@ class SchedulerMixin:
                         break
                 if done:
                     break
-        if counts_host is not None and self._metrics is not None:
-            # Acceptance observability: tokens-per-live-step across the
-            # window (1.0 = no draft accepted, spec_tokens+1 = all).
-            live = counts_host > 0
-            if live.any():
-                self._metrics.record_histogram(
-                    "app_tpu_spec_tokens_per_step",
-                    float(counts_host[live].mean()),
-                    "model", self.model_name,
-                )
-        if self._metrics is not None and steps:
+        if self._metrics is not None:
             # Per-WINDOW observability (one record each per processed
             # window, from host values already in hand — no per-token
-            # work, no device pulls): how full the batch is now (the
-            # gauge), and how full this window ran — the slots live when
-            # it was dispatched — as a histogram whose sum over count
-            # between two scrapes is the mean over exactly the windows
-            # in between.
-            in_use = sum(1 for s in self._slots if s is not None)
-            self._metrics.set_gauge(
-                "app_tpu_batch_occupancy",
-                in_use / max(1, self.n_slots),
-                "model", self.model_name,
-            )
-            self._metrics.record_histogram(
-                "app_tpu_window_occupancy",
-                sum(1 for s in snapshot if s is not None)
-                / max(1, self.n_slots),
-                "model", self.model_name,
-            )
+            # work, no device pulls).
+            dispatched_live = sum(1 for s in snapshot if s is not None)
+            # Tokens per live step across the window. Spec window: 1.0 =
+            # no draft accepted, spec_tokens+1 = all. A plain step emits
+            # one token per live slot by definition, so a plain window
+            # that had a live slot records 1.0: the counter says what
+            # engaged either way.
+            if counts_host is not None:
+                live = counts_host > 0
+                per_step = (
+                    float(counts_host[live].mean()) if live.any() else None
+                )
+            else:
+                per_step = 1.0 if steps and dispatched_live else None
+            if per_step is not None:
+                self._metrics.record_histogram(
+                    "app_tpu_spec_tokens_per_step", per_step,
+                    "model", self.model_name,
+                )
+            if steps:
+                # How full the batch is now (the gauge), and how full
+                # this window ran — the slots live when it was
+                # dispatched — as a histogram whose sum over count
+                # between two scrapes is the mean over exactly the
+                # windows in between.
+                in_use = sum(1 for s in self._slots if s is not None)
+                self._metrics.set_gauge(
+                    "app_tpu_batch_occupancy",
+                    in_use / max(1, self.n_slots),
+                    "model", self.model_name,
+                )
+                self._metrics.record_histogram(
+                    "app_tpu_window_occupancy",
+                    dispatched_live / max(1, self.n_slots),
+                    "model", self.model_name,
+                )
         self._update_slot_gauges()
 
     def _emit_token(

@@ -22,11 +22,13 @@ reaches:
   n-gram-friendly repeated-text shape accepts well above 1;
 * zero steady-state recompiles with spec on (the exit-6 fence's
   invariant, asserted engine-side);
-* the ``TPU_SPEC_TOKENS=auto`` default seam: ON only where the bench
-  gate holds (TPU backend, no conflicting feature), OFF with a boot
-  note otherwise, and both precedence directions of the
-  penalties/top_logprobs interaction (implicit default yields,
-  explicit contradiction still raises).
+* the tokens-per-step counter says what engaged: accepted counts on a
+  spec engine, exactly 1.0 a plain window on a ``spec_tokens == 0`` one;
+* the ``TPU_SPEC_TOKENS=auto`` default seam: 0 on every backend with an
+  attributable boot note (the exact verify can tie the plain window in
+  device time and never beat it; measured at 1.0 tokens a step on the
+  chip), explicit integers pass through, and an explicit contradiction
+  with penalties/top_logprobs still raises.
 
 Determinism: engines share the default seed; faults fire on exact hit
 counts through ``gofr_tpu/faults``; supervisor backoff sleeps are
@@ -44,7 +46,6 @@ from gofr_tpu import faults
 from gofr_tpu.config import MockConfig
 from gofr_tpu.metrics import new_metrics_manager
 from gofr_tpu.serving.engine import (
-    SPEC_AUTO_TOKENS,
     InferenceEngine,
     resolve_spec_tokens,
 )
@@ -111,11 +112,16 @@ def spec_metrics():
 
 
 @pytest.fixture(scope="module")
-def engines(spec_metrics):
+def plain_metrics():
+    return _spec_metrics()
+
+
+@pytest.fixture(scope="module")
+def engines(spec_metrics, plain_metrics):
     """The shared pair: a spec=0 reference and a spec=2 engine, both
     bf16 llama-tiny with prefix pools. Module-scoped — construction
     and first-dispatch compiles dominate this suite's wall clock."""
-    ref = _make_engine(0, prefix_slots=2)
+    ref = _make_engine(0, prefix_slots=2, metrics=plain_metrics)
     spec = _make_engine(G, prefix_slots=2, metrics=spec_metrics)
     yield ref, spec
     faults.reset()
@@ -347,6 +353,31 @@ def test_acceptance_counter_math(engines, spec_metrics):
     assert mean > 1.2
 
 
+@pytest.mark.parametrize("which", ["plain", "spec"])
+def test_tokens_per_step_counter_says_what_engaged(
+    engines, plain_metrics, spec_metrics, which
+):
+    """``app_tpu_spec_tokens_per_step`` stays alive at spec_tokens == 0:
+    a plain step emits one token per live slot by definition, so every
+    plain window that had a live slot records exactly 1.0; a spec engine
+    still records its accepted counts."""
+    ref, spec = engines
+    eng, metrics = (
+        (ref, plain_metrics) if which == "plain" else (spec, spec_metrics)
+    )
+    assert eng.spec_tokens == (0 if which == "plain" else G)
+    sum0, n0 = _acceptance(metrics)
+    result = eng.generate_sync(BENCH_PROMPTS[0], **GREEDY)
+    assert len(result.token_ids) == GREEDY["max_new_tokens"]
+    sum1, n1 = _acceptance(metrics)
+    assert n1 > n0  # one record a processed window
+    mean = (sum1 - sum0) / (n1 - n0)
+    if which == "plain":
+        assert mean == 1.0
+    else:
+        assert 1.0 < mean <= G + 1  # the repeated text accepts drafts
+
+
 def test_zero_steady_state_recompiles_with_spec():
     """The warm-up fence with spec on: after greedy, seeded-sampled,
     and logit_bias variants have each compiled once, further traffic
@@ -377,22 +408,26 @@ def test_zero_steady_state_recompiles_with_spec():
 
 
 def test_resolve_spec_tokens_auto_seam():
-    # ON exactly where the bench gate holds: TPU backend, no
-    # conflicting feature.
-    n, note = resolve_spec_tokens("auto", "tpu", False, 0)
-    assert n == SPEC_AUTO_TOKENS and "ON by default" in note
-    # OFF on compute-bound backends — the exact verify pays one decode
-    # forward per candidate, so the A/B measures tok/s DOWN there.
-    n, note = resolve_spec_tokens("auto", "cpu", False, 0)
-    assert n == 0 and "backend='cpu'" in note
-    # Explicitly-enabled features win over the implicit default.
+    # The plain decode window on every backend, the chip included: the
+    # sequential exact verify pays G+1 decode forwards a step, so it
+    # can tie the plain window in device time and never beat it. The
+    # note says what was resolved, where, and what was measured.
+    for backend in ("tpu", "cpu"):
+        n, note = resolve_spec_tokens("auto", backend, False, 0)
+        assert n == 0 and f"backend={backend!r}" in note
+        assert "off by default" in note and "1.0 tokens a step" in note
+        assert "TPU_PENALTIES" not in note
+    assert resolve_spec_tokens("", "tpu", False, 0)[0] == 0  # unset
+    # The note names the features an opt-in would contradict.
     n, note = resolve_spec_tokens("auto", "tpu", True, 0)
-    assert n == 0 and "TPU_PENALTIES" in note
+    assert n == 0 and "not alongside TPU_PENALTIES" in note
     n, note = resolve_spec_tokens("auto", "tpu", False, 3)
-    assert n == 0 and "TPU_TOP_LOGPROBS" in note
-    # Explicit integers pass through untouched (backend-independent);
-    # the constructor owns explicit-conflict errors.
+    assert n == 0 and "not alongside TPU_TOP_LOGPROBS" in note
+    # Explicit integers pass through untouched (backend-independent):
+    # opting in still serves the spec window exactly as before; the
+    # constructor owns explicit-conflict errors.
     assert resolve_spec_tokens("3", "cpu", True, 5) == (3, None)
+    assert resolve_spec_tokens("2", "tpu", False, 0) == (2, None)
     assert resolve_spec_tokens("0", "tpu", False, 0) == (0, None)
     assert resolve_spec_tokens("-2", "tpu", False, 0) == (0, None)
     with pytest.raises(ValueError, match="integer or 'auto'"):
@@ -416,16 +451,20 @@ def _cfg(**extra):
 
 
 def test_from_config_auto_resolves_per_backend_and_logs():
-    # On the CPU test backend, auto resolves OFF with an attributable
-    # boot note; nothing raises, nothing needs TPU_SPEC_TOKENS set.
+    # auto resolves to the plain window with an attributable boot
+    # note; nothing raises, nothing needs TPU_SPEC_TOKENS set.
     logger = _RecordingLogger()
     eng = InferenceEngine.from_config(_cfg(), logger=logger)
     try:
         assert eng.spec_tokens == 0
-        assert any("speculative decoding" in ln for ln in logger.lines)
+        assert eng.health_check()["details"]["spec_tokens"] == 0
+        assert any(
+            "speculative decoding off by default" in ln
+            for ln in logger.lines
+        )
     finally:
         eng.close()
-    # An explicit integer overrides the backend heuristic.
+    # An explicit integer opts in to the spec window.
     eng = InferenceEngine.from_config(_cfg(TPU_SPEC_TOKENS="2"))
     try:
         assert eng.spec_tokens == 2
@@ -434,15 +473,15 @@ def test_from_config_auto_resolves_per_backend_and_logs():
 
 
 def test_spec_feature_precedence_both_directions():
-    # Direction 1: the IMPLICIT default yields — a deployment that
-    # enabled penalties (or top_logprobs) before spec defaulted on
-    # keeps booting, with spec auto-disabled and a note logged.
+    # Direction 1: the default never contradicts — a deployment with
+    # penalties (or top_logprobs) enabled boots at spec 0, and the note
+    # says an opt-in would not go alongside them.
     for extra in ({"TPU_PENALTIES": "true"}, {"TPU_TOP_LOGPROBS": "3"}):
         logger = _RecordingLogger()
         eng = InferenceEngine.from_config(_cfg(**extra), logger=logger)
         try:
             assert eng.spec_tokens == 0
-            assert any("default-on skipped" in ln for ln in logger.lines)
+            assert any("not alongside TPU_" in ln for ln in logger.lines)
         finally:
             eng.close()
     # Direction 2: an EXPLICIT contradiction the user typed still
