@@ -3,8 +3,9 @@
 A cell names a configuration and a traffic mix. The configuration is
 ``benchmark/configs/<config>.json`` (or the ``file`` its entry gives), the
 mix ``benchmark/traffic/<traffic>.json``, the mix's kind
-``benchmark/traffic_kinds/<kind>.py``, a per-layer metric
-``benchmark/layer_metrics/<metric>.json`` and its reader
+``benchmark/traffic_kinds/<kind>.py``, the plain reference
+``benchmark/reference/<reference>.py`` that the configuration's file names,
+a per-layer metric ``benchmark/layer_metrics/<metric>.json`` and its reader
 ``benchmark/readers/<reader>.py``. Adding any of them is adding a file and
 one entry; nothing here lists them.
 """
@@ -15,6 +16,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 from typing import Any
 
 from benchmark.harness.server import BENCHMARK, CHECKOUT, BenchFailure
@@ -26,10 +28,12 @@ def load_json(path: str) -> Any:
 
 
 def load_file(name: str, path: str) -> Any:
-    """Import a file that is not on a package path."""
+    """Import a file that is not on a package path, under ``name``: a
+    dataclass in it looks its own module up in ``sys.modules``."""
     spec = importlib.util.spec_from_file_location(name, path)
     assert spec is not None and spec.loader is not None
     module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module
     spec.loader.exec_module(module)
     return module
 
@@ -57,6 +61,35 @@ def metrics_of(entries: list, cell: str) -> list:
     return [m for m in entries if cell in m.get("workloads", [cell])]
 
 
+def beside_configs(config_path: str, kind: str, file_name: str) -> str:
+    """What belongs to a configuration lies where its file does:
+    ``<dir>/configs/x.json`` goes with ``<dir>/<kind>/<file_name>``
+    (``benchmark/``, or the tests' own directory)."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(config_path))),
+        kind, file_name,
+    )
+
+
+def reference_path(config_path: str, config: dict) -> str:
+    """The file of the plain reference that the configuration names. This
+    only looks: the module imports jax, so the child alone loads it."""
+    name = config.get("reference")
+    if not name:
+        raise BenchFailure(
+            f"{config_path} names no plain reference: it needs a key "
+            f'"reference", the name of a module '
+            f"{beside_configs(config_path, 'reference', '<name>.py')}"
+        )
+    path = beside_configs(config_path, "reference", f"{name}.py")
+    if not os.path.isfile(path):
+        raise BenchFailure(
+            f"{config_path} names the plain reference {name!r}: "
+            f"{path} is missing"
+        )
+    return path
+
+
 def load_cell(cells_file: str, workload: str) -> Cell:
     """``cells_file`` is ``BENCHMARK.json`` or a file of the same shape
     (the tests' rehearsal cells), relative to the checkout."""
@@ -73,13 +106,10 @@ def load_cell(cells_file: str, workload: str) -> Cell:
     config_path = os.path.join(CHECKOUT, config_entry["file"])
     config = dict(load_json(config_path))
     config["name"], config["path"] = entry["config"], config_path
-    # A cell's entry may name its mix only by ``traffic``, so the mix lies
-    # where its configuration does: ``<dir>/configs/x.json`` goes with
-    # ``<dir>/traffic/<traffic>.json`` (benchmark/, or the tests' own).
-    mix = load_json(os.path.join(
-        os.path.dirname(os.path.dirname(config_path)), "traffic",
-        f"{entry['traffic']}.json",
-    ))
+    reference_path(config_path, config)  # fails here, before any boot
+    mix = load_json(
+        beside_configs(config_path, "traffic", f"{entry['traffic']}.json")
+    )
     return Cell(
         name=workload, chips=int(entry["chips"]), config=config, mix=mix,
         kind=load_module("traffic_kinds", mix["kind"]),

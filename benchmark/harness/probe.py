@@ -4,10 +4,13 @@ compared with the plain reference as log-probabilities.
 After the window the parent sends ``PROBES`` seeded greedy requests
 (``PROMPT_TOKENS`` in, ``NEW_TOKENS`` out, ``logprobs: true``) through
 ``/v1/completions`` — prefill, then decoding through the cache and the
-speculative window — and posts prompt + emitted tokens to
-``/bench/reference``, which returns the reference's teacher-forced
-log-probability of each emitted token. Log-probabilities and not tokens:
-with random weights the largest logit changes on rounding.
+decode window — and posts prompt + emitted tokens to ``/bench/reference``,
+which returns the teacher-forced log-probability of each emitted token
+under the plain reference that the configuration's file names.
+Log-probabilities and not tokens: with random weights the largest logit
+changes on rounding. Which pieces the comparison must be shown to catch is
+the reference module's to say (its ``ABLATIONS``, asked for over ``GET
+/bench/reference``): nothing here names one.
 """
 
 from __future__ import annotations
@@ -37,12 +40,14 @@ NEW_TOKENS = 8
 #   * their median difference is at most MEDIAN_TOLERANCE, and
 #   * at least AGREEING_SHARE of them are within TOKEN_TOLERANCE;
 # and the comparison proves its own teeth in every run: the reference with
-# the causal mask, the binding window or one expert removed must fail the
-# same two conditions.
+# each of its pieces removed in turn (the causal mask, the binding window,
+# one expert, for the shared decoder) must fail the same two conditions.
+# A piece whose removal changes nothing at the probe's length (a window
+# longer than the probe) is skipped; a probe in which every piece was
+# skipped has shown no teeth and does not agree.
 TOKEN_TOLERANCE = 0.25
 MEDIAN_TOLERANCE = 0.08
 AGREEING_SHARE = 0.75
-ABLATIONS = ("causal", "window", "expert")
 
 
 def differences(served: list, reference: list) -> list:
@@ -93,13 +98,13 @@ def probe_reference(server: Server, config: dict, seed: int, vocab: int) -> dict
 
     diffs = differences(served, reference(""))
     ablated = {}
-    for ablate in ABLATIONS:
+    for ablate in server.get_json("/bench/reference")["ablations"]:
         got = reference(ablate)
         if all(g is not None for g in got):  # else it changes nothing here
             found = differences(served, got)
             ablated[ablate] = {**summary(found), "agrees": agrees(found)}
     return {
-        "agrees": agrees(diffs) and not any(
+        "agrees": agrees(diffs) and bool(ablated) and not any(
             found["agrees"] for found in ablated.values()
         ),
         **summary(diffs),
@@ -108,6 +113,30 @@ def probe_reference(server: Server, config: dict, seed: int, vocab: int) -> dict
         "ablated": ablated, "abs_diffs": sorted(diffs),
         "sequences": len(sequences),
     }
+
+
+def compared(probe: dict) -> dict:
+    """Each number of the probe that decides ``correct``, beside its limit."""
+    ablated = probe["ablated"]
+    out = {
+        "probe_median_nats": {
+            "value": probe["median"], "limit": MEDIAN_TOLERANCE, "rule": "<="},
+        "probe_share_within_token_tolerance": {
+            "value": probe["share_within_token_tolerance"],
+            "limit": AGREEING_SHARE, "rule": ">="},
+        "ablations_applied": {"value": len(ablated), "limit": 1, "rule": ">="},
+        "ablations_still_agreeing": {
+            "value": sum(found["agrees"] for found in ablated.values()),
+            "limit": 0, "rule": "=="},
+    }
+    for name, found in ablated.items():  # what "still agreeing" was read from
+        out[f"ablated_{name}_median_nats"] = {
+            "value": found["median"], "limit": MEDIAN_TOLERANCE,
+            "rule": "> or the share below"}
+        out[f"ablated_{name}_share_within_token_tolerance"] = {
+            "value": found["share_within_token_tolerance"],
+            "limit": AGREEING_SHARE, "rule": "< or the median above"}
+    return out
 
 
 def streamed_ids(server: Server, greedy: dict) -> list:

@@ -13,8 +13,9 @@ program:
   has no HTTP surface;
 * ``GET /bench/device``: platform, kind and count as JAX reports them, and
   the peak memory of the fullest chip;
-* ``POST /bench/reference``: the benchmark's own plain reference
-  (``benchmark/reference``) on this engine's weights.
+* ``/bench/reference``: the plain reference that the configuration's file
+  names (``cells.reference_path``) on this engine's weights. ``GET`` says
+  which pieces it can remove, ``POST`` runs it.
 
 This is the only process of a run that imports jax.
 """
@@ -34,8 +35,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 ))))
 
-from benchmark.harness.cells import load_file  # noqa: E402
-from benchmark.harness.server import ENTRY_POINT  # noqa: E402
+from benchmark.harness.cells import load_file, reference_path  # noqa: E402
+from benchmark.harness.server import ENTRY_POINT, check  # noqa: E402
 
 
 def register_configuration(config: dict) -> None:
@@ -53,6 +54,25 @@ def register_configuration(config: dict) -> None:
     ))
 
 
+def load_reference(config_path: str, config: dict) -> Any:
+    """The configuration's reference module, held to what the harness asks
+    of one: ``ABLATIONS`` and ``reference_logprobs``."""
+    path = reference_path(config_path, config)
+    module = load_file(f"bench_reference_{config['reference']}", path)
+    ablations = getattr(module, "ABLATIONS", None)
+    check(
+        isinstance(ablations, tuple) and ablations
+        and all(isinstance(a, str) and a for a in ablations),
+        f"{path}: ABLATIONS must be a non-empty tuple of names, the pieces "
+        f"the reference can remove; found {ablations!r}",
+    )
+    check(
+        callable(getattr(module, "reference_logprobs", None)),
+        f"{path}: no reference_logprobs(engine, sequences, n_prompt, ablate)",
+    )
+    return module
+
+
 def engines_of(app: Any) -> list:
     tpu = app.container.tpu
     if hasattr(tpu, "replicas"):
@@ -60,7 +80,7 @@ def engines_of(app: Any) -> list:
     return [tpu]
 
 
-def add_bench_routes(app: Any) -> None:
+def add_bench_routes(app: Any, reference: Any) -> None:
     from gofr_tpu.http.response import Raw
 
     @app.get("/bench/device")
@@ -81,28 +101,34 @@ def add_bench_routes(app: Any) -> None:
             ),
         }, status=200)
 
+    @app.get("/bench/reference")
+    async def ablations(ctx: Any) -> Raw:  # noqa: ARG001
+        return Raw({"ablations": list(reference.ABLATIONS)}, status=200)
+
     @app.post("/bench/reference")
-    async def reference(ctx: Any) -> Raw:
+    async def logprobs(ctx: Any) -> Raw:
         """{"sequences": [[ids]], "n_prompt": n, "ablate": ""} -> the plain
         reference's teacher-forced log-probability of every token after
-        the first ``n_prompt`` of each sequence."""
-        from benchmark.reference.adapter import reference_logprobs
-
+        the first ``n_prompt`` of each sequence; null for a sequence on
+        which removing ``ablate`` changes nothing."""
         body = json.loads(ctx.request.raw.body)
         engine = engines_of(app)[0]
         loop = asyncio.get_running_loop()
         out = await loop.run_in_executor(
-            None, reference_logprobs, engine, body["sequences"],
+            None, reference.reference_logprobs, engine, body["sequences"],
             int(body["n_prompt"]), body.get("ablate") or "",
         )
         return Raw({"logprobs": out}, status=200)
 
 
 def main() -> None:
-    with open(os.environ["BENCH_CONFIG_FILE"]) as fh:
-        register_configuration(json.load(fh))
+    config_path = os.environ["BENCH_CONFIG_FILE"]
+    with open(config_path) as fh:
+        config = json.load(fh)
+    reference = load_reference(config_path, config)  # before the engine boots
+    register_configuration(config)
     app = load_file("openai_server", ENTRY_POINT).main()
-    add_bench_routes(app)
+    add_bench_routes(app, reference)
 
     def arm_fence(signum: int, frame: Any) -> None:  # noqa: ARG001
         for engine in engines_of(app):

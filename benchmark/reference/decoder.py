@@ -1,5 +1,9 @@
-"""The plain reference: the decoder both configurations share, written
-from the published description.
+"""The plain reference that ``mistral-7b`` and ``mixtral-8x7b-d4`` name
+(``"reference": "decoder"`` in their files): one mathematics, written from
+the published description, together with its knowledge of the engine's
+weight tree. A configuration with other mathematics names a file of its
+own beside this one; the harness asks a reference module for two things,
+``ABLATIONS`` and ``reference_logprobs``, and nothing else.
 
 Mistral-7B (arXiv:2310.06825) and Mixtral-8x7B (arXiv:2401.04088): a
 pre-norm decoder of RMSNorm, rotary position embedding (half-split pairs,
@@ -14,7 +18,12 @@ Plain ``jax.numpy`` in float32 under ``default_matmul_precision("highest")``
 Python loop over layers and experts, no cache, no kernels, no batching, and
 no import from ``gofr_tpu``. Weights come through a ``Weights`` object one
 matrix at a time, so nothing larger than one feed-forward matrix is ever
-added to the chip.
+added to the chip. ``EngineWeights`` is the one place that knows how the
+engine keeps them: ``{"embed": [V, d], "layers": {name: [L, ...]},
+"final_norm": [d], "lm_head": [d, V]}``; a quantised leaf is a pair
+``(q int8, s float32)`` whose scale reduces the contraction (second to
+last) axis, so the float32 matrix is ``q * s``. Matrices are already
+[in, out]. Each call returns one float32 piece and keeps nothing.
 
 Departures from the publications: none in the mathematics. ``ablate``
 removes one piece on purpose — the tests and every probe use it to show
@@ -29,7 +38,8 @@ from typing import Any, Protocol
 import jax
 import jax.numpy as jnp
 
-ABLATIONS = ("", "causal", "window", "expert")
+# The pieces ``ablate`` can remove; "" removes none.
+ABLATIONS = ("causal", "window", "expert")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,7 +71,7 @@ class Shape:
             return 0 < self.sliding_window < length
         if ablate == "expert":
             return self.num_local_experts > 0 and self.num_experts_per_tok > 1
-        return ablate in ABLATIONS
+        return ablate in ("", *ABLATIONS)
 
 
 class Weights(Protocol):
@@ -132,8 +142,10 @@ _norm = jax.jit(rms_norm, static_argnames=("eps",))
 
 
 def hidden_states(weights: Weights, shape: Shape, tokens: list[int],
-                  causal: bool = True) -> Any:
-    """[s, d]: the residual stream after the last layer's final norm."""
+                  causal: bool = True, gated: Any = _swiglu) -> Any:
+    """[s, d]: the residual stream after the last layer's final norm.
+    ``gated(h, w_gate, w_up, w_down)`` is the feed-forward: SwiGLU here; a
+    reference with another gate passes its own."""
     eps = shape.rms_norm_eps
     x = weights.embed(jnp.asarray(tokens, jnp.int32))
     for layer in range(shape.num_hidden_layers):
@@ -149,13 +161,13 @@ def hidden_states(weights: Weights, shape: Shape, tokens: list[int],
                 h, weights.matrix("router", layer), k=shape.num_experts_per_tok
             )
             for e in range(shape.num_local_experts):
-                x = x + gates[:, e:e + 1] * _swiglu(
+                x = x + gates[:, e:e + 1] * gated(
                     h, weights.matrix("w_gate", layer, e),
                     weights.matrix("w_up", layer, e),
                     weights.matrix("w_down", layer, e),
                 )
         else:
-            x = x + _swiglu(
+            x = x + gated(
                 h, weights.matrix("w_gate", layer),
                 weights.matrix("w_up", layer), weights.matrix("w_down", layer),
             )
@@ -164,15 +176,16 @@ def hidden_states(weights: Weights, shape: Shape, tokens: list[int],
 
 def teacher_forced_logprobs(
     weights: Weights, shape: Shape, tokens: list[int], n_prompt: int,
-    ablate: str = "", head_block: int = 8192,
+    ablate: str = "", head_block: int = 8192, gated: Any = _swiglu,
 ) -> list[float]:
     """log p(tokens[t] | tokens[:t]) for every t >= n_prompt, from one
     full forward pass over the whole sequence."""
-    if ablate not in ABLATIONS:
+    if ablate and ablate not in ABLATIONS:
         raise ValueError(f"unknown ablation {ablate!r}; known: {ABLATIONS}")
     with jax.default_matmul_precision("highest"):
         x = hidden_states(
-            weights, shape.ablated(ablate), tokens, causal=ablate != "causal"
+            weights, shape.ablated(ablate), tokens, causal=ablate != "causal",
+            gated=gated,
         )
         x = x[n_prompt - 1: len(tokens) - 1]  # the positions that predict
         logits = jnp.concatenate([
@@ -183,3 +196,71 @@ def teacher_forced_logprobs(
         targets = jnp.asarray(tokens[n_prompt:], jnp.int32)
         picked = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
     return [float(v) for v in picked]
+
+
+def f32(leaf: Any, *index: int) -> Any:
+    """One float32 piece of a leaf, indexed along its leading axes."""
+    if hasattr(leaf, "q") and hasattr(leaf, "s"):
+        q, s = leaf.q, leaf.s
+        for i in index:
+            q, s = q[i], s[i]
+        return q.astype(jnp.float32) * s.astype(jnp.float32)
+    for i in index:
+        leaf = leaf[i]
+    return leaf.astype(jnp.float32)
+
+
+class EngineWeights:
+    def __init__(self, params: dict) -> None:
+        self.params = params
+
+    @property
+    def vocab(self) -> int:
+        return int(self.params["embed"].shape[0])
+
+    def embed(self, tokens: Any) -> Any:
+        return self.params["embed"][tokens].astype(jnp.float32)
+
+    def vector(self, name: str, layer: int = -1) -> Any:
+        if layer < 0:
+            return f32(self.params[name])
+        return f32(self.params["layers"][name], layer)
+
+    def matrix(self, name: str, layer: int, expert: int = -1) -> Any:
+        leaf = self.params["layers"][name]
+        return f32(leaf, layer) if expert < 0 else f32(leaf, layer, expert)
+
+    def head_columns(self, lo: int, hi: int) -> Any:
+        head = self.params["lm_head"]
+        if hasattr(head, "q"):
+            return head.q[:, lo:hi].astype(jnp.float32) * head.s[:, lo:hi]
+        return head[:, lo:hi].astype(jnp.float32)
+
+
+def shape_of(cfg: Any) -> Shape:
+    """The engine's config under the published names."""
+    return Shape(
+        num_hidden_layers=cfg.n_layers,
+        num_attention_heads=cfg.n_heads,
+        num_key_value_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim,
+        rope_theta=float(cfg.rope_theta),
+        rms_norm_eps=float(cfg.norm_eps),
+        sliding_window=int(cfg.sliding_window),
+        num_local_experts=int(cfg.n_experts),
+        num_experts_per_tok=int(cfg.n_experts_active) if cfg.n_experts else 0,
+    )
+
+
+def reference_logprobs(
+    engine: Any, sequences: list, n_prompt: int, ablate: str = "",
+) -> list:
+    """Per sequence, the reference's log-probability of every token after
+    the prompt; None for an ablation that changes nothing at this length."""
+    shape = shape_of(engine.cfg)
+    weights = EngineWeights(engine.params)
+    return [
+        teacher_forced_logprobs(weights, shape, list(seq), n_prompt, ablate)
+        if shape.applies(ablate, len(seq)) else None
+        for seq in sequences
+    ]

@@ -37,7 +37,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 from benchmark.harness import cells, prom, stats, trace as tr  # noqa: E402
 from benchmark.harness.loadgen import Window  # noqa: E402
-from benchmark.harness.probe import probe_reference  # noqa: E402
+from benchmark.harness.probe import compared, probe_reference  # noqa: E402
 from benchmark.harness.rundata import RunData  # noqa: E402
 from benchmark.harness.server import (  # noqa: E402
     ENTRY_POINT, BenchFailure, Server, check, device_of, log,
@@ -311,6 +311,14 @@ def run_cell(args: argparse.Namespace) -> dict:
         result["breakdown"] = cells.load_module(
             "readers", "trace_top_ops"
         ).read(run)
+    # What ``correct`` was decided from, each number beside its limit: last
+    # in the line and last on standard error.
+    result["compared"] = {
+        "failed_requests": {"value": len(failed), "limit": 0, "rule": "=="},
+        "compiled_in_window": {
+            "value": int(got.compiled_in_window), "limit": 0, "rule": "=="},
+        **compared(got.probe),
+    }
     with open(os.path.join(directory, f"result.trace{int(traced)}.json"), "w") as fh:
         json.dump({
             "result": result, "phases": got.phases, "samples": sample_counts,
@@ -357,6 +365,8 @@ def main() -> int:
     except BenchFailure as exc:
         log(f"FAILED: {exc}")
         return 1
+    for name, found in result["compared"].items():
+        log(f"compared {name}: {found['value']} {found['rule']} {found['limit']}")
     print(json.dumps(result), flush=True)
     return 0
 

@@ -1,11 +1,12 @@
 """The yardstick's arithmetic, on hand-made inputs."""
 
 import collections
+import json
 
 import pytest
 
 import bench_paths  # noqa: F401
-from benchmark.harness import cells, peaks, prom, stats, traffic
+from benchmark.harness import cells, peaks, probe, prom, stats, traffic
 from benchmark.harness.loadgen import Record
 from benchmark.harness.rundata import RunData
 from benchmark.harness.server import BenchFailure, device_of
@@ -195,3 +196,50 @@ def test_a_run_off_the_chip_ends_before_any_result():
         device_of(FakeServer("cpu"), 1, "tpu")
     with pytest.raises(BenchFailure, match="need 4 tpu"):
         device_of(FakeServer("tpu"), 4, "tpu")
+
+
+class ProbedServer:
+    """Serves -1.0 for every token; its reference says ``reads`` for the
+    plain mathematics and for each ablation (None: it changes nothing at
+    this length)."""
+
+    def __init__(self, reads):
+        self.reads = reads
+
+    def get_json(self, path, **kw):
+        assert path == "/bench/reference"
+        return {"ablations": [name for name in self.reads if name]}
+
+    def post_json(self, path, body, **kw):
+        if path == "/bench/reference":
+            read = self.reads[body["ablate"]]
+            return {"logprobs": [
+                None if read is None else [read] * (len(seq) - body["n_prompt"])
+                for seq in body["sequences"]
+            ]}
+        n = body["max_tokens"]
+        return {"choices": [{"logprobs": {"token_logprobs": [-1.0] * n}}]}
+
+    def request(self, method, path, body=None, **kw):
+        chunk = {"choices": [{"token_ids": [7] * body["max_tokens"]}]}
+        return 200, f"data: {json.dumps(chunk)}\n\ndata: [DONE]\n".encode()
+
+
+@pytest.mark.parametrize("reads,agrees,ablated", [
+    # the reference agrees and each piece that applies is caught
+    ({"": -1.01, "mask": -4.0, "gate": None}, True, {"mask"}),
+    # the configuration's own ablation is asked for by the name it gave,
+    # and one that still agrees fails the probe
+    ({"": -1.01, "mask": -4.0, "gate": -1.02}, False, {"mask", "gate"}),
+    # no piece applied: the comparison showed no teeth
+    ({"": -1.01, "mask": None, "gate": None}, False, set()),
+    ({"": -3.0, "mask": -4.0}, False, {"mask"}),
+])
+def test_probe_asks_for_the_references_own_ablations(reads, agrees, ablated):
+    found = probe.probe_reference(ProbedServer(reads), {}, 2**31 + 3, 512)
+    assert found["agrees"] is agrees and set(found["ablated"]) == ablated
+    assert found["sequences"] == probe.PROBES
+    numbers = probe.compared(found)
+    assert numbers["ablations_applied"]["value"] == len(ablated)
+    assert numbers["probe_median_nats"]["limit"] == probe.MEDIAN_TOLERANCE
+    assert {f"ablated_{a}_median_nats" for a in ablated} <= set(numbers)

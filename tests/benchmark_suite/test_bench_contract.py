@@ -1,15 +1,17 @@
 """``BENCHMARK.json`` against its contract, and every cell from its files:
 adding a cell is adding files and one entry."""
 
+import dataclasses
 import json
 import os
 import re
 
 import pytest
 
-from bench_paths import CHECKOUT
+from bench_paths import CHECKOUT, SUITE
 from benchmark.harness import cells, stats
 from benchmark.harness.loadgen import Record
+from benchmark.harness.server import BenchFailure
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -20,10 +22,14 @@ WIDTHS = re.compile(
 )
 
 
-@pytest.fixture(scope="module")
-def bench():
+def load_bench():
     with open(os.path.join(CHECKOUT, "BENCHMARK.json")) as fh:
         return json.load(fh)
+
+
+@pytest.fixture(scope="module")
+def bench():
+    return load_bench()
 
 
 def test_names_units_sources_and_limits(bench):
@@ -97,31 +103,101 @@ def test_every_cell_loads_from_its_files_and_nothing_else(bench):
         assert hasattr(cells.load_module("readers", spec["reader"]), "read")
 
 
-def test_published_widths_are_kept(bench):
-    published = {
-        "mistral-7b": dict(hidden_size=4096, intermediate_size=14336,
-                           num_attention_heads=32, num_key_value_heads=8,
-                           num_hidden_layers=32, vocab_size=32000,
-                           sliding_window=4096, rope_theta=10000.0),
-        "mixtral-8x7b-d4": dict(hidden_size=4096, intermediate_size=14336,
-                                num_attention_heads=32, num_key_value_heads=8,
-                                num_local_experts=8, num_experts_per_tok=2,
-                                vocab_size=32000, rope_theta=1000000.0),
-    }
+# The widths every configuration shows, under the names its source
+# publishes them by; the feed-forward width goes by one of several.
+WIDTHS_SHOWN = ("hidden_size", "num_attention_heads", "num_key_value_heads",
+                "vocab_size")
+FEED_FORWARD_WIDTHS = ("intermediate_size", "moe_intermediate_size")
+
+
+def configurations():
+    """One case a configuration: a new entry is a new case."""
+    return [pytest.param(c, id=c["name"]) for c in load_bench()["configs"]]
+
+
+@pytest.mark.parametrize("entry", configurations())
+def test_published_widths_are_kept(entry):
+    """A configuration's file against ``published/<name>.json``, the keys of
+    its source's own ``config.json`` verbatim, and against the program
+    through the file's own map ``program_keys``: this test names no
+    configuration, no size and no field of the program's config."""
+    with open(os.path.join(CHECKOUT, entry["file"])) as fh:
+        config = json.load(fh)
+    path = os.path.join(SUITE, "published", f"{entry['name']}.json")
+    assert os.path.isfile(path), (
+        f"configuration {entry['name']!r} has no published sizes: add {path}, "
+        f"the keys of {entry['source']} verbatim, with that URL as \"source\""
+    )
+    with open(path) as fh:
+        published = json.load(fh)
+    assert published["source"] == entry["source"] == config["source"]
+    for key, value in published.items():
+        if key in config.get("reduced", {}):
+            assert config["reduced"][key]["published"] == value, key
+        elif key in config:
+            assert config[key] == value, (entry["name"], key)
+    shown = [*WIDTHS_SHOWN, *(["head_dim"] if "head_dim" in published else [])]
+    for key in shown:
+        assert key in published and key in config, (entry["name"], key)
+    assert any(k in published and k in config for k in FEED_FORWARD_WIDTHS)
+    if "head_dim" in config and "head_dim" not in published:
+        assert config["head_dim"] * config["num_attention_heads"] == config["hidden_size"]
+    # ... and the program agrees: the registry entry the file builds on,
+    # after its overrides, field by field of the file's own map.
     from gofr_tpu.models.registry import get_model
 
-    for c in bench["configs"]:
-        with open(os.path.join(CHECKOUT, c["file"])) as fh:
-            config = json.load(fh)
-        for key, value in published[c["name"]].items():
-            assert config[key] == value, (c["name"], key)
-        # ... and the program's registry entry the file builds on agrees.
-        program = get_model(config["base"]).config
-        assert (program.d_model, program.d_ff, program.n_heads,
-                program.n_kv_heads, program.vocab_size, program.head_dim) == (
-            config["hidden_size"], config["intermediate_size"],
-            config["num_attention_heads"], config["num_key_value_heads"],
-            config["vocab_size"], 128)
-        assert program.n_experts == config.get("num_local_experts", 0)
-        assert program.sliding_window == (config["sliding_window"] or 0)
-        assert program.rope_theta == config["rope_theta"]
+    program = dataclasses.replace(
+        get_model(config["base"]).config, **config["overrides"]
+    )
+    mapped = config["program_keys"]
+    feed_forward = [k for k in FEED_FORWARD_WIDTHS if k in config]
+    assert set(shown) | set(feed_forward) | set(config.get("reduced", {})) <= set(mapped)
+    for key, field in mapped.items():
+        # JSON's null is the program's 0: the mechanism is off
+        assert getattr(program, field) == (config[key] or 0), (key, field)
+
+
+@pytest.mark.parametrize("entry", configurations())
+def test_the_child_can_load_the_reference_a_configuration_names(entry):
+    """As the server child does before the engine boots: the module is
+    found through the configuration's file and has the two things the
+    harness asks of one."""
+    from benchmark.harness.serve_child import load_reference
+
+    path = os.path.join(CHECKOUT, entry["file"])
+    with open(path) as fh:
+        reference = load_reference(path, json.load(fh))
+    assert reference.ABLATIONS and callable(reference.reference_logprobs)
+
+
+def cells_file_with(tmp_path, **changes):
+    """A cells file of one tiny cell whose configuration file, a copy under
+    ``tmp_path``, has ``changes`` applied (None removes the key)."""
+    with open(os.path.join(SUITE, "rehearsal_cells.json")) as fh:
+        bench = json.load(fh)
+    entry = bench["configs"][0]
+    with open(os.path.join(CHECKOUT, entry["file"])) as fh:
+        config = {**json.load(fh), **changes}
+    (tmp_path / "configs").mkdir()
+    entry["file"] = str(tmp_path / "configs" / "copy.json")
+    with open(entry["file"], "w") as fh:
+        json.dump({k: v for k, v in config.items() if v is not None}, fh)
+    with open(tmp_path / "cells.json", "w") as fh:
+        json.dump(bench, fh)
+    cell = next(w["name"] for w in bench["workloads"] if w["config"] == entry["name"])
+    return str(tmp_path / "cells.json"), cell
+
+
+@pytest.mark.parametrize("changes,says", [
+    ({"reference": None}, 'it needs a key "reference"'),
+    ({"reference": "no_such_mathematics"}, "no_such_mathematics.py is missing"),
+])
+def test_a_configuration_without_its_reference_is_refused_before_any_boot(
+    tmp_path, changes, says,
+):
+    cells_file, cell = cells_file_with(tmp_path, **changes)
+    with pytest.raises(BenchFailure) as refused:
+        cells.load_cell(cells_file, cell)
+    # the message gives the path it looked for, beside the configuration
+    assert says in str(refused.value)
+    assert str(tmp_path / "reference") in str(refused.value)

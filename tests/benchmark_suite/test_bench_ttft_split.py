@@ -98,7 +98,9 @@ def test_the_new_metrics_files_agree_with_both_cells_files():
         for stem in BY_CELL:
             assert per_layer[f"{stem}.chat"]["workloads"] == [chat]
             assert per_layer[f"{stem}.batch"]["workloads"] == [batch]
-            assert per_layer[f"{stem}.chat"]["moves"] == "tpot_p90_ms"
+            # both paces are judged by their median since PR 27; the split
+            # by cell stays, so each cell's reading keeps a name of its own
+            assert per_layer[f"{stem}.chat"]["moves"] == "tpot_p50_ms"
             assert per_layer[f"{stem}.batch"]["moves"] == "tpot_p50_ms"
         for name in EVERYWHERE | {f"{s}.{c}" for s in BY_CELL
                                   for c in ("chat", "batch")}:
