@@ -349,6 +349,17 @@ class Container:
             "slots, one record per processed window", ratio_buckets,
         )
         m.new_histogram(
+            "app_tpu_kv_live_ratio",
+            "cache positions that were context when a decode window was "
+            "dispatched (the live slots' lengths) / slots x max_len, one "
+            "record per processed window", ratio_buckets,
+        )
+        m.new_gauge(
+            "app_tpu_kv_bytes_per_token",
+            "KV-cache bytes one token holds (all cache entries, keys and "
+            "values, scales included): what an operator sizes slots by",
+        )
+        m.new_histogram(
             "app_tpu_prefill_fill_ratio",
             "prompt tokens in a prefill chunk step / its TPU_PREFILL_"
             "BATCH x TPU_PREFILL_CHUNK token rows, one record per step",

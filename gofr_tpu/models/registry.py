@@ -88,6 +88,17 @@ def _register_llms() -> None:
             n_kv_heads=8, d_ff=14336, max_len=8192, rope_theta=10000.0,
             sliding_window=4096,
         ),
+        # Ouro-2.6B (ByteDance, config.json): a LOOPED decoder — the 48
+        # layers run 4 times over one set of weights, the final norm after
+        # every pass, sandwich norms on both sublayers, MHA, untied head.
+        # 192 cache entries a token (1.5 MiB in bf16, twelve times
+        # mistral-7b's) in a 2.67 B-parameter model: docs/advanced-guide/
+        # looped-models.md has the slot arithmetic.
+        "ouro-2.6b": TransformerConfig(
+            vocab_size=49152, d_model=2048, n_layers=48, n_heads=16,
+            n_kv_heads=16, d_ff=5632, max_len=65536, rope_theta=1e6,
+            norm_eps=1e-6, n_passes=4, post_norm=True,
+        ),
         # Qwen2-7B dims (HF loader accepts model_type=qwen2; QKV bias).
         "qwen2-7b": TransformerConfig(
             vocab_size=152064, d_model=3584, n_layers=28, n_heads=28,
@@ -150,6 +161,13 @@ def _register_llms() -> None:
             n_kv_heads=12, d_ff=3072, max_len=1024, norm="ln",
             ffn="mlp", act="gelu", attn_bias=True, proj_bias=True,
             pos_emb="learned",
+        ),
+        # Looped-arch test size: 2 layers run 3 times (6 cache entries),
+        # sandwich norms.
+        "looped-tiny": TransformerConfig(
+            vocab_size=512, d_model=64, n_layers=2, n_heads=4,
+            n_kv_heads=4, d_ff=128, max_len=256, rope_theta=10000.0,
+            norm_eps=1e-6, n_passes=3, post_norm=True,
         ),
         # GPT-2-arch test size (learned positions).
         "gpt2-tiny": TransformerConfig(

@@ -88,10 +88,35 @@ class TransformerConfig:
     # stores max_len positions; the window is a masking contract, which
     # is what lets max_len exceed the window.
     sliding_window: int = 0
+    # Looped (universal-transformer) stack: the n_layers weight layers run
+    # n_passes times over one set of weights, the shared final norm applied
+    # after every pass. Each pass keeps its own keys and values in each
+    # layer, so the cache holds n_passes * n_layers entries a token
+    # (``n_cache_entries``). ``exit_threshold`` is the cumulative exit
+    # probability at which a step would leave the stack early; only 1.0
+    # (every pass runs) is served — the engine refuses anything lower.
+    n_passes: int = 1
+    exit_threshold: float = 1.0
+    # Sandwich norms: a second norm on each sublayer's OUTPUT, before the
+    # residual add (``attn_post_norm`` / ``mlp_post_norm`` leaves).
+    post_norm: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def n_cache_entries(self) -> int:
+        """Leading axis of the KV cache: one entry a layer APPLICATION."""
+        return self.n_layers * self.n_passes
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Unquantised cache bytes one token holds (keys and values)."""
+        return (
+            self.n_cache_entries * 2 * self.n_kv_heads * self.head_dim
+            * jnp.dtype(self.dtype).itemsize
+        )
 
     @property
     def rope_dims(self) -> int:
@@ -106,6 +131,25 @@ class TransformerConfig:
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
+
+
+def norm_init(name: str, shape: tuple, cfg: TransformerConfig) -> jnp.ndarray:
+    """Initial scale of the norm leaf ``name``: identity, except a sandwich
+    norm's, which starts at (2 L)^-0.5.
+
+    The depth scaling that GPT-2 and Megatron give a residual branch's
+    output projection would be erased by a norm on the branch's output, so
+    it goes on that norm's scale: the 2 L branches of one pass then add
+    unit variance to the stream together. At scale 1 every branch adds a
+    unit-rms vector to a stream that a looped stack's pass norm has just
+    brought back to rms 1, and seeded random weights at the published
+    sizes (48 layers x 4 passes) amplify a bfloat16 rounding, wherever it
+    is made, into 0.2-0.4 nats at the logits (PERF.md section 6, PR 28):
+    no precision short of float32 then agrees with the reference. Scales
+    of ``norm_offset`` models are stored less 1.
+    """
+    scale = (2 * cfg.n_layers) ** -0.5 if name.endswith("_post_norm") else 1.0
+    return jnp.full(shape, scale - (1.0 if cfg.norm_offset else 0.0), cfg.dtype)
 
 
 def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
@@ -134,14 +178,17 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
         "wo": dense_init(ks[3], (L, H * hd, D), H * hd),
         # norm_offset models (Gemma) store w with the +1 applied in the
         # forward, so identity init is zeros there, ones otherwise.
-        "attn_norm": jnp.full((L, D), 0.0 if cfg.norm_offset else 1.0, cfg.dtype),
-        "mlp_norm": jnp.full((L, D), 0.0 if cfg.norm_offset else 1.0, cfg.dtype),
+        "attn_norm": norm_init("attn_norm", (L, D), cfg),
+        "mlp_norm": norm_init("mlp_norm", (L, D), cfg),
     }
     if cfg.norm == "ln":
         layers.update(
             attn_norm_b=jnp.zeros((L, D), dtype=cfg.dtype),
             mlp_norm_b=jnp.zeros((L, D), dtype=cfg.dtype),
         )
+    if cfg.post_norm:
+        for name in ("attn_post_norm", "mlp_post_norm"):
+            layers[name] = norm_init(name, (L, D), cfg)
     if cfg.proj_bias:
         layers.update(
             wo_b=jnp.zeros((L, D), dtype=cfg.dtype),
@@ -176,9 +223,7 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
     out = {
         "embed": dense_init(k_embed, (cfg.vocab_size, D), D),
         "layers": layers,
-        "final_norm": jnp.full(
-            (D,), 0.0 if cfg.norm_offset else 1.0, cfg.dtype
-        ),
+        "final_norm": norm_init("final_norm", (D,), cfg),
         "lm_head": dense_init(k_head, (D, cfg.vocab_size), D),
     }
     if cfg.norm == "ln":
@@ -187,6 +232,12 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
         out["pos_embed"] = dense_init(
             jax.random.fold_in(k_embed, 1), (cfg.max_len, D), D
         )
+    if cfg.n_passes > 1:
+        # The per-pass exit gate, sigmoid(u · w + b). Carried in the tree
+        # for checkpoints and the reference; the serving programs never
+        # read it while exit_threshold is 1 (it cannot change a logit).
+        out["exit_gate_w"] = dense_init(jax.random.fold_in(k_head, 1), (D, 1), D)
+        out["exit_gate_b"] = jnp.zeros((1,), dtype=cfg.dtype)
     return out
 
 
@@ -202,6 +253,12 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
     shards over the pipeline axis — each stage owns a contiguous slice of
     layers (see ``parallel/pipeline.py``).
     """
+    if pp and cfg.n_passes > 1:
+        raise ValueError(
+            f"pipeline-parallel parameter specs are not implemented for a "
+            f"looped stack (n_passes={cfg.n_passes}): every stage would "
+            f"need every pass's activations"
+        )
     lax_ = "pp" if pp else None  # leading (layer) axis of stacked leaves
     layers = {
         "wq": P(lax_, None, "tp"),
@@ -219,6 +276,8 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
         )
     if cfg.norm == "ln":
         layers.update(attn_norm_b=P(lax_, None), mlp_norm_b=P(lax_, None))
+    if cfg.post_norm:
+        layers.update(attn_post_norm=P(lax_, None), mlp_post_norm=P(lax_, None))
     if cfg.proj_bias:
         # Row-parallel outputs (wo, w_down) have replicated biases; the
         # column-parallel up-projection bias shards with its outputs.
@@ -255,14 +314,17 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
         out["final_norm_b"] = P(None)
     if cfg.pos_emb == "learned":
         out["pos_embed"] = P(None, None)
+    if cfg.n_passes > 1:
+        out["exit_gate_w"] = P(None, None)
+        out["exit_gate_b"] = P(None)
     return out
 
 
 def kv_cache_specs(
     quantized: bool = False, paged: bool = False, cp: bool = False
 ):
-    """Cache layout [L, slots|blocks, kv_heads, len|block, hd]: kv_heads
-    over ``tp``. Int8 mode adds per-position scales whose kv_heads axis
+    """Cache layout [entries, slots|blocks, kv_heads, len|block, hd]
+    (entries = ``cfg.n_cache_entries``, replicated): kv_heads over ``tp``. Int8 mode adds per-position scales whose kv_heads axis
     shards the same way; the paged pool shards identically (axis 2) with
     a replicated block table.
 
@@ -442,12 +504,61 @@ def _norm(x, w, cfg, b=None):
     return rms_norm(x, w, cfg.norm_eps, 1.0 if cfg.norm_offset else 0.0)
 
 
+def _post_norm(out, lp, name, cfg):
+    """Sandwich norm on a sublayer's output (``cfg.post_norm``); the
+    leaf's absence from the config is trace-time static."""
+    return _norm(out, lp[name], cfg) if cfg.post_norm else out
+
+
+def _scan_stack(body, x, params, cfg, cache_xs=()):
+    """Run the layer stack: ``body(x, (lp, *cache_slices)) -> (x, ys)``
+    over the stacked layers, ``cfg.n_passes`` times over the one set of
+    weights.
+
+    One pass — every model but a looped one — is the plain ``lax.scan``
+    over ``(layers, *cache_xs)``, the program it always was. A looped
+    stack stays ONE scan, over the n_passes * n_layers cache entries that
+    ``cache_xs`` lead with (entry ``t * L + l`` is pass t's layer l, so a
+    pass reads and writes only its own keys and values): step i takes
+    layer ``i % L``'s weights out of the stacked leaves, which is what a
+    scan does with its xs anyway, and the shared final norm ends every
+    pass but the last (the caller's own final norm ends that one). The
+    cache rides xs → ys exactly as it does for one pass, so a looped
+    model costs the prefill program no further copy of it.
+    """
+    layers = params["layers"]
+    if cfg.n_passes == 1:
+        return jax.lax.scan(body, x, (layers, *cache_xs))
+    L, n = cfg.n_layers, cfg.n_cache_entries
+
+    def pass_norm(x):
+        return _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
+
+    def step(x, scanned):
+        i, cache_slices = scanned
+        l = i % L
+        lp = jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False),
+            layers,
+        )
+        with jax.named_scope("pass"):
+            x, ys = body(x, (lp, *cache_slices))
+            with jax.named_scope("pass_norm"):
+                x = jax.lax.cond(
+                    (l == L - 1) & (i < n - 1), pass_norm, lambda x: x, x
+                )
+        return x, ys
+
+    return jax.lax.scan(step, x, (jnp.arange(n), tuple(cache_xs)))
+
+
 # The serving steps below run under ``jax.named_scope`` with a fixed
 # vocabulary — embed, attn, kv_commit, ffn, moe_router, moe_experts,
-# lm_head here; sample, draft, verify in serving/programs.py — so that an
-# op in the profiler's trace says which part of the model it belongs to
-# (its ``tf_op`` reads ``jit(spec_window)/…/attn/dot_general``). Compile-
-# time metadata only: no shape, value or fusion depends on it.
+# lm_head here, pass and pass_norm around them in a looped stack; sample,
+# draft, verify in serving/programs.py — so that an op in the profiler's
+# trace says which part of the model it belongs to (its ``tf_op`` reads
+# ``jit(spec_window)/…/attn/dot_general``). Compile-time metadata only: no
+# shape, value or fusion depends on it.
 
 
 @jax.named_scope("embed")
@@ -576,6 +687,7 @@ def _layer_prefill(x, lp, cfg, cos, sin, positions, mask, attn_fn=None,
     attn_out = _wein("bsh,hd->bsd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
     if "wo_b" in lp:
         attn_out = attn_out + lp["wo_b"]
+    attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
 
     # Parallel residual (GPT-NeoX): both branches read the SAME input;
     # sequential (default): the MLP reads the attention-updated stream.
@@ -584,6 +696,7 @@ def _layer_prefill(x, lp, cfg, cos, sin, positions, mask, attn_fn=None,
     if norm_out is not None:
         h = norm_out(h)
     ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(h, lp, cfg, aids)
+    ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
     if cfg.parallel_residual:
         return x + attn_out + ffn, (k, v)
     return mlp_in + ffn, (k, v)
@@ -608,15 +721,15 @@ def transformer_forward(
     x = _embed(params, tokens, cfg, positions)
     cos, sin = rope_frequencies(cfg.rope_dims, s, cfg.rope_theta)
 
-    def body(x, lp):
+    def body(x, scanned):
         out, _ = _layer_prefill(
-            x, lp, cfg, cos, sin, positions, mask=None, aids=aids
+            x, scanned[0], cfg, cos, sin, positions, mask=None, aids=aids
         )
         return out, None
 
     if remat:
         body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = _scan_stack(body, x, params, cfg)
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     return _lm_head("bsd,dv->bsv", x, params)
 
@@ -644,14 +757,14 @@ def transformer_prefill(
     # dense O(s²) masked softmax (VERDICT r1 weak #3).
     lengths = lengths.astype(jnp.int32)
 
-    def body(x, lp):
+    def body(x, scanned):
         out, kv = _layer_prefill(
-            x, lp, cfg, cos, sin, positions, mask=None, lengths=lengths,
-            aids=aids,
+            x, scanned[0], cfg, cos, sin, positions, mask=None,
+            lengths=lengths, aids=aids,
         )
         return out, kv
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+    x, (ks, vs) = _scan_stack(body, x, params, cfg)
     # ks: [L, b, s, KV, hd] → heads-major [L, b, KV, s, hd], pad the seq dim
     # to max_len, write each sequence's prefix into its slot.
     pad_len = cache.max_len - s
@@ -783,16 +896,18 @@ def transformer_prefill_chunk(
             )
             if "wo_b" in lp:
                 attn_out = attn_out + lp["wo_b"]
+            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
             h, lp, cfg, aids
         )
+        ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
         return x, (ck, cv, cks, cvs)
 
-    x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v, cache.k_s, cache.v_s)
+    x, (new_k, new_v, new_ks, new_vs) = _scan_stack(
+        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
     )
     cache = cache._replace(k=new_k, v=new_v, k_s=new_ks, v_s=new_vs)
 
@@ -821,7 +936,7 @@ def transformer_decode_step(
     Returns ([n_slots, vocab] logits, updated cache).
     """
     S = cache.n_slots
-    L = cfg.n_layers
+    L = cfg.n_cache_entries  # a looped stack commits every pass's entry
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = cache.lengths  # [S] — write position for each slot's new token
     x = _embed(params, tokens, cfg, positions)  # [S, D]
@@ -874,6 +989,7 @@ def transformer_decode_step(
             )
             if "wo_b" in lp:
                 attn_out = attn_out + lp["wo_b"]
+            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(
             mlp_in[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b")
@@ -881,14 +997,15 @@ def transformer_decode_step(
         ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
             h, lp, cfg, aids
         )
+        ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         if cfg.parallel_residual:
             x = x + attn_out + ffn[:, 0]
         else:
             x = mlp_in + ffn[:, 0]
         return x, (k, v)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v, cache.k_s, cache.v_s)
+    x, (new_k, new_v) = _scan_stack(
+        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
     )
     # Commit every layer's token in one scatter: [L, S, KV, hd] values at
     # [l, s, kv, write_pos[s]] (slot cache) or [l, table[s, p//B], kv,
@@ -981,16 +1098,18 @@ def transformer_verify_step(
             )
             if "wo_b" in lp:
                 attn_out = attn_out + lp["wo_b"]
+            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
             h, lp, cfg, aids
         )
+        ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
         return x, (k, v)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache.k, cache.v, cache.k_s, cache.v_s)
+    x, (new_k, new_v) = _scan_stack(
+        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
     )
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     return _lm_head("bcd,dv->bcv", x, params), new_k, new_v

@@ -1,11 +1,13 @@
 """Slot-based KV cache for autoregressive decode (net-new; SURVEY §7 hard
 part #3: persistent device state across requests).
 
-Layout: ``[n_layers, n_slots, n_kv_heads, max_len, head_dim]`` — heads-major,
+Layout: ``[n_entries, n_slots, n_kv_heads, max_len, head_dim]`` — heads-major,
 the TPU-native choice: the flash-decode kernel's per-head blocks
 ``[block_k, head_dim]`` tile directly onto the (8, 128) VMEM layout (a
 heads-minor cache would need 1-sized blocks on the second-to-last dim,
-which pallas cannot tile). The slot axis is the decode batch axis (decode
+which pallas cannot tile). ``n_entries`` is the model's
+``n_cache_entries``: one entry a layer, or one a layer and pass for a looped
+stack. The slot axis is the decode batch axis (decode
 runs over ALL slots each step — static shapes, no gather/scatter), per-step
 writes are position-local scatters, and the kv_heads axis shards over the
 tensor-parallel mesh axis without resharding between prefill and decode.
@@ -15,7 +17,7 @@ scale per (layer, slot, head, position) — decode streams the cache from
 HBM at half the bytes and the cache footprint stops bounding slot count
 at ``max_len × n_slots`` bf16 (VERDICT r2 next #9: an 8B model's bf16
 cache is ~2 GB/slot at 8k context; int8 + scales is ~1.2 GB). Scale
-layout is ``[n_layers, n_slots, n_kv_heads, 8, max_len]`` — the scale
+layout is ``[n_entries, n_slots, n_kv_heads, 8, max_len]`` — the scale
 vector a kernel needs per kv block is positions-along-lanes, and the
 8-wide replicated sublane axis makes the block ``(8, block_k)``, an
 exact f32 VMEM tile (a bare ``[block_k]`` vector block cannot tile).
@@ -51,7 +53,7 @@ class KVCache(NamedTuple):
     @classmethod
     def create(
         cls,
-        n_layers: int,
+        n_entries: int,
         n_slots: int,
         max_len: int,
         n_kv_heads: int,
@@ -59,9 +61,9 @@ class KVCache(NamedTuple):
         dtype: Any = jnp.bfloat16,
         quant: str = "",
     ) -> "KVCache":
-        shape = (n_layers, n_slots, n_kv_heads, max_len, head_dim)
+        shape = (n_entries, n_slots, n_kv_heads, max_len, head_dim)
         if (quant or "").lower() == "int8":
-            sshape = (n_layers, n_slots, n_kv_heads, 8, max_len)
+            sshape = (n_entries, n_slots, n_kv_heads, 8, max_len)
             return cls(
                 k=jnp.zeros(shape, dtype=jnp.int8),
                 v=jnp.zeros(shape, dtype=jnp.int8),
@@ -133,7 +135,7 @@ class PagedKVCache(NamedTuple):
     @classmethod
     def create(
         cls,
-        n_layers: int,
+        n_entries: int,
         n_slots: int,
         max_len: int,
         n_kv_heads: int,
@@ -151,10 +153,10 @@ class PagedKVCache(NamedTuple):
         max_blocks = max_len // block
         if n_blocks <= 0:
             n_blocks = n_slots * max_blocks + 1  # +1: parking block 0
-        shape = (n_layers, n_blocks, n_kv_heads, block, head_dim)
+        shape = (n_entries, n_blocks, n_kv_heads, block, head_dim)
         table = jnp.zeros((n_slots, max_blocks), dtype=jnp.int32)
         if (quant or "").lower() == "int8":
-            sshape = (n_layers, n_blocks, n_kv_heads, 8, block)
+            sshape = (n_entries, n_blocks, n_kv_heads, 8, block)
             return cls(
                 k=jnp.zeros(shape, dtype=jnp.int8),
                 v=jnp.zeros(shape, dtype=jnp.int8),

@@ -265,6 +265,22 @@ class InferenceEngine(
                 "exclusive (the verify step has no per-emission "
                 "alternatives plane)"
             )
+        n_passes = getattr(self.cfg, "n_passes", 1)
+        if n_passes > 1 and self.cfg.exit_threshold < 1.0:
+            raise ValueError(
+                f"{model_name}: exit_threshold={self.cfg.exit_threshold} "
+                f"< 1 is not served: slots would leave the {n_passes}-pass "
+                "stack at different passes, and the decode window and the "
+                "scheduler run every slot to the same depth (only 1.0, "
+                "every pass, is implemented)"
+            )
+        if n_passes > 1 and spec_tokens > 0:
+            raise ValueError(
+                f"{model_name}: TPU_SPEC_TOKENS={spec_tokens} is not "
+                f"served for a looped stack (n_passes={n_passes}): the "
+                "speculative window was never run against per-pass cache "
+                "entries; leave TPU_SPEC_TOKENS at auto (0)"
+            )
         self.tokenizer = tokenizer
         # GSPMD-sharded serving (TPU_TP): a caller may hand a pre-built
         # mesh (dryruns, tests composing tp×cp), or just a tp degree —
@@ -488,6 +504,7 @@ class InferenceEngine(
         self._obs = RequestObservability(
             model_name,
             metrics=metrics,
+            passes=n_passes,
             recorder=(
                 FlightRecorder(
                     capacity=max(1, flight_records),
@@ -1381,6 +1398,7 @@ class InferenceEngine(
         transient inside its own jit, so an 8B tree peaks near its
         quantized footprint."""
         jax, jnp = self._jax, self._jnp
+        from gofr_tpu.models.transformer import norm_init
         from gofr_tpu.ops.quant import (
             _QUANT_KEYS,
             quantize_array,
@@ -1401,14 +1419,14 @@ class InferenceEngine(
         def make(name: str, sds: Any) -> Any:
             counter[0] += 1
             key = jax.random.fold_in(base, counter[0])
-            if name in ("attn_norm", "mlp_norm", "final_norm"):
-                # (1+w) norm models (Gemma) use zeros as identity.
-                return jnp.full(
-                    sds.shape, 0.0 if cfg.norm_offset else 1.0, cfg.dtype
-                )
+            if name.endswith("_norm"):
+                return norm_init(name, sds.shape, cfg)
             if name.endswith("_b"):  # QKV biases: zeros, as init_transformer
                 return jnp.zeros(sds.shape, cfg.dtype)
-            fan_in = sds.shape[-1] if name == "embed" else sds.shape[-2]
+            fan_in = (
+                sds.shape[-1] if name in ("embed", "pos_embed")
+                else sds.shape[-2]
+            )
 
             def init_leaf(k: Any) -> Any:
                 w = (
@@ -1418,13 +1436,17 @@ class InferenceEngine(
 
             return jax.jit(init_leaf)(key)
 
+        # The four every model has, in the order their keys were always
+        # drawn, then whatever else the config adds (a looped stack's exit
+        # gate).
+        order = ["embed", "layers", "final_norm", "lm_head"]
+        order += sorted(set(shapes) - set(order))
         return {
-            "embed": make("embed", shapes["embed"]),
-            "layers": {
-                k: make(k, v) for k, v in shapes["layers"].items()
-            },
-            "final_norm": make("final_norm", shapes["final_norm"]),
-            "lm_head": make("lm_head", shapes["lm_head"]),
+            name: (
+                {k: make(k, v) for k, v in shapes[name].items()}
+                if name == "layers" else make(name, shapes[name])
+            )
+            for name in order
         }
 
     def _init_llm_serving_state(self) -> None:
@@ -1450,14 +1472,14 @@ class InferenceEngine(
             from gofr_tpu.ops.kv_cache import PagedKVCache
 
             make_cache = lambda: PagedKVCache.create(  # noqa: E731
-                self.cfg.n_layers, n_slots, self.max_len,
+                self.cfg.n_cache_entries, n_slots, self.max_len,
                 self.cfg.n_kv_heads, self.cfg.head_dim, self.cfg.dtype,
                 quant=self.kv_quant, block=self.kv_block,
                 n_blocks=self.kv_pool_blocks,
             )
         else:
             make_cache = lambda: KVCache.create(  # noqa: E731
-                self.cfg.n_layers, n_slots, self.max_len,
+                self.cfg.n_cache_entries, n_slots, self.max_len,
                 self.cfg.n_kv_heads, self.cfg.head_dim, self.cfg.dtype,
                 quant=self.kv_quant,
             )
@@ -1487,6 +1509,11 @@ class InferenceEngine(
         else:
             with self._placement():
                 self.cache = self._commit(make_cache())
+        if self._metrics is not None:
+            self._metrics.set_gauge(
+                "app_tpu_kv_bytes_per_token", self.kv_bytes_per_token(),
+                "model", self.model_name,
+            )
         self._radix = None
         if self.kv_block:
             # Host-side REFCOUNTED block allocator (ops/kv_cache.py):
@@ -3203,6 +3230,14 @@ class InferenceEngine(
             out["loop"] = self._loop_prof.describe()
         return out
 
+    def kv_bytes_per_token(self) -> int:
+        """Bytes of KV cache one token position holds, from the arrays as
+        allocated: every cache entry's keys and values, and the scales of
+        an int8 cache."""
+        # k: [entries, slots | blocks, kv_heads, max_len | block, head_dim]
+        k = self.cache.k
+        return self.cache.hbm_bytes() // (k.shape[1] * k.shape[3])
+
     def health_check(self) -> dict:
         details: dict[str, Any] = {
             "model": self.model_name,
@@ -3245,6 +3280,7 @@ class InferenceEngine(
                 "in_use": sum(1 for s in self._slots if s is not None),
             }
             details["max_len"] = self.max_len
+            details["kv_bytes_per_token"] = self.kv_bytes_per_token()
             # What TPU_SPEC_TOKENS (default "auto") resolved to.
             details["spec_tokens"] = self.spec_tokens
             details["pending"] = self._pending.qsize()

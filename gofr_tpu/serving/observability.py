@@ -472,8 +472,12 @@ class RequestObservability:
         recorder: Optional[FlightRecorder] = None,
         clock: Callable[[], float] = time.monotonic,
         wall_ns: Callable[[], int] = time.time_ns,
+        passes: int = 1,
     ) -> None:
         self.model_name = model_name
+        # Times the layer stack runs a forward (a looped model's n_passes):
+        # an attribute of the spans whose device time scales with it.
+        self.passes = passes
         self._metrics = metrics
         self.recorder = recorder
         self._clock = clock
@@ -624,7 +628,7 @@ class RequestObservability:
         for i, (start, end, tokens) in enumerate(tl.chunks):
             child(
                 "tpu.prefill.chunk", start, end,
-                index=i, tokens=tokens,
+                index=i, tokens=tokens, passes=self.passes,
             )
         if tl.prefill_done is not None and tl.first_token is not None:
             child("tpu.emit_flush", tl.prefill_done, tl.first_token)
@@ -642,7 +646,7 @@ class RequestObservability:
         if tl.first_token is not None:
             child(
                 "tpu.decode", tl.first_token, done,
-                tokens=tl.output_tokens,
+                tokens=tl.output_tokens, passes=self.passes,
             )
         for name, t, attrs in tl.annotations:
             child(name, t, t, **attrs)

@@ -1771,7 +1771,8 @@ class SchedulerMixin:
         decode, [2, k, S, G+1] plus a [k, S] counts array for speculative
         windows, [2, m*k, S] plus a windows-run scalar for mega windows.
         Returns ``(emitted_dev, counts_dev_or_None, slots_snapshot,
-        wrun_dev_or_None, etops_dev_or_None)`` for _process_window — the snapshot
+        wrun_dev_or_None, etops_dev_or_None, live_positions)`` for
+        _process_window — the snapshot
         matters because by processing time a retired slot may already hold
         a NEW request admitted in between."""
         # Fault seam: a raise models the device failing a decode window;
@@ -1871,8 +1872,16 @@ class SchedulerMixin:
                     eos_stop_host[i] = False
             self._push_table()
 
+        # Cache positions that are context when this window starts: each
+        # live slot's prompt plus what earlier windows already cover.
+        live_positions = 0
         for i, seq in enumerate(self._slots):
             if seq is not None:
+                req = seq.request
+                live_positions += (
+                    (req.effective_prompt_len or len(req.prompt_ids))
+                    + seq.tokens_in_flight - 1
+                )
                 seq.tokens_in_flight += (
                     min(cover, int(remaining_host[i])) if mega > 1
                     else self.window_k
@@ -1957,7 +1966,7 @@ class SchedulerMixin:
         if self._lockstep:
             lockcheck.note_device_sync("lockstep_block_until_ready")
             self._jax.block_until_ready(emitted)
-        return emitted, counts, list(self._slots), wrun, etops
+        return emitted, counts, list(self._slots), wrun, etops, live_positions
 
     def _process_window(
         self,
@@ -1966,6 +1975,7 @@ class SchedulerMixin:
         snapshot: "list[Optional[_ActiveSeq]]",
         wrun: Any = None,
         etops: Any = None,
+        live_positions: int = 0,
     ) -> None:
         t_fetch = time.time()
         # Interruptible wait: while this window's block is in flight, flush
@@ -2134,6 +2144,14 @@ class SchedulerMixin:
                 self._metrics.record_histogram(
                     "app_tpu_window_occupancy",
                     dispatched_live / max(1, self.n_slots),
+                    "model", self.model_name,
+                )
+                # The share of the cache the dense decode path reads
+                # (every position of every slot) that was context and
+                # not reserve when this window was dispatched.
+                self._metrics.record_histogram(
+                    "app_tpu_kv_live_ratio",
+                    live_positions / max(1, self.n_slots * self.max_len),
                     "model", self.model_name,
                 )
         self._update_slot_gauges()

@@ -125,6 +125,33 @@ def test_lowered_programs_name_their_ops_by_scope(
     ), sorted(set(dots))
 
 
+def test_a_looped_stack_names_its_passes():
+    """A looped model's layer ops read ``pass/attn/...``, ``pass/ffn/...``,
+    the norm that ends a pass ``pass/pass_norm/...``; an unlooped model's
+    programs carry neither name."""
+    e = engine_of("looped-tiny", 0)
+    for lowered in (lower_prefill_chunk(e), lower_window(e)):
+        names = op_names(lowered)
+        in_layers = [
+            n for n in names
+            if {"attn", "ffn"} & set(n.split("/")) and "dot_general" in n
+        ]
+        assert in_layers and all(n.startswith("pass/") for n in in_layers), (
+            sorted(set(in_layers))
+        )
+        assert any("pass_norm" in n.split("/") for n in names)
+        # embedding, head and sampling are outside the loop (the prefill
+        # chunk commits its keys inside each layer, the decode step after)
+        outside = [n for n in names
+                   if {"embed", "lm_head", "sample"} & set(n.split("/"))]
+        assert outside and not any("pass" in n.split("/") for n in outside)
+    plain = engine_of("llama-tiny", 0)
+    for lowered in (lower_prefill_chunk(plain), lower_window(plain)):
+        assert not any(
+            {"pass", "pass_norm"} & set(n.split("/")) for n in op_names(lowered)
+        )
+
+
 def test_scopes_change_no_program(monkeypatch):
     """Compile-time metadata only: with every scope turned into a no-op
     the lowered programs are the same text, locations aside."""
