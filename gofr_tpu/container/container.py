@@ -361,9 +361,15 @@ class Container:
         )
         m.new_histogram(
             "app_tpu_prefill_fill_ratio",
-            "prompt tokens in a prefill chunk step / its TPU_PREFILL_"
-            "BATCH x TPU_PREFILL_CHUNK token rows, one record per step",
+            "prompt tokens in a prefill chunk step / the token rows of "
+            "the step that ran (its row count x TPU_PREFILL_CHUNK), one "
+            "record per step",
             ratio_buckets,
+        )
+        m.new_counter(
+            "app_tpu_prefill_steps_total",
+            "prefill chunk steps dispatched, by the row count the step "
+            "ran at (rows: 1 when one row waited, else TPU_PREFILL_BATCH)",
         )
         # Disaggregated prefill/decode tiers (TPU_REPLICA_ROLES;
         # docs/advanced-guide/resilience.md): cross-tier KV-block

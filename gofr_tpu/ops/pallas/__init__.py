@@ -10,7 +10,7 @@ VMEM scratch across kv iterations):
   KV cache with per-slot lengths prefetched to SMEM so fully-invalid KV
   blocks are skipped before their DMA cost is paid;
 * :func:`flash_cache_attention` — chunked-prefill queries against the slot
-  cache in place (one fixed-shape compile serves every prompt length).
+  cache in place (one compile a row count serves every prompt length).
 
 All run under ``interpret=True`` on CPU, which is how the unit tests
 exercise them without hardware.

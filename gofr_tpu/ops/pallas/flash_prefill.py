@@ -4,9 +4,10 @@ row attends to its slot's KV-cache prefix IN PLACE.
 This is the kernel behind chunked prefill (VERDICT r1 weak #9: a long
 prompt's prefill must not stall every active decode stream): the engine
 splits prompts into fixed-size chunks and interleaves one chunk step
-between decode windows. Because the chunk shape is static, serving needs
-exactly ONE prefill compile — no bucket ladder — and arbitrary prompt
-lengths are handled by the loop count, not the program.
+between decode windows. The chunk length is static and arbitrary prompt
+lengths are handled by the loop count, not the program; the row count is
+one of the engine's two rungs (1 and ``prefill_batch``), so this kernel
+is compiled once a rung, before the engine serves.
 
 Contract (heads-major cache, ``ops/kv_cache.py``): the chunk's K/V must
 already be written into the cache at positions ``starts[p] ..

@@ -8,6 +8,8 @@ so apps that don't serve models never import jax.
 
 from __future__ import annotations
 
+import importlib
+import threading
 import traceback
 from typing import Any, Optional
 
@@ -25,6 +27,15 @@ def new_tpu_from_config(
         # Before the first jit of a serving process: JAX keeps whichever
         # cache directory its first compile saw.
         enable_compile_cache()
+        # The attention kernels' modules (jax.experimental.pallas and
+        # Mosaic behind them) are about a second of imports that the
+        # first trace of a serving program would otherwise make. The
+        # engine is about to start the device's runtime, seconds with
+        # the interpreter lock released: import them beside it.
+        threading.Thread(
+            target=importlib.import_module, args=("gofr_tpu.ops.pallas",),
+            name="tpu-kernel-import", daemon=True,
+        ).start()
         # Replica tier (docs/advanced-guide/resilience.md): TPU_REPLICAS
         # > 1 and/or TPU_REPLICA_ADDRS front the engine(s) with a
         # health-aware failover router — container.tpu becomes the POOL

@@ -712,9 +712,12 @@ class InferenceEngine(
             # streaming granularity coarsens, so serving defaults keep it
             # off and bursty/offline throughput turns it on.
             self.mega_windows = max(0, mega_windows)
-            # Chunked prefill: ONE fixed [prefill_batch, prefill_chunk]
-            # compile serves every prompt length, and chunk steps interleave
-            # with decode windows so admission never stalls active streams.
+            # Chunked prefill: [rows, prefill_chunk] steps serve every
+            # prompt length, rows chosen at each dispatch from the rungs
+            # 1 and prefill_batch by how many rows wait (both compiled
+            # before the engine serves, programs.py); chunk steps
+            # interleave with decode windows so admission never stalls
+            # active streams.
             self.prefill_chunk = max(16, min(prefill_chunk, self.max_len))
             self.prefill_batch = max(1, min(prefill_batch, n_slots))
             # Multi-chunk prefill (long-prompt dispatch amortizer): when
@@ -1807,6 +1810,10 @@ class InferenceEngine(
                 partial(quantize_params, mode=mode), donate_argnums=(0,)
             )(self.params)
         self.quant = mode
+        if getattr(self, "_prefill_steps", None):
+            # Called on a built engine (__init__ quantizes before it
+            # builds): the rungs were compiled for the old leaves.
+            self._compile_prefill_ladder()
 
     async def start(self) -> None:
         self.start_sync()

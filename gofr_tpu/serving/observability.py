@@ -192,8 +192,9 @@ class RequestTimeline:
         self.done: Optional[float] = None
         self.outcome = ""
         self.finish_reason = ""
-        # (start, end, tokens) per dispatched prefill chunk step.
-        self.chunks: list[tuple[float, float, int]] = []
+        # (start, end, tokens, rows) per dispatched prefill chunk step:
+        # this request's tokens in it, and the row count it ran at.
+        self.chunks: list[tuple[float, float, int, int]] = []
         # (name, t, attrs) — shed/replay/failover events.
         self.annotations: list[tuple[str, float, dict[str, Any]]] = []
         # (source, target, start, end, result) — disaggregated-tier KV
@@ -222,8 +223,10 @@ class RequestTimeline:
     def note_prefix_hit(self, tokens: int) -> None:
         self.prefix_hit_tokens += tokens
 
-    def note_chunk(self, start: float, end: float, tokens: int) -> None:
-        self.chunks.append((start, end, tokens))
+    def note_chunk(
+        self, start: float, end: float, tokens: int, rows: int
+    ) -> None:
+        self.chunks.append((start, end, tokens, rows))
 
     def mark_prefill_done(self, now: float) -> None:
         if self.prefill_done is None:
@@ -625,10 +628,10 @@ class RequestObservability:
             )
             if tl.chunks:
                 child("tpu.prefill_wait", tl.admitted, tl.chunks[0][0])
-        for i, (start, end, tokens) in enumerate(tl.chunks):
+        for i, (start, end, tokens, rows) in enumerate(tl.chunks):
             child(
                 "tpu.prefill.chunk", start, end,
-                index=i, tokens=tokens, passes=self.passes,
+                index=i, tokens=tokens, rows=rows, passes=self.passes,
             )
         if tl.prefill_done is not None and tl.first_token is not None:
             child("tpu.emit_flush", tl.prefill_done, tl.first_token)

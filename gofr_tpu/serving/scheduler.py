@@ -72,6 +72,7 @@ class SchedulerMixin:
     n_slots: int
     pipeline_depth: int
     prefill_batch: int
+    prefill_rungs: tuple[int, ...]
     prefill_chunk: int
     prefill_depth: int
     spec_tokens: int
@@ -147,8 +148,8 @@ class SchedulerMixin:
     _history_dev: Any
     # Compiled-program callables (LLMProgramsMixin) and engine methods
     # this loop calls across the facade.
-    _prefill_chunk_step: Any
-    _prefill_chunk_step_hist: Any
+    _prefill_step: Any  # (rows, use_bias) -> the compiled rung
+    _prefill_operands: Any  # the nine per-row arrays -> its operands
     _prefill_multi_chunk: Any
     _prefill_multi_chunk_hist: Any
     _decode_window: Any
@@ -1199,7 +1200,9 @@ class SchedulerMixin:
 
     def _dispatch_prefill_chunk(self, lap_import: bool = False) -> bool:
         """Admit pending requests into free slots and dispatch ONE
-        fixed-shape [prefill_batch, prefill_chunk] chunk step.
+        [rows, prefill_chunk] chunk step, ``rows`` the smallest rung of
+        ``prefill_rungs`` that holds the rows that wait (at most
+        ``prefill_batch``).
         ``lap_import`` is True only on the scheduler pass's first
         (seam) call: the loop profiler's tier_import stamp belongs to
         that one — see the lap site below.
@@ -1510,7 +1513,7 @@ class SchedulerMixin:
                 for _, st, _ in deep:
                     st.done += d * c
                     if st.request.timeline is not None:
-                        st.request.timeline.note_chunk(t0m, t1m, d * c)
+                        st.request.timeline.note_chunk(t0m, t1m, d * c, P)
                 if self._metrics is not None:
                     self._metrics.record_histogram(
                         "app_tpu_infer_latency", time.time() - t0,
@@ -1518,15 +1521,18 @@ class SchedulerMixin:
                     )
                 return True
 
-        tokens = np.zeros((P, c), dtype=np.int32)
-        slots = np.zeros((P,), dtype=np.int32)
-        starts = np.zeros((P,), dtype=np.int32)
-        lens = np.zeros((P,), dtype=np.int32)
-        finalize = np.zeros((P,), dtype=bool)
-        row_valid = np.zeros((P,), dtype=bool)
-        temps = np.ones((P,), dtype=np.float32)
-        topps = np.ones((P,), dtype=np.float32)
-        greedy = np.ones((P,), dtype=bool)
+        # Every compiled row is computed in full, so the step runs at
+        # the smallest rung that holds the rows that wait.
+        R = next(r for r in self.prefill_rungs if r >= len(rows))
+        tokens = np.zeros((R, c), dtype=np.int32)
+        slots = np.zeros((R,), dtype=np.int32)
+        starts = np.zeros((R,), dtype=np.int32)
+        lens = np.zeros((R,), dtype=np.int32)
+        finalize = np.zeros((R,), dtype=bool)
+        row_valid = np.zeros((R,), dtype=bool)
+        temps = np.ones((R,), dtype=np.float32)
+        topps = np.ones((R,), dtype=np.float32)
+        greedy = np.ones((R,), dtype=bool)
         for i, (slot, st) in enumerate(rows):
             ids = st.ids
             chunk = ids[st.done : st.done + c]
@@ -1539,7 +1545,7 @@ class SchedulerMixin:
             temps[i] = max(st.request.temperature, 0.0)
             topps[i] = st.request.top_p
             greedy[i] = st.request.temperature <= 0
-        for i in range(len(rows), P):
+        for i in range(len(rows), R):
             # Padding rows duplicate row 0: identical K/V writes to the
             # same cache positions are idempotent, and row_valid=False
             # keeps them out of the finalize merge.
@@ -1551,15 +1557,9 @@ class SchedulerMixin:
         t0 = time.time()
         t0m = self._obs.now()
         self._push_table()
-        args = (
-            self.params, self.cache, self._up(tokens),
-            self._up(slots), self._up(starts), self._up(lens),
-            self._up(finalize), self._up(row_valid),
-            self._up(temps), self._up(greedy), self._up(topps),
-            self._seeds_dev, self._tokens_dev, self._logps_dev,
-            self._pcounts_dev, self._nsteps_dev, self._bidx_dev,
-            self._bval_dev, self._topi_dev, self._topl_dev,
-            self._aids_dev, self._noff_dev,
+        args = self._prefill_operands(
+            tokens, slots, starts, lens, finalize, row_valid,
+            temps, greedy, topps,
         )
         # Static compile choice: the no-bias program has no bias scatter
         # at all (each variant compiles once, then caches).
@@ -1568,21 +1568,11 @@ class SchedulerMixin:
         )
         # Locals-then-commit around the dispatch (zombie fence; see
         # _dispatch_window).
-        chist = None
-        if self.spec_tokens:
-            (ccache, ctoks, clps, first_dev,
-             first_lp_dev, cpc, cnst,
-             cti, ctl, ftopi_dev, ftopl_dev, chist) = (
-                self._prefill_chunk_step_hist(
-                    *args, self._history_dev, use_bias=use_bias
-                )
-            )
-        else:
-            (ccache, ctoks, clps, first_dev,
-             first_lp_dev, cpc, cnst,
-             cti, ctl, ftopi_dev, ftopl_dev) = (
-                self._prefill_chunk_step(*args, use_bias=use_bias)
-            )
+        out = self._prefill_step(R, use_bias)(*args)
+        (ccache, ctoks, clps, first_dev,
+         first_lp_dev, cpc, cnst,
+         cti, ctl, ftopi_dev, ftopl_dev) = out[:11]
+        chist = out[11] if self.spec_tokens else None
         self._check_superseded()
         self.cache, self._tokens_dev, self._logps_dev = ccache, ctoks, clps
         self._pcounts_dev, self._nsteps_dev = cpc, cnst
@@ -1598,11 +1588,16 @@ class SchedulerMixin:
             self._metrics.record_histogram(
                 "app_tpu_batch_size", len(rows), "batcher", "prefill"
             )
-            # How much of the fixed [P, c] step was prompt: the rest of
-            # its P x c token rows is padding the device computes anyway.
+            self._metrics.increment_counter(
+                "app_tpu_prefill_steps_total",
+                "model", self.model_name, "rows", str(R),
+            )
+            # How much of the [R, c] step that ran was prompt: the rest
+            # of its R x c token rows is padding the device computes
+            # anyway.
             self._metrics.record_histogram(
                 "app_tpu_prefill_fill_ratio",
-                float(lens[: len(rows)].sum()) / (P * c),
+                float(lens[: len(rows)].sum()) / (R * c),
                 "model", self.model_name,
             )
 
@@ -1614,7 +1609,7 @@ class SchedulerMixin:
             st.done += int(lens[i])
             tl = st.request.timeline
             if tl is not None:
-                tl.note_chunk(t0m, t1m, int(lens[i]))
+                tl.note_chunk(t0m, t1m, int(lens[i]), R)
             if finalize[i]:
                 if tl is not None:
                     tl.mark_prefill_done(t1m)

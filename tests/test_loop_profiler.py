@@ -355,7 +355,9 @@ def test_collector_seconds_are_counted_once_for_the_process():
     c.lap("prefill", 1.0)
     c.begin_pass(1.0)
     rec = c.snapshot()["pinned_anomalies"][0]
-    assert rec["gc_s"] > 0.0 and rec["proc_cpu_s"] >= rec["cpu_s"] > 0.0
+    # Two clocks, each read twice and rounded to the microsecond: the
+    # process's may come out a tick under this thread's.
+    assert rec["gc_s"] > 0.0 and rec["proc_cpu_s"] >= rec["cpu_s"] - 1e-5 > 0.0
 
 
 def test_phase_context_laps_once_per_boundary_on_its_own_clock():

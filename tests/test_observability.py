@@ -162,7 +162,7 @@ def test_timeline_phase_math_with_injected_clock():
     t[0] = 100.5
     tl.mark_admitted(t[0])
     t[0] = 101.0
-    tl.note_chunk(100.5, 101.0, 7)
+    tl.note_chunk(100.5, 101.0, 7, 1)
     tl.mark_prefill_done(t[0])
     t[0] = 101.25
     tl.mark_first_token(t[0])
@@ -210,9 +210,9 @@ def _timeline_up_to(hub, t, last_mark):
     if "admitted" in reached:
         tl.mark_admitted(MARKS["admitted"])
     if "chunk0" in reached:
-        tl.note_chunk(MARKS["chunk0"], MARKS["chunk0"] + 0.0625, 256)
+        tl.note_chunk(MARKS["chunk0"], MARKS["chunk0"] + 0.0625, 256, 2)
     if "prefill_done" in reached:
-        tl.note_chunk(MARKS["chunk1"], MARKS["prefill_done"], 44)
+        tl.note_chunk(MARKS["chunk1"], MARKS["prefill_done"], 44, 1)
         tl.mark_prefill_done(MARKS["prefill_done"])
     if "first_token" in reached:
         tl.mark_first_token(MARKS["first_token"])
@@ -279,7 +279,7 @@ def test_request_without_an_http_handler_lacks_entry_and_delivery():
     )
     tl = hub.begin(prompt_tokens=4)
     tl.mark_admitted(50.5)
-    tl.note_chunk(50.75, 51.0, 4)
+    tl.note_chunk(50.75, 51.0, 4, 1)
     tl.mark_prefill_done(51.0)
     tl.mark_first_token(51.5)
     t[0] = 52.0
@@ -327,6 +327,9 @@ def test_ttft_split_records_one_histogram_sample_and_one_span_per_phase(
     chunks = [s for s in capture.by_name("tpu.prefill.chunk")
               if s.trace_id == tl.trace_id]
     assert chunks[-1].end_ns - chunks[0].start_ns == int(0.75 * 1e9)
+    # Each carries the row count of the step it rode in.
+    assert [(c.attributes["tokens"], c.attributes["rows"]) for c in chunks] \
+        == [(256, 2), (44, 1)]
 
 
 def test_flight_recorder_evicts_ring_but_pins_survive_burst():
@@ -389,12 +392,12 @@ def test_phase_histograms_record_exactly_once_per_request(metrics, engine):
         mean[name] = (sum1 - sum0) / (n1 - n0)
     # One of the four slots was live when each window was dispatched ...
     assert mean["app_tpu_window_occupancy"] == pytest.approx(0.25)
-    # ... and the prompt was all that was real in each prefill step's
-    # prefill_batch x prefill_chunk token rows.
+    # ... and one row waited at each prefill step, so the step ran at
+    # the lowest rung: the prompt over 1 x prefill_chunk token rows.
+    assert engine.prefill_rungs == (1, 4)
     assert mean["app_tpu_prefill_fill_ratio"] == pytest.approx(
-        r.prompt_tokens / (engine.prefill_batch * engine.prefill_chunk)
+        r.prompt_tokens / engine.prefill_chunk
     )
-    assert 0 < mean["app_tpu_prefill_fill_ratio"] < 0.125
 
 
 def test_one_trace_per_request_with_phase_parentage(capture, engine):

@@ -402,20 +402,23 @@ class KernelCase(NamedTuple):
     paged: bool
     windowed: bool
     extra: bool       # decode: k_new/v_new split; attention: lengths
+    rows: int = 3     # prefill_chunk: the step's row count (its rung)
 
 
 def serving_kernel_cases() -> list[KernelCase]:
     """Every kernel variant the serving path can select: bf16 and
     int8-KV, contiguous and paged, window binding or not, decode with
     and without the ``k_new/v_new`` split, full attention with and
-    without ``lengths``."""
+    without ``lengths``; the prefill chunk at 3, 2 and 1 rows (a step
+    runs at the rung that holds the rows that wait)."""
     cases = [
         KernelCase("decode", q8, paged, win, new)
         for q8, paged, win, new in itertools.product((False, True), repeat=4)
     ]
     cases += [
-        KernelCase("prefill_chunk", q8, paged, win, False)
+        KernelCase("prefill_chunk", q8, paged, win, False, rows)
         for q8, paged, win in itertools.product((False, True), repeat=3)
+        for rows in (3, 2, 1)
     ]
     cases += [
         KernelCase("attention", False, False, win, lens)
@@ -514,15 +517,15 @@ def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
             )
         valid = np.ones(got.shape[:1], bool)
     elif case.kernel == "prefill_chunk":
-        P, c = 3, g.chunk
+        P, c = case.rows, g.chunk
         q = queries((P, c, g.n_heads, g.hd))
-        slots = jnp.array([0, 3, 1], jnp.int32)
         # First chunk, a chunk straddling the window edge, a ragged
-        # last chunk deep in the cache.
+        # last chunk deep in the cache; fewer rows keep the last ones.
+        slots = jnp.array([0, 3, 1][-P:], jnp.int32)
         starts = jnp.array(
-            [0, g.window - c // 2, g.max_len - 2 * c], jnp.int32
+            [0, g.window - c // 2, g.max_len - 2 * c][-P:], jnp.int32
         )
-        lens = jnp.array([c, c, max(1, c // 2 + 1)], jnp.int32)
+        lens = jnp.array([c, c, max(1, c // 2 + 1)][-P:], jnp.int32)
         got = flash_cache_attention(
             q, k_c, v_c, slots, starts, lens, window=w,
             interpret=INTERPRET, **kern,

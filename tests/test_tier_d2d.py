@@ -285,8 +285,14 @@ def test_zero_steady_state_recompiles_repeated_device_transfers(
 ):
     """Repeated device-leg transfers after the PR 10 warm-up fence
     compile nothing: extract/move are one fixed-shape program per cache
-    geometry, warmed by the suite's earlier transfers."""
+    geometry. The first transfer here is the warm-up: under xdist's load
+    distribution the suite's earlier transfers may have run on another
+    worker's engines, and this test alone would then meet the first
+    compile of extract/move and of the decode window after its fence."""
     pf, dc, _ = engines
+    _drain(tier_pool.submit_generate(
+        _prompt(9), max_new_tokens=6, temperature=0.0
+    ))
     pf.mark_steady_state()
     dc.mark_steady_state()
     for tag in (4, 5, 6):
