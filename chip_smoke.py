@@ -622,7 +622,6 @@ def serve_phase(
         **cache_facts(cache_dir, entries_before),
         "requests_sent": server.sent,
         "requests_succeeded": server.succeeded,
-        "spec_tokens": health.get("spec_tokens"),
         "peak_hbm_bytes": [
             m.get("peak_bytes_in_use") for m in device_mem
         ],

@@ -1,4 +1,4 @@
-"""The graftlint rule set (GL001–GL025).
+"""The graftlint rule set (GL001–GL024).
 
 Each rule encodes one class of TPU-serving bug that generic linters
 cannot see because it is a *semantic* property of the jax programming
@@ -7,7 +7,7 @@ a rule should only fire where a human reviewer would at least pause —
 anything intentional gets an inline ``# graftlint: disable=RULE`` with
 its justification, which doubles as documentation at the call site.
 
-GL001–GL019 and GL023–GL025 are per-file :class:`Rule`\\ s;
+GL001–GL019, GL023 and GL024 are per-file :class:`Rule`\\ s;
 GL020–GL022 are :class:`ProjectRule`\\ s running against the cross-file
 :class:`~gofr_tpu.analysis.project.ProjectIndex` (call graph, lock
 model, thread roots) built by the two-phase runner.
@@ -2567,64 +2567,6 @@ class HandleNoDeadlineRule(Rule):
 ALL_RULES = ALL_RULES + (HandleNoDeadlineRule,)
 
 
-class DuplicatedLogitsPathRule(Rule):
-    """The speculative-decoding divergence bug (ROADMAP direction 1,
-    fixed in ISSUE 20) was exactly this: ``serving/programs.py`` called
-    a *second* transformer forward (``transformer_verify_step``) that
-    recomputed decode-position logits with a batched ``[S, G+1]``
-    contraction shape. bf16 reductions are order-sensitive, so the
-    batched contraction's different accumulation order flipped near-tie
-    argmaxes relative to the one-position decode step — 4/8 bench
-    prompts diverged token-for-token. The fix reuses the decode-step
-    program per candidate position, making the verify logits identical
-    by construction; any device program in serving/ that emits tokens
-    must derive its logits from that one shared builder.
-
-    Heuristic: in ``serving/`` scope, flag a call whose terminal name
-    ends in ``verify_step`` — the models-layer batched-verify builders
-    keep that suffix, and calling one from the serving plane
-    reintroduces a logits path with its own contraction shape. A
-    deliberate tolerance-checked use (e.g. a models-layer parity test
-    helper) carries an inline disable.
-    """
-
-    rule_id = "GL025"
-    name = "duplicated-logits-path"
-    rationale = (
-        "a second transformer forward in the serving plane recomputes "
-        "decode logits with a different contraction shape; bf16 "
-        "reduction order differs between shapes, so near-tie argmaxes "
-        "flip and token streams diverge from the decode window — "
-        "derive serving logits from the shared decode-step builder"
-    )
-
-    def applies_to(self, path: str) -> bool:
-        norm = path.replace("\\", "/")
-        return "/serving/" in norm or norm.startswith("serving/")
-
-    def check(self, tree: ast.Module, ctx: FileContext) -> Iterator[Finding]:
-        for node in ast.walk(tree):
-            if not isinstance(node, ast.Call):
-                continue
-            name = dotted_name(node.func) or ""
-            short = name.rsplit(".", 1)[-1]
-            if not short.endswith("verify_step"):
-                continue
-            yield self.finding(
-                ctx, node,
-                f"`{name}(...)` is a second decode-logits path: its "
-                "batched contraction shape accumulates bf16 in a "
-                "different order than the decode step, flipping "
-                "near-tie argmaxes — run the shared decode-step "
-                "builder (`transformer_decode_step`) over the "
-                "candidate window instead so verify logits are "
-                "bit-identical by construction",
-            )
-
-
-ALL_RULES = ALL_RULES + (DuplicatedLogitsPathRule,)
-
-
 # ----------------------------------------------------------------------
 # GL020–GL022 — project-wide concurrency rules (two-phase engine)
 # ----------------------------------------------------------------------
@@ -3024,7 +2966,6 @@ def default_rules(config: Optional[LintConfig] = None) -> list[Rule]:
         SyncOutsideDeviceWaitRule(),
         AckBeforeResultRule(),
         HandleNoDeadlineRule(),
-        DuplicatedLogitsPathRule(),
         UnguardedSharedStateRule(config.concurrency_dirs),
         LockOrderInversionRule(config.concurrency_dirs),
         BlockingUnderLockRule(config.concurrency_dirs),

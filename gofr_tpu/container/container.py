@@ -180,11 +180,6 @@ class Container:
         m.new_counter(
             "app_tpu_prefix_hits", "prompts admitted via prefix-KV reuse"
         )
-        m.new_histogram(
-            "app_tpu_spec_tokens_per_step",
-            "tokens emitted per live decode step (plain windows: 1.0)",
-            (1, 1.5, 2, 2.5, 3, 4, 5, 6, 8),
-        )
         m.new_gauge(
             "app_tpu_kv_blocks_free", "paged KV cache: free pool blocks"
         )

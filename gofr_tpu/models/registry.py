@@ -132,8 +132,8 @@ def _register_llms() -> None:
             n_kv_heads=2, d_ff=256, max_len=256, rope_theta=10000.0,
         ),
         # f32 twin: the exact-comparison oracle for tests where bf16
-        # argmax tie-breaks differ between execution shapes (e.g.
-        # speculative verify [S, G+1] vs decode [S] forwards).
+        # argmax tie-breaks differ between execution shapes (e.g. a
+        # prefill step of 1 row and of 8).
         "llama-tiny-f32": TransformerConfig(
             vocab_size=512, d_model=128, n_layers=2, n_heads=4,
             n_kv_heads=2, d_ff=256, max_len=256, rope_theta=10000.0,

@@ -34,13 +34,11 @@ from gofr_tpu.ops.attention import (
     attention,
     cache_chunk_attention,
     decode_attention,
-    verify_chunk_attention,
 )
 from gofr_tpu.ops.kv_cache import (
     KVCache,
     PagedKVCache,
     fake_quantize_kv,
-    paged_view,
     quantize_kv,
 )
 from gofr_tpu.ops.norms import layer_norm, rms_norm
@@ -554,10 +552,10 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
 
 # The serving steps below run under ``jax.named_scope`` with a fixed
 # vocabulary — embed, attn, kv_commit, ffn, moe_router, moe_experts,
-# lm_head here, pass and pass_norm around them in a looped stack; sample,
-# draft, verify in serving/programs.py — so that an op in the profiler's
-# trace says which part of the model it belongs to (its ``tf_op`` reads
-# ``jit(spec_window)/…/attn/dot_general``). Compile-time metadata only: no
+# lm_head here, pass and pass_norm around them in a looped stack; sample
+# in serving/programs.py — so that an op in the profiler's trace says which
+# part of the model it belongs to (its ``tf_op`` reads
+# ``jit(decode_window)/…/attn/dot_general``). Compile-time metadata only: no
 # shape, value or fusion depends on it.
 
 
@@ -1049,164 +1047,6 @@ def transformer_decode_step(
         )
     x = _norm(x[:, None, :], params["final_norm"], cfg, params.get("final_norm_b"))[:, 0]
     return _lm_head("bd,dv->bv", x, params), cache
-
-
-def transformer_verify_step(
-    params: dict,
-    tokens: jnp.ndarray,
-    cache: KVCache,
-    cfg: TransformerConfig,
-    aids: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Speculative-verify forward: ``c`` candidate tokens per slot in one
-    pass, cache READ-ONLY (rejected drafts need no rollback — the caller
-    commits only what it accepts via :func:`commit_chunk_kv`).
-
-    tokens: [S, c] — position j of slot s sits at global position
-    ``cache.lengths[s] + j``. Returns (logits [S, c, vocab] f32,
-    new_k [L, S, c, KV, hd], new_v [L, S, c, KV, hd]).
-    """
-    S, c = tokens.shape
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    positions = cache.lengths[:, None] + jnp.arange(c)[None, :]  # [S, c]
-    x = _embed(params, tokens, cfg, positions)  # [S, c, D]
-    cos, sin = rope_frequencies(cfg.rope_dims, cache.max_len, cfg.rope_theta)
-    paged = isinstance(cache, PagedKVCache)
-    rows = jnp.arange(S)
-
-    def body(x, scanned):
-        lp, ck, cv, cks, cvs = scanned  # read-only cache slices
-        with jax.named_scope("attn"):
-            h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
-            q, k, v = _qkv(h, lp, "bcd,dh->bch", H, KV, hd, S, c, aids=aids)
-            if cfg.pos_emb == "rope":
-                q = apply_rope(q, cos, sin, positions)
-                k = apply_rope(k, cos, sin, positions)
-            if cache.quantized:
-                # Same fake-quant rule as the decode step: the in-chunk
-                # K/V must match what commit_chunk_kv will write, or
-                # spec-on output diverges from spec-off under an int8
-                # cache.
-                k, v = fake_quantize_kv(k), fake_quantize_kv(v)
-            if paged:
-                ck, cv, cks, cvs = paged_view(
-                    cache.block_table, ck, cv, rows, cks, cvs
-                )
-            attn = verify_chunk_attention(
-                q, ck, cv, cache.lengths, k, v, k_scale=cks, v_scale=cvs,
-                window=cfg.sliding_window,
-            )
-            ao = attn.reshape(S, c, H * hd)
-            attn_out = (
-                _wein("bch,hd->bcd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
-            )
-            if "wo_b" in lp:
-                attn_out = attn_out + lp["wo_b"]
-            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
-        mlp_in = x if cfg.parallel_residual else x + attn_out
-        h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
-        ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
-            h, lp, cfg, aids
-        )
-        ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
-        x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
-        return x, (k, v)
-
-    x, (new_k, new_v) = _scan_stack(
-        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
-    )
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
-    return _lm_head("bcd,dv->bcv", x, params), new_k, new_v
-
-
-@jax.named_scope("kv_commit")
-def commit_chunk_kv(
-    cache: KVCache,
-    new_k: jnp.ndarray,
-    new_v: jnp.ndarray,
-    active: jnp.ndarray,
-    cfg: TransformerConfig,
-) -> KVCache:
-    """Scatter a verify step's K/V ([L, S, c, KV, hd]) into the cache at
-    positions ``lengths + j``. ALL c positions are written — entries past
-    the accepted count sit beyond ``lengths`` (the caller advances it by
-    accepted+1 only), are never attended, and are overwritten by later
-    steps; inactive slots park at max_len-1 like the decode step.
-    ``cache.lengths`` is NOT updated here.
-    """
-    L, S, c, KV, hd = new_k.shape
-    pos = cache.lengths[:, None] + jnp.arange(c)[None, :]  # [S, c]
-    pos = jnp.where(active[:, None], pos, cache.max_len - 1)
-    pos = jnp.minimum(pos, cache.max_len - 1)
-    li = jnp.arange(L)[:, None, None, None]
-    ki = jnp.arange(KV)[None, None, :, None]
-    if isinstance(cache, PagedKVCache):
-        B = cache.block
-        blk = jnp.take_along_axis(cache.block_table, pos // B, axis=1)
-        blk = jnp.where(active[:, None], blk, 0)  # park in block 0
-        row = blk[None, :, None, :]  # [1, S, 1, c] pool block ids
-        pi = jnp.where(active[:, None], pos % B, B - 1)[None, :, None, :]
-    else:
-        row = jnp.arange(S)[None, :, None, None]
-        pi = pos[None, :, None, :]  # [1, S, 1, c]
-    nk = new_k.transpose(0, 1, 3, 2, 4)  # [L, S, KV, c, hd]
-    nv = new_v.transpose(0, 1, 3, 2, 4)
-    if cache.quantized:
-        nk, k_sc = quantize_kv(nk)  # scales [L, S, KV, c]
-        nv, v_sc = quantize_kv(nv)
-        sidx = (
-            li[..., None], row[..., None], ki[..., None],
-            jnp.arange(8)[None, None, None, None, :], pi[..., None],
-        )
-        cache = cache._replace(
-            k_s=cache.k_s.at[sidx].set(k_sc[..., None]),
-            v_s=cache.v_s.at[sidx].set(v_sc[..., None]),
-        )
-    return cache._replace(
-        k=cache.k.at[li, row, ki, pi].set(nk.astype(cache.k.dtype)),
-        v=cache.v.at[li, row, ki, pi].set(nv.astype(cache.v.dtype)),
-    )
-
-
-@jax.named_scope("draft")
-def ngram_draft(
-    history: jnp.ndarray,
-    lengths: jnp.ndarray,
-    current: jnp.ndarray,
-    n_draft: int,
-) -> jnp.ndarray:
-    """Prompt-lookup drafting: continue the most recent prior occurrence
-    of the current context in the slot's own token history.
-
-    history: [S, max_len] int32 (prompt + generated tokens; entries past
-    lengths+1 are stale); lengths: [S] tokens in history BEFORE current;
-    current: [S] the token about to be fed to the model (already at
-    history[lengths]). Matches the bigram (history[p-1], history[p]) ==
-    (previous, current) — falling back to a unigram match when the
-    context has fewer than 2 tokens — and drafts
-    ``history[p+1 : p+1+n_draft]``. No match → repeats ``current``
-    (cheap, will simply be rejected). Returns [S, n_draft] int32.
-    """
-    S, T = history.shape
-    pos = jnp.arange(T)[None, :]  # [1, T]
-    prev_idx = jnp.maximum(lengths - 1, 0)
-    prev = jnp.take_along_axis(history, prev_idx[:, None], axis=1)[:, 0]
-    hist_prev = jnp.concatenate(
-        [jnp.zeros((S, 1), history.dtype), history[:, :-1]], axis=1
-    )
-    m1 = history == current[:, None]
-    m2 = m1 & (hist_prev == prev[:, None])
-    use_bigram = (lengths >= 2)[:, None]
-    match = jnp.where(use_bigram, m2, m1)
-    # Only positions strictly before the current token's slot qualify.
-    match = match & (pos < lengths[:, None])
-    p_star = jnp.max(jnp.where(match, pos, -1), axis=1)  # [S]
-    found = p_star >= 0
-    gidx = jnp.clip(
-        p_star[:, None] + 1 + jnp.arange(n_draft)[None, :], 0, T - 1
-    )
-    draft = jnp.take_along_axis(history, gidx, axis=1)
-    return jnp.where(found[:, None], draft, current[:, None])
 
 
 def count_params(params: dict) -> int:

@@ -317,88 +317,6 @@ def decode_attention(
     return out.reshape(b, n_heads, -1)
 
 
-def verify_chunk_attention(
-    q: jnp.ndarray,
-    k_cache: jnp.ndarray,
-    v_cache: jnp.ndarray,
-    prev_lengths: jnp.ndarray,
-    k_new: jnp.ndarray,
-    v_new: jnp.ndarray,
-    *,
-    k_scale: jnp.ndarray | None = None,
-    v_scale: jnp.ndarray | None = None,
-    scale: float | None = None,
-    window: int = 0,
-) -> jnp.ndarray:
-    """Speculative-verify attention: ``c`` fresh tokens per slot attend the
-    cache prefix PLUS themselves (causal within the chunk) — the cache
-    stays read-only, mirroring ``decode_attention``'s ``k_new`` split path
-    so rejected drafts never have to be rolled back out of the cache.
-
-    q: [b, c, n_heads, hd] (position j is global position
-    prev_lengths[b]+j); k_cache/v_cache: [b, n_kv, max_len, hd];
-    prev_lengths: [b] valid cache prefix; k_new/v_new: [b, c, n_kv, hd]
-    (the chunk's own K/V, bf16); k_scale/v_scale: int8-cache scales
-    [b, n_kv, 8, max_len]. Returns [b, c, n_heads, hd].
-    """
-    b, c, n_heads, hd = q.shape
-    n_kv, max_len = k_cache.shape[1], k_cache.shape[2]
-    if window and window >= max_len + c:
-        window = 0  # cannot bind: skip the mask work
-    rep = n_heads // n_kv
-    if scale is None:
-        scale = hd**-0.5
-    quant = k_scale is not None
-    if quant:
-        k_cache = k_cache.astype(q.dtype)
-        v_cache = v_cache.astype(q.dtype)
-    qg = q.reshape(b, c, n_kv, rep, hd)
-
-    # Cache-prefix scores: [b, kv, rep, c, max_len], valid keys < length.
-    s_c = jnp.einsum(
-        "bcgrd,bgkd->bgrck", qg, k_cache, preferred_element_type=jnp.float32
-    ) * scale
-    if quant:
-        s_c = s_c * k_scale[:, :, 0, :][:, :, None, None, :]
-    valid = jnp.arange(max_len)[None, :] < prev_lengths[:, None]  # [b, T]
-    valid = jnp.broadcast_to(valid[:, None, :], (b, c, max_len))
-    if window:
-        # Query j sits at global prev_lengths+j; cache keys must be in
-        # (q_pos - window, q_pos].
-        q_pos = prev_lengths[:, None] + jnp.arange(c)[None, :]  # [b, c]
-        valid = valid & (
-            jnp.arange(max_len)[None, None, :]
-            > (q_pos - window)[:, :, None]
-        )
-    # valid is [b, c, max_len]; scores are [b, kv, rep, c, max_len].
-    s_c = jnp.where(valid[:, None, None, :, :], s_c, NEG_INF)
-
-    # In-chunk scores: [b, kv, rep, c, c], causal (key pos <= query pos).
-    s_n = jnp.einsum(
-        "bcgrd,btgd->bgrct", qg, k_new, preferred_element_type=jnp.float32
-    ) * scale
-    causal = jnp.arange(c)[None, :] <= jnp.arange(c)[:, None]  # [c_q, c_k]
-    if window:
-        causal = causal & (
-            jnp.arange(c)[None, :] > jnp.arange(c)[:, None] - window
-        )
-    s_n = jnp.where(causal[None, None, None], s_n, NEG_INF)
-
-    # Merged softmax over both key sets.
-    m = jnp.maximum(jnp.max(s_c, axis=-1), jnp.max(s_n, axis=-1))
-    e_c = jnp.exp(s_c - m[..., None])
-    e_n = jnp.exp(s_n - m[..., None])
-    denom = jnp.sum(e_c, axis=-1) + jnp.sum(e_n, axis=-1)
-    if quant:
-        e_c = e_c * v_scale[:, :, 0, :][:, :, None, None, :]
-    out = jnp.einsum("bgrck,bgkd->bgrcd", e_c.astype(q.dtype), v_cache)
-    out = out + jnp.einsum(
-        "bgrct,btgd->bgrcd", e_n.astype(q.dtype), v_new
-    )
-    out = out / denom[..., None].astype(q.dtype)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, c, n_heads, hd)
-
-
 def cache_chunk_attention(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,

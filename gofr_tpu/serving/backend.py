@@ -30,12 +30,19 @@ def new_tpu_from_config(
         # The attention kernels' modules (jax.experimental.pallas and
         # Mosaic behind them) are about a second of imports that the
         # first trace of a serving program would otherwise make. The
-        # engine is about to start the device's runtime, seconds with
-        # the interpreter lock released: import them beside it.
+        # device's runtime takes seconds to start, with the interpreter
+        # lock released: import them beside it, and start it HERE, before
+        # the engine's constructor has Python of its own to run (left to
+        # the constructor's first device touch, the import and the
+        # constructor share the lock, and a boot costs 1.3 s more:
+        # PERF.md, PR 30).
         threading.Thread(
             target=importlib.import_module, args=("gofr_tpu.ops.pallas",),
             name="tpu-kernel-import", daemon=True,
         ).start()
+        import jax
+
+        jax.default_backend()
         # Replica tier (docs/advanced-guide/resilience.md): TPU_REPLICAS
         # > 1 and/or TPU_REPLICA_ADDRS front the engine(s) with a
         # health-aware failover router — container.tpu becomes the POOL

@@ -61,73 +61,6 @@ from gofr_tpu.serving.types import (
 from gofr_tpu.serving.watchdog import Watchdog
 
 
-def resolve_spec_tokens(
-    raw: str,
-    backend: str,
-    enable_penalties: bool,
-    top_logprobs: int,
-) -> "tuple[int, Optional[str]]":
-    """Resolve ``TPU_SPEC_TOKENS`` (``auto``/int) to a draft count.
-
-    ``auto`` — the default — resolves to 0 on every backend: the engine
-    serves the plain decode window.
-
-    * The numerics-exact spec window runs the decode-step program once
-      per candidate position, one after another, so a step costs G + 1
-      decode forwards and emits between 1 and G + 1 tokens: device
-      compute per emitted token is never below the plain window's, and
-      equals it only when every draft of every live slot is accepted.
-      What speculation can save is dispatches, and on an attached chip
-      the host's share of the loop is 2-5%, hidden behind a pipeline
-      two windows deep. Measured on the v5e at G = 2
-      (PERF_LEDGER.jsonl, PR 25; both benchmark cells):
-      ``app_tpu_spec_tokens_per_step`` 1.0 — three forwards a step for
-      one token, two thirds of all decode compute. An explicit integer
-      opts in; the streams are bit-identical either way.
-    * Penalties' evolving count plane and the top_logprobs alternatives
-      plane are per-step planes the spec window's emission block
-      excludes: the note names them, so that an operator about to opt
-      in knows an EXPLICIT ``TPU_SPEC_TOKENS>0`` alongside them raises
-      in the constructor — that combination is a contradiction the user
-      typed, not one a default created.
-
-    Returns ``(spec_tokens, note)``; ``note`` explains the auto
-    resolution so boots are attributable in logs.
-    """
-    val = (raw or "auto").strip().lower()
-    if val == "auto":
-        conflicts = [
-            name
-            for name, on in (
-                ("TPU_PENALTIES", enable_penalties),
-                ("TPU_TOP_LOGPROBS", top_logprobs > 0),
-            )
-            if on
-        ]
-        note = (
-            f"speculative decoding off by default (TPU_SPEC_TOKENS=auto "
-            f"-> 0, backend={backend!r}): the exact verify runs G+1 "
-            "decode forwards a step one after another, so it can tie "
-            "the plain decode window in device time and never beat it "
-            "(measured on the v5e at G=2: 1.0 tokens a step, three "
-            "forwards a step, on both benchmark cells); set "
-            "TPU_SPEC_TOKENS to an integer to opt in"
-        )
-        if conflicts:
-            note += (
-                " (not alongside " + "/".join(conflicts) + ": the spec "
-                "window's emission block excludes their per-step planes)"
-            )
-        return 0, note
-    try:
-        n = int(val)
-    except ValueError:
-        raise ValueError(
-            f"TPU_SPEC_TOKENS={raw!r}: expected an integer or 'auto'"
-        ) from None
-    return max(0, n), None
-
-
 class InferenceEngine(
     LLMProgramsMixin, SchedulerMixin, LoRARuntimeMixin, ModalityMixin
 ):
@@ -145,8 +78,6 @@ class InferenceEngine(
         max_wait_s: float = 0.005,
         window_k: int = 8,
         pipeline_depth: int = 2,
-        mega_windows: int = 0,
-        prefill_depth: int = 1,
         prefill_chunk: int = 256,
         prefill_batch: int = 8,
         truncate_prompts: bool = False,
@@ -154,7 +85,6 @@ class InferenceEngine(
         enable_top_p: bool = False,
         enable_penalties: bool = False,
         top_logprobs: int = 0,
-        spec_tokens: int = 0,
         kv_block: int = 0,
         kv_pool_blocks: int = 0,
         auto_prefix: bool = False,
@@ -250,21 +180,9 @@ class InferenceEngine(
         # [slots, vocab] generated-token count plane and its per-step
         # scatter only exist in the program when enabled.
         self.enable_penalties = bool(enable_penalties)
-        if self.enable_penalties and spec_tokens > 0:
-            raise ValueError(
-                "TPU_PENALTIES and TPU_SPEC_TOKENS are mutually exclusive: "
-                "penalties evolve within a step sequence, which breaks the "
-                "parallel speculative verify"
-            )
         # OpenAI top_logprobs alternatives: a compile choice — the per-
         # step [slots, vocab] top_k only exists in the program when >0.
         self.top_logprobs = max(0, top_logprobs)
-        if self.top_logprobs and spec_tokens > 0:
-            raise ValueError(
-                "TPU_TOP_LOGPROBS and TPU_SPEC_TOKENS are mutually "
-                "exclusive (the verify step has no per-emission "
-                "alternatives plane)"
-            )
         n_passes = getattr(self.cfg, "n_passes", 1)
         if n_passes > 1 and self.cfg.exit_threshold < 1.0:
             raise ValueError(
@@ -273,13 +191,6 @@ class InferenceEngine(
                 "stack at different passes, and the decode window and the "
                 "scheduler run every slot to the same depth (only 1.0, "
                 "every pass, is implemented)"
-            )
-        if n_passes > 1 and spec_tokens > 0:
-            raise ValueError(
-                f"{model_name}: TPU_SPEC_TOKENS={spec_tokens} is not "
-                f"served for a looped stack (n_passes={n_passes}): the "
-                "speculative window was never run against per-pass cache "
-                "entries; leave TPU_SPEC_TOKENS at auto (0)"
             )
         self.tokenizer = tokenizer
         # GSPMD-sharded serving (TPU_TP): a caller may hand a pre-built
@@ -701,17 +612,6 @@ class InferenceEngine(
             self.n_slots = n_slots
             self.window_k = max(1, window_k)
             self.pipeline_depth = max(1, pipeline_depth)
-            # Mega-windows (throughput mode): ONE dispatch runs up to
-            # `mega_windows` k-step windows inside a device-side
-            # lax.while_loop that early-exits when every slot's remaining
-            # budget is covered (or its EOS was emitted): one host↔device
-            # round trip per m×k steps instead of one per k. What that
-            # round trip costs on an attached chip is not measured
-            # (ROADMAP D4).
-            # Trade-off: tokens surface per mega-window, not per window —
-            # streaming granularity coarsens, so serving defaults keep it
-            # off and bursty/offline throughput turns it on.
-            self.mega_windows = max(0, mega_windows)
             # Chunked prefill: [rows, prefill_chunk] steps serve every
             # prompt length, rows chosen at each dispatch from the rungs
             # 1 and prefill_batch by how many rows wait (both compiled
@@ -720,25 +620,14 @@ class InferenceEngine(
             # active streams.
             self.prefill_chunk = max(16, min(prefill_chunk, self.max_len))
             self.prefill_batch = max(1, min(prefill_batch, n_slots))
-            # Multi-chunk prefill (long-prompt dispatch amortizer): when
-            # every prefilling row has ≥2 full chunks left before its
-            # finalize chunk, run up to this many chunks per dispatch in
-            # a device-side loop. 1 disables (every chunk is its own
-            # dispatch — the latency-interleaving default).
-            self.prefill_depth = max(1, prefill_depth)
             self.truncate_prompts = truncate_prompts
-            # Speculative decoding (n-gram prompt lookup): each device step
-            # verifies spec_tokens drafts + 1, so windows can emit up to
-            # window_k * (spec_tokens+1) tokens per slot.
-            self.spec_tokens = max(0, spec_tokens)
-            step_tokens = self.window_k * (self.spec_tokens + 1)
-            reserve = 1 + (self.pipeline_depth + 1) * step_tokens
+            reserve = 1 + (self.pipeline_depth + 1) * self.window_k
             if self.max_len <= reserve:
                 raise ValueError(
                     f"max_len={self.max_len} too small: need > {reserve} "
-                    f"(1 + (pipeline_depth+1)*window_k*(spec_tokens+1)) so "
+                    f"(1 + (pipeline_depth+1)*window_k) so "
                     f"admission can reserve pipelined-window overshoot "
-                    f"room; lower window_k/pipeline_depth/spec_tokens or "
+                    f"room; lower window_k/pipeline_depth or "
                     f"raise max_len"
                 )
             self.kv_quant = (kv_quant or "").lower()
@@ -1024,23 +913,19 @@ class InferenceEngine(
         model_name = config.get_or_default("TPU_MODEL", "llama-tiny")
         ckpt = config.get_or_default("TPU_CHECKPOINT", "")
         quant_cfg = config.get_or_default("TPU_QUANT", "")
-        # TPU_SPEC_TOKENS=auto serves the plain decode window (see
-        # resolve_spec_tokens); only an explicit integer reaches the
-        # constructor's explicit-conflict error.
-        top_logprobs_cfg = int(
-            config.get_or_default("TPU_TOP_LOGPROBS", "0")
-        )
-        penalties_cfg = config.get_or_default(
-            "TPU_PENALTIES", "false"
-        ).lower() in ("1", "true", "yes")
-        import jax
-
-        spec_tokens_cfg, spec_note = resolve_spec_tokens(
-            config.get_or_default("TPU_SPEC_TOKENS", "auto"),
-            jax.default_backend(), penalties_cfg, top_logprobs_cfg,
-        )
-        if spec_note and logger is not None:
-            logger.infof("%s", spec_note)
+        # Retired keys: the programs they selected are gone (PR 30), and
+        # a deployment that still sets one is told so, not refused.
+        for key, off in (
+            ("TPU_SPEC_TOKENS", ("auto", "0")),
+            ("TPU_MEGA_WINDOWS", ("0",)),
+            ("TPU_PREFILL_DEPTH", ("1",)),
+        ):
+            val = config.get_or_default(key, off[0]).strip().lower()
+            if val not in off and logger is not None:
+                logger.warnf(
+                    "%s=%s is retired and ignored: the plain prefill step "
+                    "and decode window serve", key, val,
+                )
         params = None
         if ckpt:
             from gofr_tpu.serving.hf_loader import (
@@ -1088,8 +973,6 @@ class InferenceEngine(
             max_wait_s=float(config.get_or_default("TPU_BATCH_WAIT_MS", "5")) / 1e3,
             window_k=int(config.get_or_default("TPU_DECODE_WINDOW", "8")),
             pipeline_depth=int(config.get_or_default("TPU_PIPELINE_DEPTH", "2")),
-            mega_windows=int(config.get_or_default("TPU_MEGA_WINDOWS", "0")),
-            prefill_depth=int(config.get_or_default("TPU_PREFILL_DEPTH", "1")),
             kv_quant=config.get_or_default("TPU_KV_QUANT", ""),
             prefix_slots=int(config.get_or_default("TPU_PREFIX_SLOTS", "0")),
             prefill_chunk=int(config.get_or_default("TPU_PREFILL_CHUNK", "256")),
@@ -1098,11 +981,12 @@ class InferenceEngine(
                 "TPU_TRUNCATE_PROMPTS", "false"
             ).lower() in ("1", "true", "yes"),
             top_k=int(config.get_or_default("TPU_TOP_K", "0")),
-            top_logprobs=top_logprobs_cfg,
+            top_logprobs=int(config.get_or_default("TPU_TOP_LOGPROBS", "0")),
             enable_top_p=config.get_or_default("TPU_TOP_P", "false").lower()
             in ("1", "true", "yes"),
-            enable_penalties=penalties_cfg,
-            spec_tokens=spec_tokens_cfg,
+            enable_penalties=config.get_or_default(
+                "TPU_PENALTIES", "false"
+            ).lower() in ("1", "true", "yes"),
             kv_block=int(config.get_or_default("TPU_KV_BLOCK", "0")),
             lora_slots=int(config.get_or_default("TPU_LORA_SLOTS", "0")),
             lora_rank=int(config.get_or_default("TPU_LORA_RANK", "16")),
@@ -1662,12 +1546,6 @@ class InferenceEngine(
             np.zeros((n_slots, tlk), dtype=np.float32)
         )
         self._slot_state_dirty = True
-        # Token history per slot (prompt + generated) — the n-gram
-        # draft source; only maintained when speculation is on.
-        self._history_dev = (
-            self._up(np.zeros((n_slots, self.max_len), dtype=np.int32))
-            if self.spec_tokens else None
-        )
         # Compile-tracked paged-pool jits: the COW copy (prefix-hit
         # boundary) and the tier-transfer importer are module-level
         # fixed-shape programs; wrapping them per engine makes a mid-
@@ -2679,10 +2557,6 @@ class InferenceEngine(
                     "logit_bias must be an object mapping token ids to "
                     "numbers"
                 ])
-            # logit_bias composes with speculation since the exact-verify
-            # redesign: the spec window samples through the same biased
-            # `sample` closure the decode window uses, so acceptance
-            # compares drafts against the biased choice itself.
             if len(logit_bias) > LOGIT_BIAS_K:
                 raise ErrorInvalidParam([
                     f"logit_bias supports at most {LOGIT_BIAS_K} entries"
@@ -2947,7 +2821,7 @@ class InferenceEngine(
                 self._active_dev, self._temps_dev, self._topp_dev,
                 self._greedy_dev, self._pcounts_dev, self._fpen_dev,
                 self._ppen_dev, self._bidx_dev, self._bval_dev,
-                self._topi_dev, self._topl_dev, self._history_dev,
+                self._topi_dev, self._topl_dev,
             ])
             components["workspace"] = workspace
             if self._prefix_pool is not None:
@@ -3288,8 +3162,6 @@ class InferenceEngine(
             }
             details["max_len"] = self.max_len
             details["kv_bytes_per_token"] = self.kv_bytes_per_token()
-            # What TPU_SPEC_TOKENS (default "auto") resolved to.
-            details["spec_tokens"] = self.spec_tokens
             details["pending"] = self._pending.qsize()
             details["prefilling"] = len(self._prefilling)
             # Disaggregated-tier role (TPU_REPLICA_ROLES): which serving

@@ -1,5 +1,5 @@
 """The LLM continuous-batching scheduler: admission, chunked prefill,
-pipelined/mega decode windows, paged-KV block accounting, and
+pipelined decode windows, paged-KV block accounting, and
 retirement. Mixin methods on InferenceEngine — split from
 ``engine.py`` along its scheduler seams (r4 VERDICT weak #10)."""
 
@@ -63,7 +63,6 @@ class SchedulerMixin:
     _lockstep: bool
     kv_block: int
     max_len: int
-    mega_windows: int
     tier_role: str
     prefix_evict_watermark: int
     effective_evict_watermark: int
@@ -74,8 +73,6 @@ class SchedulerMixin:
     prefill_batch: int
     prefill_rungs: tuple[int, ...]
     prefill_chunk: int
-    prefill_depth: int
-    spec_tokens: int
     top_logprobs: int
     window_k: int
     enable_penalties: bool
@@ -145,17 +142,11 @@ class SchedulerMixin:
     _bval_dev: Any
     _topi_dev: Any
     _topl_dev: Any
-    _history_dev: Any
     # Compiled-program callables (LLMProgramsMixin) and engine methods
     # this loop calls across the facade.
     _prefill_step: Any  # (rows, use_bias) -> the compiled rung
     _prefill_operands: Any  # the nine per-row arrays -> its operands
-    _prefill_multi_chunk: Any
-    _prefill_multi_chunk_hist: Any
     _decode_window: Any
-    _spec_window: Any
-    _mega_window: Any
-    _mega_spec_window: Any
     # Compile-tracked paged-pool jits (engine._init_llm_serving_state
     # wraps ops.kv_cache.paged_{copy,insert,extract,move}_block per
     # engine; extract/move are the device-leg tier-transfer pair).
@@ -191,8 +182,7 @@ class SchedulerMixin:
         # Windows are PIPELINED `pipeline_depth` deep: dispatch window n+D
         # before fetching window n's tokens. The host↔device round trip is
         # latency, not bandwidth — overlapping D fetches with compute makes
-        # the floor device step time. What depth an attached chip needs is
-        # not measured (ROADMAP D4).
+        # the floor device step time.
         from collections import deque
 
         inflight: deque = deque()  # _dispatch_window return tuples
@@ -307,11 +297,10 @@ class SchedulerMixin:
                 # beyond what in-flight windows already cover — a wave of
                 # same-length requests otherwise ends with `depth` pure-
                 # overshoot windows whose tokens are all discarded.
-                # (tokens_in_flight counts the GUARANTEED k emissions per
-                # window + the prefill token; emitted = in_flight - 1, so
-                # dispatch while in_flight <= budget. eos/stop retirements
-                # end earlier via processing; speculation only ever emits
-                # MORE per window than the guarantee.)
+                # (tokens_in_flight counts the k emissions per window +
+                # the prefill token; emitted = in_flight - 1, so dispatch
+                # while in_flight <= budget. eos/stop retirements end
+                # earlier via processing.)
                 wants_more = any_active and any(
                     s is not None
                     and s.tokens_in_flight <= s.request.remaining_new_tokens
@@ -1195,9 +1184,6 @@ class SchedulerMixin:
         else:
             self._wm_fruitless = sig
 
-    def _window_tokens(self) -> int:
-        return self.window_k * (self.spec_tokens + 1)
-
     def _dispatch_prefill_chunk(self, lap_import: bool = False) -> bool:
         """Admit pending requests into free slots and dispatch ONE
         [rows, prefill_chunk] chunk step, ``rows`` the smallest rung of
@@ -1223,10 +1209,8 @@ class SchedulerMixin:
             # writes are device work that would otherwise hide inside
             # "prefill" (one stamp per apply, not per block). Only the
             # PASS-SEAM call laps — re-entries from the wave-admission
-            # loop or _process_window's mega-mode readiness poll would
-            # otherwise attribute prefill work (or the device-window
-            # wait itself) to tier_import and invert the host-overhead
-            # diagnosis.
+            # loop would otherwise attribute prefill work to tier_import
+            # and invert the host-overhead diagnosis.
             with loop_phase(
                 self._loop_prof if lap_import else None, "tier_import"
             ):
@@ -1283,7 +1267,6 @@ class SchedulerMixin:
             room = (
                 self.max_len - 1 - len(req.prompt_ids)
                 - (self.pipeline_depth + 1) * self.window_k
-                * (self.spec_tokens + 1)
             )
             req.max_new_tokens = max(1, min(req.max_new_tokens, room))
             if req.replayed_tokens >= req.max_new_tokens:
@@ -1429,15 +1412,14 @@ class SchedulerMixin:
         # dispatch — the scheduler's death drain must fail every caller.
         faults.fire("scheduler.device_step", engine=self, kind="prefill")
         self._check_superseded()
-        # Host-side dispatch count (exactly one chunk step — multi OR
-        # single — leaves this method per True return): the prefix-cache
-        # tests assert a warm request takes strictly fewer steps.
+        # Host-side dispatch count (exactly one chunk step leaves this
+        # method per True return): the prefix-cache tests assert a warm
+        # request takes strictly fewer steps.
         self._prefill_chunk_steps += 1
         if self._seeds_dirty:
-            # Upload the admission-scoped planes BEFORE any dispatch —
-            # the deep multi-chunk branch below reads _aids_dev, so a
-            # flush only on the single-chunk path would prefill a long
-            # prompt with the slot's PREVIOUS occupant's adapter.
+            # Upload the admission-scoped planes BEFORE the dispatch:
+            # the step reads _aids_dev, and a stale plane would prefill
+            # with the slot's PREVIOUS occupant's adapter.
             self._seeds_dev = self._up(self._seeds_host)
             self._noff_dev = self._up(self._noff_host)
             self._bidx_dev = self._up(self._bidx_host)
@@ -1447,79 +1429,6 @@ class SchedulerMixin:
 
         P, c = self.prefill_batch, self.prefill_chunk
         rows = list(self._prefilling.items())[:P]
-
-        # Multi-chunk fast path: rows with ≥2 full chunks before their
-        # finalize chunk burn through up to prefill_depth of them in one
-        # device-side loop (no sampling, no finalize — the single-chunk
-        # step below always closes a prompt). Only DEEP rows join the
-        # batch — one short prompt admitted alongside an 8k one must not
-        # disable the amortizer for the long row; shallow rows take the
-        # single-chunk step next loop iteration. Paged mode needs no
-        # per-chunk allocation: admission already covered the whole prompt.
-        if self.prefill_depth > 1:
-            deep = [
-                (slot, st, rem)
-                for slot, st in rows
-                for rem in [(len(st.ids) - st.done - 1) // c]
-                if rem >= 2
-            ]
-            if deep:
-                d = min(min(rem for _, _, rem in deep), self.prefill_depth)
-            if deep and d >= 2:
-                D = self.prefill_depth
-                tokens3 = np.zeros((D, P, c), dtype=np.int32)
-                slots_m = np.zeros((P,), dtype=np.int32)
-                starts_m = np.zeros((P,), dtype=np.int32)
-                for i, (slot, st, _) in enumerate(deep):
-                    ids = st.ids
-                    for j in range(d):
-                        lo = st.done + j * c
-                        tokens3[j, i, :] = ids[lo : lo + c]
-                    slots_m[i] = slot
-                    starts_m[i] = st.done
-                for i in range(len(deep), P):  # pad rows duplicate row 0
-                    tokens3[:, i, :] = tokens3[:, 0, :]
-                    slots_m[i], starts_m[i] = slots_m[0], starts_m[0]
-                t0 = time.time()
-                t0m = self._obs.now()
-                self._push_table()
-                margs = (
-                    self.params, self.cache, self._up(tokens3),
-                    self._up(slots_m), self._up(starts_m),
-                    self._up(np.int32(d)),
-                )
-                # Locals-then-commit around the dispatch (same zombie
-                # fence as _dispatch_window): a wedged call that returns
-                # after abandonment must not clobber the new cache.
-                mhist = None
-                if self.spec_tokens:
-                    mcache, mhist = self._prefill_multi_chunk_hist(
-                        *margs, self._history_dev, self._aids_dev
-                    )
-                else:
-                    mcache = self._prefill_multi_chunk(
-                        *margs, self._aids_dev
-                    )
-                self._check_superseded()
-                self.cache = mcache
-                if mhist is not None:
-                    self._history_dev = mhist
-                if self._lockstep:
-                    self._jax.block_until_ready(self.cache.lengths)  # graftlint: disable=GL019 — multi-process CPU lockstep barrier (gloo collective ordering), a deliberate device wait
-                # One clock read per multi-chunk DISPATCH, shared by
-                # every row it advanced (timestamps at window
-                # granularity — graftlint GL011).
-                t1m = self._obs.now()
-                for _, st, _ in deep:
-                    st.done += d * c
-                    if st.request.timeline is not None:
-                        st.request.timeline.note_chunk(t0m, t1m, d * c, P)
-                if self._metrics is not None:
-                    self._metrics.record_histogram(
-                        "app_tpu_infer_latency", time.time() - t0,
-                        "kind", "prefill_multi",
-                    )
-                return True
 
         # Every compiled row is computed in full, so the step runs at
         # the smallest rung that holds the rows that wait.
@@ -1568,17 +1477,15 @@ class SchedulerMixin:
         )
         # Locals-then-commit around the dispatch (zombie fence; see
         # _dispatch_window).
-        out = self._prefill_step(R, use_bias)(*args)
         (ccache, ctoks, clps, first_dev,
          first_lp_dev, cpc, cnst,
-         cti, ctl, ftopi_dev, ftopl_dev) = out[:11]
-        chist = out[11] if self.spec_tokens else None
+         cti, ctl, ftopi_dev, ftopl_dev) = self._prefill_step(
+            R, use_bias
+        )(*args)
         self._check_superseded()
         self.cache, self._tokens_dev, self._logps_dev = ccache, ctoks, clps
         self._pcounts_dev, self._nsteps_dev = cpc, cnst
         self._topi_dev, self._topl_dev = cti, ctl
-        if chist is not None:
-            self._history_dev = chist
         if self._lockstep:
             self._jax.block_until_ready(first_dev)  # graftlint: disable=GL019 — multi-process CPU lockstep barrier (gloo collective ordering), a deliberate device wait
         if self._metrics is not None:
@@ -1762,14 +1669,11 @@ class SchedulerMixin:
 
     def _dispatch_window(self) -> tuple:
         """Dispatch one k-step device window (non-blocking) and start the
-        async device→host copy of its emitted block — [2, k, S] for plain
-        decode, [2, k, S, G+1] plus a [k, S] counts array for speculative
-        windows, [2, m*k, S] plus a windows-run scalar for mega windows.
-        Returns ``(emitted_dev, counts_dev_or_None, slots_snapshot,
-        wrun_dev_or_None, etops_dev_or_None, live_positions)`` for
-        _process_window — the snapshot
-        matters because by processing time a retired slot may already hold
-        a NEW request admitted in between."""
+        async device→host copy of its emitted [2, k, S] block. Returns
+        ``(emitted_dev, slots_snapshot, etops_dev_or_None,
+        live_positions)`` for _process_window — the snapshot matters
+        because by processing time a retired slot may already hold a NEW
+        request admitted in between."""
         # Fault seam: a raise models the device failing a decode window;
         # an armed action that blocks models a hung step (watchdog).
         faults.fire("scheduler.device_step", engine=self, kind="decode")
@@ -1802,54 +1706,26 @@ class SchedulerMixin:
                 self._ppen_dev = self._up(ppen)
             self._slot_state_dirty = False
 
-        # Mega-window mode: compute each slot's remaining budget on the
-        # host (it knows tokens_in_flight) and hand it to the device loop;
-        # coverage accounting uses the same number so `wants_more` gating
-        # stays exact (the device delivers ≥ min(m·k, remaining) steps per
-        # slot — early exit only fires once every remaining hits 0 or EOS,
-        # and an EOS slot is retired by processing, so accounting can
-        # never strand a live slot).
-        mega = self.mega_windows
         use_bias = any(
             seq is not None and seq.request.logit_bias
             for seq in self._slots
         )
-        remaining_host = eos_stop_host = None
-        cover = self.window_k * mega  # guaranteed MINIMUM emissions
-        if mega > 1:
-            remaining_host = np.zeros((self.n_slots,), dtype=np.int32)
-            eos_stop_host = np.zeros((self.n_slots,), dtype=bool)
-            for i, seq in enumerate(self._slots):
-                if seq is not None:
-                    remaining_host[i] = max(
-                        0,
-                        seq.request.remaining_new_tokens + 1
-                        - seq.tokens_in_flight,
-                    )
-                    eos_stop_host[i] = seq.request.stop_on_eos
 
         if self.kv_block:
             # Allocation must stay AHEAD of the window about to be
             # dispatched (its writes land before the host sees the
             # tokens). A dry pool mid-stream fails the request — the
             # honest outcome of an oversubscribed pool.
-            wt = self._window_tokens()
             for i, seq in enumerate(self._slots):
                 if seq is None:
                     continue
-                if mega > 1:
-                    # Windows this slot still WRITES real K/V for: its
-                    # remaining budget covers in ≤ ceil(remaining/k)
-                    # windows (spec emits ≥ k/window); each window writes
-                    # k*(G+1) positions. Junk past that parks at block 0.
-                    k = self.window_k
-                    windows_i = min(mega, -(-int(remaining_host[i]) // k))
-                    wt = windows_i * k * (self.spec_tokens + 1)
                 req = seq.request
                 base = req.effective_prompt_len or len(req.prompt_ids)
-                need = base + self._dispatched_tokens[i] + wt + 1
+                need = (
+                    base + self._dispatched_tokens[i] + self.window_k + 1
+                )
                 if self._ensure_blocks(i, need):
-                    self._dispatched_tokens[i] += wt
+                    self._dispatched_tokens[i] += self.window_k
                     continue
                 if not req.future.done():
                     req.future.set_exception(RuntimeError(
@@ -1859,12 +1735,6 @@ class SchedulerMixin:
                 req.stream.put(None)
                 self._obs_finish(req, "error", "kv_pool_exhausted")
                 self._release_slot(i)
-                if mega > 1:
-                    # remaining_host was computed before this loop; the
-                    # device must not spin mega windows covering a slot
-                    # whose request just failed.
-                    remaining_host[i] = 0
-                    eos_stop_host[i] = False
             self._push_table()
 
         # Cache positions that are context when this window starts: each
@@ -1877,122 +1747,62 @@ class SchedulerMixin:
                     (req.effective_prompt_len or len(req.prompt_ids))
                     + seq.tokens_in_flight - 1
                 )
-                seq.tokens_in_flight += (
-                    min(cover, int(remaining_host[i])) if mega > 1
-                    else self.window_k
-                )
-        counts = None
-        wrun = None
-        etops = None
+                seq.tokens_in_flight += self.window_k
         # Results land in LOCALS first and commit to self only after a
         # superseded check: a dispatch that BLOCKED here (a hung device
         # step — the exact case the supervisor abandons threads over) must not
         # overwrite the restarted engine's live cache/planes when its
         # stuck call finally returns.
-        hist = pc = ti = tl = None
-        if mega > 1 and self.spec_tokens:
-            (emitted, counts, wrun, toks, lps, cache, nst, hist) = (
-                self._mega_spec_window(
-                    self.params, self._tokens_dev, self._logps_dev,
-                    self.cache, self._active_dev, self._nsteps_dev,
-                    self._temps_dev, self._greedy_dev, self._topp_dev,
-                    self._history_dev, self._seeds_dev,
-                    self._bidx_dev, self._bval_dev,
-                    self._up(remaining_host), self._up(eos_stop_host),
-                    self._aids_dev,
-                    k=self.window_k, m=mega, use_bias=use_bias,
-                )
+        (emitted, etops, toks, lps, cache, nst, pc, ti, tl) = (
+            self._decode_window(
+                self.params, self._tokens_dev, self._logps_dev,
+                self.cache, self._active_dev, self._nsteps_dev,
+                self._temps_dev, self._greedy_dev, self._topp_dev,
+                self._fpen_dev, self._ppen_dev, self._pcounts_dev,
+                self._seeds_dev, self._bidx_dev, self._bval_dev,
+                self._topi_dev, self._topl_dev, self._aids_dev,
+                k=self.window_k, use_bias=use_bias,
             )
-        elif mega > 1:
-            (emitted, etops, wrun, toks, lps, cache, nst, pc, ti, tl) = (
-                self._mega_window(
-                    self.params, self._tokens_dev, self._logps_dev,
-                    self.cache, self._active_dev, self._nsteps_dev,
-                    self._temps_dev, self._greedy_dev, self._topp_dev,
-                    self._fpen_dev, self._ppen_dev, self._pcounts_dev,
-                    self._seeds_dev, self._bidx_dev, self._bval_dev,
-                    self._topi_dev, self._topl_dev,
-                    self._up(remaining_host), self._up(eos_stop_host),
-                    self._aids_dev,
-                    k=self.window_k, m=mega, use_bias=use_bias,
-                )
-            )
-        elif self.spec_tokens:
-            (emitted, counts, toks, lps, cache, nst, hist) = (
-                self._spec_window(
-                    self.params, self._tokens_dev, self._logps_dev,
-                    self.cache, self._active_dev, self._nsteps_dev,
-                    self._temps_dev, self._greedy_dev, self._topp_dev,
-                    self._history_dev, self._seeds_dev,
-                    self._bidx_dev, self._bval_dev, self._aids_dev,
-                    k=self.window_k, use_bias=use_bias,
-                )
-            )
-        else:
-            (emitted, etops, toks, lps, cache, nst, pc, ti, tl) = (
-                self._decode_window(
-                    self.params, self._tokens_dev, self._logps_dev,
-                    self.cache, self._active_dev, self._nsteps_dev,
-                    self._temps_dev, self._greedy_dev, self._topp_dev,
-                    self._fpen_dev, self._ppen_dev, self._pcounts_dev,
-                    self._seeds_dev, self._bidx_dev, self._bval_dev,
-                    self._topi_dev, self._topl_dev, self._aids_dev,
-                    k=self.window_k, use_bias=use_bias,
-                )
-            )
+        )
         self._check_superseded()
         self._tokens_dev, self._logps_dev = toks, lps
         self.cache, self._nsteps_dev = cache, nst
-        if hist is not None:
-            self._history_dev = hist
-        if pc is not None:
-            self._pcounts_dev, self._topi_dev, self._topl_dev = pc, ti, tl
+        self._pcounts_dev, self._topi_dev, self._topl_dev = pc, ti, tl
         if etops is not None and not any(
             seq is not None and seq.request.top_logprobs
             for seq in self._slots
         ):
-            # Nobody asked for alternatives: skip the [2, m*k, S, K]
+            # Nobody asked for alternatives: skip the [2, k, S, K]
             # device→host block entirely (the program computes it either
             # way; the fetch is what costs on the dispatch path).
             etops = None
-        extras = [a for a in (counts, wrun, etops) if a is not None]
-        for arr in (emitted, *extras):
-            arr.copy_to_host_async()
+        emitted.copy_to_host_async()
+        if etops is not None:
+            etops.copy_to_host_async()
         if self._lockstep:
             lockcheck.note_device_sync("lockstep_block_until_ready")
             self._jax.block_until_ready(emitted)
-        return emitted, counts, list(self._slots), wrun, etops, live_positions
+        return emitted, list(self._slots), etops, live_positions
 
     def _process_window(
         self,
         emitted: Any,
-        counts: Any,
         snapshot: "list[Optional[_ActiveSeq]]",
-        wrun: Any = None,
-        etops: Any = None,
-        live_positions: int = 0,
+        etops: Any,
+        live_positions: int,
     ) -> None:
         t_fetch = time.time()
         # Interruptible wait: while this window's block is in flight, flush
         # any prefill first-token fetches that land first (unloaded TTFT
-        # would otherwise be gated on the window fetch). Mega mode also
-        # keeps ADMITTING during the wait — prefill chunks for queued
-        # requests ride the device queue behind the in-flight mega window,
-        # overlapping next-wave admission with current-wave decode.
-        if (self._prefill_emits or wrun is not None) and hasattr(
-            emitted, "is_ready"
-        ):
+        # would otherwise be gated on the window fetch).
+        if self._prefill_emits and hasattr(emitted, "is_ready"):
             while not emitted.is_ready():
-                if wrun is not None:
-                    self._dispatch_prefill_chunk()
                 self._flush_prefill_emits()
                 # Device-readiness poll: there is no host-side event to
                 # wait on for an in-flight device computation, and the
                 # 1 ms granularity is what lets prefill emits interleave
                 # with the window fetch. Not a latency-adding sleep.
                 time.sleep(0.001)  # graftlint: disable=GL004
-        # Decode: [2, k, S] (mega: [2, m*k, S], first wrun*k valid).
-        # Spec: [2, k, S, G+1] + counts [k, S].
         lockcheck.note_device_sync("decode_window_fetch")
         # Named in the profiler's trace: the one place this thread
         # blocks on the device, beside the device's own ops.
@@ -2005,12 +1815,7 @@ class SchedulerMixin:
         # tokens on replayed streams and release slots/blocks of the
         # restarted scheduler's allocator.
         self._check_superseded()
-        counts_host = np.asarray(counts) if counts is not None else None
         etops_host = np.asarray(etops) if etops is not None else None
-        steps = (
-            self.window_k if wrun is None
-            else int(np.asarray(wrun)) * self.window_k
-        )
         if self._metrics is not None:
             # decode_fetch = host-blocking time (what pipelining hides).
             self._metrics.record_histogram(
@@ -2059,96 +1864,61 @@ class SchedulerMixin:
                 seq.first_token_at = now
                 if seq.request.timeline is not None:
                     seq.request.timeline.mark_first_token(mono_now)
-            if counts_host is None:
-                step_toks = (
-                    ((emitted_host[0, step, i], emitted_host[1, step, i]),)
-                    for step in range(steps)
-                )  # enumerate() below recovers the step index for etops
-            else:
-                step_toks = (
-                    tuple(
-                        (emitted_host[0, step, i, j], emitted_host[1, step, i, j])
-                        for j in range(int(counts_host[step, i]))
-                    )
-                    for step in range(steps)
-                )
             want_top = (
                 etops_host is not None and seq.request.top_logprobs
             )
-            done = False
-            for step, toks in enumerate(step_toks):
-                for tok_f, lp in toks:
-                    if seq.first_emitted and not seq.first_skip_done:
-                        # This position repeats the prefill-sampled token
-                        # that _flush_prefill_emits already emitted.
-                        seq.first_skip_done = True
-                        continue
-                    tok = int(tok_f)
-                    top = None
-                    if want_top:
-                        top = [
-                            (int(etops_host[0, step, i, j]),
-                             float(etops_host[1, step, i, j]))
-                            for j in range(seq.request.top_logprobs)
-                        ]
-                    seq.last_token = tok
-                    seq.n_generated += 1
-                    self._emit_token(seq, tok, float(lp), top)
-                    if self._finished(seq):
-                        self._retire(i, seq)
-                        if self._slots[i] is seq:
-                            self._release_slot(i)
-                        done = True
-                        break
-                if done:
+            for step in range(self.window_k):
+                if seq.first_emitted and not seq.first_skip_done:
+                    # This position repeats the prefill-sampled token
+                    # that _flush_prefill_emits already emitted.
+                    seq.first_skip_done = True
+                    continue
+                tok = int(emitted_host[0, step, i])
+                top = None
+                if want_top:
+                    top = [
+                        (int(etops_host[0, step, i, j]),
+                         float(etops_host[1, step, i, j]))
+                        for j in range(seq.request.top_logprobs)
+                    ]
+                seq.last_token = tok
+                seq.n_generated += 1
+                self._emit_token(
+                    seq, tok, float(emitted_host[1, step, i]), top
+                )
+                if self._finished(seq):
+                    self._retire(i, seq)
+                    if self._slots[i] is seq:
+                        self._release_slot(i)
                     break
         if self._metrics is not None:
             # Per-WINDOW observability (one record each per processed
             # window, from host values already in hand — no per-token
             # work, no device pulls).
             dispatched_live = sum(1 for s in snapshot if s is not None)
-            # Tokens per live step across the window. Spec window: 1.0 =
-            # no draft accepted, spec_tokens+1 = all. A plain step emits
-            # one token per live slot by definition, so a plain window
-            # that had a live slot records 1.0: the counter says what
-            # engaged either way.
-            if counts_host is not None:
-                live = counts_host > 0
-                per_step = (
-                    float(counts_host[live].mean()) if live.any() else None
-                )
-            else:
-                per_step = 1.0 if steps and dispatched_live else None
-            if per_step is not None:
-                self._metrics.record_histogram(
-                    "app_tpu_spec_tokens_per_step", per_step,
-                    "model", self.model_name,
-                )
-            if steps:
-                # How full the batch is now (the gauge), and how full
-                # this window ran — the slots live when it was
-                # dispatched — as a histogram whose sum over count
-                # between two scrapes is the mean over exactly the
-                # windows in between.
-                in_use = sum(1 for s in self._slots if s is not None)
-                self._metrics.set_gauge(
-                    "app_tpu_batch_occupancy",
-                    in_use / max(1, self.n_slots),
-                    "model", self.model_name,
-                )
-                self._metrics.record_histogram(
-                    "app_tpu_window_occupancy",
-                    dispatched_live / max(1, self.n_slots),
-                    "model", self.model_name,
-                )
-                # The share of the cache the dense decode path reads
-                # (every position of every slot) that was context and
-                # not reserve when this window was dispatched.
-                self._metrics.record_histogram(
-                    "app_tpu_kv_live_ratio",
-                    live_positions / max(1, self.n_slots * self.max_len),
-                    "model", self.model_name,
-                )
+            # How full the batch is now (the gauge), and how full this
+            # window ran — the slots live when it was dispatched — as a
+            # histogram whose sum over count between two scrapes is the
+            # mean over exactly the windows in between.
+            in_use = sum(1 for s in self._slots if s is not None)
+            self._metrics.set_gauge(
+                "app_tpu_batch_occupancy",
+                in_use / max(1, self.n_slots),
+                "model", self.model_name,
+            )
+            self._metrics.record_histogram(
+                "app_tpu_window_occupancy",
+                dispatched_live / max(1, self.n_slots),
+                "model", self.model_name,
+            )
+            # The share of the cache the dense decode path reads
+            # (every position of every slot) that was context and
+            # not reserve when this window was dispatched.
+            self._metrics.record_histogram(
+                "app_tpu_kv_live_ratio",
+                live_positions / max(1, self.n_slots * self.max_len),
+                "model", self.model_name,
+            )
         self._update_slot_gauges()
 
     def _emit_token(

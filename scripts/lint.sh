@@ -4,7 +4,7 @@
 #               plus import sorting scoped to the analysis package;
 #   mypy      — scoped strictness (config/logging/service/scheduler strict,
 #               rest permissive; see [tool.mypy] in pyproject.toml);
-#   graftlint — TPU-correctness rules GL001–GL025 (per-file TPU rules
+#   graftlint — TPU-correctness rules GL001–GL024 (per-file TPU rules
 #               plus project-wide concurrency analysis) against the committed
 #               baseline (gofr_tpu/analysis; docs/advanced-guide/
 #               static-analysis.md).
@@ -18,7 +18,7 @@ failed=0
 
 if command -v ruff >/dev/null 2>&1; then
   echo "== ruff =="
-  ruff check gofr_tpu/ tests/ examples/ bench.py chip_smoke.py __graft_entry__.py || failed=1
+  ruff check gofr_tpu/ tests/ examples/ chip_smoke.py __graft_entry__.py || failed=1
   ruff check --select I gofr_tpu/analysis tests/test_graftlint.py || failed=1
 else
   echo "== ruff == SKIPPED (not installed; pip install ruff)"

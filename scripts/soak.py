@@ -1,7 +1,7 @@
 """Serving soak: continuous mixed load across the full feature matrix.
 
-One engine (mega windows + paged KV + penalties + top_logprobs +
-multi-LoRA + sliding window) takes wave after wave of requests churning
+One engine (paged KV + penalties + top_logprobs + multi-LoRA +
+sliding window) takes wave after wave of requests churning
 seeds, penalties, logit_bias, top_logprobs, stop sequences, adapters,
 and mid-flight cancellations, with adapters loaded/unloaded between
 waves. After every wave the engine must return to VERIFIED IDLE: all
@@ -47,7 +47,7 @@ def main() -> int:
     cfg = dataclasses.replace(tiny.config, sliding_window=32)
     register_model(dataclasses.replace(tiny, name="soak-swa-tiny", config=cfg))
     eng = InferenceEngine(
-        "soak-swa-tiny", n_slots=8, max_len=256, window_k=4, mega_windows=4,
+        "soak-swa-tiny", n_slots=8, max_len=256, window_k=4,
         enable_penalties=True, top_logprobs=2, kv_block=32,
         tokenizer=ByteTokenizer(), lora_slots=2, lora_rank=4,
     )
@@ -78,7 +78,7 @@ def main() -> int:
     # static compile switches are use_bias (2 variants per program) and
     # the engine-level feature flags; penalties/seeds/top_logprobs ride
     # as dynamic operands. Measured: 12 churn waves hold jit cache sizes
-    # at {prefill: 2, mega: 2} with RSS flat at 454 MB. The r4 soak's
+    # at two a program with RSS flat at 454 MB. The r4 soak's
     # 0.27→0.52 GB was first-touch compile warmup, not monotonic growth.
     # This assertion makes any regression (a new static arg minting
     # per-request variants) fail the soak loudly: peak RSS after the

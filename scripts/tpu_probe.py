@@ -110,49 +110,6 @@ def main() -> None:
     full = jax.jit(window)
     base = probe("full int8 (argmax)", full, params8, tokens, cache)
 
-    # --- mega window: M k-step windows in a while_loop per dispatch (the
-    # r4 serving throughput mode). vs `full`: quantifies (a) whether the
-    # while_loop costs device time over the plain scan, (b) the dispatch
-    # amortization — one host call per M*K steps.
-    for M in (4, 16):
-        def mega(params, tokens, cache, M=M):
-            def body(carry, _):
-                tokens, cache = carry
-                logits, cache = tr.transformer_decode_step(
-                    params, tokens, cache, active, cfg
-                )
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (nxt, cache), None
-
-            def win(state):
-                i, tokens, cache = state
-                (tokens, cache), _ = jax.lax.scan(
-                    body, (tokens, cache), length=K
-                )
-                return i + 1, tokens, cache
-
-            _, tokens, cache = jax.lax.while_loop(
-                lambda s: s[0] < M, win,
-                (jnp.asarray(0, jnp.int32), tokens, cache),
-            )
-            return tokens, cache.lengths
-
-        try:
-            fn = jax.jit(mega)
-            jax.block_until_ready(fn(params8, tokens, cache))
-            t0 = time.perf_counter()
-            out = fn(params8, tokens, cache)
-            jax.block_until_ready(out)
-            per_step = (time.perf_counter() - t0) / (M * K) * 1e3
-            print(
-                f"probe: mega M={M:<3} (one dispatch)  {per_step:8.3f} "
-                f"ms/step  → {SLOTS / per_step * 1e3:7.0f} tok/s "
-                f"@ {SLOTS} slots",
-                flush=True,
-            )
-        except Exception as exc:  # noqa: BLE001 — probe is advisory
-            print(f"probe: mega M={M} FAILED: {exc!r}", flush=True)
-
     # --- attention monkeypatched out (still writes K/V into the cache).
     real_attn = tr.decode_attention
     tr.decode_attention = (

@@ -1424,65 +1424,6 @@ def test_gl024_accepts_budgeted_and_out_of_scope(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# GL025 — a second decode-logits path in the serving plane
-# ----------------------------------------------------------------------
-
-
-def test_gl025_flags_batched_verify_forward_in_serving(tmp_path):
-    # The once-shipped bug class: serving calls a batched verify
-    # forward whose contraction shape accumulates bf16 in a different
-    # order than the decode step, so near-tie argmaxes flip.
-    ids, findings = _lint(
-        tmp_path, "serving/programs.py",
-        """
-        def body(carry, _):
-            logits, cache = transformer_verify_step(
-                params, inputs, cache, active, cfg
-            )
-            return carry, logits
-
-        def other(carry, _):
-            return carry, models.custom_verify_step(params, inputs)
-        """,
-        select=["GL025"],
-    )
-    assert ids == ["GL025", "GL025"]
-    assert "contraction shape" in findings[0].message
-    assert "transformer_decode_step" in findings[0].message
-
-
-def test_gl025_accepts_decode_step_and_out_of_scope(tmp_path):
-    # Reusing the decode-step builder is the fix, not a finding; the
-    # models layer (parity tests, builders) legitimately calls the
-    # batched verify; deliberate tolerance-checked uses carry a disable.
-    ids, _ = _lint(
-        tmp_path, "serving/programs.py",
-        """
-        def pos_body(pcarry, tok_j):
-            cache_i, n_i = pcarry
-            logits, cache_i = transformer_decode_step(
-                params, tok_j, cache_i, active, cfg
-            )
-            return (cache_i, n_i), logits
-
-        def parity(params, inputs, cache):
-            return transformer_verify_step(params, inputs, cache)  # graftlint: disable=GL025 — tolerance-checked models-layer parity harness
-        """,
-        select=["GL025"],
-    )
-    assert ids == []
-    ids, _ = _lint(
-        tmp_path, "models/transformer.py",
-        """
-        def build(params, inputs, cache):
-            return transformer_verify_step(params, inputs, cache)
-        """,
-        select=["GL025"],
-    )
-    assert ids == []
-
-
-# ----------------------------------------------------------------------
 # suppressions
 # ----------------------------------------------------------------------
 

@@ -260,8 +260,6 @@ def test_what_is_not_implemented_for_a_looped_stack_is_refused_at_boot():
     ))
     with pytest.raises(ValueError, match="exit_threshold=0.9 < 1 is not served"):
         engine_of("looped-tiny-early-exit")
-    with pytest.raises(ValueError, match="TPU_SPEC_TOKENS=2 is not served"):
-        engine_of(spec_tokens=2)
     with pytest.raises(ValueError, match="pipeline-parallel"):
         transformer_param_specs(CFG, pp=True)
     specs = transformer_param_specs(CFG)  # tp specs cover every leaf

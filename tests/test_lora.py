@@ -154,48 +154,11 @@ def test_concurrent_adapters_batch_isolation():
         eng.stop_sync()
 
 
-def test_mega_window_adapter_parity():
-    """Mega-window dispatch honors per-slot adapters identically."""
-    leaves = _rand_adapter(31)
-    plain = _engine()
-    mega = _engine(mega_windows=4)
-    try:
-        plain.load_lora("t", leaves)
-        mega.load_lora("t", leaves)
-        assert _gen(plain, "ab", adapter="t") == _gen(
-            mega, "ab", adapter="t"
-        )
-    finally:
-        plain.stop_sync()
-        mega.stop_sync()
-
-
-def test_spec_window_adapter_parity():
-    """Greedy speculative decoding is lossless under an adapter too."""
-    leaves = _rand_adapter(41)
-    plain = _engine()
-    spec = InferenceEngine(
-        "llama-tiny-f32", n_slots=4, max_len=128, window_k=4,
-        tokenizer=ByteTokenizer(), lora_slots=2, lora_rank=4,
-        spec_tokens=2,
-    )
-    spec.start_sync()
-    try:
-        plain.load_lora("t", leaves)
-        spec.load_lora("t", leaves)
-        assert _gen(plain, "ab", adapter="t") == _gen(
-            spec, "ab", adapter="t"
-        )
-    finally:
-        plain.stop_sync()
-        spec.stop_sync()
-
-
 def test_ffn_targets_through_engine():
-    """FFN LoRA targets (w_gate/w_up/w_down) apply on EVERY serving path
-    — chunked prefill, decode, and speculative verify — not just the
-    full-sequence forward (regression: the three inline layer bodies
-    dropped aids on their _ffn_dense calls)."""
+    """FFN LoRA targets (w_gate/w_up/w_down) apply on both serving paths,
+    chunked prefill and decode, not just the full-sequence forward
+    (regression: the inline layer bodies dropped aids on their _ffn_dense
+    calls)."""
     all_targets = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
     key = jax.random.PRNGKey(61)
     leaves = {}
@@ -206,41 +169,38 @@ def test_ffn_targets_through_engine():
             0.5 * jax.random.normal(k1, (CFG.n_layers, d_in, 4)),
             0.5 * jax.random.normal(k2, (CFG.n_layers, 4, d_out)),
         )
-    for spec_tokens in (0, 2):
-        eng = InferenceEngine(
+    eng = InferenceEngine(
+        "llama-tiny-f32", n_slots=4, max_len=128, window_k=4,
+        tokenizer=ByteTokenizer(), lora_slots=1, lora_rank=4,
+        lora_targets=",".join(all_targets),
+    )
+    eng.start_sync()
+    try:
+        eng.load_lora("full", leaves)
+        got = _gen(eng, "hello", adapter="full")
+        merged_eng = InferenceEngine(
             "llama-tiny-f32", n_slots=4, max_len=128, window_k=4,
-            tokenizer=ByteTokenizer(), lora_slots=1, lora_rank=4,
-            lora_targets=",".join(all_targets), spec_tokens=spec_tokens,
+            tokenizer=ByteTokenizer(),
+            params=_merged_params(eng.params, leaves),
         )
-        eng.start_sync()
+        merged_eng.start_sync()
         try:
-            eng.load_lora("full", leaves)
-            got = _gen(eng, "hello", adapter="full")
-            merged_eng = InferenceEngine(
-                "llama-tiny-f32", n_slots=4, max_len=128, window_k=4,
-                tokenizer=ByteTokenizer(),
-                params=_merged_params(eng.params, leaves),
-            )
-            merged_eng.start_sync()
-            try:
-                assert got == _gen(merged_eng, "hello"), (
-                    f"spec_tokens={spec_tokens}"
-                )
-            finally:
-                merged_eng.stop_sync()
+            assert got == _gen(merged_eng, "hello")
         finally:
-            eng.stop_sync()
+            merged_eng.stop_sync()
+    finally:
+        eng.stop_sync()
 
 
-def test_multi_chunk_prefill_uses_fresh_adapter():
-    """Deep multi-chunk prefill (prefill_depth>1) must prefill with the
-    REQUEST's adapter, not the slot's previous occupant's (regression:
-    the aids plane uploaded only on the single-chunk path)."""
+def test_a_prompt_of_several_chunks_prefills_with_its_own_requests_adapter():
+    """Every chunk step of a long prompt must run with the REQUEST's
+    adapter, not the slot's previous occupant's (the aids plane uploads
+    before the first dispatch after an admission)."""
     leaves = _rand_adapter(71)
     long_prompt = "abcdefgh" * 16  # 128 chars → 8 chunks of 16
     kw = dict(
         n_slots=2, max_len=256, window_k=4, tokenizer=ByteTokenizer(),
-        prefill_chunk=16, prefill_depth=4,
+        prefill_chunk=16,
     )
     eng = InferenceEngine(
         "llama-tiny-f32", lora_slots=1, lora_rank=4, **kw

@@ -108,100 +108,6 @@ def test_prefill_decode_matches_full_forward():
     assert cache.lengths[1] == 0  # inactive slot length untouched
 
 
-def test_verify_step_matches_sequential_decode():
-    """Speculative verify (c tokens, read-only cache, one pass) must produce
-    the same logits as feeding those c tokens through sequential decode
-    steps, and commit_chunk_kv must leave the same cache behind."""
-    import dataclasses
-
-    from gofr_tpu.models.transformer import (
-        commit_chunk_kv,
-        transformer_prefill,
-        transformer_verify_step,
-    )
-
-    cfg = dataclasses.replace(get_model("llama-tiny").config, dtype=jnp.float32)
-    params = init_transformer(jax.random.PRNGKey(0), cfg)
-    b, prompt_len, c = 2, 9, 4
-    key = jax.random.PRNGKey(5)
-    tokens = jax.random.randint(key, (b, prompt_len + c), 0, cfg.vocab_size)
-
-    def fresh_cache():
-        cache = KVCache.create(
-            cfg.n_layers, n_slots=4, max_len=64, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, dtype=cfg.dtype,
-        )
-        slots = jnp.array([0, 3])
-        lengths = jnp.array([prompt_len, prompt_len])
-        _, cache = transformer_prefill(
-            params, tokens[:, :prompt_len], lengths, cache, slots, cfg
-        )
-        return cache, slots
-
-    cache_v, slots = fresh_cache()
-    active = jnp.zeros((4,), dtype=bool).at[slots].set(True)
-    slot_tokens = jnp.zeros((4, c), dtype=tokens.dtype).at[slots].set(
-        tokens[:, prompt_len:]
-    )
-    logits_v, nk, nv = transformer_verify_step(params, slot_tokens, cache_v, cfg)
-    cache_v = commit_chunk_kv(cache_v, nk, nv, active, cfg)
-    cache_v = cache_v._replace(
-        lengths=cache_v.lengths + c * active.astype(jnp.int32)
-    )
-
-    cache_d, _ = fresh_cache()
-    for j in range(c):
-        step_tokens = jnp.zeros((4,), dtype=tokens.dtype).at[slots].set(
-            tokens[:, prompt_len + j]
-        )
-        logits_d, cache_d = transformer_decode_step(
-            params, step_tokens, cache_d, active, cfg
-        )
-        np.testing.assert_allclose(
-            np.asarray(logits_v[slots, j]),
-            np.asarray(logits_d[slots]),
-            rtol=1e-4, atol=1e-4,
-            err_msg=f"verify position {j} diverged from sequential decode",
-        )
-    # Same cache contents at the written positions (and same lengths).
-    np.testing.assert_array_equal(
-        np.asarray(cache_v.lengths), np.asarray(cache_d.lengths)
-    )
-    span = slice(prompt_len, prompt_len + c)
-    np.testing.assert_allclose(
-        np.asarray(cache_v.k[:, slots, :, span]),
-        np.asarray(cache_d.k[:, slots, :, span]),
-        rtol=1e-5, atol=1e-5,
-    )
-    np.testing.assert_allclose(
-        np.asarray(cache_v.v[:, slots, :, span]),
-        np.asarray(cache_d.v[:, slots, :, span]),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
-def test_ngram_draft_lookup():
-    from gofr_tpu.models.transformer import ngram_draft
-
-    T = 16
-    hist = jnp.zeros((3, T), dtype=jnp.int32)
-    # Slot 0: "5 6 7 8 ... 5" → bigram (4,5)? history: 1 2 5 6 7 2 5 ; cur=5
-    hist = hist.at[0, :7].set(jnp.array([1, 2, 5, 6, 7, 2, 5]))
-    # Slot 1: no prior occurrence of cur.
-    hist = hist.at[1, :4].set(jnp.array([3, 4, 5, 9]))
-    # Slot 2: unigram fallback (length 1).
-    hist = hist.at[2, :2].set(jnp.array([7, 7]))
-    lengths = jnp.array([6, 3, 1])
-    current = jnp.array([5, 8, 7])  # sits at history[lengths]
-    draft = ngram_draft(hist, lengths, current, 3)
-    # Slot 0: bigram (2,5) last matched at p=2 → draft = history[3:6] = 6 7 2.
-    np.testing.assert_array_equal(np.asarray(draft[0]), [6, 7, 2])
-    # Slot 1: no match → repeats current.
-    np.testing.assert_array_equal(np.asarray(draft[1]), [8, 8, 8])
-    # Slot 2: unigram 7 matched at p=0 → draft = history[1:4] = 7 0 0.
-    np.testing.assert_array_equal(np.asarray(draft[2]), [7, 0, 0])
-
-
 def test_prefill_respects_padding(tiny):
     """Right-padded short prompt must give same last-token logits as unpadded."""
     cfg, params = tiny

@@ -96,21 +96,6 @@ def test_presence_penalty_deviates_and_mild_frequency_differs(base_tokens):
         eng.stop_sync()
 
 
-def test_mega_windows_compose(base_tokens):
-    eng = _engine(enable_penalties=True, mega_windows=4)
-    ref = _engine(enable_penalties=True)
-    for e in (eng, ref):
-        e.start_sync()
-    try:
-        assert _greedy(eng, frequency_penalty=1.5) == _greedy(
-            ref, frequency_penalty=1.5
-        )
-        assert _greedy(eng) == base_tokens
-    finally:
-        eng.stop_sync()
-        ref.stop_sync()
-
-
 def test_penalties_require_flag_and_range():
     eng = _engine()  # feature compiled OUT
     eng.start_sync()
@@ -126,11 +111,6 @@ def test_penalties_require_flag_and_range():
             eng.submit_generate(PROMPT, presence_penalty=3.0)
     finally:
         eng.stop_sync()
-
-
-def test_penalties_reject_speculation():
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        _engine(enable_penalties=True, spec_tokens=2)
 
 
 class TestLogitBias:
@@ -174,20 +154,6 @@ class TestLogitBias:
         finally:
             eng.stop_sync()
 
-    def test_bias_with_mega_and_penalties(self, base_tokens):
-        eng = _engine(enable_penalties=True, mega_windows=4)
-        eng.start_sync()
-        try:
-            banned = int(base_tokens[0])
-            toks = eng.generate_sync(
-                PROMPT, max_new_tokens=16, temperature=0.0,
-                stop_on_eos=False, logit_bias={banned: -100},
-                frequency_penalty=0.5, timeout=120,
-            ).token_ids
-            assert banned not in toks
-        finally:
-            eng.stop_sync()
-
 
 class TestTopLogprobs:
     """OpenAI top_logprobs alternatives (TPU_TOP_LOGPROBS compile gate)."""
@@ -214,29 +180,6 @@ class TestTopLogprobs:
                 assert alts[0][1] >= alts[1][1] >= alts[2][1]
         finally:
             eng.stop_sync()
-
-    def test_mega_and_plain_agree(self):
-        a = _engine(top_logprobs=2)
-        b = _engine(top_logprobs=2, mega_windows=4)
-        for e in (a, b):
-            e.start_sync()
-        try:
-            ra, rb = (
-                e.generate_sync(
-                    PROMPT, max_new_tokens=10, temperature=0.0,
-                    stop_on_eos=False, top_logprobs=2, timeout=120,
-                )
-                for e in (a, b)
-            )
-            assert ra.token_ids == rb.token_ids
-            assert [
-                [t for t, _ in alts] for alts in ra.token_top_logprobs
-            ] == [
-                [t for t, _ in alts] for alts in rb.token_top_logprobs
-            ]
-        finally:
-            a.stop_sync()
-            b.stop_sync()
 
     def test_requires_compile_flag_and_cap(self):
         eng = _engine()

@@ -132,7 +132,6 @@ def test_an_engine_compiles_one_program_a_rung_before_it_serves(
     assert e.prefill_rungs == rungs
     stats = e.compile_stats()
     assert stats["programs"]["prefill_chunk"]["compiles"] == len(rungs)
-    assert stats["programs"]["prefill_chunk_hist"]["compiles"] == 0
     assert stats["total"] == len(rungs)
 
 
@@ -224,24 +223,6 @@ def test_no_rung_compiles_after_the_fence_however_many_rows_wait():
         e.close()
 
 
-def test_under_speculation_the_ladder_is_the_hist_programs():
-    e = InferenceEngine(
-        "llama-tiny", tokenizer=ByteTokenizer(), n_slots=2, max_len=128,
-        prefill_chunk=CHUNK, spec_tokens=2,
-    )
-    programs = e.compile_stats()["programs"]
-    assert programs["prefill_chunk_hist"]["compiles"] == 2
-    assert programs["prefill_chunk"]["compiles"] == 0
-    e.start_sync()
-    try:
-        serve_together(e, [tokens_of(1, 7)])
-        e.mark_steady_state()
-        serve_together(e, [tokens_of(2, 20), tokens_of(3, 9)])
-        assert e.compile_stats()["steady_state_recompiles"] == 0
-    finally:
-        e.close()
-
-
 # ----------------------------------------------------------------------
 # (b) a row's result does not depend on its co-riders
 # ----------------------------------------------------------------------
@@ -300,3 +281,53 @@ def test_a_prompt_prefills_to_the_same_tokens_beside_any_number_of_others(
     np.testing.assert_allclose(
         got.token_logprobs, alone.token_logprobs, atol=1e-4
     )
+
+
+# ----------------------------------------------------------------------
+# (c) a prompt's stream does not depend on where its chunks are cut
+# ----------------------------------------------------------------------
+
+CHUNKS = (16, 32, 64)
+
+
+@pytest.fixture(scope="module")
+def by_chunk():
+    """prefill_chunk -> a started engine, built at a test's first ask."""
+    built = {}
+
+    def engine(chunk):
+        if chunk not in built:
+            e = InferenceEngine(
+                "llama-tiny-f32", tokenizer=ByteTokenizer(), n_slots=4,
+                max_len=128, prefill_chunk=chunk, window_k=4,
+            )
+            e.start_sync()
+            built[chunk] = e
+        return built[chunk]
+
+    yield engine
+    for e in built.values():
+        e.close()
+
+
+@pytest.mark.parametrize("beside", [0, 3], ids=["alone", "beside-3"])
+@pytest.mark.parametrize("spans", [1, 3, 5])
+def test_a_prompts_stream_is_the_same_wherever_its_chunks_are_cut(
+    by_chunk, spans, beside,
+):
+    """A prompt that spans 1, 3 or 5 chunks of 16 (so 1, 2, 3 of 32 and 1,
+    1, 2 of 64), alone and beside three others of mixed lengths, yields
+    the same greedy tokens at every ``prefill_chunk`` and, in float32, the
+    same log-probabilities to 1e-4: chunking is a dispatch shape, and a
+    request must not see it."""
+    probe = tokens_of(40 + spans, (spans - 1) * 16 + 9)
+    others = [tokens_of(50 + i, 5 + 23 * i) for i in range(beside)]
+    results = [
+        serve_together(by_chunk(chunk), others + [probe], new_tokens=6)[-1]
+        for chunk in CHUNKS
+    ]
+    for got in results[1:]:
+        assert got.token_ids == results[0].token_ids
+        np.testing.assert_allclose(
+            got.token_logprobs, results[0].token_logprobs, atol=1e-4
+        )

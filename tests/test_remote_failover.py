@@ -415,6 +415,7 @@ def test_cancelled_caller_stops_stream_consumption_without_failover():
     offered = []
     replica.set_handoff(lambda req: offered.append(req) or True)
     holder = {}
+    handed_over = threading.Event()
 
     def lines(**ctx):
         # Trip the CANCEL TOKEN (not the future) mid-delivery — the
@@ -422,6 +423,10 @@ def test_cancelled_caller_stops_stream_consumption_without_failover():
         # notice at the next event, walk away quietly, and resolve the
         # future with the same typed error the in-proc reap uses.
         yield _sse([9, 8])
+        # submit() starts this consumer before it returns the request:
+        # under load the generator gets here first, and a KeyError from
+        # it would read as a transport fault and fail over.
+        assert handed_over.wait(10)
         holder["req"].cancel.cancel()
         yield _sse([7, 6])
         yield from _sse_lines([5], done=True)
@@ -429,6 +434,7 @@ def test_cancelled_caller_stops_stream_consumption_without_failover():
     faults.arm("http.stream.open", action=lines)
     req = replica.submit("x", max_new_tokens=8, temperature=0.0)
     holder["req"] = req
+    handed_over.set()
     assert _drain(req) == [9, 8]
     with pytest.raises(ErrorRequestCancelled):
         req.future.result(timeout=10)

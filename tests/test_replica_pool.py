@@ -83,8 +83,7 @@ def _metrics_manager():
         m.new_counter(name)
     for name in POOL_INSTRUMENTS_GAUGES:
         m.new_gauge(name)
-    for name in ("app_tpu_infer_latency", "app_tpu_batch_size",
-                 "app_tpu_spec_tokens_per_step"):
+    for name in ("app_tpu_infer_latency", "app_tpu_batch_size"):
         m.new_histogram(name)
     return m
 

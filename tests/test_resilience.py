@@ -63,7 +63,6 @@ def _metrics_manager():
         m.new_gauge(name)
     m.new_histogram("app_tpu_infer_latency")
     m.new_histogram("app_tpu_batch_size")
-    m.new_histogram("app_tpu_spec_tokens_per_step")
     return m
 
 

@@ -142,106 +142,6 @@ def test_greedy_matches_cache_free_rollout(llm_engine):
     assert r.token_ids == seq[-n_new:]
 
 
-@pytest.fixture(scope="module")
-def f32_plain_engine():
-    # f32 predates the exact-verify redesign, which made spec-on vs
-    # spec-off bit-identical at bf16 too (the verify path now IS the
-    # decode-step program — see tests/test_spec_decoding.py for the
-    # bf16 identity suite); kept at f32 for variety across dtypes.
-    eng = InferenceEngine(
-        "llama-tiny-f32", n_slots=4, max_len=256, tokenizer=ByteTokenizer()
-    )
-    eng.start_sync()
-    yield eng
-    eng.stop_sync()
-
-
-def test_speculative_decoding_lossless_greedy(f32_plain_engine):
-    """Greedy generation with n-gram speculation must produce EXACTLY the
-    tokens of plain greedy decode (acceptance is by exact match), for
-    several concurrent requests; sampled-temperature requests still
-    complete in the same batch (they take no drafts)."""
-    spec = InferenceEngine(
-        "llama-tiny-f32", n_slots=4, max_len=256, tokenizer=ByteTokenizer(),
-        spec_tokens=3,
-    )
-    spec.start_sync()
-    try:
-        prompts = ["hello world", "abab abab abab", "the cat sat on"]
-        want = [
-            f32_plain_engine.generate_sync(
-                p, max_new_tokens=12, temperature=0.0, stop_on_eos=False
-            ).token_ids
-            for p in prompts
-        ]
-        reqs = [
-            spec.submit_generate(
-                p, max_new_tokens=12, temperature=0.0, stop_on_eos=False
-            )
-            for p in prompts
-        ]
-        noise = spec.submit_generate(
-            "noise", max_new_tokens=8, temperature=0.9, stop_on_eos=False
-        )
-        got = [r.future.result(timeout=120).token_ids for r in reqs]
-        assert got == want
-        assert len(noise.future.result(timeout=120).token_ids) == 8
-    finally:
-        spec.stop_sync()
-
-
-def test_speculative_decoding_lossless_int8_kv():
-    """Spec-on == spec-off under an int8 KV cache too: the verify path
-    fake-quantizes in-chunk K/V so it attends exactly what commit writes
-    (f32 weights so argmax ties can't flip between execution shapes)."""
-    results = []
-    for spec_tokens in (0, 3):
-        eng = InferenceEngine(
-            "llama-tiny-f32", n_slots=2, max_len=256,
-            tokenizer=ByteTokenizer(), kv_quant="int8",
-            spec_tokens=spec_tokens,
-        )
-        eng.start_sync()
-        try:
-            results.append(
-                eng.generate_sync(
-                    "quantized spec", max_new_tokens=14, temperature=0.0,
-                    stop_on_eos=False,
-                ).token_ids
-            )
-        finally:
-            eng.stop_sync()
-    assert results[0] == results[1]
-
-
-def test_spec_streaming_order(f32_plain_engine):
-    """Streaming through the spec engine yields the same token order as
-    the non-spec engine's result."""
-    spec = InferenceEngine(
-        "llama-tiny-f32", n_slots=2, max_len=256, tokenizer=ByteTokenizer(),
-        spec_tokens=2,
-    )
-    spec.start_sync()
-    try:
-        want = f32_plain_engine.generate_sync(
-            "stream spec", max_new_tokens=9, temperature=0.0,
-            stop_on_eos=False,
-        ).token_ids
-
-        async def run():
-            toks = []
-            async for tok in spec.generate_stream(
-                "stream spec", max_new_tokens=9, temperature=0.0,
-                stop_on_eos=False,
-            ):
-                toks.append(tok)
-            return toks
-
-        assert asyncio.run(run()) == want
-    finally:
-        spec.stop_sync()
-
-
 def test_paged_cache_matches_slot_cache(llm_engine):
     """TPU_KV_BLOCK engine produces the same greedy tokens as the slot
     cache, across concurrent requests and block boundaries (max_len 128,
@@ -344,16 +244,16 @@ def test_paged_oversized_prompt_fails_without_deadlock():
         paged.stop_sync()
 
 
-def test_paged_with_int8_kv_and_spec():
-    """Paged × int8 KV × speculation compose: same tokens as the plain
-    slot-cache engine (f32 oracle model)."""
+def test_paged_with_int8_kv():
+    """Paged × int8 KV compose: same tokens as the plain slot-cache
+    engine (f32 oracle model)."""
     plain = InferenceEngine(
         "llama-tiny-f32", n_slots=2, max_len=128, tokenizer=ByteTokenizer(),
         kv_quant="int8",
     )
     paged = InferenceEngine(
         "llama-tiny-f32", n_slots=2, max_len=128, tokenizer=ByteTokenizer(),
-        kv_quant="int8", kv_block=32, spec_tokens=2,
+        kv_quant="int8", kv_block=32,
     )
     for eng in (plain, paged):
         eng.start_sync()
@@ -370,6 +270,55 @@ def test_paged_with_int8_kv_and_spec():
     finally:
         plain.stop_sync()
         paged.stop_sync()
+
+
+@pytest.fixture(scope="module")
+def penalties_and_alternatives_engines():
+    """The same engine at the default dispatch shape (8, 2) and at (1, 1),
+    both compiled with penalties and two top_logprobs alternatives."""
+    engines = [
+        InferenceEngine(
+            "llama-tiny", n_slots=2, max_len=128, tokenizer=ByteTokenizer(),
+            window_k=k, pipeline_depth=depth, enable_penalties=True,
+            top_logprobs=2,
+        )
+        for k, depth in ((8, 2), (1, 1))
+    ]
+    for e in engines:
+        e.start_sync()
+    yield engines
+    for e in engines:
+        e.stop_sync()
+
+
+@pytest.mark.parametrize("penalty", ["frequency_penalty", "presence_penalty"])
+def test_penalties_and_top_logprobs_compose_at_the_default_shape(
+    penalties_and_alternatives_engines, penalty,
+):
+    """One request uses a penalty and asks for alternatives: both per-step
+    planes ride the one decode window. The alternatives come from the
+    penalised distribution the choice was made from, the penalty changes
+    the stream, and the count plane carries across dispatches the same
+    way whatever their length (the (1, 1) engine's stream is the same)."""
+    default, stepwise = penalties_and_alternatives_engines
+
+    def serve(e, **kw):
+        return e.generate_sync(
+            "aaaa aaaa aaaa", max_new_tokens=20, temperature=0.0,
+            stop_on_eos=False, top_logprobs=2, timeout=120, **kw,
+        )
+
+    plain, penalised = serve(default), serve(default, **{penalty: 1.5})
+    assert len(penalised.token_ids) == 20
+    assert penalised.token_ids != plain.token_ids
+    assert len(penalised.token_top_logprobs) == 20
+    for tok, lp, alts in zip(
+        penalised.token_ids, penalised.token_logprobs,
+        penalised.token_top_logprobs,
+    ):
+        assert len(alts) == 2 and alts[0][0] == tok
+        assert alts[0][1] == pytest.approx(lp, abs=1e-4)
+    assert serve(stepwise, **{penalty: 1.5}).token_ids == penalised.token_ids
 
 
 def test_top_p_sampling():
@@ -888,16 +837,16 @@ def test_typed_grpc_embed_and_classify():
         vision.stop_sync()
 
 
-def test_moe_model_serves_with_spec_and_paged():
+def test_moe_model_serves_with_paged():
     """The MoE FFN path (top-k routed experts) through the FULL serving
-    stack — continuous batching, speculation, paged cache — not just the
-    forward: decode/verify share _ffn_moe with training."""
+    stack — continuous batching, paged cache — not just the forward:
+    prefill and decode share _ffn_moe with training."""
     plain = InferenceEngine(
         "moe-tiny", n_slots=2, max_len=128, tokenizer=ByteTokenizer(),
     )
     fancy = InferenceEngine(
         "moe-tiny", n_slots=2, max_len=128, tokenizer=ByteTokenizer(),
-        spec_tokens=2, kv_block=32,
+        kv_block=32,
     )
     plain.start_sync()
     fancy.start_sync()
@@ -911,10 +860,10 @@ def test_moe_model_serves_with_spec_and_paged():
             stop_on_eos=False,
         ).token_ids
         assert len(want) == 8
-        # bf16 MoE: routing ties can flip between the [S,1] decode and
-        # [S,c] verify shapes, so exact equality is only guaranteed for
-        # the prefix before any divergence — require a common first
-        # token and full lengths instead of exact match.
+        # bf16 MoE: routing ties can flip between the contiguous and
+        # the paged attention's reduction orders, so exact equality is
+        # only guaranteed for the prefix before any divergence — require
+        # a common first token and full lengths instead of exact match.
         assert got[0] == want[0]
         assert len(got) == 8
     finally:

@@ -150,8 +150,8 @@ def test_stop_start_cycle_preserves_service_and_prefixes(engine):
 
 def test_mixed_sampling_features_concurrent_stress():
     """Cross-feature interaction stress: concurrent requests mixing
-    seeds, penalties, logit_bias, top_logprobs, and uneven budgets on a
-    mega-window engine — per-request invariants must hold even as the
+    seeds, penalties, logit_bias, top_logprobs, and uneven budgets on
+    one engine — per-request invariants must hold even as the
     slot-state/admission uploads interleave."""
     import random
 
@@ -159,7 +159,7 @@ def test_mixed_sampling_features_concurrent_stress():
     from gofr_tpu.serving.tokenizer import ByteTokenizer
 
     eng = InferenceEngine(
-        "llama-tiny", n_slots=4, max_len=128, window_k=4, mega_windows=4,
+        "llama-tiny", n_slots=4, max_len=128, window_k=4,
         enable_penalties=True, top_logprobs=2, tokenizer=ByteTokenizer(),
     )
     eng.start_sync()
@@ -208,7 +208,7 @@ def test_mixed_sampling_features_concurrent_stress():
 def test_lora_cross_feature_concurrent_stress():
     """Adapters join the cross-feature stress: concurrent requests mix
     LoRA adapters with seeds, penalties, logit_bias and uneven budgets
-    on one mega-window engine. Invariants: greedy same-adapter repeats
+    on one engine. Invariants: greedy same-adapter repeats
     are identical, adapters differ from base, budgets exact, bias bans
     hold under adapters too."""
     import random
@@ -222,7 +222,7 @@ def test_lora_cross_feature_concurrent_stress():
 
     cfg = get_model("llama-tiny").config
     eng = InferenceEngine(
-        "llama-tiny", n_slots=4, max_len=128, window_k=4, mega_windows=4,
+        "llama-tiny", n_slots=4, max_len=128, window_k=4,
         enable_penalties=True, tokenizer=ByteTokenizer(),
         lora_slots=2, lora_rank=4,
     )

@@ -67,8 +67,7 @@ def _metrics_manager():
                  "app_tpu_kv_slots_in_use", "app_tpu_hbm_used_bytes",
                  "app_tpu_kv_blocks_free"):
         m.new_gauge(name)
-    for name in ("app_tpu_infer_latency", "app_tpu_batch_size",
-                 "app_tpu_spec_tokens_per_step"):
+    for name in ("app_tpu_infer_latency", "app_tpu_batch_size"):
         m.new_histogram(name)
     return m
 

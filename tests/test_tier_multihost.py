@@ -99,7 +99,6 @@ HISTOGRAMS = (
     "app_tpu_tier_transfer_seconds",
     "app_tpu_infer_latency",
     "app_tpu_batch_size",
-    "app_tpu_spec_tokens_per_step",
 )
 
 

@@ -80,7 +80,6 @@ TIER_HISTOGRAMS = (
     "app_tpu_tier_transfer_seconds",
     "app_tpu_infer_latency",
     "app_tpu_batch_size",
-    "app_tpu_spec_tokens_per_step",
 )
 
 #: 96 tokens = exactly 3 full 32-token KV blocks — the whole-prompt-

@@ -4,9 +4,9 @@ Two halves, both on the CPU at tiny size:
 
 * the model's ops: the lowered text of the serving programs names every
   op by the ``jax.named_scope`` it ran under — the fixed vocabulary
-  ``embed attn kv_commit ffn moe_router moe_experts lm_head sample draft
-  verify`` — so a device op in a capture says which part of the model
-  it belongs to (its ``tf_op`` is this ``op_name``);
+  ``embed attn kv_commit ffn moe_router moe_experts lm_head sample`` —
+  so a device op in a capture says which part of the model it belongs
+  to (its ``tf_op`` is this ``op_name``);
 * the host's phases: a ``ProfilerCapture`` around a few scheduler passes
   holds ``loop/<phase>`` and ``window_fetch`` events on a host line of
   the same ``.xplane.pb``, read back with ``jax.profiler.ProfileData``.
@@ -28,23 +28,25 @@ from gofr_tpu.serving.loop_profiler import PHASES
 from gofr_tpu.serving.profiler_capture import ProfilerCapture
 from gofr_tpu.serving.tokenizer import ByteTokenizer
 
+# ``draft`` and ``verify`` named the spec window's ops until PR 30: counted
+# here so that a fork that brings them back shows in ``absent`` below.
 VOCABULARY = {
     "embed", "attn", "kv_commit", "ffn", "moe_router", "moe_experts",
     "lm_head", "sample", "draft", "verify",
 }
 
 
-def engine_of(model: str, spec_tokens: int) -> InferenceEngine:
+def engine_of(model: str) -> InferenceEngine:
     return InferenceEngine(
         model, tokenizer=ByteTokenizer(), n_slots=2, max_len=128,
-        prefill_chunk=32, spec_tokens=spec_tokens,
+        prefill_chunk=32,
     )
 
 
 def scopes_in(lowered) -> collections.Counter:
     """How many ops of the lowered program ran under each scope of the
-    vocabulary: every component of every op_name, e.g. both ``verify``
-    and ``sample`` for ``verify/while/body/closed_call/sample/div``."""
+    vocabulary: every component of every op_name, e.g. both ``pass``
+    and ``attn`` for ``pass/attn/dot_general``."""
     return collections.Counter(
         part for name in op_names(lowered) for part in name.split("/")
         if part in VOCABULARY
@@ -72,10 +74,6 @@ def lower_prefill_chunk(e: InferenceEngine):
         e._nsteps_dev, e._bidx_dev, e._bval_dev, e._topi_dev, e._topl_dev,
         e._aids_dev, e._noff_dev,
     ]
-    if e.spec_tokens:
-        return e._prefill_chunk_step_hist.__wrapped__.lower(
-            *args, e._history_dev, use_bias=False
-        )
     return e._prefill_chunk_step.__wrapped__.lower(*args, use_bias=False)
 
 
@@ -83,13 +81,6 @@ def lower_window(e: InferenceEngine):
     jnp = e._jnp
     S = e.n_slots
     active, ones = jnp.ones((S,), bool), jnp.ones((S,), jnp.float32)
-    if e.spec_tokens:
-        return e._spec_window.__wrapped__.lower(
-            e.params, e._tokens_dev, e._logps_dev, e.cache, active,
-            e._nsteps_dev, ones, active, ones, e._history_dev, e._seeds_dev,
-            e._bidx_dev, e._bval_dev, e._aids_dev,
-            k=e.window_k, use_bias=False,
-        )
     return e._decode_window.__wrapped__.lower(
         e.params, e._tokens_dev, e._logps_dev, e.cache, active,
         e._nsteps_dev, ones, active, ones, e._fpen_dev, e._ppen_dev,
@@ -99,23 +90,18 @@ def lower_window(e: InferenceEngine):
     )
 
 
-@pytest.mark.parametrize("model,spec_tokens,ffn_scopes,absent", [
-    ("llama-tiny", 0, {"ffn"}, {"moe_router", "moe_experts", "draft", "verify"}),
-    ("moe-tiny", 2, {"moe_router", "moe_experts"}, {"ffn"}),
+@pytest.mark.parametrize("model,ffn_scopes,absent", [
+    ("llama-tiny", {"ffn"}, {"moe_router", "moe_experts", "draft", "verify"}),
+    ("moe-tiny", {"moe_router", "moe_experts"}, {"ffn", "draft", "verify"}),
 ])
-def test_lowered_programs_name_their_ops_by_scope(
-    model, spec_tokens, ffn_scopes, absent,
-):
-    e = engine_of(model, spec_tokens)  # built, never started: nothing runs
+def test_lowered_programs_name_their_ops_by_scope(model, ffn_scopes, absent):
+    e = engine_of(model)  # built, never started: nothing runs
     lowered_window = lower_window(e)
     prefill = scopes_in(lower_prefill_chunk(e))
     window = scopes_in(lowered_window)
     everywhere = {"embed", "attn", "kv_commit", "lm_head", "sample"} | ffn_scopes
     assert everywhere <= set(prefill), sorted(prefill)
     assert everywhere <= set(window), sorted(window)
-    # The drafter and the verify forwards are the spec window's alone.
-    speculative = {"draft", "verify"} if spec_tokens else set()
-    assert speculative <= set(window) and not speculative & set(prefill)
     assert not absent & (set(prefill) | set(window))
     # The matrix products carry a name: the attention and FFN einsums are
     # the device's time, and an unnamed one is what this PR is against.
@@ -129,7 +115,7 @@ def test_a_looped_stack_names_its_passes():
     """A looped model's layer ops read ``pass/attn/...``, ``pass/ffn/...``,
     the norm that ends a pass ``pass/pass_norm/...``; an unlooped model's
     programs carry neither name."""
-    e = engine_of("looped-tiny", 0)
+    e = engine_of("looped-tiny")
     for lowered in (lower_prefill_chunk(e), lower_window(e)):
         names = op_names(lowered)
         in_layers = [
@@ -145,7 +131,7 @@ def test_a_looped_stack_names_its_passes():
         outside = [n for n in names
                    if {"embed", "lm_head", "sample"} & set(n.split("/"))]
         assert outside and not any("pass" in n.split("/") for n in outside)
-    plain = engine_of("llama-tiny", 0)
+    plain = engine_of("llama-tiny")
     for lowered in (lower_prefill_chunk(plain), lower_window(plain)):
         assert not any(
             {"pass", "pass_norm"} & set(n.split("/")) for n in op_names(lowered)
@@ -157,7 +143,7 @@ def test_scopes_change_no_program(monkeypatch):
     the lowered programs are the same text, locations aside."""
     import contextlib
 
-    e = engine_of("llama-tiny", 2)
+    e = engine_of("llama-tiny")
     named = [lower_prefill_chunk(e).as_text(), lower_window(e).as_text()]
 
     @contextlib.contextmanager
@@ -168,7 +154,7 @@ def test_scopes_change_no_program(monkeypatch):
     # Decorated functions captured the real scope at import; the with-
     # blocks and a rebuilt engine's closures take the no-op. Enough to
     # show the text does not depend on it.
-    bare_engine = engine_of("llama-tiny", 2)
+    bare_engine = engine_of("llama-tiny")
     bare = [
         lower_prefill_chunk(bare_engine).as_text(),
         lower_window(bare_engine).as_text(),
@@ -177,7 +163,7 @@ def test_scopes_change_no_program(monkeypatch):
 
 
 def test_capture_holds_the_loops_phases_on_a_host_line():
-    e = engine_of("llama-tiny", 0)
+    e = engine_of("llama-tiny")
     e.start_sync()
     try:
         e.generate_sync(  # compile outside the capture
