@@ -349,6 +349,13 @@ class Container:
             "dispatched (the live slots' lengths) / slots x max_len, one "
             "record per processed window", ratio_buckets,
         )
+        m.new_histogram(
+            "app_tpu_decode_read_ratio",
+            "positions of every slot the dense decode attention read at a "
+            "decode window's last step (the rung that holds the longest "
+            "live slot) / max_len, one record per processed window; 1.0: "
+            "the whole cache", ratio_buckets,
+        )
         m.new_gauge(
             "app_tpu_kv_bytes_per_token",
             "KV-cache bytes one token holds (all cache entries, keys and "

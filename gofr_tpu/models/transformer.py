@@ -34,6 +34,8 @@ from gofr_tpu.ops.attention import (
     attention,
     cache_chunk_attention,
     decode_attention,
+    decode_read_index,
+    decode_read_rungs,
 )
 from gofr_tpu.ops.kv_cache import (
     KVCache,
@@ -522,7 +524,9 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
     scan does with its xs anyway, and the shared final norm ends every
     pass but the last (the caller's own final norm ends that one). The
     cache rides xs → ys exactly as it does for one pass, so a looped
-    model costs the prefill program no further copy of it.
+    model costs the prefill program no further copy of it. (The decode
+    step, which only reads the cache, scans the entries' indices instead
+    and closes over the planes.)
     """
     layers = params["layers"]
     if cfg.n_passes == 1:
@@ -927,14 +931,24 @@ def transformer_decode_step(
     cfg: TransformerConfig,
     dense_attn: bool = False,
     aids: Optional[jnp.ndarray] = None,
+    bound_read: bool = True,
 ) -> tuple[jnp.ndarray, KVCache]:
     """One decode step over ALL cache slots (static batch = n_slots).
 
     tokens: [n_slots] current token per slot (anything for inactive slots);
     active: [n_slots] bool — only active slots get their K/V write kept and
-    their length bumped; inactive rows are wasted FLOPs, which is the right
-    trade on TPU (static shapes, no gather/scatter of the cache, the whole
-    [L, S, KV, max_len, hd] buffers update in place via donation).
+    their length bumped; an inactive row's logits are discarded. Every
+    slot is computed however few are live (static shapes, no gather or
+    scatter of the cache, the whole [L, S, KV, max_len, hd] buffers
+    update in place via donation); what IS bounded by what is live is
+    the dense attention's read of a contiguous cache: the first
+    ``decode_read_rungs(max_len)[i]`` positions of every slot, the
+    smallest rung that holds the longest ACTIVE slot, chosen here on the
+    device at every step (so it follows the lengths as they grow inside a
+    window). An inactive slot's stale length does not count.
+    bound_read: False keeps the whole read — for a cache whose position
+    axis is sharded (context parallel), where a prefix lives on the first
+    chips only.
     Returns ([n_slots, vocab] logits, updated cache).
     """
     S = cache.n_slots
@@ -959,10 +973,21 @@ def transformer_decode_step(
     # Round-tripping the full cache through scan ys instead costs ~11 ms
     # of pure HBM copy per step at llama-1b/32 slots (the nested window
     # scan defeats XLA's ys/xs aliasing — scripts/tpu_probe.py).
+    # The stacked planes are closed over and the scan carries the entry's
+    # index: the attention slices entry i where it reads it (inside the
+    # rung's branch, where the slice fuses into the dot), which is what a
+    # scan over the planes as xs lowers to anyway. Handing a branch the
+    # entry already sliced would copy it out whole first, every layer.
     paged = isinstance(cache, PagedKVCache)
+    read = None
+    if bound_read and not paged:
+        read = decode_read_index(
+            decode_read_rungs(cache.max_len),
+            jnp.max(jnp.where(active, cache.lengths, 0)),
+        )
 
     def body(x, scanned):
-        lp, ck, cv, cks, cvs = scanned  # ck/cv: [S, KV, max_len, hd]
+        lp, entry = scanned
         with jax.named_scope("attn"):
             h = _norm(
                 x[:, None, :], lp["attn_norm"], cfg, lp.get("attn_norm_b")
@@ -979,11 +1004,11 @@ def transformer_decode_step(
                 # int8).
                 k, v = fake_quantize_kv(k), fake_quantize_kv(v)
             attn = decode_attention(
-                q, ck, cv, positions, k_new=k, v_new=v, k_scale=cks,
-                v_scale=cvs,
+                q, cache.k, cache.v, positions, k_new=k, v_new=v,
+                k_scale=cache.k_s, v_scale=cache.v_s,
                 block_table=cache.block_table if paged else None,
                 kernel=False if dense_attn else None,
-                window=cfg.sliding_window,
+                window=cfg.sliding_window, layer=entry, read=read,
             )
             ao = attn.reshape(S, H * hd)
             attn_out = (
@@ -1006,9 +1031,7 @@ def transformer_decode_step(
             x = mlp_in + ffn[:, 0]
         return x, (k, v)
 
-    x, (new_k, new_v) = _scan_stack(
-        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
-    )
+    x, (new_k, new_v) = _scan_stack(body, x, params, cfg, (jnp.arange(L),))
     # Commit every layer's token in one scatter: [L, S, KV, hd] values at
     # [l, s, kv, write_pos[s]] (slot cache) or [l, table[s, p//B], kv,
     # p%B] (paged pool; inactive slots park in block 0) — donation makes

@@ -23,9 +23,11 @@ _FLASH_ENV = os.environ.get("GOFR_TPU_FLASH", "auto")
 # GOFR_TPU_FLASH_DECODE: overrides GOFR_TPU_FLASH for DECODE attention
 # only. The decode kernel launches grid (slots × kv_heads × kv_blocks)
 # tiny programs per layer (length-skipping, O(true context) HBM reads);
-# the dense path is one fused XLA op reading the full max_len cache.
-# Which wins is a measured trade (per-program overhead vs full-length
-# reads) — this knob lets the bench A/B it on hardware.
+# the dense path is two fused XLA ops reading one rung of every slot,
+# the one that holds the longest live slot (``decode_read_rungs``).
+# Which wins is a measured trade (per-program overhead vs reading every
+# slot to the longest one's rung) — this knob lets the bench A/B it on
+# hardware.
 _FLASH_DECODE_ENV = os.environ.get("GOFR_TPU_FLASH_DECODE", "")
 if _FLASH_DECODE_ENV not in ("", "0", "1"):
     raise ValueError(
@@ -70,20 +72,38 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _effective_window(window: int, k_cache: jnp.ndarray, block_table) -> int:
+def _effective_window(window: int, positions: int, block_table) -> int:
     """0 when the sliding window cannot bind within the cache capacity.
 
-    Contiguous caches are [b, KV, max_len, hd] (capacity = shape[2]); a
-    paged pool is [n_blocks, KV, block, hd] where shape[2] is the BLOCK
-    axis — capacity is the table's row length × block.
+    ``positions`` is the cache's position axis: contiguous caches are
+    [b, KV, max_len, hd] (capacity = max_len); a paged pool is [n_blocks,
+    KV, block, hd] where that axis is the BLOCK — capacity is the table's
+    row length × block.
     """
     if not window:
         return 0
-    if block_table is None:
-        capacity = k_cache.shape[2]
-    else:
-        capacity = block_table.shape[1] * k_cache.shape[2]
-    return 0 if window >= capacity else window
+    if block_table is not None:
+        positions *= block_table.shape[1]
+    return 0 if window >= positions else window
+
+
+def decode_read_rungs(max_len: int) -> tuple[int, ...]:
+    """The prefixes of a ``max_len`` cache the dense decode path may read:
+    its quarters, each rounded up to a multiple of 128 (the lane tile) and
+    capped at ``max_len``. 2,048 gives 512 / 1,024 / 1,536 / 2,048, 384
+    gives 128 / 256 / 384; a cache of 128 positions or fewer has the one
+    rung, today's whole read."""
+    return tuple(sorted({
+        min(max_len, -(-(max_len * q) // (4 * 128)) * 128)
+        for q in (1, 2, 3, 4)
+    }))
+
+
+def decode_read_index(rungs: tuple[int, ...], longest):
+    """Index of the smallest rung that holds ``longest`` cached positions
+    (a length equal to a rung fits it). ``longest`` is a Python int on the
+    host or a traced scalar on the device: the same rule in both places."""
+    return sum(longest > r for r in rungs[:-1])
 
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -175,6 +195,116 @@ def attention(
     return out.reshape(b, s_q, n_heads, hd)
 
 
+def _decode_takes_kernel(
+    kernel: bool | None, max_len: int, paged: bool, window: int
+) -> bool:
+    """Whether decode attention runs the Pallas kernel; ``window`` is the
+    effective (binding) one."""
+    if kernel is not None:
+        return kernel
+    kernel = _flash_decode_enabled()
+    if (
+        kernel
+        and _FLASH_DECODE_ENV == ""
+        and _FLASH_ENV in ("", "auto")
+        and not paged
+        and not window
+    ):
+        # A contiguous cache of at most 2,048 positions takes the dense
+        # path, which reads only the rung that holds the longest live
+        # slot (``decode_read_rungs``). Measured on the v5e (PERF.md §6
+        # PR 31, mistral-7b int8, 14 slots of 2,048, ~3 live): a layer's
+        # attention costs 159 us dense over the whole cache, 81 us at
+        # the 1,024 rung, and 344 us through the kernel, which skips by
+        # each slot's own length but launches slots x kv_heads x
+        # kv_blocks small programs (187 us) after the layer's K and V
+        # planes were copied out of the stacked cache for it (2 x 79 us);
+        # a decode window 125.0 / 105.6 / 174 ms, the median pace 18.3 /
+        # 15.5 / 26.0 ms a token. The paged pool always takes the kernel
+        # (its dense fallback must materialize a gather first), and so
+        # does a binding window (the kernel reads only the window's
+        # blocks).
+        kernel = max_len > 2048
+    return kernel
+
+
+def decode_read_plan(
+    max_len: int, *, paged: bool = False, window: int = 0,
+    kernel: bool | None = None,
+) -> tuple[int, ...]:
+    """The prefixes ``decode_attention``'s ``read`` selects among for such
+    a cache: ``decode_read_rungs`` where the dense path over a contiguous
+    cache runs, the one whole read where the kernel or a paged pool does
+    (the host's counter and the device's program ask the same function)."""
+    window = _effective_window(window, max_len, None)
+    if paged or _decode_takes_kernel(kernel, max_len, paged, window):
+        return (max_len,)
+    return decode_read_rungs(max_len)
+
+
+def _entry_prefix(plane: jnp.ndarray, layer, n: int, axis: int):
+    """The first ``n`` positions along ``axis`` of one cache entry:
+    ``plane`` itself, or entry ``layer`` of a stacked ``[entries, ...]``
+    plane, taken with one dynamic_slice so that it fuses into the op
+    that reads it and the entry is never copied out whole."""
+    if layer is None:
+        return jax.lax.slice_in_dim(plane, 0, n, axis=axis)
+    sizes = list(plane.shape)
+    sizes[0], sizes[axis + 1] = 1, n
+    starts = [layer] + [0] * (plane.ndim - 1)
+    return jax.lax.dynamic_slice(plane, starts, sizes)[0]
+
+
+def _dense_decode(
+    qg, k_cache, v_cache, lengths, k_new, v_new, k_scale, v_scale,
+    scale: float, window: int,
+):
+    """Dense decode attention over the positions handed in — the whole
+    cache or a prefix of it that holds every kept slot's ``lengths``.
+    qg: [b, kv, rep, hd]; caches [b, kv, n, hd]; scales [b, kv, 1, n]
+    (int8) or None. Returns [b, kv, rep, hd]."""
+    n = k_cache.shape[2]
+    quant = k_scale is not None
+    if quant:  # int8 cache: dequant via score/prob scaling, not the cache
+        k_cache = k_cache.astype(qg.dtype)
+        v_cache = v_cache.astype(qg.dtype)
+    scores = jnp.einsum(
+        "bgrd,bgkd->bgrk", qg, k_cache, preferred_element_type=jnp.float32
+    ) * scale  # [b, kv, rep, n]
+    if quant:
+        scores = scores * k_scale
+
+    valid = jnp.arange(n)[None, :] < lengths[:, None]  # [b, n]
+    if window:
+        # Query position: ``lengths`` (split path — the new token) or
+        # ``lengths-1`` (already-written convention). Keys must sit in
+        # (q_pos - window, q_pos].
+        q_pos = lengths if k_new is not None else lengths - 1
+        valid &= jnp.arange(n)[None, :] > (q_pos - window)[:, None]
+    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+
+    if k_new is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+        if quant:
+            probs = probs * v_scale
+        return jnp.einsum("bgrk,bgkd->bgrd", probs.astype(qg.dtype), v_cache)
+
+    # Split path: merge the current token's (always-valid) score into the
+    # cache-prefix softmax without writing it to the cache first.
+    s_new = jnp.einsum(
+        "bgrd,bgd->bgr", qg, k_new, preferred_element_type=jnp.float32
+    ) * scale  # [b, kv, rep]
+    m = jnp.maximum(jnp.max(scores, axis=-1), s_new)  # [b, kv, rep]
+    e_c = jnp.exp(scores - m[..., None])  # [b, kv, rep, n]
+    e_n = jnp.exp(s_new - m)  # [b, kv, rep]
+    denom = jnp.sum(e_c, axis=-1) + e_n
+    if quant:
+        e_c = e_c * v_scale
+    out = jnp.einsum("bgrk,bgkd->bgrd", e_c.astype(qg.dtype), v_cache)
+    out = out + e_n[..., None].astype(qg.dtype) * v_new[:, :, None, :]
+    return out / denom[..., None].astype(qg.dtype)
+
+
 def decode_attention(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,
@@ -189,6 +319,8 @@ def decode_attention(
     scale: float | None = None,
     kernel: bool | None = None,
     window: int = 0,
+    layer: jnp.ndarray | None = None,
+    read: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-token decode attention against per-slot caches.
 
@@ -216,33 +348,42 @@ def decode_attention(
     ``k_new``/``v_new`` stay bf16 (quantization happens at commit).
     kernel: None → auto (pallas flash-decode kernel on TPU; override with
     GOFR_TPU_FLASH_DECODE / GOFR_TPU_DECODE_BLOCK_K).
+    layer: the caches and scales are the STACKED ``[entries, ...]`` planes
+    and entry ``layer`` (a traced index) is the one attended: the decode
+    step's layer scan closes over the planes and hands in its index, so
+    the dense path slices the entry where it reads it.
+    read: the dense path over a contiguous cache reads only the first
+    ``decode_read_rungs(max_len)[read]`` positions of every slot (a traced
+    index, ``decode_read_index`` of the longest length whose output is
+    kept). Positions at or beyond a slot's length weigh exp(-1e30 - m)
+    = 0 in the whole read, so a slot that fits the rung gets the same
+    output; a slot that does not must be one whose output the caller
+    discards. None, the kernel and the paged pool read as before (the
+    kernel skips by each slot's own length).
     """
     if (k_new is None) != (v_new is None):
         raise ValueError("pass k_new and v_new together")
+    # [.., n_kv, max_len, hd] in every layout (a paged pool: the block).
+    max_len = k_cache.shape[-2]
     # A window that cannot bind is dropped (capacity-aware: a paged
-    # pool's shape[2] is the BLOCK axis, not capacity). A BINDING window
+    # pool's shape[-2] is the BLOCK axis, not capacity). A BINDING window
     # keeps the kernel path — flash_decode masks it in-kernel and skips
     # whole blocks below the window (O(window) HBM reads, vs the dense
     # paged fallback's per-step full gather).
-    window = _effective_window(window, k_cache, block_table)
-    if kernel is None:
-        kernel = _flash_decode_enabled()
-        if (
-            kernel
-            and _FLASH_DECODE_ENV == ""
-            and _FLASH_ENV in ("", "auto")
-            and block_table is None
-            and not window
-        ):
-            # Measured auto heuristic (BASELINE.md round 3): at short
-            # max_len ONE fused dense op beats the kernel's grid of tiny
-            # programs (llama-1b/1024: 2.4 vs 5.1 ms per stack; engine
-            # 2421 vs 1931 tok/s); length-skipping only pays once the
-            # full-length reads the dense path can't skip get big. The
-            # paged pool always takes the kernel — its dense fallback
-            # must materialize a gather first — and so does a binding
-            # window (the kernel reads only the window's blocks).
-            kernel = k_cache.shape[2] > 2048
+    window = _effective_window(window, max_len, block_table)
+    paged = block_table is not None
+    kernel = _decode_takes_kernel(kernel, max_len, paged, window)
+    bounded = read is not None and not kernel and not paged
+    rungs = decode_read_rungs(max_len) if bounded else (max_len,)
+    if layer is not None and len(rungs) < 2:
+        # One read of the whole entry: index it as a scan over the
+        # planes would have.
+        k_cache, v_cache, k_scale, v_scale = (
+            None if p is None
+            else jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+            for p in (k_cache, v_cache, k_scale, v_scale)
+        )
+        layer = None
     if kernel:
         from gofr_tpu.ops.pallas import flash_decode
 
@@ -252,7 +393,7 @@ def decode_attention(
             scale=scale, block_k=_DECODE_BLOCK_K, window=window,
             interpret=_interpret(),
         )
-    if block_table is not None:
+    if paged:
         # Paged pool + dense fallback: gather each row's blocks into a
         # contiguous view, then fall through to the regular dense math
         # (the kernel path above indexes the pool in place instead).
@@ -262,58 +403,33 @@ def decode_attention(
             block_table, k_cache, v_cache, jnp.arange(q.shape[0]),
             k_scale, v_scale,
         )
-    n_heads = q.shape[1]
-    n_kv = k_cache.shape[1]
-    n_rep = n_heads // n_kv
+    b, n_heads = q.shape[0], q.shape[1]
+    n_kv = k_cache.shape[-3]
     if scale is None:
         scale = q.shape[-1] ** -0.5
-
     # Group query heads by their KV head: [b, kv, rep, hd].
-    b, max_len = k_cache.shape[0], k_cache.shape[2]
-    qg = q.reshape(b, n_kv, n_rep, -1)
+    qg = q.reshape(b, n_kv, n_heads // n_kv, -1)
 
-    quant = k_scale is not None
-    if quant:  # int8 cache: dequant via score/prob scaling, not the cache
-        k_cache = k_cache.astype(q.dtype)
-        v_cache = v_cache.astype(q.dtype)
-    scores = jnp.einsum(
-        "bgrd,bgkd->bgrk", qg, k_cache, preferred_element_type=jnp.float32
-    ) * scale  # [b, kv, rep, max_len]
-    if quant:
-        scores = scores * k_scale[:, :, 0, None, :]
-
-    valid = jnp.arange(max_len)[None, :] < lengths[:, None]  # [b, max_len]
-    if window:
-        # Query position: ``lengths`` (split path — the new token) or
-        # ``lengths-1`` (already-written convention). Keys must sit in
-        # (q_pos - window, q_pos].
-        q_pos = lengths if k_new is not None else lengths - 1
-        valid &= jnp.arange(max_len)[None, :] > (q_pos - window)[:, None]
-    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
-
-    if k_new is None:
-        probs = jax.nn.softmax(scores, axis=-1)
-        if quant:
-            probs = probs * v_scale[:, :, 0, :][:, :, None, :]
-        out = jnp.einsum(
-            "bgrk,bgkd->bgrd", probs.astype(q.dtype), v_cache
+    def over(n: int):
+        """The dense mathematics over the first ``n`` positions, each
+        plane sliced here: inside its rung's branch."""
+        # The scale planes are sublane-replicated: row 0 is the scale.
+        ks, vs = (
+            None if p is None else _entry_prefix(p, layer, n, 3)[:, :, :1]
+            for p in (k_scale, v_scale)
         )
-        return out.reshape(b, n_heads, -1)
+        return _dense_decode(
+            qg, _entry_prefix(k_cache, layer, n, 2),
+            _entry_prefix(v_cache, layer, n, 2), lengths, k_new, v_new,
+            ks, vs, scale, window,
+        )
 
-    # Split path: merge the current token's (always-valid) score into the
-    # cache-prefix softmax without writing it to the cache first.
-    s_new = jnp.einsum(
-        "bgrd,bgd->bgr", qg, k_new, preferred_element_type=jnp.float32
-    ) * scale  # [b, kv, rep]
-    m = jnp.maximum(jnp.max(scores, axis=-1), s_new)  # [b, kv, rep]
-    e_c = jnp.exp(scores - m[..., None])  # [b, kv, rep, max_len]
-    e_n = jnp.exp(s_new - m)  # [b, kv, rep]
-    denom = jnp.sum(e_c, axis=-1) + e_n
-    if quant:
-        e_c = e_c * v_scale[:, :, 0, :][:, :, None, :]
-    out = jnp.einsum("bgrk,bgkd->bgrd", e_c.astype(q.dtype), v_cache)
-    out = out + e_n[..., None].astype(q.dtype) * v_new[:, :, None, :]
-    out = out / denom[..., None].astype(q.dtype)
+    if len(rungs) < 2:
+        out = over(k_cache.shape[-2])
+    else:
+        out = jax.lax.switch(
+            read, [functools.partial(over, n) for n in rungs]
+        )
     return out.reshape(b, n_heads, -1)
 
 
@@ -345,7 +461,7 @@ def cache_chunk_attention(
     (the CPU/tests fallback). Rows with t >= lens[p] return 0.
     kernel: None → auto (pallas on TPU).
     """
-    window = _effective_window(window, k_cache, block_table)
+    window = _effective_window(window, k_cache.shape[2], block_table)
     if kernel is None:
         kernel = _flash_enabled()
     if kernel:

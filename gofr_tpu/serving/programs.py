@@ -53,6 +53,9 @@ class LLMProgramsMixin:
     prefill_batch: int
     prefill_chunk: int
     prefill_rungs: tuple[int, ...]
+    decode_read_rungs: tuple[int, ...]
+    max_len: int
+    kv_block: int
     _slot_state_dirty: bool
     _up: Any  # host→device placement callable
     _compiles: Any  # serving.device_telemetry.CompileTracker
@@ -80,12 +83,22 @@ class LLMProgramsMixin:
             transformer_decode_step,
             transformer_prefill_chunk,
         )
+        from gofr_tpu.ops.attention import decode_read_plan
         cfg, top_k = self.cfg, self._top_k
         # pallas kernels don't auto-partition under GSPMD: mesh-sharded
         # serving takes the dense attention formulations, which XLA
         # partitions (per-head locality under tp; sharded-softmax
         # collectives under cp).
         dense_attn = self.mesh is not None
+        # The decode step's dense attention reads the rung of the cache
+        # that holds the longest live slot. Where the position axis is
+        # sharded (context parallel) a prefix lives on the first chips
+        # only, so that cache keeps the whole read.
+        bound_read = self.mesh is None or "cp" not in self.mesh.axis_names
+        self.decode_read_rungs = decode_read_plan(
+            self.max_len, paged=bool(self.kv_block),
+            window=cfg.sliding_window, kernel=False if dense_attn else None,
+        ) if bound_read else (self.max_len,)
 
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -293,7 +306,7 @@ class LLMProgramsMixin:
                 tokens, logps, cache, nsteps, pcounts, topi, topl = carry
                 logits, cache = transformer_decode_step(
                     params, tokens, cache, active, cfg,
-                    dense_attn=dense_attn, aids=aids,
+                    dense_attn=dense_attn, aids=aids, bound_read=bound_read,
                 )
                 pen = (pcounts, fpen, ppen) if enable_penalties else None
                 sub = row_keys(seeds, nsteps)

@@ -16,6 +16,7 @@ import numpy as np
 
 from gofr_tpu import faults
 from gofr_tpu.analysis import lockcheck
+from gofr_tpu.ops.attention import decode_read_index
 from gofr_tpu.serving.loop_profiler import loop_phase
 from gofr_tpu.serving.types import (
     _ActiveSeq,
@@ -72,6 +73,7 @@ class SchedulerMixin:
     pipeline_depth: int
     prefill_batch: int
     prefill_rungs: tuple[int, ...]
+    decode_read_rungs: tuple[int, ...]
     prefill_chunk: int
     top_logprobs: int
     window_k: int
@@ -1671,7 +1673,7 @@ class SchedulerMixin:
         """Dispatch one k-step device window (non-blocking) and start the
         async device→host copy of its emitted [2, k, S] block. Returns
         ``(emitted_dev, slots_snapshot, etops_dev_or_None,
-        live_positions)`` for _process_window — the snapshot matters
+        live_positions, longest)`` for _process_window — the snapshot matters
         because by processing time a retired slot may already hold a NEW
         request admitted in between."""
         # Fault seam: a raise models the device failing a decode window;
@@ -1738,15 +1740,19 @@ class SchedulerMixin:
             self._push_table()
 
         # Cache positions that are context when this window starts: each
-        # live slot's prompt plus what earlier windows already cover.
-        live_positions = 0
+        # live slot's prompt plus what earlier windows already cover; and
+        # the longest of them, which decides how much of every slot the
+        # window's dense attention reads.
+        live_positions = longest = 0
         for i, seq in enumerate(self._slots):
             if seq is not None:
                 req = seq.request
-                live_positions += (
+                held = (
                     (req.effective_prompt_len or len(req.prompt_ids))
                     + seq.tokens_in_flight - 1
                 )
+                live_positions += held
+                longest = max(longest, held)
                 seq.tokens_in_flight += self.window_k
         # Results land in LOCALS first and commit to self only after a
         # superseded check: a dispatch that BLOCKED here (a hung device
@@ -1782,7 +1788,7 @@ class SchedulerMixin:
         if self._lockstep:
             lockcheck.note_device_sync("lockstep_block_until_ready")
             self._jax.block_until_ready(emitted)
-        return emitted, list(self._slots), etops, live_positions
+        return emitted, list(self._slots), etops, live_positions, longest
 
     def _process_window(
         self,
@@ -1790,6 +1796,7 @@ class SchedulerMixin:
         snapshot: "list[Optional[_ActiveSeq]]",
         etops: Any,
         live_positions: int,
+        longest: int,
     ) -> None:
         t_fetch = time.time()
         # Interruptible wait: while this window's block is in flight, flush
@@ -1917,6 +1924,17 @@ class SchedulerMixin:
             self._metrics.record_histogram(
                 "app_tpu_kv_live_ratio",
                 live_positions / max(1, self.n_slots * self.max_len),
+                "model", self.model_name,
+            )
+            # The share of every slot that the window's last step read:
+            # the device's own rule (ops/attention.decode_read_index)
+            # over the longest live slot, which that step finds
+            # window_k - 1 positions further on. 1.0: the whole cache.
+            rungs = self.decode_read_rungs
+            self._metrics.record_histogram(
+                "app_tpu_decode_read_ratio",
+                rungs[decode_read_index(rungs, longest + self.window_k - 1)]
+                / rungs[-1],
                 "model", self.model_name,
             )
         self._update_slot_gauges()

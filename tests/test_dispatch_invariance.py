@@ -123,6 +123,53 @@ def test_seeded_sampled_stream_is_the_baselines(engine, baseline):
 
 
 # ----------------------------------------------------------------------
+# who shares the batch (ISSUE 31)
+# ----------------------------------------------------------------------
+
+# The decode step reads the rung of the cache that holds the LONGEST live
+# slot, so what a neighbour holds decides how much of every slot is read.
+# That may not reach a request either. 256 positions have the rungs 128
+# and 256; the neighbour's 122-token prompt crosses 128 inside the first
+# decode windows, while the short request beside it is decoding.
+NEIGHBOUR = "n" * 122
+
+
+@pytest.fixture(
+    scope="module", params=["llama-tiny", "moe-tiny", "looped-tiny"],
+    ids=["dense", "moe", "looped"],
+)
+def rung_engine(request):
+    e = InferenceEngine(
+        request.param, n_slots=4, max_len=256, tokenizer=ByteTokenizer(),
+    )
+    assert e.decode_read_rungs == (128, 256)
+    e.start_sync()
+    yield e
+    e.stop_sync()
+
+
+@pytest.mark.parametrize("sampling", [
+    {"temperature": 0.0}, {"temperature": 0.8, "seed": 7},
+], ids=["greedy", "seeded"])
+def test_a_stream_is_the_same_beside_a_neighbour_that_crosses_a_rung(
+    rung_engine, sampling,
+):
+    def submit(prompt: str):
+        return rung_engine.submit_generate(
+            prompt, max_new_tokens=24, stop_on_eos=False, **sampling
+        )
+
+    alone = submit(PROMPT).future.result(timeout=120)
+    neighbour, beside = submit(NEIGHBOUR), submit(PROMPT)
+    beside = beside.future.result(timeout=120)
+    crossed = neighbour.future.result(timeout=120)
+    assert len(crossed.token_ids) == 24
+    assert crossed.prompt_tokens < 128 < crossed.prompt_tokens + 24
+    assert beside.token_ids == alone.token_ids
+    assert len(alone.token_ids) == 24
+
+
+# ----------------------------------------------------------------------
 # the retired keys, and the programs an engine holds
 # ----------------------------------------------------------------------
 

@@ -367,6 +367,74 @@ def test_layer_off_mints_no_timeline():
 # ----------------------------------------------------------------------
 
 
+def _windows_spied(engine):
+    """Wrap ``_process_window`` so the test sees each processed window's
+    ``longest`` (the longest live slot when it was dispatched)."""
+    seen: list[int] = []
+    process = engine._process_window
+
+    def spy(emitted, snapshot, etops, live_positions, longest):
+        seen.append(longest)
+        return process(emitted, snapshot, etops, live_positions, longest)
+
+    engine._process_window = spy
+    return seen, lambda: engine.__dict__.pop("_process_window")
+
+
+def test_decode_read_ratio_records_the_host_rules_rung_once_a_window(
+    metrics, engine,
+):
+    """One record a processed window, beside the occupancy: the rung the
+    window's LAST step read (the device's rule, asked on the host about
+    the longest live slot window_k - 1 positions on) over max_len. The
+    prompt is chosen so that the context crosses the 128 rung of this
+    256-position cache while it decodes."""
+    from gofr_tpu.ops.attention import decode_read_index, decode_read_rungs
+
+    name = "app_tpu_decode_read_ratio"
+    rungs = decode_read_rungs(engine.max_len)
+    assert engine.decode_read_rungs == rungs == (128, 256)
+    seen, unspy = _windows_spied(engine)
+    n0, sum0 = _hist_sum_count(metrics, name)
+    w0, _ = _hist_sum_count(metrics, "app_tpu_window_occupancy")
+    try:
+        r = engine.generate_sync(
+            "x" * 100, max_new_tokens=40, temperature=0.0, stop_on_eos=False,
+        )
+    finally:
+        unspy()
+    assert len(r.token_ids) == 40
+    n1, sum1 = _hist_sum_count(metrics, name)
+    w1, _ = _hist_sum_count(metrics, "app_tpu_window_occupancy")
+    assert n1 - n0 == w1 - w0 == len(seen) >= 5
+    want = [
+        rungs[decode_read_index(rungs, longest + engine.window_k - 1)]
+        / engine.max_len
+        for longest in seen
+    ]
+    assert set(want) == {0.5, 1.0}  # both rungs were read
+    assert sum1 - sum0 == pytest.approx(sum(want))
+
+
+def test_decode_read_ratio_is_one_for_a_cache_with_one_rung(metrics):
+    eng = InferenceEngine(
+        "llama-tiny-f32", n_slots=2, max_len=64, tokenizer=ByteTokenizer(),
+        metrics=metrics,
+    )
+    assert eng.decode_read_rungs == (64,)
+    eng.start_sync()
+    try:
+        eng.generate_sync(
+            "one rung", max_new_tokens=12, temperature=0.0, stop_on_eos=False,
+        )
+    finally:
+        eng.stop_sync()
+    n, total = _hist_sum_count(
+        metrics, "app_tpu_decode_read_ratio", model="llama-tiny-f32"
+    )
+    assert n >= 2 and total == pytest.approx(n)
+
+
 def test_phase_histograms_record_exactly_once_per_request(metrics, engine):
     ratios = ("app_tpu_window_occupancy", "app_tpu_prefill_fill_ratio")
     before = {name: _hist_count(metrics, name) for name in PHASES}

@@ -486,6 +486,43 @@ def test_context_parallel_serving_matches_single_device():
         assert got.token_ids == ref.token_ids, axes
 
 
+@pytest.mark.parametrize("axes,rungs", [
+    ({"TPU_MESH_TP": "2"}, (128, 256)), ({"TPU_MESH_CP": "2"}, (256,)),
+], ids=["tp-bounded", "cp-whole"])
+def test_a_mesh_bounds_the_decode_read_unless_positions_are_sharded(
+    axes, rungs,
+):
+    """A mesh takes the dense decode attention, whose read is bounded by
+    the longest live slot's rung (ISSUE 31): under tp the kv-head axis
+    shards and the bound holds; under cp the POSITION axis shards, a
+    prefix would live on the first chips only, and the whole read stays.
+    Either way the stream is the single device's while the context
+    crosses the 128 rung of a 256-position cache."""
+    prompt, kw = "m" * 120, dict(
+        max_new_tokens=16, temperature=0.0, stop_on_eos=False,
+    )
+    single = InferenceEngine(
+        "llama-tiny", n_slots=2, max_len=256, tokenizer=ByteTokenizer(),
+    )
+    assert single.decode_read_rungs == (128, 256)
+    single.start_sync()
+    try:
+        ref = single.generate_sync(prompt, **kw)
+    finally:
+        single.stop_sync()
+    sharded = InferenceEngine.from_config(MockConfig({
+        "TPU_MODEL": "llama-tiny", "TPU_KV_SLOTS": "2",
+        "TPU_MAX_LEN": "256", **axes,
+    }))
+    assert sharded.decode_read_rungs == rungs
+    sharded.start_sync()
+    try:
+        got = sharded.generate_sync(prompt, **kw)
+    finally:
+        sharded.stop_sync()
+    assert got.token_ids == ref.token_ids
+
+
 def test_ctx_infer_through_http_app(free_port):
     """ctx.infer end to end through the HTTP surface."""
     import http.client
