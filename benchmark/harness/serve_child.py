@@ -41,7 +41,9 @@ from benchmark.harness.server import ENTRY_POINT, check  # noqa: E402
 
 def register_configuration(config: dict) -> None:
     """``dataclasses.replace(get_model(base).config, **overrides)`` under the
-    name the file's ``TPU_MODEL`` asks for (the configuration's own)."""
+    name the file's ``TPU_MODEL`` asks for (the configuration's own), with
+    any number of overrides: ``cells.check_cut`` has held them to the cuts
+    that the file declares."""
     from gofr_tpu.models.registry import ModelSpec, get_model, register_model
 
     if not config.get("overrides"):
@@ -52,6 +54,20 @@ def register_configuration(config: dict) -> None:
         config=dataclasses.replace(base.config, **config["overrides"]),
         init=base.init, eos_token=base.eos_token, forward=base.forward,
     ))
+    print(served_line(config), flush=True)  # into the server's log
+
+
+def served_line(config: dict) -> str:
+    """The program's config as the registry now gives it under the name the
+    engine will ask for, one line of the server's log: a rehearsal of a cut
+    configuration reads from it that the child applied every override."""
+    from gofr_tpu.models.registry import get_model
+
+    name = config["env"]["TPU_MODEL"]
+    return "benchmark: serving " + json.dumps(
+        {"model": name, "config": dataclasses.asdict(get_model(name).config)},
+        default=str,
+    )
 
 
 def load_reference(config_path: str, config: dict) -> Any:

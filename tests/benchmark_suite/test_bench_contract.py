@@ -86,12 +86,9 @@ def test_every_cell_loads_from_its_files_and_nothing_else(bench):
         assert "setup_s" in {m["name"] for m in cell.end_to_end}
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         entry = next(c for c in bench["configs"] if c["name"] == w["config"])
-        # reduced names exactly what the file says it changed from the source
-        assert sorted(entry["reduced"]) == sorted(cell.config["reduced"])
-        for key in entry["reduced"]:
-            change = cell.config["reduced"][key]
-            assert cell.config[key] == change["here"] != change["published"]
-            assert cell.config["overrides"] == {change["program_key"]: change["here"]}
+        # a cut is held to the harness's one rule (load_cell has asked it
+        # already: a chip run refuses what this refuses)
+        cells.check_cut(entry, cell.config)
         # the longest request fits a slot with the scheduler's margin
         longest = (cell.mix["prompt_tokens"]["max"]
                    + cell.mix["output_tokens"]["max"])
@@ -115,21 +112,12 @@ def configurations():
     return [pytest.param(c, id=c["name"]) for c in load_bench()["configs"]]
 
 
-@pytest.mark.parametrize("entry", configurations())
-def test_published_widths_are_kept(entry):
-    """A configuration's file against ``published/<name>.json``, the keys of
-    its source's own ``config.json`` verbatim, and against the program
-    through the file's own map ``program_keys``: this test names no
-    configuration, no size and no field of the program's config."""
-    with open(os.path.join(CHECKOUT, entry["file"])) as fh:
-        config = json.load(fh)
-    path = os.path.join(SUITE, "published", f"{entry['name']}.json")
-    assert os.path.isfile(path), (
-        f"configuration {entry['name']!r} has no published sizes: add {path}, "
-        f"the keys of {entry['source']} verbatim, with that URL as \"source\""
-    )
-    with open(path) as fh:
-        published = json.load(fh)
+def published_widths_kept(entry, config, published):
+    """A configuration's file against the keys of its source's own
+    ``config.json`` verbatim, and against the program through the file's own
+    map ``program_keys``. It names no configuration, no size and no field of
+    the program's config; ``reduced`` is taken key by key, so a file cut on
+    several keys goes through the same lines as one cut on none."""
     assert published["source"] == entry["source"] == config["source"]
     for key, value in published.items():
         if key in config.get("reduced", {}):
@@ -158,6 +146,21 @@ def test_published_widths_are_kept(entry):
 
 
 @pytest.mark.parametrize("entry", configurations())
+def test_published_widths_are_kept(entry):
+    """Once a configuration of ``BENCHMARK.json``, against
+    ``published/<name>.json``."""
+    with open(os.path.join(CHECKOUT, entry["file"])) as fh:
+        config = json.load(fh)
+    path = os.path.join(SUITE, "published", f"{entry['name']}.json")
+    assert os.path.isfile(path), (
+        f"configuration {entry['name']!r} has no published sizes: add {path}, "
+        f"the keys of {entry['source']} verbatim, with that URL as \"source\""
+    )
+    with open(path) as fh:
+        published_widths_kept(entry, config, json.load(fh))
+
+
+@pytest.mark.parametrize("entry", configurations())
 def test_the_child_can_load_the_reference_a_configuration_names(entry):
     """As the server child does before the engine boots: the module is
     found through the configuration's file and has the two things the
@@ -170,16 +173,23 @@ def test_the_child_can_load_the_reference_a_configuration_names(entry):
     assert reference.ABLATIONS and callable(reference.reference_logprobs)
 
 
-def cells_file_with(tmp_path, **changes):
+def cells_file_with(tmp_path, cells_file="rehearsal_cells.json", listed=None,
+                    **changes):
     """A cells file of one tiny cell whose configuration file, a copy under
-    ``tmp_path``, has ``changes`` applied (None removes the key)."""
-    with open(os.path.join(SUITE, "rehearsal_cells.json")) as fh:
+    ``tmp_path``, has ``changes`` applied (None removes the key) and whose
+    entry lists ``listed`` as reduced (None leaves the entry's list). The
+    copy finds the tests' references and traffic beside it."""
+    with open(os.path.join(SUITE, cells_file)) as fh:
         bench = json.load(fh)
     entry = bench["configs"][0]
     with open(os.path.join(CHECKOUT, entry["file"])) as fh:
         config = {**json.load(fh), **changes}
     (tmp_path / "configs").mkdir()
+    for beside in ("reference", "traffic"):
+        os.symlink(os.path.join(SUITE, beside), tmp_path / beside)
     entry["file"] = str(tmp_path / "configs" / "copy.json")
+    if listed is not None:
+        entry["reduced"] = listed
     with open(entry["file"], "w") as fh:
         json.dump({k: v for k, v in config.items() if v is not None}, fh)
     with open(tmp_path / "cells.json", "w") as fh:
@@ -201,3 +211,141 @@ def test_a_configuration_without_its_reference_is_refused_before_any_boot(
     # the message gives the path it looked for, beside the configuration
     assert says in str(refused.value)
     assert str(tmp_path / "reference") in str(refused.value)
+
+
+# Planted cuts: copies of the tests' two-key share of moe-tiny
+# (configs/tiny-moe-share.json: depth 2 -> 1, vocabulary 512 -> 256), whose
+# "published" sizes are the registry's entry read through program_keys.
+SHARE = "rehearsal_cells_share.json"
+DEPTH = {"published": 2, "here": 1, "program_key": "n_layers", "why": "planted"}
+EXPERTS = {"published": 4, "here": 2, "program_key": "n_experts", "why": "planted"}
+VOCABULARY = {"published": 512, "here": 256, "program_key": "vocab_size",
+              "why": "planted"}
+UNCUT = {"num_hidden_layers": 2, "num_local_experts": 4, "vocab_size": 512}
+
+
+def cut_on(**cuts):
+    """The changes to the share's file that cut it on exactly ``cuts``."""
+    return {
+        **UNCUT, **{key: cut["here"] for key, cut in cuts.items()},
+        "reduced": cuts,
+        "overrides": {cut["program_key"]: cut["here"] for cut in cuts.values()},
+    }
+
+
+def registry_as_published(config):
+    from gofr_tpu.models.registry import get_model
+
+    base = get_model(config["base"]).config
+    return {"source": config["source"],
+            **{key: getattr(base, field)
+               for key, field in config["program_keys"].items()}}
+
+
+@pytest.mark.parametrize("cuts", [
+    pytest.param({}, id="no-cut"),
+    pytest.param({"num_hidden_layers": DEPTH}, id="one-key"),
+    pytest.param({"num_hidden_layers": DEPTH, "num_local_experts": EXPERTS,
+                  "vocab_size": VOCABULARY}, id="three-keys"),
+])
+def test_a_cut_on_any_number_of_keys_loads_and_keeps_the_published_widths(
+    tmp_path, cuts,
+):
+    cells_file, cell = cells_file_with(
+        tmp_path, SHARE, listed=sorted(cuts), **cut_on(**cuts),
+        **({} if cuts else {"deployment": None}),
+    )
+    config = cells.load_cell(cells_file, cell).config
+    assert config["overrides"] == {c["program_key"]: c["here"] for c in cuts.values()}
+    with open(cells_file) as fh:
+        (entry,) = json.load(fh)["configs"]
+    published_widths_kept(entry, config, registry_as_published(config))
+
+
+BOTH = cut_on(num_hidden_layers=DEPTH, vocab_size=VOCABULARY)
+
+
+@pytest.mark.parametrize("cells_file,listed,changes,says", [
+    pytest.param(
+        SHARE, None, {"overrides": {**BOTH["overrides"], "sliding_window": 24}},
+        ["overrides sets 'sliding_window' to 24", "reduced declares nothing"],
+        id="an-override-that-reduced-does-not-declare"),
+    pytest.param(
+        SHARE, None, {"overrides": {"n_layers": 1}},
+        ["reduced declares 'vocab_size' cut to 256",
+         "overrides gives it 'nothing'"],
+        id="a-declared-cut-with-no-override"),
+    pytest.param(
+        SHARE, None, {"overrides": {"n_layers": 1, "vocab_size": 384}},
+        ["reduced declares 'vocab_size' cut to 256", "overrides gives it 384"],
+        id="an-override-of-another-value"),
+    pytest.param(
+        SHARE, None,
+        {"reduced": {**BOTH["reduced"], "vocab_size": {**VOCABULARY, "published": 256}}},
+        ["reduced['vocab_size']", "here 256 equal to published 256"],
+        id="here-equal-to-published"),
+    pytest.param(
+        SHARE, None, {"vocab_size": 384},
+        ["its own 'vocab_size' is 384", "says here 256"],
+        id="the-file's-own-key-differs-from-here"),
+    pytest.param(
+        SHARE, None,
+        {"reduced": {**BOTH["reduced"], "vocab_size": {**VOCABULARY, "why": ""}}},
+        ["reduced['vocab_size'] has no 'why'"],
+        id="a-cut-without-its-why"),
+    pytest.param(
+        SHARE, None, {"deployment": " "},
+        ["cut on ['num_hidden_layers', 'vocab_size']", "states no deployment"],
+        id="a-cut-with-an-empty-deployment"),
+    pytest.param(
+        SHARE, ["num_hidden_layers"], {},
+        ["the entry's reduced lists ['num_hidden_layers']",
+         "declares ['num_hidden_layers', 'vocab_size']", "differ in ['vocab_size']"],
+        id="the-entry's-list-differs-from-the-file's"),
+    # The one exemption is for the tests' tiny models alone: tiny-dense
+    # overrides its window and declares nothing, which any other source
+    # may not.
+    pytest.param(
+        "rehearsal_cells.json", None, {"source": "https://example.org/config.json"},
+        ["overrides sets 'sliding_window' to 24", "reduced declares nothing"],
+        id="an-undeclared-override-outside-the-tests"),
+])
+def test_a_cut_that_is_not_declared_whole_is_refused_before_any_boot(
+    tmp_path, cells_file, listed, changes, says,
+):
+    planted, cell = cells_file_with(tmp_path, cells_file, listed=listed, **changes)
+    with pytest.raises(BenchFailure) as refused:
+        cells.load_cell(planted, cell)
+    # the message names the file, the key and both values
+    assert str(tmp_path / "configs" / "copy.json") in str(refused.value)
+    for part in says:
+        assert part in str(refused.value)
+
+
+@pytest.mark.parametrize("changes,fails_on", [
+    # run at another size than published, and not listed
+    pytest.param({"reduced": {"num_hidden_layers": DEPTH}}, "vocab_size",
+                 id="a-cut-not-listed"),
+    # listed, with another published value than the source's
+    pytest.param(
+        {"reduced": {**BOTH["reduced"], "vocab_size": {**VOCABULARY, "published": 1024}}},
+        "vocab_size", id="listed-with-another-published-value"),
+    # listed as published, and the program is told another size
+    pytest.param({"overrides": {"n_layers": 1, "vocab_size": 128}},
+                 "vocab_size", id="the-program-is-told-another-size"),
+    # a width changed is a width changed, listed or not
+    pytest.param({"hidden_size": 64}, "hidden_size", id="a-width"),
+])
+def test_a_cut_of_the_vocabulary_passes_the_published_check_only_as_listed(
+    changes, fails_on,
+):
+    with open(os.path.join(SUITE, SHARE)) as fh:
+        (entry,) = json.load(fh)["configs"]
+    with open(os.path.join(CHECKOUT, entry["file"])) as fh:
+        config = json.load(fh)
+    assert "vocab_size" in WIDTHS_SHOWN and "vocab_size" in config["reduced"]
+    published = registry_as_published(config)
+    published_widths_kept(entry, config, published)  # as committed, it passes
+    with pytest.raises(AssertionError) as failed:
+        published_widths_kept(entry, {**config, **changes}, published)
+    assert fails_on in str(failed.value)

@@ -28,7 +28,7 @@ def test_idle_share_and_self_times_on_hand_made_intervals():
     ]
     trace = tr.DeviceTrace(
         devices={"/device:TPU:0": sorted(ops, key=lambda o: o[1])},
-        modules={"/device:TPU:0": [("jit_spec_window(123)", 0.0, 800e3),
+        modules={"/device:TPU:0": [("jit_decode_window(123)", 0.0, 800e3),
                                    ("jit_prefill(9)", 990e3, 10e3)]},
     )
     assert trace.window_s() == pytest.approx(1e-3)
@@ -42,14 +42,14 @@ def test_idle_share_and_self_times_on_hand_made_intervals():
     breakdown = top.read(run_with(trace))
     listed = dict(map(tuple, breakdown["device_ops"]))
     assert listed == pytest.approx({
-        "module jit_spec_window": 800e-6, "module jit_prefill": 10e-6,
+        "module jit_decode_window": 800e-6, "module jit_prefill": 10e-6,
         "op while.1": 300e-6, "op fusion.1": 210e-6, "op flash_kernel": 150e-6,
         "op fusion.2": 100e-6,
     })
     ops_s = [s for name, s in listed.items() if name.startswith("op ")]
     assert sum(ops_s) == pytest.approx(tr.busy_s(trace))  # self times add up
     assert [n for n, _ in breakdown["device_ops"]][:3] == [
-        "module jit_spec_window", "module jit_prefill", "op while.1",
+        "module jit_decode_window", "module jit_prefill", "op while.1",
     ]  # programs first, then operations, most time first
     gaps = dict(map(tuple, breakdown["idle_gaps"]))
     assert gaps == pytest.approx({
@@ -81,7 +81,7 @@ def test_round_trip_through_the_recorded_form_and_short_names():
     hlo = ("%fusion.322 = bf16[14,4096]{1,0:T(8,128)(2,1)S(1)} fusion(bf16[14,4096]"
            "{1,0} %get-tuple-element.3137), kind=kOutput, calls=%fused_computation.74")
     assert tr.short_name(hlo) == "%fusion.322 bf16[14,4096]"
-    assert tr.short_name("jit_spec_window(7887516207321774033)") == "jit_spec_window"
+    assert tr.short_name("jit_decode_window(7887516207321774033)") == "jit_decode_window"
     assert tr.short_name("flash_kernel") == "flash_kernel"
 
 
