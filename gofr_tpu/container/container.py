@@ -373,6 +373,20 @@ class Container:
             "prefill chunk steps dispatched, by the row count the step "
             "ran at (rows: 1 when one row waited, else TPU_PREFILL_BATCH)",
         )
+        m.new_counter(
+            "app_tpu_moe_routes_total",
+            "routes (computed token x expert layer x chosen expert) of a "
+            "grouped expert layer, by where the chosen expert lives: held "
+            "here, or absent (left out, another chip's share); from counts "
+            "the prefill and decode steps return beside their tokens",
+        )
+        m.new_histogram(
+            "app_tpu_moe_expert_load_ratio",
+            "rows of the fullest held expert / mean rows of the held "
+            "experts, averaged over a prefill step's expert layers, one "
+            "record per prefill step (1.0: even load)",
+            (1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0, 64.0, 256.0),
+        )
         # Disaggregated prefill/decode tiers (TPU_REPLICA_ROLES;
         # docs/advanced-guide/resilience.md): cross-tier KV-block
         # transfers by outcome, their wall-clock cost, and whether the

@@ -162,6 +162,37 @@ def _register_llms() -> None:
             ffn="mlp", act="gelu", attn_bias=True, proj_bias=True,
             pos_emb="learned",
         ),
+        # openPangu-Ultra-MoE-718B (FreedomIntelligence, config.json,
+        # model_type pangu_ultra_moe), the published sizes whole: latent
+        # attention (one 512 + 64 row a token a layer in the cache),
+        # 3 dense layers then 58 with 256 routed experts (sigmoid scores,
+        # 8 a token, normalised, x 2.5) and a shared one, sandwich norms.
+        # No chip holds one expert layer (24.6 GB in bf16): it is served
+        # as a share (n_layers, n_dense_layers, n_experts_held and
+        # vocab_size overridden; benchmark/configs/
+        # openpangu-ultra-moe-718b-ep16.json, docs/advanced-guide/
+        # latent-attention-expert-share.md). The multi-token-prediction
+        # module (num_nextn_predict_layers 1) is not built: ROADMAP M5.
+        "openpangu-ultra-moe-718b": TransformerConfig(
+            vocab_size=153600, d_model=7680, n_layers=61, n_heads=128,
+            n_kv_heads=128, d_ff=18432, max_len=131072, rope_theta=25.6e6,
+            norm_eps=1e-5, post_norm=True, q_lora_rank=1536,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, n_experts=256, n_experts_active=8,
+            d_ff_expert=2048, n_shared_experts=1, n_dense_layers=3,
+            router_score="sigmoid", routed_scale=2.5,
+        ),
+        # Its test size: 1 dense + 2 expert layers, 8 experts, 2 a token,
+        # 1 shared, sandwich norms, a 16 + 8 row a token in the cache.
+        "mla-moe-tiny": TransformerConfig(
+            vocab_size=512, d_model=64, n_layers=3, n_heads=4,
+            n_kv_heads=4, d_ff=160, max_len=256, rope_theta=10000.0,
+            norm_eps=1e-5, post_norm=True, q_lora_rank=32,
+            kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, n_experts=8, n_experts_active=2,
+            d_ff_expert=48, n_shared_experts=1, n_dense_layers=1,
+            router_score="sigmoid", routed_scale=2.5,
+        ),
         # Looped-arch test size: 2 layers run 3 times (6 cache entries),
         # sandwich norms.
         "looped-tiny": TransformerConfig(

@@ -36,9 +36,13 @@ from gofr_tpu.ops.attention import (
     decode_attention,
     decode_read_index,
     decode_read_rungs,
+    latent_chunk_attention,
+    latent_decode_attention,
+    pad_last,
 )
 from gofr_tpu.ops.kv_cache import (
     KVCache,
+    LatentKVCache,
     PagedKVCache,
     fake_quantize_kv,
     quantize_kv,
@@ -100,10 +104,56 @@ class TransformerConfig:
     # Sandwich norms: a second norm on each sublayer's OUTPUT, before the
     # residual add (``attn_post_norm`` / ``mlp_post_norm`` leaves).
     post_norm: bool = False
+    # Latent attention (MLA): ``kv_lora_rank`` > 0 turns it on. Queries go
+    # through a ``q_lora_rank`` bottleneck with a norm; keys and values are
+    # up-projections of ONE ``kv_lora_rank``-wide latent a token (normed)
+    # beside ``qk_rope_head_dim`` rotary values shared by every head. A
+    # head's query and key are ``qk_nope_head_dim + qk_rope_head_dim``
+    # wide, its value ``v_head_dim``. The cache holds the latent and the
+    # rotary values: one ``cache_row``-wide row a token a layer
+    # (``ops/kv_cache.LatentKVCache``), whatever ``n_kv_heads`` says.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # The expert layer beyond Mixtral's. ``d_ff_expert``: a routed (and
+    # shared) expert's width where it is not ``d_ff`` (0: ``d_ff``).
+    # ``n_shared_experts``: experts every token passes through, of width
+    # ``n_shared_experts * d_ff_expert``, beside the routed ones.
+    # ``n_dense_layers``: the leading layers that keep a dense FFN of width
+    # ``d_ff``. ``n_experts_held``: how many of the ``n_experts`` routed
+    # experts THIS program holds (0: all) — one chip's share under expert
+    # parallelism; the held range is ``expert_share_index * n_experts_held``
+    # onward, the router keeps its ``n_experts`` outputs and
+    # ``n_experts_active`` a token, and what the absent experts would add
+    # is left out. ``router_score``: softmax over the router's outputs
+    # (Mixtral) or a sigmoid of each; the chosen experts' scores are
+    # renormalised to sum to 1 (over ALL the chosen, held here or not).
+    # ``routed_scale`` multiplies the routed part.
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    n_dense_layers: int = 0
+    n_experts_held: int = 0
+    expert_share_index: int = 0
+    router_score: str = "softmax"  # "softmax" | "sigmoid"
+    routed_scale: float = 1.0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def head_dim(self) -> int:
+        if self.is_latent:  # a head's query and key
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def cache_row(self) -> int:
+        """Values one token holds in one cache entry of a latent cache: the
+        normed latent, then the rotary key values."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def n_cache_entries(self) -> int:
@@ -112,20 +162,65 @@ class TransformerConfig:
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Unquantised cache bytes one token holds (keys and values)."""
+        """Unquantised cache bytes one token holds: keys and values of
+        every kv head, or for latent attention the one ``cache_row``."""
+        per_entry = (
+            self.cache_row if self.is_latent
+            else 2 * self.n_kv_heads * self.head_dim
+        )
         return (
-            self.n_cache_entries * 2 * self.n_kv_heads * self.head_dim
-            * jnp.dtype(self.dtype).itemsize
+            self.n_cache_entries * per_entry * jnp.dtype(self.dtype).itemsize
         )
 
     @property
     def rope_dims(self) -> int:
+        if self.is_latent:
+            return self.qk_rope_head_dim
         nd = int(self.head_dim * self.rotary_pct)
         return nd - (nd % 2)  # rotate-half needs an even subspace
 
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        """[lo, hi) of the router's outputs whose experts live here."""
+        lo = self.expert_share_index * self.experts_held
+        return lo, lo + self.experts_held
+
+    @property
+    def expert_product(self) -> str:
+        """How an expert layer multiplies: "einsum" computes every expert
+        for every row (``_ffn_moe``: all held, Mixtral's router, no shared
+        expert, no scale), anything else is "grouped": the routes sorted by
+        expert, each expert multiplied by its own rows
+        (``_ffn_moe_grouped``). ROADMAP S5 moves the all-held layer over by
+        changing this rule."""
+        einsum = (
+            self.experts_held == self.n_experts
+            and self.router_score == "softmax"
+            and not self.n_shared_experts and self.routed_scale == 1.0
+        )
+        return "einsum" if einsum else "grouped"
+
+    @property
+    def counts_routes(self) -> bool:
+        """The serving steps return, beside their tokens, how many routes
+        landed on held experts: only the grouped expert layer counts."""
+        return self.is_moe and self.expert_product == "grouped"
 
 
 # ---------------------------------------------------------------------------
@@ -152,35 +247,47 @@ def norm_init(name: str, shape: tuple, cfg: TransformerConfig) -> jnp.ndarray:
     return jnp.full(shape, scale - (1.0 if cfg.norm_offset else 0.0), cfg.dtype)
 
 
-def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
-    """Random-init params as a pytree with stacked per-layer leaves."""
-    k_embed, k_layers, k_head = jax.random.split(key, 3)
+def _dense_init(key, shape, fan_in, dtype):
+    scale = fan_in**-0.5
+    return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
-    def dense_init(key, shape, fan_in):
-        scale = fan_in**-0.5
-        return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(
-            cfg.dtype
-        )
 
-    D, H, KV, hd, F, L = (
-        cfg.d_model,
-        cfg.n_heads,
-        cfg.n_kv_heads,
-        cfg.head_dim,
-        cfg.d_ff,
-        cfg.n_layers,
+def _init_layer_group(key: jax.Array, cfg: TransformerConfig, L: int,
+                      moe: bool) -> dict:
+    """The stacked leaves of ``L`` layers of one kind: with an expert FFN
+    (``moe``) or a dense one."""
+    dense_init = partial(_dense_init, dtype=cfg.dtype)
+    D, H, KV, hd, F = (
+        cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
     )
-    ks = jax.random.split(k_layers, 12)
-    layers: dict[str, jnp.ndarray] = {
-        "wq": dense_init(ks[0], (L, D, H * hd), D),
-        "wk": dense_init(ks[1], (L, D, KV * hd), D),
-        "wv": dense_init(ks[2], (L, D, KV * hd), D),
-        "wo": dense_init(ks[3], (L, H * hd, D), H * hd),
-        # norm_offset models (Gemma) store w with the +1 applied in the
-        # forward, so identity init is zeros there, ones otherwise.
-        "attn_norm": norm_init("attn_norm", (L, D), cfg),
-        "mlp_norm": norm_init("mlp_norm", (L, D), cfg),
-    }
+    ks = jax.random.split(key, 12)
+    if cfg.is_latent:
+        R, C = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        )
+        layers: dict[str, jnp.ndarray] = {
+            "wq_down": dense_init(ks[0], (L, D, R), D),
+            "q_norm": norm_init("q_norm", (L, R), cfg),
+            "wq_up": dense_init(ks[1], (L, R, H * (nope + rope)), R),
+            # [c_kv | k_r]: the latent, then the rotary key values
+            "wkv_down": dense_init(ks[2], (L, D, C + rope), D),
+            "kv_norm": norm_init("kv_norm", (L, C), cfg),
+            "wk_up": dense_init(ks[8], (L, C, H * nope), C),
+            "wv_up": dense_init(ks[9], (L, C, H * vd), C),
+            "wo": dense_init(ks[3], (L, H * vd, D), H * vd),
+        }
+    else:
+        layers = {
+            "wq": dense_init(ks[0], (L, D, H * hd), D),
+            "wk": dense_init(ks[1], (L, D, KV * hd), D),
+            "wv": dense_init(ks[2], (L, D, KV * hd), D),
+            "wo": dense_init(ks[3], (L, H * hd, D), H * hd),
+        }
+    # norm_offset models (Gemma) store w with the +1 applied in the
+    # forward, so identity init is zeros there, ones otherwise.
+    layers["attn_norm"] = norm_init("attn_norm", (L, D), cfg)
+    layers["mlp_norm"] = norm_init("mlp_norm", (L, D), cfg)
     if cfg.norm == "ln":
         layers.update(
             attn_norm_b=jnp.zeros((L, D), dtype=cfg.dtype),
@@ -201,14 +308,27 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
             wk_b=jnp.zeros((L, KV * hd), dtype=cfg.dtype),
             wv_b=jnp.zeros((L, KV * hd), dtype=cfg.dtype),
         )
-    if cfg.is_moe:
-        E = cfg.n_experts
-        layers.update(
-            router=dense_init(ks[4], (L, D, E), D),
-            w_gate=dense_init(ks[5], (L, E, D, F), D),
-            w_up=dense_init(ks[6], (L, E, D, F), D),
-            w_down=dense_init(ks[7], (L, E, F, D), F),
-        )
+    if moe:
+        # The router keeps its published width; the expert leaves hold
+        # the experts that live here. A grouped expert layer's are not in
+        # the stack (``init_experts``).
+        E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.expert_width
+        layers["router"] = dense_init(ks[4], (L, D, E), D)
+        if cfg.expert_product != "grouped":
+            layers.update(
+                w_gate=dense_init(ks[5], (L, Eh, D, Fe), D),
+                w_up=dense_init(ks[6], (L, Eh, D, Fe), D),
+                w_down=dense_init(ks[7], (L, Eh, Fe, D), Fe),
+            )
+        if cfg.n_shared_experts:
+            Fs = cfg.n_shared_experts * Fe
+            layers.update(
+                ws_gate=dense_init(ks[10], (L, D, Fs), D),
+                ws_up=dense_init(ks[11], (L, D, Fs), D),
+                ws_down=dense_init(
+                    jax.random.fold_in(ks[11], 1), (L, Fs, D), Fs
+                ),
+            )
     elif cfg.ffn == "mlp":
         layers.update(
             w_up=dense_init(ks[6], (L, D, F), D),
@@ -220,12 +340,68 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
             w_up=dense_init(ks[6], (L, D, F), D),
             w_down=dense_init(ks[7], (L, F, D), F),
         )
+    return layers
+
+
+def init_experts(key: jax.Array, cfg: TransformerConfig) -> list:
+    """The held routed experts of a grouped expert layer, ONE SET OF LEAVES A
+    LAYER (``params["experts"][l]`` = ``{"w_gate", "w_up": [held, d, f],
+    "w_down": [held, f, d]}``) and not stacked leaves that the layer scan
+    slices: the grouped product is a custom call, and a custom call cannot
+    take a slice of a stacked leaf, so every step copied the layer's 1.5 GB
+    of expert weights out of it first (0.30 s of a 2.86 s capture on the
+    v5e, PERF.md section 6, PR 33; PR 31 met the same with the decode
+    kernel's K and V planes). Each leaf is an operand of its own, and the
+    layer scan picks its layer's by ``lax.switch`` on the layer's index."""
+    Eh, D, Fe = cfg.experts_held, cfg.d_model, cfg.expert_width
+
+    @partial(jax.jit, static_argnames=("shape", "fan_in"))
+    def leaf(key, shape, fan_in):
+        # One expert at a time under a scan: drawn whole and op by op, a
+        # leaf's float32 normals and their scaled copy (2 GB for 16 experts
+        # of 7,680 x 2,048) stood beside 9 GB of leaves already made, and
+        # the boot's peak reached 16.4 GB of the chip's 16.9 (PR 33).
+        return jax.lax.map(
+            lambda k: _dense_init(k, shape, fan_in, cfg.dtype),
+            jax.random.split(key, Eh),
+        )
+
+    experts = []
+    for l in range(cfg.n_moe_layers):
+        kg, ku, kd = jax.random.split(jax.random.fold_in(key, l), 3)
+        experts.append({
+            "w_gate": leaf(kg, (D, Fe), D),
+            "w_up": leaf(ku, (D, Fe), D),
+            "w_down": leaf(kd, (Fe, D), Fe),
+        })
+    return experts
+
+
+def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """Random-init params as a pytree with stacked per-layer leaves:
+    ``layers``, and before them ``dense_layers`` where the model leads with
+    ``cfg.n_dense_layers`` layers of another kind (a dense FFN before the
+    expert layers): leaves of two shapes cannot share one stack. A grouped
+    expert layer's held experts are ``experts``, a set of leaves a layer
+    (``init_experts``)."""
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    dense_init = partial(_dense_init, dtype=cfg.dtype)
+    D = cfg.d_model
+    n_dense = cfg.n_dense_layers if cfg.is_moe else 0
     out = {
         "embed": dense_init(k_embed, (cfg.vocab_size, D), D),
-        "layers": layers,
+        "layers": _init_layer_group(
+            k_layers, cfg, cfg.n_layers - n_dense, cfg.is_moe
+        ),
         "final_norm": norm_init("final_norm", (D,), cfg),
         "lm_head": dense_init(k_head, (D, cfg.vocab_size), D),
     }
+    if n_dense:
+        out["dense_layers"] = _init_layer_group(
+            jax.random.fold_in(k_layers, 1), cfg, n_dense, False
+        )
+    if cfg.counts_routes:
+        out["experts"] = init_experts(jax.random.fold_in(k_layers, 2), cfg)
     if cfg.norm == "ln":
         out["final_norm_b"] = jnp.zeros((D,), dtype=cfg.dtype)
     if cfg.pos_emb == "learned":
@@ -259,57 +435,103 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
             f"looped stack (n_passes={cfg.n_passes}): every stage would "
             f"need every pass's activations"
         )
+    n_dense = cfg.n_dense_layers if cfg.is_moe else 0
+    if pp and cfg.counts_routes:
+        raise ValueError(
+            "pipeline-parallel parameter specs are not implemented for a "
+            "grouped expert layer: its experts are a set of leaves a layer, "
+            "not a stacked axis to cut into stages"
+        )
+    if pp and n_dense:
+        raise ValueError(
+            f"pipeline-parallel parameter specs are not implemented for a "
+            f"stack of two layer groups (n_dense_layers={n_dense} before "
+            f"the expert layers): the stages would cut across the groups"
+        )
     lax_ = "pp" if pp else None  # leading (layer) axis of stacked leaves
-    layers = {
-        "wq": P(lax_, None, "tp"),
-        "wk": P(lax_, None, "tp"),
-        "wv": P(lax_, None, "tp"),
-        "wo": P(lax_, "tp", None),
-        "attn_norm": P(lax_, None),
-        "mlp_norm": P(lax_, None),
-    }
-    if cfg.attn_bias:
-        layers.update(
-            wq_b=P(lax_, "tp"),
-            wk_b=P(lax_, "tp"),
-            wv_b=P(lax_, "tp"),
-        )
-    if cfg.norm == "ln":
-        layers.update(attn_norm_b=P(lax_, None), mlp_norm_b=P(lax_, None))
-    if cfg.post_norm:
-        layers.update(attn_post_norm=P(lax_, None), mlp_post_norm=P(lax_, None))
-    if cfg.proj_bias:
-        # Row-parallel outputs (wo, w_down) have replicated biases; the
-        # column-parallel up-projection bias shards with its outputs.
-        layers.update(
-            wo_b=P(lax_, None),
-            w_up_b=P(lax_, "tp"),
-            w_down_b=P(lax_, None),
-        )
-    if cfg.is_moe:
-        layers.update(
-            router=P(lax_, None, None),
-            w_gate=P(lax_, "tp", None, None),
-            w_up=P(lax_, "tp", None, None),
-            w_down=P(lax_, "tp", None, None),
-        )
-    elif cfg.ffn == "mlp":
-        layers.update(
-            w_up=P(lax_, None, "tp"),
-            w_down=P(lax_, "tp", None),
-        )
-    else:
-        layers.update(
-            w_gate=P(lax_, None, "tp"),
-            w_up=P(lax_, None, "tp"),
-            w_down=P(lax_, "tp", None),
-        )
+
+    def group(moe: bool) -> dict:
+        if cfg.is_latent:
+            # Heads over tp on the up-projections and wo's rows; the
+            # bottlenecks and the one latent row a token are replicated.
+            layers = {
+                "wq_down": P(lax_, None, None),
+                "q_norm": P(lax_, None),
+                "wq_up": P(lax_, None, "tp"),
+                "wkv_down": P(lax_, None, None),
+                "kv_norm": P(lax_, None),
+                "wk_up": P(lax_, None, "tp"),
+                "wv_up": P(lax_, None, "tp"),
+                "wo": P(lax_, "tp", None),
+            }
+        else:
+            layers = {
+                "wq": P(lax_, None, "tp"),
+                "wk": P(lax_, None, "tp"),
+                "wv": P(lax_, None, "tp"),
+                "wo": P(lax_, "tp", None),
+            }
+        layers.update(attn_norm=P(lax_, None), mlp_norm=P(lax_, None))
+        if cfg.attn_bias:
+            layers.update(
+                wq_b=P(lax_, "tp"),
+                wk_b=P(lax_, "tp"),
+                wv_b=P(lax_, "tp"),
+            )
+        if cfg.norm == "ln":
+            layers.update(attn_norm_b=P(lax_, None), mlp_norm_b=P(lax_, None))
+        if cfg.post_norm:
+            layers.update(
+                attn_post_norm=P(lax_, None), mlp_post_norm=P(lax_, None)
+            )
+        if cfg.proj_bias:
+            # Row-parallel outputs (wo, w_down) have replicated biases; the
+            # column-parallel up-projection bias shards with its outputs.
+            layers.update(
+                wo_b=P(lax_, None),
+                w_up_b=P(lax_, "tp"),
+                w_down_b=P(lax_, None),
+            )
+        if moe:
+            layers["router"] = P(lax_, None, None)
+            if cfg.expert_product != "grouped":
+                layers.update(
+                    w_gate=P(lax_, "tp", None, None),
+                    w_up=P(lax_, "tp", None, None),
+                    w_down=P(lax_, "tp", None, None),
+                )
+            if cfg.n_shared_experts:
+                layers.update(
+                    ws_gate=P(lax_, None, "tp"),
+                    ws_up=P(lax_, None, "tp"),
+                    ws_down=P(lax_, "tp", None),
+                )
+        elif cfg.ffn == "mlp":
+            layers.update(
+                w_up=P(lax_, None, "tp"),
+                w_down=P(lax_, "tp", None),
+            )
+        else:
+            layers.update(
+                w_gate=P(lax_, None, "tp"),
+                w_up=P(lax_, None, "tp"),
+                w_down=P(lax_, "tp", None),
+            )
+        return layers
+
     out = {
         "embed": P("tp", None),
-        "layers": layers,
+        "layers": group(cfg.is_moe),
         "final_norm": P(None),
         "lm_head": P(None, "tp"),
     }
+    if n_dense:
+        out["dense_layers"] = group(False)
+    if cfg.counts_routes:
+        out["experts"] = [
+            {name: P("tp", None, None) for name in ("w_gate", "w_up", "w_down")}
+            for _ in range(cfg.n_moe_layers)
+        ]
     if cfg.norm == "ln":
         out["final_norm_b"] = P(None)
     if cfg.pos_emb == "learned":
@@ -321,7 +543,8 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
 
 
 def kv_cache_specs(
-    quantized: bool = False, paged: bool = False, cp: bool = False
+    quantized: bool = False, paged: bool = False, cp: bool = False,
+    latent: bool = False,
 ):
     """Cache layout [entries, slots|blocks, kv_heads, len|block, hd]
     (entries = ``cfg.n_cache_entries``, replicated): kv_heads over ``tp``. Int8 mode adds per-position scales whose kv_heads axis
@@ -333,7 +556,16 @@ def kv_cache_specs(
     sequence and GSPMD partitions the dense decode/prefill attention
     (sharded softmax reductions become collectives). This is what lets
     max_len exceed one chip's cache HBM. Not combinable with paging.
+
+    ``latent``: refused. A latent cache (``LatentKVCache``) holds one row a
+    token with no head axis to shard, and is served on one chip only.
     """
+    if latent:
+        raise ValueError(
+            "a latent cache has no partition specs: its one row a token "
+            "has no kv-head axis for tp, and serving it over a mesh "
+            "(TPU_TP > 1, cp) is not implemented"
+        )
     seq = "cp" if cp else None
     kv = P(None, None, "tp", seq, None)
     if paged:
@@ -515,8 +747,9 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
     over the stacked layers, ``cfg.n_passes`` times over the one set of
     weights.
 
-    One pass — every model but a looped one — is the plain ``lax.scan``
-    over ``(layers, *cache_xs)``, the program it always was. A looped
+    One pass over one kind of layer — every model but a looped one or one
+    that leads with dense layers — is the plain ``lax.scan`` over
+    ``(layers, *cache_xs)``, the program it always was. A looped
     stack stays ONE scan, over the n_passes * n_layers cache entries that
     ``cache_xs`` lead with (entry ``t * L + l`` is pass t's layer l, so a
     pass reads and writes only its own keys and values): step i takes
@@ -527,10 +760,38 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
     model costs the prefill program no further copy of it. (The decode
     step, which only reads the cache, scans the entries' indices instead
     and closes over the planes.)
+
+    Two kinds of layer (``params["dense_layers"]``, the leading layers with
+    a dense FFN, then ``params["layers"]``): leaves of two shapes cannot
+    share one stack, so the two stacked groups run in order, one scan
+    each, over one run of cache entries: each takes its own stretch of
+    ``cache_xs``, and their ys are joined along the entry axis. ``body``
+    tells the kinds apart by the leaves it is handed (``_ffn_any``). ``x``
+    may be any pytree a body carries (the latent prefill body carries the
+    cache plane beside the stream, and its ys are small).
     """
-    layers = params["layers"]
+    groups = [params[g] for g in ("dense_layers", "layers") if g in params]
+    if "experts" in params:
+        # A grouped expert layer finds its own experts' leaves by its
+        # index among the expert layers (``moe_grouped_experts``).
+        groups[-1] = {**groups[-1], "expert_layer": jnp.arange(cfg.n_moe_layers)}
+    if cfg.n_passes == 1 and len(groups) == 1:
+        return jax.lax.scan(body, x, (groups[0], *cache_xs))
     if cfg.n_passes == 1:
-        return jax.lax.scan(body, x, (layers, *cache_xs))
+        joined, lo = [], 0
+        for layers in groups:
+            hi = lo + jax.tree.leaves(layers)[0].shape[0]
+            stretch = jax.tree.map(lambda a: a[lo:hi], tuple(cache_xs))  # noqa: B023
+            x, ys = jax.lax.scan(body, x, (layers, *stretch))
+            joined.append(ys)
+            lo = hi
+        return x, jax.tree.map(lambda *ys: jnp.concatenate(ys), *joined)
+    if len(groups) > 1:
+        raise ValueError(
+            f"a looped stack (n_passes={cfg.n_passes}) of two layer groups "
+            f"(n_dense_layers={cfg.n_dense_layers}) is not implemented"
+        )
+    layers = groups[0]
     L, n = cfg.n_layers, cfg.n_cache_entries
 
     def pass_norm(x):
@@ -556,7 +817,10 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
 
 # The serving steps below run under ``jax.named_scope`` with a fixed
 # vocabulary — embed, attn, kv_commit, ffn, moe_router, moe_experts,
-# lm_head here, pass and pass_norm around them in a looped stack; sample
+# lm_head here, pass and pass_norm around them in a looped stack, mla_q and
+# mla_kv (latent attention's projections; attn is then the scores and the
+# weighted sum, the value up-projection and wo), moe_dispatch (sorting the
+# routes by expert and back) and moe_shared in a grouped expert layer; sample
 # in serving/programs.py — so that an op in the profiler's trace says which
 # part of the model it belongs to (its ``tf_op`` reads
 # ``jit(decode_window)/…/attn/dot_general``). Compile-time metadata only: no
@@ -602,8 +866,18 @@ def _ffn_dense(x, lp, cfg, aids=None):
 def _ffn_moe(x, lp, cfg):
     """Top-k MoE FFN. x: [b, s, D]. Dense-einsum formulation: every expert
     computes, weighted by routing probs — the XLA-friendly formulation for
-    small expert counts (no ragged dispatch); capacity-based a2a dispatch is
-    the scale-out variant (see parallel/moe_dispatch)."""
+    small expert counts (no ragged dispatch), all of them held here.
+    ``mixtral-8x7b-d4`` (8 of 8 held, softmax) keeps it in PR 33: moving it
+    to the sorted, grouped product of ``_ffn_moe_grouped`` is ROADMAP S5's
+    change, with a claim of its own. A share of the experts must not come
+    here: this spends ``held x rows`` where the share needs the routes
+    that land on it."""
+    if cfg.expert_product != "einsum":
+        raise ValueError(
+            "the dense einsum computes every expert for every row with "
+            "Mixtral's router: a share of the experts, sigmoid scores, a "
+            "shared expert or a routed scale go through _ffn_moe_grouped"
+        )
     b, s, D = x.shape
     with jax.named_scope("moe_router"):
         router_logits = _wein(
@@ -624,6 +898,229 @@ def _ffn_moe(x, lp, cfg):
         hidden = _act(cfg)(gate) * up
         out = _wein("bsef,efd->bsed", hidden, lp["w_down"])
         return jnp.einsum("bsed,bse->bsd", out, weights.astype(x.dtype))
+
+
+def moe_route(xf, router, cfg):
+    """The router over ALL ``n_experts`` outputs, float32 throughout (the
+    activations and the router's weights are exact in the product; it
+    accumulates in float32): xf [T, D] -> (expert ids [T, k], gates [T, k]
+    float32). The gates are normalised over all k chosen experts, held
+    here or not, and carry the routed scale."""
+    logits = jnp.einsum(
+        "td,de->te", xf, router, preferred_element_type=jnp.float32
+    )
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router_score {cfg.router_score!r}")
+    top, idx = jax.lax.top_k(scores, cfg.n_experts_active)
+    top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    return idx, top * cfg.routed_scale
+
+
+def moe_grouped_experts(xf, idx, gates, experts, cfg, layer=0):
+    """The held experts' part of the layer by a sorted, grouped product:
+    sum over a token's routes that land on a held expert of gate x
+    SwiGLU_expert(token). xf [T, D]; idx, gates [T, k]; ``experts`` the
+    expert layers' own leaves (``init_experts``: a dict a layer, holding the
+    ``cfg.held_range`` experts) and ``layer`` which of them this is (a
+    traced index inside the layer scan). Returns ([T, D], held [T, k] bool,
+    the held experts' row counts [held] int32).
+
+    The routes (token, choice) are sorted by expert, absent ones last;
+    ``jax.lax.ragged_dot`` multiplies each expert's weights by its own run
+    of rows, so the product's work grows with the routes that land here
+    and not with ``held x rows``: on the v5e in tiles of 512 rows an expert
+    (the three products over a buffer of 16,384 rows: 0.8 / 6.2 / 6.4 /
+    11.9 ms with 0 / 1,024 / 8,192 / 16,384 of them held, PERF.md section
+    6, PR 33), and rows past the last held route are not computed (they are
+    zeroed before the way back). The sort, the gather and the way back DO
+    run over all T x k rows, a third of the layer's held part at a prefill
+    step: a buffer bounded by the routes that land is ROADMAP S5's. No
+    capacity and no dropped token: the sorted buffer holds every route, so a
+    router that sends every row to one held expert loses none."""
+    T, k = idx.shape
+    lo, hi = cfg.held_range
+    n_held, M = hi - lo, T * k
+    with jax.named_scope("moe_dispatch"):
+        held = (idx >= lo) & (idx < hi)
+        local = jnp.where(held, idx - lo, n_held).reshape(M)
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.zeros((n_held + 1,), jnp.int32).at[local].add(1)[:n_held]
+        rows = xf[order // k]  # [M, D], each expert's rows together
+
+    def product(w, rows, sizes):
+        hidden = _act(cfg)(
+            jax.lax.ragged_dot(rows, w["w_gate"], sizes)
+        ) * jax.lax.ragged_dot(rows, w["w_up"], sizes)
+        return jax.lax.ragged_dot(hidden, w["w_down"], sizes)  # [M, D]
+
+    with jax.named_scope("moe_experts"):
+        if len(experts) == 1:
+            out = product(experts[0], rows, sizes)
+        else:  # each layer's leaves are operands of their own: no slice
+            out = jax.lax.switch(
+                layer, [partial(product, w) for w in experts], rows, sizes
+            )
+    with jax.named_scope("moe_dispatch"):
+        out = jnp.where(
+            (jnp.arange(M) < jnp.sum(sizes))[:, None], out, 0
+        )
+        back = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M))
+        weights = jnp.where(held, gates, 0.0).astype(xf.dtype)
+        routed = jnp.einsum(
+            "tkd,tk->td", out[back].reshape(T, k, -1), weights
+        )
+    return routed, held, sizes
+
+
+def _ffn_moe_grouped(x, lp, cfg, valid=None, experts=None):
+    """The expert layer of a model that is told which experts it holds:
+    routes over the router's published width, computes its own experts'
+    part by a sorted, grouped product, adds the shared expert. On one chip
+    it runs without its exchange, and what the absent experts would add is
+    left out. x: [b, s, D]; lp: the layer's stacked leaves (router, shared
+    expert) with ``expert_layer``, its index into ``experts``, the expert
+    layers' own leaves (``params["experts"]``); valid: [b, s] bool, the
+    tokens whose routes count (None: all). Returns (out [b, s, D], (routes on held experts a
+    row [b] int32, the held experts' rows [held] int32))."""
+    b, s, D = x.shape
+    xf = x.reshape(b * s, D)
+    with jax.named_scope("moe_router"):
+        idx, gates = moe_route(xf, lp["router"], cfg)
+    out, held, sizes = moe_grouped_experts(
+        xf, idx, gates, experts, cfg, lp.get("expert_layer", 0)
+    )
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(
+                xf, lp["ws_gate"], lp["ws_up"], lp["ws_down"], cfg
+            )
+    if valid is None:  # every token counts: the held experts' rows as sorted
+        return out.reshape(b, s, D), (
+            jnp.sum(held, axis=1).reshape(b, s).sum(1), sizes
+        )
+    counted = held & valid.reshape(b * s, 1)
+    n_held = sizes.shape[0]
+    lo, _ = cfg.held_range
+    load = jnp.zeros((n_held + 1,), jnp.int32).at[
+        jnp.where(counted, idx - lo, n_held).reshape(-1)
+    ].add(1)[:n_held]
+    stats = (jnp.sum(counted, axis=1).reshape(b, s).sum(1), load)
+    return out.reshape(b, s, D), stats
+
+
+def _swiglu(x, w_gate, w_up, w_down, cfg):
+    return (_act(cfg)(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def _ffn_any(h, lp, cfg, aids=None, valid=None, experts=None):
+    """The layer's FFN by the leaves it holds (a stack that leads with
+    dense layers hands both kinds through one body): (out, route counts).
+    The counts are None unless ``cfg.counts_routes``; a dense layer then
+    counts no route. ``experts``: ``params.get("experts")``, a grouped expert
+    layer's own leaves."""
+    if "router" not in lp:
+        stats = None
+        if cfg.counts_routes:
+            stats = (
+                jnp.zeros((h.shape[0],), jnp.int32),
+                jnp.zeros((cfg.experts_held,), jnp.int32),
+            )
+        return _ffn_dense(h, lp, cfg, aids), stats
+    if cfg.expert_product == "grouped":
+        return _ffn_moe_grouped(h, lp, cfg, valid, experts)
+    return _ffn_moe(h, lp, cfg), None
+
+
+def route_stats(stats, rows_valid=None):
+    """What a serving step returns beside its tokens, from the layers'
+    counts ``(held routes a row [L, rows], held experts' rows [L, held])``:
+    ([rows] float32 routes that landed on held experts, summed over the
+    layers; the step's expert load ratio, the fullest held expert's rows
+    over the mean of the held, averaged over the expert layers that saw a
+    route)."""
+    held_rows, load = stats
+    load = load.astype(jnp.float32)
+    total = jnp.sum(load, axis=1)
+    ratio = jnp.max(load, axis=1) * load.shape[1] / jnp.maximum(total, 1.0)
+    seen = (total > 0).astype(jnp.float32)
+    return (
+        jnp.sum(held_rows, axis=0).astype(jnp.float32),
+        jnp.sum(ratio * seen) / jnp.maximum(jnp.sum(seen), 1.0),
+    )
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA): the projections the three bodies share
+# ---------------------------------------------------------------------------
+
+
+@jax.named_scope("mla_q")
+def _mla_queries(h, lp, cfg, cos, sin, positions, absorb):
+    """h [b, s, D] -> a head's query through the ``q_lora_rank`` bottleneck
+    and its norm, RoPE (half-split pairs, ``ops/rotary.py``) on the rotary
+    part: ``[q_nope | q_rope]`` [b, s, H, nope + rope], or with ``absorb``
+    the form that scores a cache row as it lies,
+    ``[q_nope W_uk^T | q_rope]`` [b, s, H, rank + rope]."""
+    b, s, _ = h.shape
+    H, nope = cfg.n_heads, cfg.qk_nope_head_dim
+    c_q = _norm(_wein("bsd,dr->bsr", h, lp["wq_down"]), lp["q_norm"], cfg)
+    q = _wein("bsr,rh->bsh", c_q, lp["wq_up"]).reshape(b, s, H, -1)
+    q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)
+    if absorb:
+        q_nope = jnp.einsum(
+            "bshn,rhn->bshr", q_nope, lp["wk_up"].reshape(-1, H, nope)
+        )
+    return jnp.concatenate([q_nope, q_rope], axis=-1)
+
+
+@jax.named_scope("mla_kv")
+def _mla_rows(h, lp, cfg, cos, sin, positions):
+    """h [b, s, D] -> the cache row of each token [b, s, rank + rope]: the
+    latent after its norm, then the rotary key values (one set for every
+    head) after RoPE."""
+    C = cfg.kv_lora_rank
+    kv = _wein("bsd,dr->bsr", h, lp["wkv_down"])
+    k_r = apply_rope(kv[:, :, None, C:], cos, sin, positions)[:, :, 0]
+    return jnp.concatenate(
+        [_norm(kv[..., :C], lp["kv_norm"], cfg), k_r], axis=-1
+    )
+
+
+def _mla_out(o, lp, cfg, absorbed):
+    """Per-head attention results [b, s, H, .] -> [b, s, D]: with
+    ``absorbed`` they are weighted sums of latents and go through the value
+    up-projection first."""
+    b, s, H, _ = o.shape
+    if absorbed:
+        o = jnp.einsum(
+            "bshr,rhv->bshv", o, lp["wv_up"].reshape(-1, H, cfg.v_head_dim)
+        )
+    return _wein("bsh,hd->bsd", o.reshape(b, s, -1), lp["wo"])
+
+
+def _mla_full(h, lp, cfg, cos, sin, positions):
+    """Latent attention over a whole sequence, expanded form, no cache (the
+    test-only full forward): every token's latent is expanded to per-head
+    keys and values."""
+    b, s, _ = h.shape
+    H, C = cfg.n_heads, cfg.kv_lora_rank
+    q = _mla_queries(h, lp, cfg, cos, sin, positions, absorb=False)
+    rows = _mla_rows(h, lp, cfg, cos, sin, positions)
+    with jax.named_scope("attn"):
+        k_nope = _wein("bsr,rh->bsh", rows[..., :C], lp["wk_up"])
+        k = jnp.concatenate([
+            k_nope.reshape(b, s, H, -1),
+            jnp.broadcast_to(
+                rows[:, :, None, C:], (b, s, H, cfg.qk_rope_head_dim)
+            ),
+        ], axis=-1)
+        v = _wein("bsr,rh->bsh", rows[..., :C], lp["wv_up"]).reshape(b, s, H, -1)
+        o = attention(q, k, v, causal=True, kernel=False)
+        return _mla_out(o, lp, cfg, absorbed=False)
 
 
 @jax.named_scope("lm_head")
@@ -649,7 +1146,7 @@ def _qkv(h, lp, eq, H, KV, hd, *lead, aids=None):
 
 
 def _layer_prefill(x, lp, cfg, cos, sin, positions, mask, attn_fn=None,
-                   lengths=None, norm_out=None, aids=None):
+                   lengths=None, norm_out=None, aids=None, experts=None):
     """One decoder layer over a full sequence. Returns (x, (k, v)).
 
     attn_fn: optional override for the attention call, e.g. a
@@ -669,26 +1166,37 @@ def _layer_prefill(x, lp, cfg, cos, sin, positions, mask, attn_fn=None,
     h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
     if norm_out is not None:
         h = norm_out(h)
-    q, k, v = _qkv(h, lp, "bsd,dh->bsh", H, KV, hd, b, s, aids=aids)
-    if cfg.pos_emb == "rope":
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-    if attn_fn is None:
-        attn = attention(
-            q, k, v, causal=True, mask=mask, lengths=lengths,
-            window=cfg.sliding_window,
-        )
-    else:
-        if cfg.sliding_window:
+    if cfg.is_latent:
+        if attn_fn is not None or mask is not None or lengths is not None:
             raise ValueError(
-                "sliding_window is not supported with ring/Ulysses "
-                "context-parallel attention"
+                "latent attention runs the plain causal full forward only: "
+                "no context-parallel attn_fn, mask or padded lengths"
             )
-        attn = attn_fn(q, k, v, mask)
-    ao = attn.reshape(b, s, H * hd)
-    attn_out = _wein("bsh,hd->bsd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
-    if "wo_b" in lp:
-        attn_out = attn_out + lp["wo_b"]
+        k = v = None  # the serving cache is the chunk and decode steps'
+        attn_out = _mla_full(h, lp, cfg, cos, sin, positions)
+    else:
+        q, k, v = _qkv(h, lp, "bsd,dh->bsh", H, KV, hd, b, s, aids=aids)
+        if cfg.pos_emb == "rope":
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        if attn_fn is None:
+            attn = attention(
+                q, k, v, causal=True, mask=mask, lengths=lengths,
+                window=cfg.sliding_window,
+            )
+        else:
+            if cfg.sliding_window:
+                raise ValueError(
+                    "sliding_window is not supported with ring/Ulysses "
+                    "context-parallel attention"
+                )
+            attn = attn_fn(q, k, v, mask)
+        ao = attn.reshape(b, s, H * hd)
+        attn_out = (
+            _wein("bsh,hd->bsd", ao, lp["wo"]) + _lora(ao, lp, "wo", aids)
+        )
+        if "wo_b" in lp:
+            attn_out = attn_out + lp["wo_b"]
     attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
 
     # Parallel residual (GPT-NeoX): both branches read the SAME input;
@@ -697,7 +1205,7 @@ def _layer_prefill(x, lp, cfg, cos, sin, positions, mask, attn_fn=None,
     h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
     if norm_out is not None:
         h = norm_out(h)
-    ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(h, lp, cfg, aids)
+    ffn, _ = _ffn_any(h, lp, cfg, aids, experts=experts)
     ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
     if cfg.parallel_residual:
         return x + attn_out + ffn, (k, v)
@@ -725,7 +1233,8 @@ def transformer_forward(
 
     def body(x, scanned):
         out, _ = _layer_prefill(
-            x, scanned[0], cfg, cos, sin, positions, mask=None, aids=aids
+            x, scanned[0], cfg, cos, sin, positions, mask=None, aids=aids,
+            experts=params.get("experts"),
         )
         return out, None
 
@@ -750,6 +1259,11 @@ def transformer_prefill(
 
     tokens: [b, s_pad]; lengths: [b] true lengths; slots: [b] cache slots.
     """
+    if cfg.is_latent:
+        raise ValueError(
+            "latent attention fills its cache by transformer_prefill_chunk "
+            "only: the unchunked prefill writes K and V planes"
+        )
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = _embed(params, tokens, cfg, positions)
@@ -762,7 +1276,7 @@ def transformer_prefill(
     def body(x, scanned):
         out, kv = _layer_prefill(
             x, scanned[0], cfg, cos, sin, positions, mask=None,
-            lengths=lengths, aids=aids,
+            lengths=lengths, aids=aids, experts=params.get("experts"),
         )
         return out, kv
 
@@ -807,7 +1321,9 @@ def transformer_prefill_chunk(
     cfg: TransformerConfig,
     dense_attn: bool = False,
     aids: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, KVCache]:
+    row_valid: Optional[jnp.ndarray] = None,
+    stats: bool = False,
+) -> tuple:
     """Chunked serving prefill: one [P, c] chunk step.
 
     The engine splits prompts into chunks and interleaves chunk steps with
@@ -826,6 +1342,10 @@ def transformer_prefill_chunk(
     Returns ([P, vocab] logits at each row's LAST VALID token, cache).
     ``cache.lengths`` is NOT updated here — the engine sets it when a
     prompt's final chunk lands.
+    stats: also return, third, the step's route counts (``route_stats``:
+    per row the routes that landed on held experts, and the expert load
+    ratio; None unless ``cfg.counts_routes``), over the valid tokens of the
+    rows that ``row_valid`` ([P] bool; None: all) does not mark as padding.
     """
     P, c = tokens.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -833,6 +1353,17 @@ def transformer_prefill_chunk(
     x = _embed(params, tokens, cfg, positions)  # [P, c, D]
     cos, sin = rope_frequencies(cfg.rope_dims, cache.max_len, cfg.rope_theta)
     paged = isinstance(cache, PagedKVCache)
+    counted = None  # the tokens whose routes count: a counting model's only
+    if cfg.counts_routes:
+        counted = jnp.arange(c)[None, :] < lens[:, None]  # [P, c]
+        if row_valid is not None:
+            counted &= row_valid[:, None]
+    if cfg.is_latent:
+        x, cache, counts = _latent_chunk_layers(
+            params, x, cache, slots, starts, lens, positions, cos, sin,
+            counted, cfg, aids,
+        )
+        return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
 
     idx_kv = jnp.arange(KV)[None, :, None]
     s_kv = jnp.arange(KV)[None, :, None, None]
@@ -905,22 +1436,94 @@ def transformer_prefill_chunk(
             attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
-        ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
-            h, lp, cfg, aids
+        ffn, counts = _ffn_any(
+            h, lp, cfg, aids, counted, params.get("experts")
         )
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
-        return x, (ck, cv, cks, cvs)
+        return x, (ck, cv, cks, cvs, counts)
 
-    x, (new_k, new_v, new_ks, new_vs) = _scan_stack(
+    x, (new_k, new_v, new_ks, new_vs, counts) = _scan_stack(
         body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
     )
     cache = cache._replace(k=new_k, v=new_v, k_s=new_ks, v_s=new_vs)
+    return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
 
+
+def _chunk_logits(params, x, lens, cache, counts, cfg, stats):
+    """The chunk step's way out: final norm, the head at each row's last
+    valid token, and the route counts where they were asked for."""
     x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
     last_idx = jnp.maximum(lens - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    return _lm_head("pd,dv->pv", x_last, params), cache
+    logits = _lm_head("pd,dv->pv", x_last, params)
+    if not stats:
+        return logits, cache
+    return logits, cache, None if counts is None else route_stats(counts)
+
+
+def _lane_pad(rows, plane):
+    """Cache rows as the plane stores them: its dtype, zeros up to its
+    lane-tile width (``LatentKVCache``)."""
+    return pad_last(rows.astype(plane.dtype), plane.shape[-1])
+
+
+def _latent_cache(cache, cfg):
+    if not isinstance(cache, LatentKVCache):
+        raise ValueError(
+            f"latent attention (kv_lora_rank={cfg.kv_lora_rank}) is served "
+            f"over a LatentKVCache, one row a token; got "
+            f"{type(cache).__name__}"
+        )
+    return cache
+
+
+def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
+                         cos, sin, counted, cfg, aids):
+    """The chunk step's layer stack over a latent cache. The stacked plane
+    rides the scan's CARRY and each layer writes its chunk's rows into its
+    own entry in place: the plane as xs and ys, as the K and V planes ride,
+    would be a second whole copy of the cache while the step runs, and
+    joining two layer groups' ys a third.
+
+    Prefill runs the EXPANDED form (each block's latents expanded to
+    per-head keys and values); the absorbed form, decode's, lost here at
+    every length measured on the v5e (PERF.md section 6, PR 33: 30% more
+    FLOPs a position and a running sum four times as wide)."""
+    cache = _latent_cache(cache, cfg)
+    H, C = cfg.n_heads, cfg.kv_lora_rank
+    scale = cfg.head_dim**-0.5
+
+    def body(carry, scanned):
+        x, plane = carry
+        lp, entry = scanned
+        h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
+        q = _mla_queries(h, lp, cfg, cos, sin, positions, absorb=False)
+        rows = _mla_rows(h, lp, cfg, cos, sin, positions)
+        # Write the chunk's rows, then attend the cache in place.
+        with jax.named_scope("kv_commit"):
+            plane = plane.at[entry, slots[:, None], 0, positions].set(
+                _lane_pad(rows, plane)
+            )
+        with jax.named_scope("attn"):
+            o = latent_chunk_attention(
+                q, plane, slots, starts, lens,
+                lp["wk_up"].reshape(C, H, -1), lp["wv_up"].reshape(C, H, -1),
+                scale=scale, layer=entry,
+            )
+            attn_out = _mla_out(o, lp, cfg, absorbed=False)
+            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)
+        x = x + attn_out
+        h = _norm(x, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
+        ffn, counts = _ffn_any(
+            h, lp, cfg, aids, counted, params.get("experts")
+        )
+        return (x + _post_norm(ffn, lp, "mlp_post_norm", cfg), plane), counts
+
+    (x, plane), counts = _scan_stack(
+        body, (x, cache.k), params, cfg, (jnp.arange(cfg.n_cache_entries),)
+    )
+    return x, cache._replace(k=plane), counts
 
 
 def transformer_decode_step(
@@ -932,7 +1535,8 @@ def transformer_decode_step(
     dense_attn: bool = False,
     aids: Optional[jnp.ndarray] = None,
     bound_read: bool = True,
-) -> tuple[jnp.ndarray, KVCache]:
+    stats: bool = False,
+) -> tuple:
     """One decode step over ALL cache slots (static batch = n_slots).
 
     tokens: [n_slots] current token per slot (anything for inactive slots);
@@ -950,6 +1554,9 @@ def transformer_decode_step(
     axis is sharded (context parallel), where a prefix lives on the first
     chips only.
     Returns ([n_slots, vocab] logits, updated cache).
+    stats: also return, third, [n_slots] float32: each slot's routes that
+    landed on held experts in this step, over the layers (None unless
+    ``cfg.counts_routes``; an inactive slot's count is the caller's to drop).
     """
     S = cache.n_slots
     L = cfg.n_cache_entries  # a looped stack commits every pass's entry
@@ -979,12 +1586,50 @@ def transformer_decode_step(
     # scan over the planes as xs lowers to anyway. Handing a branch the
     # entry already sliced would copy it out whole first, every layer.
     paged = isinstance(cache, PagedKVCache)
+    # The slots whose routes count (a counting model's only).
+    counted = active[:, None] if cfg.counts_routes else None
     read = None
     if bound_read and not paged:
         read = decode_read_index(
             decode_read_rungs(cache.max_len),
             jnp.max(jnp.where(active, cache.lengths, 0)),
         )
+
+    def latent_body(x, scanned):
+        """The absorbed form: the queries are projected into the latent
+        space and the cache is read as it lies, never expanded."""
+        lp, entry = scanned
+        pos2 = positions[:, None]  # [S, 1]
+        h = _norm(x[:, None, :], lp["attn_norm"], cfg, lp.get("attn_norm_b"))
+        q = _mla_queries(h, lp, cfg, cos, sin, pos2, absorb=True)[:, 0]
+        row = _mla_rows(h, lp, cfg, cos, sin, pos2)[:, 0]  # [S, row]
+        with jax.named_scope("attn"):
+            o = latent_decode_attention(
+                q, cache.k, positions, row, rank=cfg.kv_lora_rank,
+                scale=cfg.head_dim**-0.5, layer=entry, read=read,
+            )
+            attn_out = _mla_out(o[:, None], lp, cfg, absorbed=True)
+            attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)[:, 0]
+        x = x + attn_out
+        h = _norm(x[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
+        ffn, counts = _ffn_any(h, lp, cfg, aids, counted, params.get("experts"))
+        ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
+        return x + ffn[:, 0], (row, counts)
+
+    if cfg.is_latent:
+        _latent_cache(cache, cfg)
+        x, (rows, counts) = _scan_stack(
+            latent_body, x, params, cfg, (jnp.arange(L),)
+        )
+        with jax.named_scope("kv_commit"):
+            cache = cache._replace(
+                k=cache.k.at[
+                    jnp.arange(L)[:, None], slot_idx[None, :], 0,
+                    write_pos[None, :],
+                ].set(_lane_pad(rows, cache.k)),
+                lengths=cache.lengths + active.astype(jnp.int32),
+            )
+        return _decode_logits(params, x, cache, counts, cfg, stats)
 
     def body(x, scanned):
         lp, entry = scanned
@@ -1021,17 +1666,17 @@ def transformer_decode_step(
         h = _norm(
             mlp_in[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b")
         )
-        ffn = _ffn_moe(h, lp, cfg) if cfg.is_moe else _ffn_dense(
-            h, lp, cfg, aids
-        )
+        ffn, counts = _ffn_any(h, lp, cfg, aids, counted, params.get("experts"))
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         if cfg.parallel_residual:
             x = x + attn_out + ffn[:, 0]
         else:
             x = mlp_in + ffn[:, 0]
-        return x, (k, v)
+        return x, (k, v, counts)
 
-    x, (new_k, new_v) = _scan_stack(body, x, params, cfg, (jnp.arange(L),))
+    x, (new_k, new_v, counts) = _scan_stack(
+        body, x, params, cfg, (jnp.arange(L),)
+    )
     # Commit every layer's token in one scatter: [L, S, KV, hd] values at
     # [l, s, kv, write_pos[s]] (slot cache) or [l, table[s, p//B], kv,
     # p%B] (paged pool; inactive slots park in block 0) — donation makes
@@ -1068,8 +1713,17 @@ def transformer_decode_step(
             v=cache.v.at[li, row, ki, wp].set(new_v.astype(cache.v.dtype)),
             lengths=cache.lengths + active.astype(jnp.int32),
         )
+    return _decode_logits(params, x, cache, counts, cfg, stats)
+
+
+def _decode_logits(params, x, cache, counts, cfg, stats):
+    """The decode step's way out: final norm, the head, and each slot's
+    held routes where they were asked for."""
     x = _norm(x[:, None, :], params["final_norm"], cfg, params.get("final_norm_b"))[:, 0]
-    return _lm_head("bd,dv->bv", x, params), cache
+    logits = _lm_head("bd,dv->bv", x, params)
+    if not stats:
+        return logits, cache
+    return logits, cache, None if counts is None else route_stats(counts)[0]
 
 
 def count_params(params: dict) -> int:

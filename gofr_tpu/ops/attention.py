@@ -192,7 +192,7 @@ def attention(
 
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
-    return out.reshape(b, s_q, n_heads, hd)
+    return out.reshape(b, s_q, n_heads, -1)  # v's width, where it is not q's
 
 
 def _decode_takes_kernel(
@@ -230,12 +230,16 @@ def _decode_takes_kernel(
 
 def decode_read_plan(
     max_len: int, *, paged: bool = False, window: int = 0,
-    kernel: bool | None = None,
+    kernel: bool | None = None, latent: bool = False,
 ) -> tuple[int, ...]:
     """The prefixes ``decode_attention``'s ``read`` selects among for such
     a cache: ``decode_read_rungs`` where the dense path over a contiguous
     cache runs, the one whole read where the kernel or a paged pool does
-    (the host's counter and the device's program ask the same function)."""
+    (the host's counter and the device's program ask the same function).
+    A latent cache is always read on the dense bounded path
+    (``latent_decode_attention``), whatever ``max_len``."""
+    if latent:
+        return decode_read_rungs(max_len)
     window = _effective_window(window, max_len, None)
     if paged or _decode_takes_kernel(kernel, max_len, paged, window):
         return (max_len,)
@@ -431,6 +435,173 @@ def decode_attention(
             read, [functools.partial(over, n) for n in rungs]
         )
     return out.reshape(b, n_heads, -1)
+
+
+def pad_last(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` with zeros appended along its last axis up to ``width``."""
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def latent_decode_attention(
+    q: jnp.ndarray,
+    plane: jnp.ndarray,
+    lengths: jnp.ndarray,
+    row_new: jnp.ndarray,
+    *,
+    rank: int,
+    scale: float,
+    layer: jnp.ndarray | None = None,
+    read: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """Absorbed-form decode attention over a latent cache, read as it lies.
+
+    Every head attends the SAME row of a token: ``[latent | rotary key]``,
+    ``rank`` + rope values, with the values the first ``rank`` of it. That
+    is multi-query attention with one kv head whose value is a prefix of
+    its key, so this is the dense split-softmax of ``_dense_decode`` with
+    one group of ``n_heads`` queries, bounded by PR 31's rungs at every
+    ``max_len`` (neither Pallas decode kernel takes a key wider than its
+    value, so no auto rule sends this geometry to one).
+
+    q: [b, n_heads, rank + rope], the queries projected into the latent
+    space beside their rotary part; plane: the stacked
+    ``[entries, b, 1, max_len, width]`` cache (width >= rank + rope, zeros
+    beyond the content) with ``layer`` the entry attended, or one entry
+    ``[b, 1, max_len, width]``; lengths: [b]
+    cached positions; row_new: [b, rank + rope], the current token's row,
+    attended split from the cache as in ``decode_attention``; read: index
+    into ``decode_read_rungs(max_len)`` (None: the whole read). Returns
+    [b, n_heads, rank]: per head the weighted sum of latents, for the
+    caller's value up-projection.
+    """
+    max_len = plane.shape[-2]
+    rungs = decode_read_rungs(max_len) if read is not None else (max_len,)
+    # The plane is allocated in whole lane tiles: zeros beyond a row's
+    # content, so zeros on the query there leave every score as it was.
+    qg = pad_last(q, plane.shape[-1])[:, None]  # [b, 1, heads, width]
+    k_new = pad_last(row_new, plane.shape[-1])[:, None]  # [b, 1, width]
+
+    def over(n: int):
+        rows = _entry_prefix(plane, layer, n, 2)  # [b, 1, n, row]
+        return _dense_decode(
+            qg, rows, rows[..., :rank], lengths, k_new, k_new[..., :rank],
+            None, None, scale, 0,
+        )
+
+    if len(rungs) < 2:
+        out = over(max_len)
+    else:
+        out = jax.lax.switch(
+            read, [functools.partial(over, n) for n in rungs]
+        )
+    return out[:, 0]
+
+
+# Positions a step of ``latent_chunk_attention``'s loop scores at once. The
+# float32 scores of a block are [rows, heads, chunk, block]: 8 x 128 x 256 x
+# 512 x 4 B = 537 MB, where the whole 8,192 positions would be 8.6 GB.
+LATENT_CHUNK_BLOCK = 512
+
+
+def latent_chunk_attention(
+    q: jnp.ndarray,
+    plane: jnp.ndarray,
+    slots: jnp.ndarray,
+    starts: jnp.ndarray,
+    lens: jnp.ndarray,
+    w_uk: jnp.ndarray,
+    w_uv: jnp.ndarray,
+    *,
+    scale: float,
+    layer: jnp.ndarray | None = None,
+    block: int = LATENT_CHUNK_BLOCK,
+) -> jnp.ndarray:
+    """Chunked-prefill attention over a latent cache, expanded form, in
+    blocks of positions with a running softmax, so that no array of
+    rows x heads x chunk x max_len is ever alive; the loop ends at the
+    block that holds the longest row's last position, whatever ``max_len``.
+    The chunk's rows must already be written into the cache.
+
+    q: [P, c, n_heads, nope + rope], ``[q_nope | q_rope]``; plane: the
+    stacked ``[entries, S, 1, max_len, width]`` cache (width >= rank + rope,
+    zeros beyond the content) with ``layer`` the entry, or one entry;
+    slots/starts/lens: [P] as in ``cache_chunk_attention``; w_uk
+    [rank, heads, nope], w_uv [rank, heads, vd]: each block's latents are
+    expanded to per-head keys and values before they are scored. Returns
+    [P, c, n_heads, vd]. ``block`` >= ``max_len`` is the unblocked
+    mathematics (one step). Rows with t >= lens[p] return 0.
+    """
+    P, c, n_heads, _ = q.shape
+    max_len = plane.shape[-2]
+    block = min(block, max_len)
+    if max_len % block:
+        raise ValueError(f"max_len {max_len} is not a multiple of {block}")
+    t = jnp.arange(c)
+    pos = starts[:, None] + t[None, :]  # [P, c] global query positions
+    live = t[None, :] < lens[:, None]  # [P, c]
+    rank, nope = w_uk.shape[0], w_uk.shape[-1]
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    rope = q_rope.shape[-1]
+
+    def rows_at(i):
+        """[P, block, row]: block i of the attended entry, each row's slot."""
+        if layer is None:
+            blk = jax.lax.dynamic_slice_in_dim(plane, i * block, block, 2)
+        else:
+            sizes = list(plane.shape)
+            sizes[0], sizes[3] = 1, block
+            blk = jax.lax.dynamic_slice(
+                plane, [layer, 0, 0, i * block, 0], sizes
+            )[0]
+        return blk[slots, 0]
+
+    def step(i, carry):
+        m, l, acc = carry  # [P, H, c], [P, H, c], [P, H, c, vd]
+        rows = rows_at(i)
+        latent = rows[..., :rank]
+        k_nope = jnp.einsum("pkr,rhn->pkhn", latent, w_uk)
+        s = jnp.einsum(
+            "pchn,pkhn->phck", q_nope, k_nope,
+            preferred_element_type=jnp.float32,
+        ) + jnp.einsum(
+            "pchr,pkr->phck", q_rope, rows[..., rank:rank + rope],
+            preferred_element_type=jnp.float32,
+        )
+        values = jnp.einsum("pkr,rhv->pkhv", latent, w_uv)
+        k_pos = i * block + jnp.arange(block)
+        valid = (k_pos[None, None, :] <= pos[:, :, None]) & live[:, :, None]
+        valid = valid[:, None]  # [P, 1, c, block]
+        s = jnp.where(valid, s * scale, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # A row with nothing valid yet keeps m at NEG_INF: exp(s - m) would
+        # be 1 there, so the mask is applied to the weights too.
+        e = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(e, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "phck,pkhv->phcv", e.astype(q.dtype), values,
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    init = (
+        jnp.full((P, n_heads, c), NEG_INF, jnp.float32),
+        jnp.zeros((P, n_heads, c), jnp.float32),
+        jnp.zeros((P, n_heads, c, w_uv.shape[-1]), jnp.float32),
+    )
+    if block == max_len:
+        _, l, acc = step(0, init)
+    else:
+        # Blocks up to the one that holds the longest row's last position.
+        last = jnp.max(jnp.where(lens > 0, starts + lens, 0))
+        _, l, acc = jax.lax.fori_loop(
+            0, (last + block - 1) // block, step, init
+        )
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    out = jnp.where(live[:, None, :, None], out, 0.0)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [P, c, H, vd]
 
 
 def cache_chunk_attention(

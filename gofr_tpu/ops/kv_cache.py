@@ -98,6 +98,64 @@ class KVCache(NamedTuple):
         return int(total)
 
 
+class LatentKVCache(NamedTuple):
+    """The contiguous cache of a latent-attention model: ONE row a token a
+    layer, ``[n_entries, n_slots, 1, max_len, width]``. A row's content is
+    ``cfg.cache_row`` values (the normed latent, then the rotary key
+    values) and there is no V plane: the values are the first
+    ``kv_lora_rank`` of the same row, read by the absorbed attention as
+    they lie. ``width`` is the content rounded up to the 128-lane tile
+    (576 -> 640, zeros beyond the content): the chip stores a minor
+    dimension in whole tiles whatever its logical size, and with a
+    576-wide plane XLA's layout assignment turned the whole cache round
+    (positions minor, to save the padding) before every decode step's
+    layer loop and back for the commit's scatter, two copies of 1.5 GB a
+    step (compiled for the v5e, PERF.md section 6, PR 33); at a lane
+    multiple it reads the plane where it lies and the commit is in place.
+    The axes keep ``KVCache``'s places (a kv-head axis of 1), so the slot
+    discipline, ``lengths`` and every reader of ``k.shape[1]`` /
+    ``k.shape[3]`` hold; a class of its own because ``KVCache`` is K and V
+    planes by construction (its ``hbm_bytes`` counts two, and every
+    consumer that writes ``_replace(k=..., v=...)``, the prefix pool, the
+    KV export payloads and int8 scales among them, must fail here and not
+    quietly fill a plane that nothing reads: the engine refuses those at
+    boot)."""
+
+    k: jnp.ndarray  # [entries, slots, 1, max_len, width]
+    lengths: jnp.ndarray  # [slots] int32
+
+    @staticmethod
+    def width_for(row: int) -> int:
+        """The plane's last axis for ``row`` values of content."""
+        return -(-row // 128) * 128
+
+    @classmethod
+    def create(
+        cls, n_entries: int, n_slots: int, max_len: int, row: int,
+        dtype: Any = jnp.bfloat16,
+    ) -> "LatentKVCache":
+        shape = (n_entries, n_slots, 1, max_len, cls.width_for(row))
+        return cls(
+            k=jnp.zeros(shape, dtype=dtype),
+            lengths=jnp.zeros((n_slots,), dtype=jnp.int32),
+        )
+
+    # What the engine asks of any contiguous cache.
+    v = k_s = v_s = None
+    quantized = False
+
+    @property
+    def n_slots(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def max_len(self) -> int:
+        return self.k.shape[3]
+
+    def hbm_bytes(self) -> int:
+        return int(self.k.size * self.k.dtype.itemsize)
+
+
 class PagedKVCache(NamedTuple):
     """Block-pool KV cache (the vLLM idea, TPU-shaped).
 

@@ -23,6 +23,7 @@ Design:
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import math
 import os
@@ -57,6 +58,7 @@ from gofr_tpu.serving.types import (
     _PrefillState,
     GenerationResult,
     LOGIT_BIAS_K,
+    next_token,
 )
 from gofr_tpu.serving.watchdog import Watchdog
 
@@ -191,6 +193,19 @@ class InferenceEngine(
                 "stack at different passes, and the decode window and the "
                 "scheduler run every slot to the same depth (only 1.0, "
                 "every pass, is implemented)"
+            )
+        if quant and getattr(self.cfg, "counts_routes", False):
+            raise ValueError(
+                f"{model_name}: TPU_QUANT={quant} is not served: the grouped "
+                "expert product (_ffn_moe_grouped) takes bf16 "
+                "weights (jax.lax.ragged_dot has no int8 / int4 operand)"
+            )
+        if getattr(self.cfg, "is_latent", False):
+            self._refuse_for_latent_cache(
+                mesh=mesh, tp=tp, kv_quant=kv_quant,
+                kv_block=kv_block, auto_prefix=auto_prefix,
+                prefix_slots=prefix_slots, lora_slots=lora_slots,
+                lora_targets=lora_targets,
             )
         self.tokenizer = tokenizer
         # GSPMD-sharded serving (TPU_TP): a caller may hand a pre-built
@@ -416,6 +431,13 @@ class InferenceEngine(
             model_name,
             metrics=metrics,
             passes=n_passes,
+            model_attrs={
+                **({"experts_held": self.cfg.experts_held,
+                    "router_width": self.cfg.n_experts}
+                   if getattr(self.cfg, "counts_routes", False) else {}),
+                **({"cache_row": self.cfg.cache_row}
+                   if getattr(self.cfg, "is_latent", False) else {}),
+            },
             recorder=(
                 FlightRecorder(
                     capacity=max(1, flight_records),
@@ -1261,6 +1283,48 @@ class InferenceEngine(
             ).start()
         return engine
 
+    def _refuse_for_latent_cache(
+        self, *, mesh: Any, tp: int, kv_quant: str,
+        kv_block: int, auto_prefix: bool, prefix_slots: int,
+        lora_slots: int, lora_targets: str,
+    ) -> None:
+        """What cannot run over a latent cache yet (one row a token a
+        layer, ``ops.kv_cache.LatentKVCache``), refused before anything is
+        initialised, each by the setting that asked for it. Only a
+        latent-attention model's constructor comes here."""
+        name, cfg = self.model_name, self.cfg
+        why = (
+            f"{name}: latent attention keeps one {cfg.cache_row}-value row "
+            "a token a layer in a contiguous cache of its own; "
+        )
+        refused = [
+            (int(tp or 0) > 1 or mesh is not None,
+             "TPU_TP > 1 (or a mesh) is not served: the row has no kv-head "
+             "axis to shard and the grouped expert product has no expert "
+             "axis yet"),
+            (int(kv_block or 0) > 0,
+             "TPU_KV_BLOCK > 0 (the paged pool) is not served: the pool, "
+             "its Pallas kernels and the KV export / import payloads "
+             "(tier transfers, KVB1) move K and V planes"),
+            (bool(auto_prefix),
+             "TPU_AUTO_PREFIX (the radix prefix cache) is not served: it "
+             "aliases blocks of the paged pool"),
+            (int(prefix_slots or 0) > 0,
+             "TPU_PREFIX_SLOTS > 0 (the prefix pool) is not served: it "
+             "copies K and V rows"),
+            (bool(kv_quant),
+             f"TPU_KV_QUANT={kv_quant} is not served: int8 latent rows "
+             "have no scales plane"),
+            (int(lora_slots or 0) > 0,
+             f"TPU_LORA_SLOTS > 0 (targets {lora_targets!r}) is not "
+             "served: there is no wq / wk / wv to adapt, and no LoRA on "
+             "the latent projections (wq_down, wq_up, wkv_down, wk_up, "
+             "wv_up) or beside routed experts"),
+        ]
+        for asked, message in refused:
+            if asked:
+                raise ValueError(why + message)
+
     def _placement(self) -> Any:
         """Context in which NEW arrays land on a pinned engine's own
         device instead of staging on the process default (a 7B tree
@@ -1331,7 +1395,7 @@ class InferenceEngine(
         return {
             name: (
                 {k: make(k, v) for k, v in shapes[name].items()}
-                if name == "layers" else make(name, shapes[name])
+                if isinstance(shapes[name], dict) else make(name, shapes[name])
             )
             for name in order
         }
@@ -1363,6 +1427,13 @@ class InferenceEngine(
                 self.cfg.n_kv_heads, self.cfg.head_dim, self.cfg.dtype,
                 quant=self.kv_quant, block=self.kv_block,
                 n_blocks=self.kv_pool_blocks,
+            )
+        elif getattr(self.cfg, "is_latent", False):
+            from gofr_tpu.ops.kv_cache import LatentKVCache
+
+            make_cache = lambda: LatentKVCache.create(  # noqa: E731
+                self.cfg.n_cache_entries, n_slots, self.max_len,
+                self.cfg.cache_row, self.cfg.dtype,
             )
         else:
             make_cache = lambda: KVCache.create(  # noqa: E731
@@ -1444,6 +1515,9 @@ class InferenceEngine(
         self._prefilling: dict[int, _PrefillState] = {}
         # (first_dev, first_lp_dev, row, slot, seq) awaiting async fetch.
         self._prefill_emits: list = []
+        # (route counts on device, rows, prompt tokens) of prefill steps
+        # whose async copy has not landed (a grouped expert layer only).
+        self._moe_counts: Any = collections.deque()
         # Paged mode: requests held back waiting for free pool blocks.
         from collections import deque as _deque
 
@@ -1851,6 +1925,13 @@ class InferenceEngine(
         tier, retries exhausted AND no sibling adopted it, transfer cap
         hit) means the scheduler decodes locally — the fused fallback,
         so a collapsed decode tier degrades service, never drops it."""
+        if exporter is not None and getattr(self.cfg, "is_latent", False):
+            raise ValueError(
+                f"{self.model_name}: a prefill-tier role "
+                "(TPU_REPLICA_ROLES) is not served over a latent cache: "
+                "KV export / import payloads move K and V blocks of the "
+                "paged pool, and a latent row has neither"
+            )
         self._tier_exporter = exporter
 
     def handoff_prefilled(self, req: _GenRequest, payload: Any) -> Optional[str]:
@@ -2753,9 +2834,8 @@ class InferenceEngine(
     ) -> "AsyncIterator[int]":
         """Async iterator over generated token ids."""
         req = self.submit_generate(prompt, **kw)
-        loop = asyncio.get_running_loop()
         while True:
-            tok = await loop.run_in_executor(None, req.stream.get)
+            tok = await next_token(req.stream)
             if tok is None:
                 return
             yield tok
@@ -3114,8 +3194,8 @@ class InferenceEngine(
     def kv_bytes_per_token(self) -> int:
         """Bytes of KV cache one token position holds, from the arrays as
         allocated: every cache entry's keys and values, and the scales of
-        an int8 cache."""
-        # k: [entries, slots | blocks, kv_heads, max_len | block, head_dim]
+        an int8 cache; for a latent cache every entry's one row."""
+        # k: [entries, slots | blocks, kv_heads | 1, max_len | block, width]
         k = self.cache.k
         return self.cache.hbm_bytes() // (k.shape[1] * k.shape[3])
 

@@ -476,11 +476,16 @@ class RequestObservability:
         clock: Callable[[], float] = time.monotonic,
         wall_ns: Callable[[], int] = time.time_ns,
         passes: int = 1,
+        model_attrs: Optional[dict] = None,
     ) -> None:
         self.model_name = model_name
         # Times the layer stack runs a forward (a looped model's n_passes):
         # an attribute of the spans whose device time scales with it.
         self.passes = passes
+        # Further attributes of the same two spans, from the model's config
+        # (an expert share's experts_held and router_width, a latent
+        # cache's cache_row); none for a model without them.
+        self.model_attrs = dict(model_attrs or {})
         self._metrics = metrics
         self.recorder = recorder
         self._clock = clock
@@ -632,6 +637,7 @@ class RequestObservability:
             child(
                 "tpu.prefill.chunk", start, end,
                 index=i, tokens=tokens, rows=rows, passes=self.passes,
+                **self.model_attrs,
             )
         if tl.prefill_done is not None and tl.first_token is not None:
             child("tpu.emit_flush", tl.prefill_done, tl.first_token)
@@ -650,6 +656,7 @@ class RequestObservability:
             child(
                 "tpu.decode", tl.first_token, done,
                 tokens=tl.output_tokens, passes=self.passes,
+                **self.model_attrs,
             )
         for name, t, attrs in tl.annotations:
             child(name, t, t, **attrs)

@@ -28,6 +28,7 @@ from typing import Any, AsyncIterator, Callable, Optional, Union
 
 from gofr_tpu.errors import GofrError
 from gofr_tpu.http.response import Raw, Stream
+from gofr_tpu.serving.types import next_token
 
 
 class OpenAIRequestError(GofrError):
@@ -249,7 +250,6 @@ def add_openai_routes(
 
         async def events() -> AsyncIterator[str]:
             created = int(time.time())
-            loop = asyncio.get_running_loop()
             emitted_ids: list[int] = []
             sent_tokens = 0  # ids already attached to a yielded chunk
             printed = ""
@@ -284,7 +284,7 @@ def add_openai_routes(
                 hold = max((len(s) for s in stops), default=0)
                 stopped = False
                 while not stopped:
-                    tok = await loop.run_in_executor(None, req.stream.get)
+                    tok = await next_token(req.stream)
                     if tok is None:
                         break
                     emitted_ids.append(tok)

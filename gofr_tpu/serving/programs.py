@@ -98,7 +98,11 @@ class LLMProgramsMixin:
         self.decode_read_rungs = decode_read_plan(
             self.max_len, paged=bool(self.kv_block),
             window=cfg.sliding_window, kernel=False if dense_attn else None,
+            latent=cfg.is_latent,
         ) if bound_read else (self.max_len,)
+        # A grouped expert layer counts its routes; the steps return the
+        # counts beside their tokens (no program of another model changes).
+        count_routes = cfg.counts_routes
 
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -236,10 +240,20 @@ class LLMProgramsMixin:
             finalize RESETS the slot's row (new request) and counts the
             first sampled token; the first token itself is never penalized
             (its counts are the zeros just written)."""
-            logits, cache = transformer_prefill_chunk(
+            # A counting model's step also returns its route counts; they
+            # ride back as [rows + 1] float32 beside the first tokens: each
+            # row's routes that landed on held experts (over its valid
+            # tokens and the layers), then the step's expert load ratio.
+            logits, cache, *counts = transformer_prefill_chunk(
                 params, tokens, cache, slots, starts, lens, cfg,
                 dense_attn=dense_attn, aids=aids[slots],
+                row_valid=row_valid if count_routes else None,
+                stats=count_routes,
             )
+            moe = None
+            if count_routes:
+                ((held, load_ratio),) = counts
+                moe = rep(jnp.concatenate([held, load_ratio[None]]))
             # Sample at the slot's counter OFFSET (noff): 0 for fresh
             # admissions, the delivered-token count for replayed requests
             # — so a non-greedy stream carried across a restart continues
@@ -275,9 +289,9 @@ class LLMProgramsMixin:
                 topl = jnp.where(has[:, None], ftopl[idx], topl)
                 return (cache, all_tokens, all_logps, rep(first),
                         rep(first_lp), pcounts, nsteps, topi, topl,
-                        rep(ftopi), rep(ftopl))
+                        rep(ftopi), rep(ftopl), moe)
             return (cache, all_tokens, all_logps, rep(first), rep(first_lp),
-                    pcounts, nsteps, topi, topl, None, None)
+                    pcounts, nsteps, topi, topl, None, None, moe)
 
         @partial(
             jax.jit, static_argnames=("k", "use_bias"),
@@ -304,9 +318,10 @@ class LLMProgramsMixin:
                 """One decode step: forward + sample + penalty count
                 scatter."""
                 tokens, logps, cache, nsteps, pcounts, topi, topl = carry
-                logits, cache = transformer_decode_step(
+                logits, cache, *held = transformer_decode_step(
                     params, tokens, cache, active, cfg,
                     dense_attn=dense_attn, aids=aids, bound_read=bound_read,
+                    stats=count_routes,
                 )
                 pen = (pcounts, fpen, ppen) if enable_penalties else None
                 sub = row_keys(seeds, nsteps)
@@ -327,14 +342,18 @@ class LLMProgramsMixin:
                 )
                 if not top_lp_k:
                     ntopi, ntopl = topi, topl
-                return (nxt, nlp, cache, nsteps, pcounts, ntopi, ntopl), ys
-
-            (final, final_lp, cache, nsteps, pcounts, topi, topl), ys = (
-                jax.lax.scan(
-                    body,
-                    (tokens, logps, cache, nsteps, pcounts, topi, topl),
-                    length=k,
+                # ``held``: this step's routes on held experts, a slot
+                # (a third plane of the emitted block, where counted).
+                return (nxt, nlp, cache, nsteps, pcounts, ntopi, ntopl), (
+                    ys, *held
                 )
+
+            (final, final_lp, cache, nsteps, pcounts, topi, topl), (
+                ys, *held
+            ) = jax.lax.scan(
+                body,
+                (tokens, logps, cache, nsteps, pcounts, topi, topl),
+                length=k,
             )
             if top_lp_k:
                 etoks, elps, etopi, etopl = ys
@@ -342,7 +361,7 @@ class LLMProgramsMixin:
             else:
                 etoks, elps = ys
                 etops = None
-            emitted = jnp.stack([etoks.astype(jnp.float32), elps])
+            emitted = jnp.stack([etoks.astype(jnp.float32), elps, *held])
             return (rep(emitted), etops, final, final_lp, cache, nsteps,
                     pcounts, topi, topl)
 

@@ -30,6 +30,10 @@ from gofr_tpu.serving.types import (
 # write to race).
 _EPOCH_ATTR = "gofr_sched_epoch"
 
+# Full prefill steps' worth of rows (x prefill_batch) that wave admission
+# may dispatch between two decode windows (_scheduler_loop).
+WAVE_STEPS = 2
+
 
 class SchedulerSuperseded(BaseException):
     """The supervisor restarted the engine around this (previously
@@ -87,6 +91,7 @@ class SchedulerMixin:
     _slots: "list[Optional[_ActiveSeq]]"
     _prefilling: "dict[int, _PrefillState]"
     _prefill_emits: list
+    _moe_counts: Any  # deque of (device counts, rows, tokens)
     _replay: "list[_GenRequest]"
     _tenant_queued: "dict[str, int]"
     _slot_blocks: "list[list[int]]"
@@ -253,26 +258,33 @@ class SchedulerMixin:
                     # decode windows: a long prompt's prefill proceeds in
                     # bounded slices and never freezes active token
                     # streams (VERDICT r1 #9).
-                    progressed = self._dispatch_prefill_chunk(
-                        lap_import=True
-                    )
+                    rows = self._dispatch_prefill_chunk(lap_import=True)
+                    progressed = rows > 0
                     # Wave admission: on a cold start or a retirement
                     # wave the 1:1 interleave would refill capacity one
                     # chunk per window — at 64 slots that is ~15 windows
                     # of a mostly-idle device (measured: the 64-slot
                     # bench lost ~2 s per wave to it). While live streams
-                    # fill under a quarter of the slots, the marginal
-                    # inter-token latency of another ~1-4 ms chunk step
-                    # is noise next to the idle capacity, so keep
-                    # draining; past that, protect the live streams'
-                    # latency (1:1 again).
-                    if progressed:
-                        while (
-                            sum(1 for s in self._slots if s is not None) * 4
-                            < self.n_slots
-                            and self._dispatch_prefill_chunk()
-                        ):
-                            pass
+                    # fill under a quarter of the slots, keep draining;
+                    # past that, protect the live streams' latency (1:1
+                    # again). The drain holds every live stream still,
+                    # so it ends at WAVE_STEPS full steps' rows between
+                    # two windows: short prompts (a few rows a step) are
+                    # drained whole as before, while prompts of dozens of
+                    # chunks, whose steps are full and cost more than a
+                    # window each, cannot freeze the streams for seconds
+                    # until a quarter of the slots is live again (PR 33:
+                    # 32 slots of ~18-chunk prompts hovered at that
+                    # quarter, drains of 5 to 25 steps).
+                    while (
+                        0 < rows < WAVE_STEPS * self.prefill_batch
+                        and sum(1 for s in self._slots if s is not None) * 4
+                        < self.n_slots
+                    ):
+                        more = self._dispatch_prefill_chunk()
+                        if not more:
+                            break
+                        rows += more
                 with loop_phase(prof, "emit_flush"):
                     self._flush_prefill_emits()
                 any_active = any(s is not None for s in self._slots)
@@ -448,6 +460,7 @@ class SchedulerMixin:
             while self._wait_kv:
                 _fail(self._wait_kv.popleft())
             self._prefill_emits.clear()
+            self._moe_counts.clear()
             if salvaged:
                 self._replay.extend(salvaged)
         # Handoffs run with the submit lock RELEASED (see _fail above).
@@ -1186,7 +1199,7 @@ class SchedulerMixin:
         else:
             self._wm_fruitless = sig
 
-    def _dispatch_prefill_chunk(self, lap_import: bool = False) -> bool:
+    def _dispatch_prefill_chunk(self, lap_import: bool = False) -> int:
         """Admit pending requests into free slots and dispatch ONE
         [rows, prefill_chunk] chunk step, ``rows`` the smallest rung of
         ``prefill_rungs`` that holds the rows that wait (at most
@@ -1198,7 +1211,8 @@ class SchedulerMixin:
         Each row advances one slot's prompt by up to ``prefill_chunk``
         tokens; rows whose prompt completes sample their first token and
         merge it into the decode token vector ON DEVICE (no host roundtrip
-        between prefill and decode). Returns True if a step was dispatched.
+        between prefill and decode). Returns the prompt rows of the step
+        it dispatched, 0 if there was none to dispatch.
         """
         # Disaggregated-tier imports (shipped KV blocks → radix index)
         # apply HERE, immediately ahead of the admission pops, so a
@@ -1409,7 +1423,7 @@ class SchedulerMixin:
                         "model", self.model_name,
                     )
         if not self._prefilling:
-            return False
+            return 0
         # Fault seam: a raise here is a device failure at prefill
         # dispatch — the scheduler's death drain must fail every caller.
         faults.fire("scheduler.device_step", engine=self, kind="prefill")
@@ -1481,10 +1495,17 @@ class SchedulerMixin:
         # _dispatch_window).
         (ccache, ctoks, clps, first_dev,
          first_lp_dev, cpc, cnst,
-         cti, ctl, ftopi_dev, ftopl_dev) = self._prefill_step(
+         cti, ctl, ftopi_dev, ftopl_dev, moe_dev) = self._prefill_step(
             R, use_bias
         )(*args)
         self._check_superseded()
+        if moe_dev is not None:
+            # The step's route counts ride back beside its first tokens:
+            # an async copy started here, read when it has landed.
+            moe_dev.copy_to_host_async()
+            self._moe_counts.append(
+                (moe_dev, len(rows), int(lens[: len(rows)].sum()))
+            )
         self.cache, self._tokens_dev, self._logps_dev = ccache, ctoks, clps
         self._pcounts_dev, self._nsteps_dev = cpc, cnst
         self._topi_dev, self._topl_dev = cti, ctl
@@ -1590,7 +1611,7 @@ class SchedulerMixin:
                          slot, seq)
                     )
         self._update_slot_gauges()
-        return True
+        return len(rows)
 
     def _flush_prefill_emits(self) -> None:
         """Emit first tokens whose async prefill fetch has landed.
@@ -1599,6 +1620,7 @@ class SchedulerMixin:
         if a decode window's processing got there first (the loaded case),
         the entry is dropped.
         """
+        self._flush_moe_counts()
         if not self._prefill_emits:
             return
         self._check_superseded()
@@ -1668,6 +1690,40 @@ class SchedulerMixin:
                 if self._slots[slot] is seq:
                     self._release_slot(slot)
         self._prefill_emits = keep
+
+    def _count_routes(self, held: float, tokens: int) -> None:
+        """``tokens`` computed tokens' routes, ``held`` of them on experts
+        that live here, into ``app_tpu_moe_routes_total{where}``."""
+        routes = (
+            tokens * self.cfg.n_moe_layers * self.cfg.n_experts_active
+        )
+        for where, n in (("held", held), ("absent", routes - held)):
+            self._metrics.add_counter(
+                "app_tpu_moe_routes_total", n,
+                "model", self.model_name, "where", where,
+            )
+
+    def _flush_moe_counts(self) -> None:
+        """Record the route counts of the prefill steps whose async copy
+        has landed (a grouped expert layer's engine only): the routes that
+        landed on held experts against all the step's routes, and one
+        record of the step's expert load ratio."""
+        while self._moe_counts:
+            counts, n_rows, tokens = self._moe_counts[0]
+            try:
+                if not counts.is_ready():
+                    return
+            except AttributeError:  # fake/CPU backends: always ready
+                pass
+            self._moe_counts.popleft()
+            if self._metrics is None:
+                continue
+            host = np.asarray(counts)  # graftlint: disable=GL001 — landed (is_ready): a copy, not a sync
+            self._count_routes(float(host[:n_rows].sum()), tokens)
+            self._metrics.record_histogram(
+                "app_tpu_moe_expert_load_ratio", float(host[-1]),
+                "model", self.model_name,
+            )
 
     def _dispatch_window(self) -> tuple:
         """Dispatch one k-step device window (non-blocking) and start the
@@ -1903,6 +1959,15 @@ class SchedulerMixin:
             # window, from host values already in hand — no per-token
             # work, no device pulls).
             dispatched_live = sum(1 for s in snapshot if s is not None)
+            if emitted_host.shape[0] > 2 and dispatched_live:
+                # A grouped expert layer's third plane: each slot's routes
+                # on held experts at each step. Every live slot computed
+                # all window_k steps, whatever was emitted of them.
+                live = [i for i, s in enumerate(snapshot) if s is not None]
+                self._count_routes(
+                    float(emitted_host[2][:, live].sum()),
+                    dispatched_live * self.window_k,
+                )
             # How full the batch is now (the gauge), and how full this
             # window ran — the slots live when it was dispatched — as a
             # histogram whose sum over count between two scrapes is the

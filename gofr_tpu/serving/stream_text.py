@@ -14,9 +14,10 @@ JSON gRPC servicers expose (and that must match the unary replies):
 
 from __future__ import annotations
 
-import asyncio
 import time
 from typing import Any, AsyncIterator
+
+from gofr_tpu.serving.types import next_token
 
 
 def normalize_stop(stop: Any) -> list[str]:
@@ -40,7 +41,6 @@ async def stream_generation(
     """
     stops = normalize_stop(kw.get("stop"))
     req = engine.submit_generate(prompt, **kw)
-    loop = asyncio.get_running_loop()
     # Monotonic: ttft/duration are INTERVALS — an NTP step between
     # submit and first token would skew (or negate) a wall-clock diff.
     start = time.monotonic()
@@ -53,7 +53,7 @@ async def stream_generation(
     finished = False
     try:
         while True:
-            tok = await loop.run_in_executor(None, req.stream.get)
+            tok = await next_token(req.stream)
             if tok is None:
                 break
             if first_at is None:

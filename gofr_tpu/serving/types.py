@@ -5,11 +5,12 @@ module); the engine re-exports the public names."""
 
 from __future__ import annotations
 
+import asyncio
 import queue
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Optional
+from typing import TYPE_CHECKING, Any, Optional
 
 from gofr_tpu.serving.lifecycle import CancelToken, Deadline
 
@@ -23,6 +24,78 @@ _PREFILL_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 # logit_bias entries per request — the OpenAI cap. The [slots, K] planes
 # upload only on admission, so K is cheap padding (~77 KB at 32 slots).
 LOGIT_BIAS_K = 300
+
+
+# Seconds one read of a stream may hold a thread of the loop's default
+# executor before it waits on the loop instead (next_token).
+POOL_READ_S = 3.0
+
+
+class TokenStream(queue.Queue):
+    """One request's tokens, scheduler thread -> consumer; ``None`` ends it.
+
+    A ``queue.Queue`` for a consumer that may block (``get``); an asyncio
+    consumer can also await ``aget``, which holds **no thread** while it
+    waits. Until PR 33 every SSE handler parked ``get`` on the loop's
+    default executor (``min(32, cores + 4)`` workers) for as long as its
+    stream had nothing: with more open streams than workers, the streams
+    still in a long prefill held every worker and the decoding streams'
+    tokens sat in their queues (32 streams of 10-25 s prefill on 17
+    workers: no stream saw its second token before the 16th first token).
+    """
+
+    def __init__(self) -> None:
+        super().__init__()
+        # (loop, event) of the asyncio consumer, set by its first ``aget``.
+        self._waker: Optional[tuple[Any, asyncio.Event]] = None
+        # A wake-up is on its way to the loop: one call a burst of puts,
+        # not one a token (a decode window puts window_k tokens at once).
+        self._signalled = False
+
+    def put(self, item: Any, block: bool = True,
+            timeout: Optional[float] = None) -> None:
+        super().put(item, block, timeout)
+        waker = self._waker
+        if waker is not None and not self._signalled:
+            self._signalled = True
+            try:
+                waker[0].call_soon_threadsafe(waker[1].set)
+            except RuntimeError:
+                pass  # the loop is closed: the consumer is gone
+
+    async def aget(self) -> Any:
+        """The next item, awaited on the running loop. One consumer."""
+        if self._waker is None:
+            self._waker = (asyncio.get_running_loop(), asyncio.Event())
+        event = self._waker[1]
+        while True:
+            # Cleared BEFORE the look: a put after the look finds the flag
+            # down and signals; one before it is in the queue already.
+            self._signalled = False
+            event.clear()
+            try:
+                return self.get_nowait()
+            except queue.Empty:
+                await event.wait()
+
+
+async def next_token(stream: TokenStream) -> Any:
+    """Await a request stream's next item from an asyncio handler.
+
+    The read starts where it always ran, blocking on a thread of the
+    loop's default executor, and a stream that gives nothing for
+    ``POOL_READ_S`` hands the thread back and waits on the loop. A token
+    that is a decode window away is read as before; a stream in a long
+    prefill or a queue stops holding a worker the decoding streams need.
+    (Waiting on the loop from the start is the whole repair, and it moves a
+    closed loop of more streams than workers to another operating point:
+    PERF.md section 6, PR 33; ROADMAP S6.)
+    """
+    loop = asyncio.get_running_loop()
+    try:
+        return await loop.run_in_executor(None, stream.get, True, POOL_READ_S)
+    except queue.Empty:
+        return await stream.aget()
 
 
 @dataclass
@@ -114,7 +187,7 @@ class _GenRequest:
     temperature: float
     stop_on_eos: bool
     top_p: float = 1.0
-    stream: "queue.Queue[Optional[int]]" = field(default_factory=queue.Queue)
+    stream: TokenStream = field(default_factory=TokenStream)
     future: Future = field(default_factory=Future)
     enqueued_at: float = field(default_factory=time.time)
     token_ids: list[int] = field(default_factory=list)

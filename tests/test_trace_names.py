@@ -33,7 +33,11 @@ from gofr_tpu.serving.tokenizer import ByteTokenizer
 VOCABULARY = {
     "embed", "attn", "kv_commit", "ffn", "moe_router", "moe_experts",
     "lm_head", "sample", "draft", "verify",
+    # PR 33: latent attention's projections, and a grouped expert layer's
+    # sort-and-group and shared expert
+    "mla_q", "mla_kv", "moe_dispatch", "moe_shared",
 }
+LATENT_MOE = {"mla_q", "mla_kv", "moe_dispatch", "moe_shared"}
 
 
 def engine_of(model: str) -> InferenceEngine:
@@ -91,8 +95,14 @@ def lower_window(e: InferenceEngine):
 
 
 @pytest.mark.parametrize("model,ffn_scopes,absent", [
-    ("llama-tiny", {"ffn"}, {"moe_router", "moe_experts", "draft", "verify"}),
-    ("moe-tiny", {"moe_router", "moe_experts"}, {"ffn", "draft", "verify"}),
+    ("llama-tiny", {"ffn"},
+     {"moe_router", "moe_experts", "draft", "verify"} | LATENT_MOE),
+    ("moe-tiny", {"moe_router", "moe_experts"},
+     {"ffn", "draft", "verify"} | LATENT_MOE),
+    # a leading dense layer (ffn), then expert layers by the grouped product
+    # over a latent cache: every scope of the model's vocabulary
+    ("mla-moe-tiny", {"ffn", "moe_router", "moe_experts"} | LATENT_MOE,
+     {"draft", "verify"}),
 ])
 def test_lowered_programs_name_their_ops_by_scope(model, ffn_scopes, absent):
     e = engine_of(model)  # built, never started: nothing runs
