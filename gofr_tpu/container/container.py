@@ -380,11 +380,20 @@ class Container:
             "here, or absent (left out, another chip's share); from counts "
             "the prefill and decode steps return beside their tokens",
         )
+        m.new_counter(
+            "app_tpu_moe_product_steps_total",
+            "dispatched steps of an expert model by the product its expert "
+            "layers ran (product=grouped: each expert multiplied by the rows "
+            "routed to it; einsum: every expert by every row; picked from "
+            "the step's rows) and program (prefill_chunk|decode_window, one "
+            "a dispatch)",
+        )
         m.new_histogram(
             "app_tpu_moe_expert_load_ratio",
             "rows of the fullest held expert / mean rows of the held "
             "experts, averaged over a prefill step's expert layers, one "
-            "record per prefill step (1.0: even load)",
+            "record per prefill step whose expert layers ran grouped (1.0: "
+            "even load)",
             (1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0, 64.0, 256.0),
         )
         # Disaggregated prefill/decode tiers (TPU_REPLICA_ROLES;

@@ -51,6 +51,13 @@ from gofr_tpu.ops.norms import layer_norm, rms_norm
 from gofr_tpu.ops.rotary import apply_rope, rope_frequencies
 
 
+# Rows of one expert that the grouped product of a stacked expert layer
+# multiplies at a time (``moe_tiled_experts``): 512 FLOP a weight byte at
+# int8, above the v5e's ridge (240), so a tile is compute-bound; an expert's
+# run of rows is padded to a multiple of it.
+EXPERT_ROW_TILE = 256
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 128256
@@ -202,25 +209,57 @@ class TransformerConfig:
         return lo, lo + self.experts_held
 
     @property
-    def expert_product(self) -> str:
-        """How an expert layer multiplies: "einsum" computes every expert
-        for every row (``_ffn_moe``: all held, Mixtral's router, no shared
-        expert, no scale), anything else is "grouped": the routes sorted by
-        expert, each expert multiplied by its own rows
-        (``_ffn_moe_grouped``). ROADMAP S5 moves the all-held layer over by
-        changing this rule."""
-        einsum = (
-            self.experts_held == self.n_experts
+    def experts_stacked(self) -> bool:
+        """An expert layer with every expert held, Mixtral's router, no
+        shared expert and no scale: its expert leaves are stacked in
+        ``params["layers"]`` (``[L, E, d, f]``, quantisable), and either
+        the einsum or the tiles multiply them (``expert_product``). Any other
+        expert layer (a share of the experts, sigmoid scores, a shared
+        expert, a routed scale) keeps a set of leaves a layer
+        (``init_experts``) for ``jax.lax.ragged_dot``."""
+        return (
+            self.is_moe and self.experts_held == self.n_experts
             and self.router_score == "softmax"
             and not self.n_shared_experts and self.routed_scale == 1.0
         )
-        return "einsum" if einsum else "grouped"
+
+    def expert_product(self, rows: int, sharded: bool = False) -> str:
+        """How an expert layer multiplies in a step of ``rows`` token rows
+        (``b * s``, static in the traced step):
+
+        * "einsum": every expert computes every row, ``rows x n_experts``
+          expert rows (``_ffn_moe``);
+        * "tiles": the routes sorted by expert, each expert's slice of the
+          stacked leaves multiplied by its own rows only, in tiles of
+          ``EXPERT_ROW_TILE`` rows: at most ``rows x n_experts_active`` and
+          a tile of padding an expert (``moe_tiled_experts``);
+        * "ragged": the same sort over a set of bf16 leaves a layer, through
+          ``jax.lax.ragged_dot`` (``_ffn_moe_grouped``).
+
+        The rule, from the shapes alone: a stacked all-held layer
+        (``experts_stacked``) goes to the tiles where their worst case is
+        the smaller of the two row counts, ``rows x k + E x tile < rows x
+        E``: Mixtral's ``[8, 256]`` prefill step (2,048 rows: 6,144 against
+        16,384) does, its ``[1, 256]`` rung (2,560 against 2,048) and its
+        decode step (64 rows, which reads every expert's weights either way)
+        keep the einsum. Under a mesh (``sharded``) the einsum stays: GSPMD
+        partitions it over the expert axis. An expert layer whose leaves are
+        not stacked is "ragged" at every shape."""
+        if not self.experts_stacked:
+            return "ragged"
+        grouped_rows = (
+            rows * self.n_experts_active + self.n_experts * EXPERT_ROW_TILE
+        )
+        if sharded or grouped_rows >= rows * self.n_experts:
+            return "einsum"
+        return "tiles"
 
     @property
     def counts_routes(self) -> bool:
         """The serving steps return, beside their tokens, how many routes
-        landed on held experts: only the grouped expert layer counts."""
-        return self.is_moe and self.expert_product == "grouped"
+        landed on held experts: an expert layer that may hold a share of
+        the experts counts (its leaves are a set a layer)."""
+        return self.is_moe and not self.experts_stacked
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +353,7 @@ def _init_layer_group(key: jax.Array, cfg: TransformerConfig, L: int,
         # the stack (``init_experts``).
         E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.expert_width
         layers["router"] = dense_init(ks[4], (L, D, E), D)
-        if cfg.expert_product != "grouped":
+        if cfg.experts_stacked:
             layers.update(
                 w_gate=dense_init(ks[5], (L, Eh, D, Fe), D),
                 w_up=dense_init(ks[6], (L, Eh, D, Fe), D),
@@ -494,7 +533,7 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
             )
         if moe:
             layers["router"] = P(lax_, None, None)
-            if cfg.expert_product != "grouped":
+            if cfg.experts_stacked:
                 layers.update(
                     w_gate=P(lax_, "tp", None, None),
                     w_up=P(lax_, "tp", None, None),
@@ -863,18 +902,32 @@ def _ffn_dense(x, lp, cfg, aids=None):
     return _wein("bsf,fd->bsd", h, lp["w_down"]) + _lora(h, lp, "w_down", aids)
 
 
-def _ffn_moe(x, lp, cfg):
-    """Top-k MoE FFN. x: [b, s, D]. Dense-einsum formulation: every expert
-    computes, weighted by routing probs — the XLA-friendly formulation for
-    small expert counts (no ragged dispatch), all of them held here.
-    ``mixtral-8x7b-d4`` (8 of 8 held, softmax) keeps it in PR 33: moving it
-    to the sorted, grouped product of ``_ffn_moe_grouped`` is ROADMAP S5's
-    change, with a claim of its own. A share of the experts must not come
-    here: this spends ``held x rows`` where the share needs the routes
-    that land on it."""
-    if cfg.expert_product != "einsum":
+def _ffn_moe(x, lp, cfg, valid=None, stack=None):
+    """Top-k MoE FFN over a stacked all-held expert layer
+    (``cfg.experts_stacked``: Mixtral). x: [b, s, D]. One router, two
+    products, picked by the caller from the step's shape
+    (``cfg.expert_product``):
+
+    * the dense einsum (``stack`` None): every expert computes every row,
+      weighted by routing probs, six of Mixtral's eight by zero. At a
+      decode step's 64 rows it reads each expert's weights once and runs at
+      the weight-read bound (PERF.md section 6, PR 34), and GSPMD
+      partitions it under a mesh;
+    * the sorted, grouped product in tiles (``stack``: the stacked leaves
+      ``params["layers"]`` and this layer's index in them,
+      ``moe_tiled_experts``): each expert multiplies only the rows routed
+      to it, and a token that ``valid`` ([b, s] bool; None: all) leaves out
+      is not multiplied at all.
+
+    The router's expression is the same in both, so a token's experts are
+    the same in its prefill step and in its decode steps. Returns (out,
+    counts): counts None from the einsum, else (routes a row [b] int32, the
+    experts' rows [E] int32). A share of the experts must not come here:
+    the einsum spends ``held x rows`` where the share needs the routes that
+    land on it."""
+    if not cfg.experts_stacked:
         raise ValueError(
-            "the dense einsum computes every expert for every row with "
+            "the stacked expert layer holds every expert behind "
             "Mixtral's router: a share of the experts, sigmoid scores, a "
             "shared expert or a routed scale go through _ffn_moe_grouped"
         )
@@ -886,6 +939,17 @@ def _ffn_moe(x, lp, cfg):
         probs = jax.nn.softmax(router_logits, axis=-1)
         topk_probs, topk_idx = jax.lax.top_k(probs, cfg.n_experts_active)
         topk_probs = topk_probs / jnp.sum(topk_probs, axis=-1, keepdims=True)
+    if stack is not None:
+        k = cfg.n_experts_active
+        valid = jnp.ones((b, s), bool) if valid is None else valid
+        out, sizes = moe_tiled_experts(
+            x.reshape(b * s, D), topk_idx.reshape(b * s, k),
+            topk_probs.reshape(b * s, k), valid.reshape(b * s), *stack, cfg,
+        )
+        return out.reshape(b, s, D), (
+            jnp.sum(valid, axis=1).astype(jnp.int32) * k, sizes
+        )
+    with jax.named_scope("moe_router"):
         # weights[b,s,E]: zero except the chosen experts.
         weights = jnp.zeros_like(probs).at[
             jnp.arange(b)[:, None, None],
@@ -897,7 +961,86 @@ def _ffn_moe(x, lp, cfg):
         up = _wein("bsd,edf->bsef", x, lp["w_up"])
         hidden = _act(cfg)(gate) * up
         out = _wein("bsef,efd->bsed", hidden, lp["w_down"])
-        return jnp.einsum("bsed,bse->bsd", out, weights.astype(x.dtype))
+        return jnp.einsum("bsed,bse->bsd", out, weights.astype(x.dtype)), None
+
+
+def moe_tiled_experts(xf, idx, gates, valid, layers, layer, cfg,
+                      tile=EXPERT_ROW_TILE):
+    """A stacked all-held expert layer by a sorted, grouped product in
+    tiles: sum over a token's routes of gate x SwiGLU_expert(token), each
+    expert's weights multiplied by its own rows only. xf [T, D]; idx, gates
+    [T, k]; valid [T] bool; ``layers`` the stacked leaves (``w_gate``,
+    ``w_up`` [L, E, D, F], ``w_down`` [L, E, F, D]: arrays, Q8 or Q4),
+    ``layer`` this layer's index in them (traced, inside the layer scan) and
+    ``tile`` the rows of one expert multiplied at a time (static). Returns
+    ([T, D], the experts' row counts [E] int32).
+
+    The routes (token, choice) of the valid tokens are sorted by expert (a
+    counting sort: a route's place is its expert's start plus its rank
+    among that expert's routes), each expert's run padded to a multiple of
+    ``tile`` rows; the routes of a token that holds nothing
+    sort past the end and are in no run. A loop over the tiles that hold a
+    route, each one expert's, multiplies ``[tile, D]`` by that expert's
+    slice of the stacked leaf through ``_wein``: the mathematics of the
+    einsum form (int8 values exact in bf16, float32 accumulation, the
+    expert's own per-channel scale on the output). The slice is taken
+    WHERE IT IS READ, layer and expert in one ``dynamic_slice`` of the
+    stacked leaf inside the loop, so it fuses into the product's operand
+    read: a layer's slice handed to the loop from outside would be copied
+    out of the stack first, 1.41 GB a layer for Mixtral (PR 31 and PR 33
+    met that copy with a custom call's operands). Work: the valid routes
+    and at most a tile an expert, whatever the router's balance; no
+    capacity and no dropped route, the buffer holds every route."""
+    T, k = idx.shape
+    E, M = cfg.n_experts, T * k
+    n_tiles = (M + E * (tile - 1)) // tile  # sum of ceil(n_e / tile) <= this
+    with jax.named_scope("moe_dispatch"):
+        expert = jnp.where(valid[:, None], idx, E).reshape(M)
+        mine = expert[:, None] == jnp.arange(E)[None, :]  # [M, E]
+        rank = jnp.sum(jnp.where(mine, jnp.cumsum(mine, axis=0) - 1, 0), axis=1)
+        sizes = jnp.sum(mine, axis=0).astype(jnp.int32)
+        padded = (sizes + tile - 1) // tile * tile
+        ends = jnp.cumsum(padded)
+        place = jnp.where(
+            expert < E, (ends - padded)[jnp.minimum(expert, E - 1)] + rank,
+            n_tiles * tile,
+        )  # [M]: a route's row in the buffer; past its end: in no run
+        source = jnp.zeros((n_tiles * tile,), jnp.int32).at[place].set(
+            jnp.arange(M, dtype=jnp.int32) // k, mode="drop"
+        )
+        rows = xf[source]  # [n_tiles * tile, D], each expert's rows together
+        tile_expert = jnp.minimum(
+            jnp.sum(jnp.arange(n_tiles)[:, None] >= (ends // tile)[None, :], axis=1),
+            E - 1,
+        )
+
+    def expert_leaf(name, e):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_slice(
+                a, (layer, e) + (0,) * (a.ndim - 2), (1, 1) + a.shape[2:]
+            ).reshape(a.shape[2:]),
+            layers[name],
+        )
+
+    def one_tile(t, out):
+        e = tile_expert[t]
+        x_t = jax.lax.dynamic_slice_in_dim(rows, t * tile, tile)
+        hidden = _act(cfg)(
+            _wein("td,df->tf", x_t, expert_leaf("w_gate", e))
+        ) * _wein("td,df->tf", x_t, expert_leaf("w_up", e))
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, _wein("tf,fd->td", hidden, expert_leaf("w_down", e)),
+            t * tile, 0,
+        )
+
+    with jax.named_scope("moe_experts"):
+        out = jax.lax.fori_loop(
+            0, ends[-1] // tile, one_tile, jnp.zeros_like(rows)
+        )
+    with jax.named_scope("moe_dispatch"):
+        weights = jnp.where(valid[:, None], gates, 0.0).astype(xf.dtype)
+        back = out[jnp.minimum(place, n_tiles * tile - 1)].reshape(T, k, -1)
+        return jnp.einsum("tkd,tk->td", back, weights), sizes
 
 
 def moe_route(xf, router, cfg):
@@ -1016,23 +1159,34 @@ def _swiglu(x, w_gate, w_up, w_down, cfg):
     return (_act(cfg)(x @ w_gate) * (x @ w_up)) @ w_down
 
 
-def _ffn_any(h, lp, cfg, aids=None, valid=None, experts=None):
+def _ffn_any(h, lp, cfg, aids=None, valid=None, experts=None, stack=None):
     """The layer's FFN by the leaves it holds (a stack that leads with
     dense layers hands both kinds through one body): (out, route counts).
-    The counts are None unless ``cfg.counts_routes``; a dense layer then
-    counts no route. ``experts``: ``params.get("experts")``, a grouped expert
-    layer's own leaves."""
+    The counts are None unless the expert layer ran grouped (a dense layer
+    of such a model counts no route). ``experts``: ``params.get("experts")``,
+    the own leaves of an expert layer that is not stacked; ``stack``: where
+    the caller picked the grouped product for a stacked one (``_ffn_moe``)."""
     if "router" not in lp:
         stats = None
-        if cfg.counts_routes:
+        if cfg.counts_routes or stack is not None:
             stats = (
                 jnp.zeros((h.shape[0],), jnp.int32),
                 jnp.zeros((cfg.experts_held,), jnp.int32),
             )
         return _ffn_dense(h, lp, cfg, aids), stats
-    if cfg.expert_product == "grouped":
-        return _ffn_moe_grouped(h, lp, cfg, valid, experts)
-    return _ffn_moe(h, lp, cfg), None
+    if cfg.experts_stacked:
+        return _ffn_moe(h, lp, cfg, valid, stack)
+    return _ffn_moe_grouped(h, lp, cfg, valid, experts)
+
+
+def _expert_stack(params, cfg, entry):
+    """``_ffn_moe``'s ``stack`` for the layer that writes cache entry
+    ``entry`` (None where the step keeps the einsum): the stacked expert
+    leaves, closed over and not scanned, and the layer's index in them."""
+    if entry is None:
+        return None
+    n_dense = cfg.n_layers - cfg.n_moe_layers
+    return params["layers"], entry % cfg.n_layers - n_dense
 
 
 def route_stats(stats, rows_valid=None):
@@ -1323,6 +1477,7 @@ def transformer_prefill_chunk(
     aids: Optional[jnp.ndarray] = None,
     row_valid: Optional[jnp.ndarray] = None,
     stats: bool = False,
+    sharded: bool = False,
 ) -> tuple:
     """Chunked serving prefill: one [P, c] chunk step.
 
@@ -1344,8 +1499,11 @@ def transformer_prefill_chunk(
     prompt's final chunk lands.
     stats: also return, third, the step's route counts (``route_stats``:
     per row the routes that landed on held experts, and the expert load
-    ratio; None unless ``cfg.counts_routes``), over the valid tokens of the
-    rows that ``row_valid`` ([P] bool; None: all) does not mark as padding.
+    ratio; None unless the step's expert layers ran grouped), over the valid
+    tokens of the rows that ``row_valid`` ([P] bool; None: all) does not
+    mark as padding. A grouped stacked expert layer does not multiply the
+    other tokens at all (their outputs are the caller's to drop).
+    sharded: the weights lie on a mesh (``cfg.expert_product``).
     """
     P, c = tokens.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -1353,15 +1511,24 @@ def transformer_prefill_chunk(
     x = _embed(params, tokens, cfg, positions)  # [P, c, D]
     cos, sin = rope_frequencies(cfg.rope_dims, cache.max_len, cfg.rope_theta)
     paged = isinstance(cache, PagedKVCache)
-    counted = None  # the tokens whose routes count: a counting model's only
-    if cfg.counts_routes:
+    # The product of a stacked expert layer, from this step's shape.
+    tiled = cfg.expert_product(P * c, sharded) == "tiles"
+    counted = None  # the tokens whose routes count: a grouped layer's only
+    if cfg.counts_routes or tiled:
         counted = jnp.arange(c)[None, :] < lens[:, None]  # [P, c]
         if row_valid is not None:
             counted &= row_valid[:, None]
+    # Where a padding row's tokens are not multiplied, it no longer computes
+    # what the row it duplicates computes, and its writes to that row's
+    # slot would race with the row's own: they go past the end instead,
+    # where a scatter drops them (the paged pool parks them in block 0).
+    write_pos = positions
+    if tiled and row_valid is not None:
+        write_pos = jnp.where(row_valid[:, None], positions, cache.max_len)
     if cfg.is_latent:
         x, cache, counts = _latent_chunk_layers(
             params, x, cache, slots, starts, lens, positions, cos, sin,
-            counted, cfg, aids,
+            counted, cfg, aids, tiled, write_pos,
         )
         return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
 
@@ -1377,29 +1544,29 @@ def transformer_prefill_chunk(
         bt_rows = cache.block_table[slots]  # [P, max_blocks]
         blk = jnp.take_along_axis(
             bt_rows,
-            jnp.minimum(positions // B, bt_rows.shape[1] - 1),
+            jnp.minimum(write_pos // B, bt_rows.shape[1] - 1),
             axis=1,
         )  # [P, c]
         # Padding columns past max_len MUST park in block 0: the slot
         # cache dropped them as out-of-bounds scatter updates, but the
         # min-clamp above would remap them INTO the last real block on
         # top of live prompt K/V.
-        in_range = positions < cache.max_len
+        in_range = write_pos < cache.max_len
         blk = jnp.where(in_range, blk, 0)
-        off = jnp.where(in_range, positions % B, B - 1)
+        off = jnp.where(in_range, write_pos % B, B - 1)
         idx_row = blk[:, None, :]  # [P, 1, c] pool block per position
         idx_pos = off[:, None, :]
         s_row = blk[:, None, None, :]
         s_pos = off[:, None, None, :]
     else:
         idx_row = slots[:, None, None]
-        idx_pos = positions[:, None, :]  # [P, 1, c]
+        idx_pos = write_pos[:, None, :]  # [P, 1, c]
         # Scale-write indices (int8 mode): [S, KV, 8, max_len] layer slice.
         s_row = slots[:, None, None, None]
-        s_pos = positions[:, None, None, :]  # [P, 1, 1, c]
+        s_pos = write_pos[:, None, None, :]  # [P, 1, 1, c]
 
     def body(x, scanned):
-        lp, ck, cv, cks, cvs = scanned  # ck/cv: [S, KV, max_len, hd]
+        lp, ck, cv, cks, cvs, *entry = scanned  # ck/cv: [S, KV, max_len, hd]
         with jax.named_scope("attn"):
             h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
             q, k, v = _qkv(h, lp, "pcd,dh->pch", H, KV, hd, P, c, aids=aids)
@@ -1437,14 +1604,19 @@ def transformer_prefill_chunk(
         mlp_in = x if cfg.parallel_residual else x + attn_out
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn, counts = _ffn_any(
-            h, lp, cfg, aids, counted, params.get("experts")
+            h, lp, cfg, aids, counted, params.get("experts"),
+            _expert_stack(params, cfg, entry[0] if tiled else None),
         )
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
         return x, (ck, cv, cks, cvs, counts)
 
+    # A grouped stacked layer slices its experts out of the stack itself,
+    # so its body is also handed its cache entry's index.
     x, (new_k, new_v, new_ks, new_vs, counts) = _scan_stack(
-        body, x, params, cfg, (cache.k, cache.v, cache.k_s, cache.v_s)
+        body, x, params, cfg,
+        (cache.k, cache.v, cache.k_s, cache.v_s,
+         *((jnp.arange(cfg.n_cache_entries),) if tiled else ())),
     )
     cache = cache._replace(k=new_k, v=new_v, k_s=new_ks, v_s=new_vs)
     return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
@@ -1479,7 +1651,7 @@ def _latent_cache(cache, cfg):
 
 
 def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
-                         cos, sin, counted, cfg, aids):
+                         cos, sin, counted, cfg, aids, tiled, write_pos):
     """The chunk step's layer stack over a latent cache. The stacked plane
     rides the scan's CARRY and each layer writes its chunk's rows into its
     own entry in place: the plane as xs and ys, as the K and V planes ride,
@@ -1502,7 +1674,7 @@ def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
         rows = _mla_rows(h, lp, cfg, cos, sin, positions)
         # Write the chunk's rows, then attend the cache in place.
         with jax.named_scope("kv_commit"):
-            plane = plane.at[entry, slots[:, None], 0, positions].set(
+            plane = plane.at[entry, slots[:, None], 0, write_pos].set(
                 _lane_pad(rows, plane)
             )
         with jax.named_scope("attn"):
@@ -1516,7 +1688,8 @@ def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
         x = x + attn_out
         h = _norm(x, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn, counts = _ffn_any(
-            h, lp, cfg, aids, counted, params.get("experts")
+            h, lp, cfg, aids, counted, params.get("experts"),
+            _expert_stack(params, cfg, entry if tiled else None),
         )
         return (x + _post_norm(ffn, lp, "mlp_post_norm", cfg), plane), counts
 
@@ -1536,6 +1709,7 @@ def transformer_decode_step(
     aids: Optional[jnp.ndarray] = None,
     bound_read: bool = True,
     stats: bool = False,
+    sharded: bool = False,
 ) -> tuple:
     """One decode step over ALL cache slots (static batch = n_slots).
 
@@ -1557,6 +1731,9 @@ def transformer_decode_step(
     stats: also return, third, [n_slots] float32: each slot's routes that
     landed on held experts in this step, over the layers (None unless
     ``cfg.counts_routes``; an inactive slot's count is the caller's to drop).
+    sharded: the weights lie on a mesh (``cfg.expert_product``: a stacked
+    expert layer picks its product from the slot count, as a prefill step
+    does from its rows; an inactive slot's row is then not multiplied).
     """
     S = cache.n_slots
     L = cfg.n_cache_entries  # a looped stack commits every pass's entry
@@ -1587,7 +1764,8 @@ def transformer_decode_step(
     # entry already sliced would copy it out whole first, every layer.
     paged = isinstance(cache, PagedKVCache)
     # The slots whose routes count (a counting model's only).
-    counted = active[:, None] if cfg.counts_routes else None
+    tiled = cfg.expert_product(S, sharded) == "tiles"
+    counted = active[:, None] if cfg.counts_routes or tiled else None
     read = None
     if bound_read and not paged:
         read = decode_read_index(
@@ -1612,7 +1790,10 @@ def transformer_decode_step(
             attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)[:, 0]
         x = x + attn_out
         h = _norm(x[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
-        ffn, counts = _ffn_any(h, lp, cfg, aids, counted, params.get("experts"))
+        ffn, counts = _ffn_any(
+            h, lp, cfg, aids, counted, params.get("experts"),
+            _expert_stack(params, cfg, entry if tiled else None),
+        )
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         return x + ffn[:, 0], (row, counts)
 
@@ -1666,7 +1847,10 @@ def transformer_decode_step(
         h = _norm(
             mlp_in[:, None, :], lp["mlp_norm"], cfg, lp.get("mlp_norm_b")
         )
-        ffn, counts = _ffn_any(h, lp, cfg, aids, counted, params.get("experts"))
+        ffn, counts = _ffn_any(
+            h, lp, cfg, aids, counted, params.get("experts"),
+            _expert_stack(params, cfg, entry if tiled else None),
+        )
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         if cfg.parallel_residual:
             x = x + attn_out + ffn[:, 0]

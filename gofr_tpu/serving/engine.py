@@ -197,8 +197,10 @@ class InferenceEngine(
         if quant and getattr(self.cfg, "counts_routes", False):
             raise ValueError(
                 f"{model_name}: TPU_QUANT={quant} is not served: the grouped "
-                "expert product (_ffn_moe_grouped) takes bf16 "
-                "weights (jax.lax.ragged_dot has no int8 / int4 operand)"
+                "expert product of a layer that holds a share of the experts "
+                "(_ffn_moe_grouped) takes bf16 weights (jax.lax.ragged_dot "
+                "has no int8 / int4 operand; a stacked all-held expert "
+                "layer's products take quantised leaves)"
             )
         if getattr(self.cfg, "is_latent", False):
             self._refuse_for_latent_cache(

@@ -53,6 +53,7 @@ class LLMProgramsMixin:
     prefill_batch: int
     prefill_chunk: int
     prefill_rungs: tuple[int, ...]
+    moe_products: dict[tuple[str, int], str]
     decode_read_rungs: tuple[int, ...]
     max_len: int
     kv_block: int
@@ -100,9 +101,23 @@ class LLMProgramsMixin:
             window=cfg.sliding_window, kernel=False if dense_attn else None,
             latent=cfg.is_latent,
         ) if bound_read else (self.max_len,)
-        # A grouped expert layer counts its routes; the steps return the
-        # counts beside their tokens (no program of another model changes).
+        # An expert layer that may hold a share of the experts counts its
+        # routes; the steps return the counts beside their tokens (no
+        # program of another model changes). A stacked expert layer picks
+        # its product at each traced step from the step's rows
+        # (``cfg.expert_product``); a prefill step that ran grouped returns
+        # its expert load the same way.
         count_routes = cfg.counts_routes
+        sharded = self.mesh is not None
+
+        def moe_product(rows: int) -> Optional[str]:
+            """app_tpu_moe_product_steps_total's ``product`` of a step of
+            ``rows`` token rows: "einsum", or "grouped" (tiles of a stacked
+            layer, ``ragged_dot`` over a set of leaves); None: no experts."""
+            if not cfg.is_moe:
+                return None
+            product = cfg.expert_product(rows, sharded)
+            return "einsum" if product == "einsum" else "grouped"
 
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -233,25 +248,32 @@ class LLMProgramsMixin:
             """One [rows, c] chunk (``jit_prefill_chunk_step`` in the
             profiler's trace): write K/V + attend; on rows whose prompt
             finishes (finalize) sample the first token and merge it into
-            the decode token vector ON DEVICE. Padding rows duplicate row 0
-            (identical K/V writes are idempotent; the merge below is
-            per-slot select, not scatter, so duplicates can't race).
+            the decode token vector ON DEVICE. Padding rows duplicate row 0:
+            where every row is multiplied their K/V writes are row 0's own
+            (idempotent); in a step whose stacked expert layers ran in tiles
+            a padding row's tokens are not multiplied, so it writes nothing
+            (its writes go past ``max_len`` and are dropped,
+            ``transformer_prefill_chunk``). Either way only the
+            ``row_valid``-masked merge below keeps it out of the slots (a
+            per-slot select, not a scatter, so duplicates can't race).
             pcounts: per-slot generated-token counts (penalties feature) —
             finalize RESETS the slot's row (new request) and counts the
             first sampled token; the first token itself is never penalized
             (its counts are the zeros just written)."""
-            # A counting model's step also returns its route counts; they
-            # ride back as [rows + 1] float32 beside the first tokens: each
-            # row's routes that landed on held experts (over its valid
-            # tokens and the layers), then the step's expert load ratio.
+            # A step whose expert layers ran grouped also returns its route
+            # counts; they ride back as [rows + 1] float32 beside the first
+            # tokens: each row's routes that landed on held experts (over
+            # its valid tokens and the layers), then the step's expert load
+            # ratio.
+            grouped = moe_product(tokens.shape[0] * tokens.shape[1]) == "grouped"
             logits, cache, *counts = transformer_prefill_chunk(
                 params, tokens, cache, slots, starts, lens, cfg,
                 dense_attn=dense_attn, aids=aids[slots],
-                row_valid=row_valid if count_routes else None,
-                stats=count_routes,
+                row_valid=row_valid if grouped else None,
+                stats=grouped, sharded=sharded,
             )
             moe = None
-            if count_routes:
+            if grouped:
                 ((held, load_ratio),) = counts
                 moe = rep(jnp.concatenate([held, load_ratio[None]]))
             # Sample at the slot's counter OFFSET (noff): 0 for fresh
@@ -321,7 +343,7 @@ class LLMProgramsMixin:
                 logits, cache, *held = transformer_decode_step(
                     params, tokens, cache, active, cfg,
                     dense_attn=dense_attn, aids=aids, bound_read=bound_read,
-                    stats=count_routes,
+                    stats=count_routes, sharded=sharded,
                 )
                 pen = (pcounts, fpen, ppen) if enable_penalties else None
                 sub = row_keys(seeds, nsteps)
@@ -374,6 +396,14 @@ class LLMProgramsMixin:
         self._prefill_chunk_step = wrap("prefill_chunk", prefill_chunk_step)
         self._decode_window = wrap("decode_window", decode_window)
         self.prefill_rungs = prefill_rungs(self.prefill_batch)
+        # The expert product each program runs, by (program, its rows or
+        # slots), for app_tpu_moe_product_steps_total: the rule the traced
+        # steps applied.
+        self.moe_products = {
+            ("decode_window", self.n_slots): moe_product(self.n_slots),
+            **{("prefill_chunk", rows): moe_product(rows * self.prefill_chunk)
+               for rows in self.prefill_rungs},
+        } if cfg.is_moe else {}
         self._compile_prefill_ladder()
 
     def _compile_prefill_ladder(self) -> None:

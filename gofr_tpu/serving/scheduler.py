@@ -77,6 +77,7 @@ class SchedulerMixin:
     pipeline_depth: int
     prefill_batch: int
     prefill_rungs: tuple[int, ...]
+    moe_products: dict[tuple[str, int], str]
     decode_read_rungs: tuple[int, ...]
     prefill_chunk: int
     top_logprobs: int
@@ -1522,6 +1523,7 @@ class SchedulerMixin:
                 "app_tpu_prefill_steps_total",
                 "model", self.model_name, "rows", str(R),
             )
+            self._count_moe_product("prefill_chunk", R)
             # How much of the [R, c] step that ran was prompt: the rest
             # of its R x c token rows is padding the device computes
             # anyway.
@@ -1703,11 +1705,23 @@ class SchedulerMixin:
                 "model", self.model_name, "where", where,
             )
 
+    def _count_moe_product(self, program: str, rows: int) -> None:
+        """One dispatched step of an expert model, under the product its
+        expert layers ran (``programs.moe_products``): the share of steps in
+        which the grouped product engaged."""
+        product = self.moe_products.get((program, rows))
+        if product is not None and self._metrics is not None:
+            self._metrics.increment_counter(
+                "app_tpu_moe_product_steps_total", "model", self.model_name,
+                "product", product, "program", program,
+            )
+
     def _flush_moe_counts(self) -> None:
         """Record the route counts of the prefill steps whose async copy
-        has landed (a grouped expert layer's engine only): the routes that
-        landed on held experts against all the step's routes, and one
-        record of the step's expert load ratio."""
+        has landed (steps whose expert layers ran grouped): one record of
+        the step's expert load ratio and, where the engine may hold a
+        share of the experts, the routes that landed on held experts
+        against all the step's routes."""
         while self._moe_counts:
             counts, n_rows, tokens = self._moe_counts[0]
             try:
@@ -1719,7 +1733,8 @@ class SchedulerMixin:
             if self._metrics is None:
                 continue
             host = np.asarray(counts)  # graftlint: disable=GL001 — landed (is_ready): a copy, not a sync
-            self._count_routes(float(host[:n_rows].sum()), tokens)
+            if self.cfg.counts_routes:
+                self._count_routes(float(host[:n_rows].sum()), tokens)
             self._metrics.record_histogram(
                 "app_tpu_moe_expert_load_ratio", float(host[-1]),
                 "model", self.model_name,
@@ -1830,6 +1845,7 @@ class SchedulerMixin:
         self._tokens_dev, self._logps_dev = toks, lps
         self.cache, self._nsteps_dev = cache, nst
         self._pcounts_dev, self._topi_dev, self._topl_dev = pc, ti, tl
+        self._count_moe_product("decode_window", self.n_slots)
         if etops is not None and not any(
             seq is not None and seq.request.top_logprobs
             for seq in self._slots

@@ -634,8 +634,13 @@ def test_the_refusals_outside_the_constructor(engine):
             KVCache.create(3, 2, 128, 4, 24, jnp.float32),
             jnp.ones((2,), bool), CFG,
         )
-    # Mixtral's dense einsum refuses a share instead of computing held x rows
-    assert (CFG.expert_product, SHARE.expert_product) == ("grouped", "grouped")
-    assert get_model("moe-tiny").config.expert_product == "einsum"
+    # Mixtral's stacked layer refuses a share instead of computing held x
+    # rows: a share is "ragged" at every shape, where a stacked all-held
+    # layer picks its product from the step's rows (test_moe_tiled_product)
+    rows = (1, 32, 2048)
+    assert {c.expert_product(r) for c in (CFG, SHARE) for r in rows} == {"ragged"}
+    assert not CFG.experts_stacked and get_model("moe-tiny").config.experts_stacked
+    assert [get_model("moe-tiny").config.expert_product(r) for r in rows] == [
+        "einsum", "einsum", "tiles"]
     with pytest.raises(ValueError, match="go through _ffn_moe_grouped"):
         _ffn_moe(jnp.zeros((1, 4, CFG.d_model)), {}, SHARE)
