@@ -396,6 +396,33 @@ class Container:
             "even load)",
             (1.0, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0, 16.0, 64.0, 256.0),
         )
+        # A hybrid stack (sparse attention + lightning layers;
+        # docs/advanced-guide/hybrid-sparse-linear-models.md).
+        m.new_counter(
+            "app_tpu_sparse_attn_queries_total",
+            "queries (computed token x sparse layer) of a block-sparse "
+            "attention layer by the branch each took: selected (the chosen "
+            "blocks of keys only) or dense (under the dense length), and by "
+            "program; from counts the steps return beside their tokens",
+        )
+        m.new_histogram(
+            "app_tpu_sparse_attn_read_ratio",
+            "positions attended through the choice of blocks / positions in "
+            "context, over a decode window's live slots past the dense "
+            "length; one record per window that had one", ratio_buckets,
+        )
+        m.new_gauge(
+            "app_tpu_state_bytes_per_slot",
+            "bytes a slot holds whatever its length (the lightning layers' "
+            "float32 states), from the arrays as allocated; beside "
+            "app_tpu_kv_bytes_per_token, which counts what grows with tokens",
+        )
+        m.new_counter(
+            "app_tpu_state_resets_total",
+            "prompts whose first chunk started a slot's fixed-size state "
+            "from zero (a slot admitted again must not see its former "
+            "occupant's)",
+        )
         # Disaggregated prefill/decode tiers (TPU_REPLICA_ROLES;
         # docs/advanced-guide/resilience.md): cross-tier KV-block
         # transfers by outcome, their wall-clock cost, and whether the

@@ -50,6 +50,20 @@ def list_models() -> list[str]:
     return sorted(_REGISTRY)
 
 
+def _kinds(runs: str) -> tuple:
+    """``"s l l"`` -> the mixers' published names, a layer a letter."""
+    from gofr_tpu.models.transformer import LIN_KIND, SPARSE_KIND
+
+    return tuple({"s": SPARSE_KIND, "l": LIN_KIND}[c] for c in runs.split())
+
+
+# MiniCPM-SALA's ``mixer_types`` as published (8 sparse, 24 lightning).
+_SALA_KINDS = _kinds(
+    "s l l l l l l l l s l l l l l l s s l l l l s l l l l l l s s s"
+)
+_SALA_TINY_KINDS = _kinds("s l l s s l l l")
+
+
 def _register_llms() -> None:
     from gofr_tpu.models.transformer import init_transformer
 
@@ -112,14 +126,14 @@ def _register_llms() -> None:
             vocab_size=256000, d_model=3072, n_layers=28, n_heads=16,
             n_kv_heads=16, d_ff=24576, max_len=8192, rope_theta=10000.0,
             norm_eps=1e-6, head_dim_override=256, act="gelu",
-            norm_offset=True, embed_scale=True,
+            norm_offset=True, embed_scale=3072**0.5,
         ),
         # Gemma-2B: MQA (1 kv head), head_dim 256.
         "gemma-2b": TransformerConfig(
             vocab_size=256000, d_model=2048, n_layers=18, n_heads=8,
             n_kv_heads=1, d_ff=16384, max_len=8192, rope_theta=10000.0,
             norm_eps=1e-6, head_dim_override=256, act="gelu",
-            norm_offset=True, embed_scale=True,
+            norm_offset=True, embed_scale=2048**0.5,
         ),
         # ~1.1B config that fits one v5e chip comfortably for benching.
         "llama-1b": TransformerConfig(
@@ -193,6 +207,46 @@ def _register_llms() -> None:
             d_ff_expert=48, n_shared_experts=1, n_dense_layers=1,
             router_score="sigmoid", routed_scale=2.5,
         ),
+        # MiniCPM-SALA (openbmb, config.json, model_type minicpm_sala), the
+        # published sizes whole: 32 layers of two kinds of mixer in the
+        # published order (mixer_types: 8 ``minicpm4`` block-sparse
+        # attention layers, 32 query / 2 kv heads, no rotary values, and 24
+        # ``lightning-attn`` linear-attention layers, 32 heads with a
+        # [128, 128] float32 state a slot), qk norms, output gates, the
+        # family's three scalars. 18.95 GB in bf16: no one chip holds it; it
+        # is served cut in depth (n_layers and layer_kinds overridden;
+        # benchmark/configs/minicpm-sala-d16.json, docs/advanced-guide/
+        # hybrid-sparse-linear-models.md). config.json has no key for the
+        # selection's sizes (MiniCPM4's sparse_config) nor the decay.
+        "minicpm-sala": TransformerConfig(
+            vocab_size=73448, d_model=4096, n_layers=32, n_heads=32,
+            n_kv_heads=2, d_ff=16384, max_len=524288, rope_theta=10000.0,
+            norm_eps=1e-6, head_dim_override=128,
+            layer_kinds=_SALA_KINDS, published_layer_kinds=_SALA_KINDS,
+            lin_heads=32, lin_head_dim=128, qk_norm=True,
+            attn_out_gate=True, lin_out_gate=True, lin_out_norm=True,
+            attn_rope=False, lin_rope=True, embed_scale=12.0,
+            scale_depth=1.4, mup_denominator=32, dim_model_base=256,
+            sparse_kernel=32, sparse_stride=16, sparse_block=64,
+            sparse_topk=64, sparse_init_blocks=1, sparse_window=2048,
+            sparse_dense_len=8192,
+        ),
+        # Its test size: 8 layers of both kinds in irregular runs, a tiny
+        # selection (dense under 32 positions, blocks of 4, top 4 with the
+        # first block and the last 2 forced, compressed keys over 4 keys
+        # every 2) so that every branch is reached within 128 positions.
+        "sala-tiny": TransformerConfig(
+            vocab_size=512, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+            d_ff=128, max_len=256, rope_theta=10000.0, norm_eps=1e-6,
+            head_dim_override=16,
+            layer_kinds=_SALA_TINY_KINDS, published_layer_kinds=_SALA_TINY_KINDS,
+            lin_heads=4, lin_head_dim=16, qk_norm=True, attn_out_gate=True,
+            lin_out_gate=True, lin_out_norm=True, attn_rope=False,
+            lin_rope=True, embed_scale=12.0, scale_depth=1.4,
+            mup_denominator=8, dim_model_base=16, sparse_kernel=4,
+            sparse_stride=2, sparse_block=4, sparse_topk=4,
+            sparse_init_blocks=1, sparse_window=8, sparse_dense_len=32,
+        ),
         # Looped-arch test size: 2 layers run 3 times (6 cache entries),
         # sandwich norms.
         "looped-tiny": TransformerConfig(
@@ -220,7 +274,7 @@ def _register_llms() -> None:
             vocab_size=512, d_model=128, n_layers=2, n_heads=4,
             n_kv_heads=2, d_ff=256, max_len=256, rope_theta=10000.0,
             norm_eps=1e-6, head_dim_override=64, act="gelu",
-            norm_offset=True, embed_scale=True,
+            norm_offset=True, embed_scale=128**0.5,
         ),
     }
     eos_tokens = {"gemma-7b": 1, "gemma-2b": 1, "gemma-tiny": 1,

@@ -39,13 +39,22 @@ from gofr_tpu.ops.attention import (
     latent_chunk_attention,
     latent_decode_attention,
     pad_last,
+    sparse_block_scores,
+    sparse_chunk_attention,
+    sparse_decode_attention,
 )
 from gofr_tpu.ops.kv_cache import (
+    HybridCache,
     KVCache,
     LatentKVCache,
     PagedKVCache,
     fake_quantize_kv,
     quantize_kv,
+)
+from gofr_tpu.ops.linear_attention import (
+    lightning_chunk,
+    lightning_log_decay,
+    lightning_step,
 )
 from gofr_tpu.ops.norms import layer_norm, rms_norm
 from gofr_tpu.ops.rotary import apply_rope, rope_frequencies
@@ -56,6 +65,37 @@ from gofr_tpu.ops.rotary import apply_rope, rope_frequencies
 # int8, above the v5e's ridge (240), so a tile is compute-bound; an expert's
 # run of rows is padded to a multiple of it.
 EXPERT_ROW_TILE = 256
+
+# The two kinds of mixer of a hybrid stack, in the source's own words
+# (MiniCPM-SALA's ``mixer_types``), and where each kind's stacked leaves live.
+SPARSE_KIND = "minicpm4"
+LIN_KIND = "lightning-attn"
+KIND_LEAVES = {SPARSE_KIND: "layers", LIN_KIND: "lin_layers"}
+
+
+# Initial scale of the per-head query and key norms of a hybrid stack. At 1,
+# random weights give scores of unit variance, a softmax over 9,000 keys that
+# is all but uniform, an attention output of |v| / sqrt(3,000), and sparse
+# layers that no comparison could tell from layers left out. At 1.6 the
+# scores' deviation is 2.56 and the softmax as peaked as a trained model's
+# (a few dozen keys carry it), so that WHICH blocks a query picks shows in
+# the logits. A lightning layer's output norm takes the factor out again.
+QK_NORM_INIT = 1.6
+
+
+class Kinds(tuple):
+    """``TransformerConfig.layer_kinds``: a tuple (hashable, so the config
+    stays a static argument) that also equals the JSON list it came from."""
+
+    def __eq__(self, other: object) -> bool:
+        if isinstance(other, list):
+            other = tuple(other)
+        return tuple.__eq__(self, other)
+
+    def __ne__(self, other: object) -> bool:
+        return not self == other
+
+    __hash__ = tuple.__hash__
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,7 +121,9 @@ class TransformerConfig:
     head_dim_override: int = 0
     act: str = "silu"  # "silu" | "gelu" | "gelu_exact"
     norm_offset: bool = False
-    embed_scale: bool = False
+    # What the embedding is multiplied by (0: nothing): sqrt(d_model) for
+    # Gemma, ``scale_emb`` for MiniCPM.
+    embed_scale: float = 0.0
     # GPT-NeoX/Pythia-family switches: LayerNorm (with bias) instead of
     # RMSNorm, x + attn(ln1 x) + mlp(ln2 x) parallel residual, partial
     # rotary (rope on the first rotary_pct of head_dim), a non-gated
@@ -145,6 +187,133 @@ class TransformerConfig:
     expert_share_index: int = 0
     router_score: str = "softmax"  # "softmax" | "sigmoid"
     routed_scale: float = 1.0
+    # A stack of two kinds of mixer (MiniCPM-SALA): ``layer_kinds`` names
+    # each layer's, in order and in the source's words (``mixer_types``):
+    # ``SPARSE_KIND`` layers are grouped-query softmax attention over K and V
+    # planes that, past ``sparse_dense_len`` positions, attends only the
+    # ``sparse_topk`` blocks of ``sparse_block`` keys a query picks by its
+    # scores against compressed keys (the mean of ``sparse_kernel`` keys
+    # every ``sparse_stride``), the first ``sparse_init_blocks`` and the
+    # blocks of the last ``sparse_window`` positions always among them;
+    # ``LIN_KIND`` layers are lightning linear attention, ``lin_heads`` heads
+    # of ``lin_head_dim`` that keep a [head_dim, head_dim] float32 state a
+    # head a slot and no keys (``ops/linear_attention.py``). Empty: every
+    # layer is plain attention. JSON hands a list; it is held as a
+    # ``Kinds`` (a tuple, hashable, that also equals that list).
+    # ``published_layer_kinds``: the source's whole list where
+    # ``layer_kinds`` is a cut of it: a lightning layer's decay follows its
+    # PUBLISHED index (``layer_offset`` onward).
+    layer_kinds: tuple = ()
+    published_layer_kinds: tuple = ()
+    lin_heads: int = 0
+    lin_head_dim: int = 0
+    # RMSNorm over each head's queries and keys (one learned scale a
+    # projection), a sigmoid output gate (``wg``) on each kind of mixer, an
+    # RMSNorm over the lightning heads' joined output, rotary values by kind.
+    qk_norm: bool = False
+    attn_out_gate: bool = False
+    lin_out_gate: bool = False
+    lin_out_norm: bool = False
+    attn_rope: bool = True
+    lin_rope: bool = True
+    # MiniCPM's scalars: every sublayer's output times ``scale_depth /
+    # sqrt(mup_denominator)`` before the residual add, the final hidden
+    # state times ``dim_model_base / d_model`` before the head (0: off).
+    scale_depth: float = 0.0
+    mup_denominator: int = 0
+    dim_model_base: int = 0
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_topk: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
+    sparse_dense_len: int = 0
+
+    def __post_init__(self) -> None:
+        for name in ("layer_kinds", "published_layer_kinds"):
+            object.__setattr__(self, name, Kinds(getattr(self, name)))
+        if self.layer_kinds:
+            self._check_hybrid()
+
+    def _check_hybrid(self) -> None:
+        kinds = self.layer_kinds
+        if len(kinds) != self.n_layers or set(kinds) - {SPARSE_KIND, LIN_KIND}:
+            raise ValueError(
+                f"layer_kinds names {len(kinds)} layers of kinds "
+                f"{sorted(set(kinds))} for n_layers={self.n_layers}: one of "
+                f"{SPARSE_KIND!r}, {LIN_KIND!r} a layer"
+            )
+        if self.is_moe or self.is_latent or self.n_passes > 1:
+            raise ValueError(
+                "a stack of sparse and lightning layers with experts, latent "
+                "attention or passes is not implemented"
+            )
+        k, st, b = self.sparse_kernel, self.sparse_stride, self.sparse_block
+        forced = self.sparse_init_blocks + self.sparse_window // max(b, 1)
+        if not (
+            0 < st <= k and b > 0 and b % st == 0 and self.sparse_window % b == 0
+            and 0 < forced <= self.sparse_topk
+            and self.sparse_dense_len >= max(k, self.sparse_topk * b)
+        ):
+            raise ValueError(
+                f"sparse sizes kernel={k} stride={st} block={b} "
+                f"topk={self.sparse_topk} init_blocks={self.sparse_init_blocks} "
+                f"window={self.sparse_window} dense_len={self.sparse_dense_len}: "
+                "a block is whole strides, the window whole blocks, the "
+                "forced blocks fit the choice, and the choice fits the "
+                "context at which it starts (dense_len >= topk x block)"
+            )
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_kinds)
+
+    @property
+    def layer_runs(self) -> tuple:
+        """The stack as runs of like layers in order: ((kind, count), ...)."""
+        runs: list = []
+        for kind in self.layer_kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return tuple((kind, n) for kind, n in runs)
+
+    @property
+    def n_sparse_layers(self) -> int:
+        return sum(k == SPARSE_KIND for k in self.layer_kinds)
+
+    @property
+    def n_lin_layers(self) -> int:
+        return sum(k == LIN_KIND for k in self.layer_kinds)
+
+    @property
+    def layer_offset(self) -> int:
+        """The published index of the first layer kept: where
+        ``layer_kinds`` first occurs in ``published_layer_kinds`` (0 where
+        nothing is published or it does not occur)."""
+        whole, kept = tuple(self.published_layer_kinds), tuple(self.layer_kinds)
+        for lo in range(len(whole) - len(kept) + 1):
+            if whole[lo:lo + len(kept)] == kept:
+                return lo
+        return 0
+
+    @property
+    def residual_scale(self) -> float:
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / self.mup_denominator**0.5
+
+    @property
+    def logit_scale(self) -> float:
+        return self.dim_model_base / self.d_model if self.dim_model_base else 1.0
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot holds whatever its length: the lightning layers'
+        float32 states."""
+        return self.n_lin_layers * self.lin_heads * self.lin_head_dim**2 * 4
 
     @property
     def is_latent(self) -> bool:
@@ -164,20 +333,33 @@ class TransformerConfig:
 
     @property
     def n_cache_entries(self) -> int:
-        """Leading axis of the KV cache: one entry a layer APPLICATION."""
+        """Leading axis of the KV cache: one entry a layer APPLICATION (of
+        a hybrid stack: a sparse layer; a lightning layer keeps no keys)."""
+        if self.is_hybrid:
+            return self.n_sparse_layers
         return self.n_layers * self.n_passes
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Unquantised cache bytes one token holds: keys and values of
-        every kv head, or for latent attention the one ``cache_row``."""
+        """Unquantised cache bytes one token holds, as the mathematics
+        counts them: keys and values of every kv head, or for latent
+        attention the one ``cache_row``; a hybrid stack's sparse layers add
+        a compressed key every ``sparse_stride`` tokens. (What the arrays
+        as allocated hold a token is the cache's own ``bytes_per_token``,
+        which the engine reports: a latent row is allocated in whole lane
+        tiles.) What a slot holds whatever its length is
+        ``state_bytes_per_slot``."""
         per_entry = (
             self.cache_row if self.is_latent
             else 2 * self.n_kv_heads * self.head_dim
         )
-        return (
-            self.n_cache_entries * per_entry * jnp.dtype(self.dtype).itemsize
-        )
+        total = self.n_cache_entries * per_entry
+        if self.is_hybrid:
+            total += (
+                self.n_cache_entries * self.n_kv_heads * self.head_dim
+                // self.sparse_stride
+            )
+        return total * jnp.dtype(self.dtype).itemsize
 
     @property
     def rope_dims(self) -> int:
@@ -423,6 +605,8 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
     expert layers): leaves of two shapes cannot share one stack. A grouped
     expert layer's held experts are ``experts``, a set of leaves a layer
     (``init_experts``)."""
+    if cfg.is_hybrid:
+        return _init_hybrid(key, cfg)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     dense_init = partial(_dense_init, dtype=cfg.dtype)
     D = cfg.d_model
@@ -456,6 +640,78 @@ def init_transformer(key: jax.Array, cfg: TransformerConfig) -> dict:
     return out
 
 
+def _init_hybrid(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """The weight tree of a stack of two kinds of mixer: each kind's leaves
+    stacked among their own (``KIND_LEAVES``: ``layers`` the sparse
+    attention layers, ``lin_layers`` the lightning ones; their shapes
+    differ, and only a lightning layer has ``out_norm`` and ``log_decay``).
+    ``log_decay`` [lightning layers, heads] float32 is a CONSTANT held beside
+    the weights (``ops/linear_attention.lightning_log_decay`` of each
+    layer's published index): another convention is other numbers there,
+    not other code. A leaf is drawn a layer at a time: drawn whole, a
+    feed-forward leaf's float32 normals (3.2 GB) stand beside the tree.
+    ``assumed`` in the configuration's file: weights random, the head's
+    columns wider by 1 / logit_scale."""
+    k_embed, k_sparse, k_lin, k_head = jax.random.split(key, 4)
+    D, F, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
+    H, KV, Hl, hl = cfg.n_heads, cfg.n_kv_heads, cfg.lin_heads, cfg.lin_head_dim
+
+    @partial(jax.jit, static_argnames=("n", "shape", "fan_in"))
+    def stacked(key, n, shape, fan_in):
+        return jax.lax.map(
+            lambda k: _dense_init(k, shape, fan_in, cfg.dtype),
+            jax.random.split(key, n),
+        )
+
+    def group(key, n, heads, kv_heads, width, gate):
+        ks = jax.random.split(key, 8)
+        ones = lambda *shape: norm_init("", (n, *shape), cfg)  # noqa: E731
+        leaves = {
+            "wq": stacked(ks[0], n, (D, heads * width), D),
+            "wk": stacked(ks[1], n, (D, kv_heads * width), D),
+            "wv": stacked(ks[2], n, (D, kv_heads * width), D),
+            "wo": stacked(ks[3], n, (heads * width, D), heads * width),
+            "w_gate": stacked(ks[4], n, (D, F), D),
+            "w_up": stacked(ks[5], n, (D, F), D),
+            "w_down": stacked(ks[6], n, (F, D), F),
+            "attn_norm": ones(D), "mlp_norm": ones(D),
+        }
+        if cfg.qk_norm:
+            leaves.update(
+                q_norm=ones(width) * QK_NORM_INIT, k_norm=ones(width) * QK_NORM_INIT
+            )
+        if gate:
+            leaves["wg"] = stacked(ks[7], n, (D, heads * width), D)
+        return leaves
+
+    sparse = group(
+        k_sparse, cfg.n_sparse_layers, H, KV, hd, cfg.attn_out_gate
+    )
+    lin = group(k_lin, cfg.n_lin_layers, Hl, Hl, hl, cfg.lin_out_gate)
+    if cfg.lin_out_norm:
+        lin["out_norm"] = norm_init("", (cfg.n_lin_layers, Hl * hl), cfg)
+    published = [
+        cfg.layer_offset + i for i, kind in enumerate(cfg.layer_kinds)
+        if kind == LIN_KIND
+    ]
+    lin["log_decay"] = lightning_log_decay(
+        Hl, published, len(cfg.published_layer_kinds) or cfg.n_layers
+    )
+    return {
+        "embed": _dense_init(k_embed, (cfg.vocab_size, D), D, cfg.dtype),
+        KIND_LEAVES[SPARSE_KIND]: sparse,
+        KIND_LEAVES[LIN_KIND]: lin,
+        "final_norm": norm_init("final_norm", (D,), cfg),
+        # Drawn 1 / logit_scale wider than another model's random head: the
+        # hidden state reaches it scaled by dim_model_base / d_model (1/16),
+        # and logits of variance 1/256 are a uniform distribution that no
+        # comparison with the reference could tell from any other.
+        "lm_head": _dense_init(
+            k_head, (D, cfg.vocab_size), D * cfg.logit_scale**2, cfg.dtype
+        ),
+    }
+
+
 def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
     """PartitionSpecs over logical axes ('dp', 'tp', optionally 'pp') for
     every param leaf.
@@ -468,6 +724,12 @@ def transformer_param_specs(cfg: TransformerConfig, pp: bool = False) -> dict:
     shards over the pipeline axis — each stage owns a contiguous slice of
     layers (see ``parallel/pipeline.py``).
     """
+    if cfg.is_hybrid:
+        raise ValueError(
+            "a stack of sparse and lightning layers has no partition specs: "
+            "serving it over a mesh (TPU_TP > 1, pipeline stages) is not "
+            "implemented"
+        )
     if pp and cfg.n_passes > 1:
         raise ValueError(
             f"pipeline-parallel parameter specs are not implemented for a "
@@ -809,6 +1071,8 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
     may be any pytree a body carries (the latent prefill body carries the
     cache plane beside the stream, and its ys are small).
     """
+    if cfg.is_hybrid:
+        return _scan_runs(body, x, params, cfg)
     groups = [params[g] for g in ("dense_layers", "layers") if g in params]
     if "experts" in params:
         # A grouped expert layer finds its own experts' leaves by its
@@ -854,12 +1118,57 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
     return jax.lax.scan(step, x, (jnp.arange(n), tuple(cache_xs)))
 
 
+def _scan_runs(bodies, carry, params, cfg):
+    """A stack whose layers of two kinds interleave in an order of their own
+    (``cfg.layer_runs``: MiniCPM-SALA's 16 kept layers are 6 runs: 1, 6, 2,
+    4, 1, 2): a sequence of runs ``(kind, count)``, each ONE ``lax.scan`` over
+    the run's indices among its kind's stacked leaves
+    (``params[KIND_LEAVES[kind]]``). ``bodies[kind](carry, lp, entry) ->
+    (carry, ys)``: ``entry`` is the layer's index among the layers of its
+    kind, by which each kind indexes its own state in the cache (the carry
+    holds the planes that a layer writes). A layer's leaves are taken out of
+    the stack by ``dynamic_index_in_dim`` inside the scan's body, which is
+    what a scan does with its xs, so each fuses into the product that reads
+    it: a static slice ``leaf[lo:hi]`` handed to the scan would copy the
+    run's weights out of the stack at every step. Returns (carry, {kind: ys
+    joined over that kind's layers, in their order})."""
+    seen = dict.fromkeys(KIND_LEAVES, 0)
+    joined: dict = {kind: [] for kind in KIND_LEAVES}
+    for kind, count in cfg.layer_runs:
+        leaves, body = params[KIND_LEAVES[kind]], bodies[kind]
+
+        def step(carry, entry, leaves=leaves, body=body):
+            lp = jax.tree.map(
+                lambda w: jax.lax.dynamic_index_in_dim(
+                    w, entry, 0, keepdims=False
+                ),
+                leaves,
+            )
+            return body(carry, lp, entry)
+
+        carry, ys = jax.lax.scan(
+            step, carry, seen[kind] + jnp.arange(count)
+        )
+        joined[kind].append(ys)
+        seen[kind] += count
+    return carry, {
+        kind: jax.tree.map(lambda *ys: jnp.concatenate(ys), *runs)
+        for kind, runs in joined.items() if runs
+    }
+
+
 # The serving steps below run under ``jax.named_scope`` with a fixed
 # vocabulary — embed, attn, kv_commit, ffn, moe_router, moe_experts,
 # lm_head here, pass and pass_norm around them in a looped stack, mla_q and
 # mla_kv (latent attention's projections; attn is then the scores and the
 # weighted sum, the value up-projection and wo), moe_dispatch (sorting the
-# routes by expert and back) and moe_shared in a grouped expert layer; sample
+# routes by expert and back) and moe_shared in a grouped expert layer; in a
+# hybrid stack lin_qkv, lin_scan (the chunk-wise product or the recurrence's
+# step, and the state's update) and lin_out (norm, gate, wo) of a lightning
+# layer, sparse_index (compressed keys' scores and the choice of blocks) and
+# sparse_gather (the decode step's gather of the chosen blocks) of a sparse
+# one, whose attn is the attention over what was chosen, its gate and wo,
+# and kv_commit K, V, compressed keys and state; sample
 # in serving/programs.py — so that an op in the profiler's trace says which
 # part of the model it belongs to (its ``tf_op`` reads
 # ``jit(decode_window)/…/attn/dot_general``). Compile-time metadata only: no
@@ -868,14 +1177,14 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
 
 @jax.named_scope("embed")
 def _embed(params, tokens, cfg, positions=None):
-    """Token embedding lookup; Gemma scales by sqrt(d_model) — the scalar
-    is cast to the activation dtype first (HF casts the normalizer to the
-    hidden dtype, and bf16 parity needs the same rounding). Learned
-    position embeddings (GPT-2) add the position table here; rope models
-    ignore ``positions``."""
+    """Token embedding lookup; ``cfg.embed_scale`` (Gemma: sqrt(d_model);
+    MiniCPM: ``scale_emb``) multiplies it — the scalar is cast to the
+    activation dtype first (HF casts the normalizer to the hidden dtype, and
+    bf16 parity needs the same rounding). Learned position embeddings
+    (GPT-2) add the position table here; rope models ignore ``positions``."""
     x = params["embed"][tokens]
     if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model**0.5, dtype=x.dtype)
+        x = x * jnp.asarray(cfg.embed_scale, dtype=x.dtype)
     if cfg.pos_emb == "learned":
         pos = jnp.clip(positions, 0, params["pos_embed"].shape[0] - 1)
         x = x + params["pos_embed"][pos]
@@ -1277,6 +1586,15 @@ def _mla_full(h, lp, cfg, cos, sin, positions):
         return _mla_out(o, lp, cfg, absorbed=False)
 
 
+def _final_norm(x, params, cfg):
+    """The norm before the head, and MiniCPM's ``dim_model_base / d_model``
+    on its output where the config has one."""
+    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
+    if cfg.logit_scale != 1.0:
+        x = x * jnp.asarray(cfg.logit_scale, dtype=x.dtype)
+    return x
+
+
 @jax.named_scope("lm_head")
 def _lm_head(eq, x, params):
     return _wein(eq, x, params["lm_head"]).astype(jnp.float32)
@@ -1383,6 +1701,13 @@ def transformer_forward(
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     x = _embed(params, tokens, cfg, positions)
+    if cfg.is_hybrid:
+        if remat or aids is not None:
+            raise ValueError(
+                "a stack of sparse and lightning layers runs the plain full "
+                "forward only: no remat, no LoRA"
+            )
+        return _hybrid_forward(params, x, positions, cfg)
     cos, sin = rope_frequencies(cfg.rope_dims, s, cfg.rope_theta)
 
     def body(x, scanned):
@@ -1399,6 +1724,24 @@ def transformer_forward(
     return _lm_head("bsd,dv->bsv", x, params)
 
 
+def _hybrid_forward(params, x, positions, cfg):
+    """The full forward of a hybrid stack: the chunk step's layers over one
+    chunk that is the whole sequence, each sequence a slot of a cache made
+    for the call (the test-only path: the chunk-wise form over the whole
+    sequence is quadratic in it)."""
+    b, s, _ = x.shape
+    unit = max(cfg.sparse_block, cfg.sparse_stride)
+    max_len = -(-s // unit) * unit
+    if max_len > 512:  # whole steps of sparse_chunk_attention's loop
+        max_len = -(-max_len // 512) * 512
+    cache = HybridCache.for_config(cfg, b, max_len)
+    x, _, _ = _hybrid_chunk_layers(
+        params, x, cache, jnp.arange(b), jnp.zeros((b,), jnp.int32),
+        jnp.full((b,), s, jnp.int32), positions, cfg, jnp.ones((b,), bool),
+    )
+    return _lm_head("bsd,dv->bsv", _final_norm(x, params, cfg), params)
+
+
 def transformer_prefill(
     params: dict,
     tokens: jnp.ndarray,
@@ -1413,10 +1756,11 @@ def transformer_prefill(
 
     tokens: [b, s_pad]; lengths: [b] true lengths; slots: [b] cache slots.
     """
-    if cfg.is_latent:
+    if cfg.is_latent or cfg.is_hybrid:
         raise ValueError(
-            "latent attention fills its cache by transformer_prefill_chunk "
-            "only: the unchunked prefill writes K and V planes"
+            "latent attention and a stack of sparse and lightning layers "
+            "fill their caches by transformer_prefill_chunk only: the "
+            "unchunked prefill writes K and V planes"
         )
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -1504,11 +1848,21 @@ def transformer_prefill_chunk(
     mark as padding. A grouped stacked expert layer does not multiply the
     other tokens at all (their outputs are the caller's to drop).
     sharded: the weights lie on a mesh (``cfg.expert_product``).
+    A hybrid stack (``cfg.is_hybrid``) honours ``row_valid`` always (a
+    padding row writes nothing: its state would not be row 0's own write),
+    and its ``stats`` are [P] int32: each row's valid queries past
+    ``sparse_dense_len`` (``_hybrid_chunk_layers``).
     """
     P, c = tokens.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = starts[:, None] + jnp.arange(c)[None, :]  # [P, c] global
     x = _embed(params, tokens, cfg, positions)  # [P, c, D]
+    if cfg.is_hybrid:
+        x, cache, counts = _hybrid_chunk_layers(
+            params, x, cache, slots, starts, lens, positions, cfg,
+            jnp.ones((P,), bool) if row_valid is None else row_valid,
+        )
+        return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
     cos, sin = rope_frequencies(cfg.rope_dims, cache.max_len, cfg.rope_theta)
     paged = isinstance(cache, PagedKVCache)
     # The product of a stacked expert layer, from this step's shape.
@@ -1625,12 +1979,14 @@ def transformer_prefill_chunk(
 def _chunk_logits(params, x, lens, cache, counts, cfg, stats):
     """The chunk step's way out: final norm, the head at each row's last
     valid token, and the route counts where they were asked for."""
-    x = _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
+    x = _final_norm(x, params, cfg)
     last_idx = jnp.maximum(lens - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
     logits = _lm_head("pd,dv->pv", x_last, params)
     if not stats:
         return logits, cache
+    if cfg.is_hybrid:  # the layers' counts are the step's own
+        return logits, cache, counts
     return logits, cache, None if counts is None else route_stats(counts)
 
 
@@ -1699,6 +2055,342 @@ def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
     return x, cache._replace(k=plane), counts
 
 
+# ---------------------------------------------------------------------------
+# a stack of sparse attention and lightning layers (MiniCPM-SALA)
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_cache(cache, cfg):
+    if not isinstance(cache, HybridCache):
+        raise ValueError(
+            "a stack of sparse and lightning layers is served over a "
+            "HybridCache (K, V and compressed keys of the sparse layers, a "
+            f"state a lightning layer); got {type(cache).__name__}"
+        )
+    return cache
+
+
+def _head_norm(x, w, cfg):
+    """``qk_norm``: RMSNorm over each head's values, one learned scale a
+    projection."""
+    return rms_norm(x, w, cfg.norm_eps) if cfg.qk_norm else x
+
+
+def _hybrid_qkv(h, lp, eq, heads, kv_heads, width, lead, cfg, rope):
+    """A hybrid layer's queries, keys and values: the projections into
+    ``heads`` / ``kv_heads`` heads of ``width``, the per-head norms, and
+    rotary values where ``rope`` = (cos, sin, positions [lead[0], s]) is
+    given (a decode step's ``h`` [S, D] has s = 1)."""
+    q, k, v = _qkv(h, lp, eq, heads, kv_heads, width, *lead)
+    q = _head_norm(q, lp.get("q_norm"), cfg)
+    k = _head_norm(k, lp.get("k_norm"), cfg)
+    if rope is not None:
+        cos, sin, positions = rope
+        if len(lead) == 1:  # one token a slot
+            q = apply_rope(q[:, None], cos, sin, positions)[:, 0]
+            k = apply_rope(k[:, None], cos, sin, positions)[:, 0]
+        else:
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+    return q, k, v
+
+
+def _gated_out(o, h, lp, eq_in, eq_out, gate):
+    """``(o * sigmoid(h wg)) wo``: the mixer's output gate and projection."""
+    if gate:
+        o = o * jax.nn.sigmoid(_wein(eq_in, h, lp["wg"]))
+    return _wein(eq_out, o, lp["wo"])
+
+
+def _hybrid_ffn(x, lp, cfg):
+    h = _norm(x, lp["mlp_norm"], cfg)
+    return x + cfg.residual_scale * _ffn_dense(h, lp, cfg)
+
+
+def _hybrid_ropes(cfg, max_len, positions):
+    """``_hybrid_qkv``'s ``rope`` for each kind of layer: (cos, sin,
+    positions), or None for a kind that takes no rotary values."""
+    def tables(on, width):
+        if not on:
+            return None
+        return (*rope_frequencies(width, max_len, cfg.rope_theta), positions)
+    return tables(cfg.attn_rope, cfg.head_dim), tables(cfg.lin_rope, cfg.lin_head_dim)
+
+
+def _sparse_sizes(cfg):
+    return dict(
+        kernel=cfg.sparse_kernel, stride=cfg.sparse_stride,
+        block=cfg.sparse_block, init_blocks=cfg.sparse_init_blocks,
+        window=cfg.sparse_window, scale=cfg.head_dim**-0.5,
+    )
+
+
+def _hybrid_chunk_layers(params, x, cache, slots, starts, lens, positions,
+                         cfg, row_valid):
+    """The chunk step's layer stack over a ``HybridCache``, run by run
+    (``_scan_runs``). Every plane rides the scans' CARRY and a layer writes
+    its own entry in place, as the latent plane does: as xs and ys a plane
+    would stand twice while the step runs.
+
+    A sparse layer writes the chunk's keys and values, then the compressed
+    keys of the windows that END in this chunk (means over keys read back
+    from the K plane, the first of them reaching ``kernel - stride`` keys
+    into the chunk before), then picks each query's blocks (only if some
+    query of the step lies past ``sparse_dense_len``: below it every causal
+    block is allowed) and attends over blocks of positions under that mask.
+    A lightning layer reads each row's state (zeros where the row starts a
+    prompt: the reset of a slot admitted again), runs the chunk-wise form
+    over the row's valid positions and writes the state back. A padding row
+    (``row_valid`` False) writes nothing: its K, V and compressed keys go
+    past the end and its state to a slot that is not there, where a scatter
+    drops them. Returns (x, cache, [P] int32: each row's valid queries that
+    lie past the dense length, which each sparse layer ran through the
+    choice)."""
+    cache = _hybrid_cache(cache, cfg)
+    P, c, _ = x.shape
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    Hl, hl = cfg.lin_heads, cfg.lin_head_dim
+    S, max_len, M = cache.n_slots, cache.max_len, cache.ck.shape[3]
+    r = cfg.residual_scale
+    attn_rope, lin_rope = _hybrid_ropes(cfg, max_len, positions)
+    sizes = _sparse_sizes(cfg)
+    kernel, stride = cfg.sparse_kernel, cfg.sparse_stride
+    valid_lens = jnp.where(row_valid, lens, 0)
+    live = (jnp.arange(c)[None, :] < lens[:, None]) & row_valid[:, None]
+    selected = live & (positions >= cfg.sparse_dense_len)  # [P, c]
+    write_pos = jnp.where(row_valid[:, None], positions, max_len)
+    state_slot = jnp.where(row_valid, slots, S)
+    idx_row = slots[:, None, None]
+    idx_kv = jnp.arange(KV)[None, :, None]
+    # The compressed keys whose windows end in (start, start + len].
+    n_w = c // stride + 1
+    m = jnp.maximum((starts - kernel) // stride + 1, 0)[:, None] + jnp.arange(n_w)
+    w_pos = jnp.minimum(
+        m[:, :, None] * stride + jnp.arange(kernel), max_len - 1
+    )  # [P, n_w, kernel]
+    w_done = (m * stride + kernel <= (starts + lens)[:, None]) & row_valid[:, None]
+    w_row = jnp.where(w_done, m, M)  # [P, n_w]
+
+    def sparse_layer(carry, lp, entry):
+        x, k_pl, v_pl, ck_pl, state = carry
+        with jax.named_scope("attn"):
+            h = _norm(x, lp["attn_norm"], cfg)
+            q, k, v = _hybrid_qkv(
+                h, lp, "pcd,dh->pch", H, KV, hd, (P, c), cfg, attn_rope
+            )
+        with jax.named_scope("kv_commit"):
+            k_pl = k_pl.at[entry, idx_row, idx_kv, write_pos[:, None, :]].set(
+                k.transpose(0, 2, 1, 3)
+            )
+            v_pl = v_pl.at[entry, idx_row, idx_kv, write_pos[:, None, :]].set(
+                v.transpose(0, 2, 1, 3)
+            )
+            keys = k_pl[
+                entry, slots[:, None, None, None], idx_kv[..., None],
+                w_pos[:, None],
+            ]  # [P, KV, n_w, kernel, hd]
+            ck_pl = ck_pl.at[entry, idx_row, idx_kv, w_row[:, None, :]].set(
+                jnp.mean(keys.astype(jnp.float32), axis=3).astype(ck_pl.dtype)
+            )
+        with jax.named_scope("sparse_index"):
+
+            def choose():
+                ck = jax.lax.dynamic_index_in_dim(
+                    ck_pl, entry, 0, keepdims=False
+                )[slots]
+                scores = sparse_block_scores(q, ck, positions, **sizes)
+                # The top-k SET as a mask without a scatter. Adjacent blocks
+                # tie exactly whenever one window overlaps both, and top_k
+                # takes the lower index first: so of the blocks that tie
+                # with the k-th, those up to the k-th's own index.
+                top, at = jax.lax.top_k(scores, cfg.sparse_topk)
+                b = jnp.arange(scores.shape[-1])
+                picked = (scores > top[..., -1:]) | (
+                    (scores == top[..., -1:]) & (b <= at[..., -1:])
+                )
+                return picked | ~selected[:, None, :, None]
+
+            allowed = jax.lax.cond(
+                jnp.any(selected), choose,
+                lambda: jnp.ones(
+                    (P, KV, c, max_len // cfg.sparse_block), bool
+                ),
+            )
+        with jax.named_scope("attn"):
+            o = sparse_chunk_attention(
+                q, k_pl, v_pl, slots, starts, lens, allowed,
+                sel_block=cfg.sparse_block, scale=sizes["scale"], layer=entry,
+            ).reshape(P, c, H * hd)
+            x = x + r * _gated_out(
+                o, h, lp, "pcd,dh->pch", "pch,hd->pcd", cfg.attn_out_gate
+            )
+        return (_hybrid_ffn(x, lp, cfg), k_pl, v_pl, ck_pl, state), None
+
+    def lin_layer(carry, lp, entry):
+        x, k_pl, v_pl, ck_pl, state = carry
+        with jax.named_scope("lin_qkv"):
+            h = _norm(x, lp["attn_norm"], cfg)
+            q, k, v = _hybrid_qkv(
+                h, lp, "pcd,dh->pch", Hl, Hl, hl, (P, c), cfg, lin_rope
+            )
+        with jax.named_scope("lin_scan"):
+            before = jax.lax.dynamic_index_in_dim(
+                state, entry, 0, keepdims=False
+            )[slots]
+            before = jnp.where((starts == 0)[:, None, None, None], 0.0, before)
+            o, after = lightning_chunk(
+                q, k, v, before, lp["log_decay"], valid_lens, hl**-0.5
+            )
+        with jax.named_scope("kv_commit"):
+            state = state.at[entry, state_slot].set(after)
+        with jax.named_scope("lin_out"):
+            o = o.reshape(P, c, Hl * hl)
+            if cfg.lin_out_norm:
+                o = rms_norm(o, lp["out_norm"], cfg.norm_eps)
+            x = x + r * _gated_out(
+                o, h, lp, "pcd,dh->pch", "pch,hd->pcd", cfg.lin_out_gate
+            )
+        return (_hybrid_ffn(x, lp, cfg), k_pl, v_pl, ck_pl, state), None
+
+    (x, k_pl, v_pl, ck_pl, state), _ = _scan_stack(
+        {SPARSE_KIND: sparse_layer, LIN_KIND: lin_layer},
+        (x, cache.k, cache.v, cache.ck, cache.state), params, cfg,
+    )
+    cache = cache._replace(k=k_pl, v=v_pl, ck=ck_pl, state=state)
+    return x, cache, jnp.sum(selected, axis=1).astype(jnp.int32)
+
+
+def _hybrid_decode_layers(params, x, cache, active, cfg):
+    """The decode step's layer stack over a ``HybridCache``. The K, V and
+    compressed-key planes stay READ-ONLY inside the scans and one scatter
+    commits every sparse layer's token after them, as for every other cache;
+    the lightning state rides the carry and each layer updates its own entry
+    in place (an inactive slot's stays as it was).
+
+    A sparse layer, for a slot at position t: under ``sparse_dense_len`` the
+    bounded dense read of the slot's prefix (``decode_attention``'s rungs,
+    by the longest ACTIVE slot that is under it; skipped when none is); from
+    it on, scores against the slot's compressed keys (with the one that this
+    token completes, not yet in the plane), the choice of ``sparse_topk``
+    blocks a kv head and a gather of those blocks only (skipped when no
+    active slot is past it). Returns (x, state, (k, v, ck rows of the
+    sparse layers' token, [S] each), ck's row index [S], (attended, context)
+    [S] float32 each: the positions a slot's query attended through the
+    choice, 0 where it took the dense read, and its context)."""
+    cache = _hybrid_cache(cache, cfg)
+    S, max_len, M = cache.n_slots, cache.max_len, cache.ck.shape[3]
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    Hl, hl = cfg.lin_heads, cfg.lin_head_dim
+    r = cfg.residual_scale
+    positions = cache.lengths
+    pos2 = positions[:, None]
+    attn_rope, lin_rope = _hybrid_ropes(cfg, max_len, pos2)
+    sizes = _sparse_sizes(cfg)
+    kernel, stride = cfg.sparse_kernel, cfg.sparse_stride
+    selected = active & (positions >= cfg.sparse_dense_len)
+    dense = active & ~selected
+    read = decode_read_index(
+        decode_read_rungs(max_len), jnp.max(jnp.where(dense, positions, 0))
+    )
+    # The compressed key that this token completes: window m_new ends at it.
+    m_new = (positions + 1 - kernel) // stride
+    has_new = ((positions + 1 - kernel) % stride == 0) & (m_new >= 0) & active
+    w_pos = jnp.clip(
+        (positions + 1 - kernel)[:, None] + jnp.arange(kernel - 1),
+        0, max_len - 1,
+    )  # [S, kernel - 1]: the window's keys before this one
+    s_idx = jnp.arange(S)[:, None, None]
+    g_idx = jnp.arange(KV)[None, :, None]
+
+    def sparse_layer(carry, lp, entry):
+        x, state = carry
+        with jax.named_scope("attn"):
+            h = _norm(x[:, None, :], lp["attn_norm"], cfg)[:, 0]
+            q, k, v = _hybrid_qkv(
+                h, lp, "bd,dh->bh", H, KV, hd, (S,), cfg, attn_rope
+            )
+        with jax.named_scope("sparse_index"):
+            before = cache.k[entry, s_idx, g_idx, w_pos[:, None, :]]
+            ck_new = (
+                (jnp.sum(before.astype(jnp.float32), axis=2)
+                 + k.astype(jnp.float32)) / kernel
+            ).astype(cache.ck.dtype)  # [S, KV, hd]
+
+        def chosen_blocks():
+            with jax.named_scope("sparse_index"):
+                ck = jax.lax.dynamic_index_in_dim(
+                    cache.ck, entry, 0, keepdims=False
+                )
+                scores = sparse_block_scores(
+                    q[:, None], ck, pos2, **sizes,
+                    new=(ck_new, m_new, has_new),
+                )[:, :, 0]  # [S, KV, n_blocks]
+                chosen = jax.lax.top_k(scores, cfg.sparse_topk)[1]
+            with jax.named_scope("sparse_gather"):
+                return sparse_decode_attention(
+                    q, cache.k, cache.v, chosen, positions, k, v,
+                    sel_block=cfg.sparse_block, layer=entry,
+                    scale=sizes["scale"],
+                )
+
+        def dense_read():
+            return decode_attention(
+                q, cache.k, cache.v, positions, k_new=k, v_new=v,
+                kernel=False, layer=entry, read=read,
+            )
+
+        with jax.named_scope("attn"):
+            o_sel, attended = jax.lax.cond(
+                jnp.any(selected), chosen_blocks,
+                lambda: (jnp.zeros_like(q), jnp.zeros((S,), jnp.int32)),
+            )
+            o_dense = jax.lax.cond(
+                jnp.any(dense), dense_read, lambda: jnp.zeros_like(q)
+            )
+            o = jnp.where(selected[:, None, None], o_sel, o_dense)
+            x = x + r * _gated_out(
+                o.reshape(S, H * hd), h, lp, "bd,dh->bh", "bh,hd->bd",
+                cfg.attn_out_gate,
+            )
+        x = _hybrid_ffn(x[:, None, :], lp, cfg)[:, 0]
+        return (x, state), (k, v, ck_new, jnp.where(selected, attended, 0))
+
+    def lin_layer(carry, lp, entry):
+        x, state = carry
+        with jax.named_scope("lin_qkv"):
+            h = _norm(x[:, None, :], lp["attn_norm"], cfg)[:, 0]
+            q, k, v = _hybrid_qkv(
+                h, lp, "bd,dh->bh", Hl, Hl, hl, (S,), cfg, lin_rope
+            )
+        with jax.named_scope("lin_scan"):
+            o, after = lightning_step(
+                q, k, v,
+                jax.lax.dynamic_index_in_dim(state, entry, 0, keepdims=False),
+                lp["log_decay"], active, hl**-0.5,
+            )
+        with jax.named_scope("kv_commit"):
+            state = jax.lax.dynamic_update_index_in_dim(state, after, entry, 0)
+        with jax.named_scope("lin_out"):
+            o = o.reshape(S, Hl * hl)
+            if cfg.lin_out_norm:
+                o = rms_norm(o, lp["out_norm"], cfg.norm_eps)
+            x = x + r * _gated_out(
+                o, h, lp, "bd,dh->bh", "bh,hd->bd", cfg.lin_out_gate
+            )
+        return (_hybrid_ffn(x[:, None, :], lp, cfg)[:, 0], state), None
+
+    (x, state), ys = _scan_stack(
+        {SPARSE_KIND: sparse_layer, LIN_KIND: lin_layer},
+        (x, cache.state), params, cfg,
+    )
+    new_k, new_v, new_ck, attended = ys[SPARSE_KIND]
+    counts = (
+        attended[0].astype(jnp.float32),
+        jnp.where(selected, positions + 1, 0).astype(jnp.float32),
+    )
+    return x, state, (new_k, new_v, new_ck), jnp.where(has_new, m_new, M), counts
+
+
 def transformer_decode_step(
     params: dict,
     tokens: jnp.ndarray,
@@ -1734,12 +2426,32 @@ def transformer_decode_step(
     sharded: the weights lie on a mesh (``cfg.expert_product``: a stacked
     expert layer picks its product from the slot count, as a prefill step
     does from its rows; an inactive slot's row is then not multiplied).
+    A hybrid stack's ``stats`` are ([S], [S]) float32: the positions each
+    slot's query attended through the choice of blocks (0 where it took the
+    dense read) and its context (``_hybrid_decode_layers``).
     """
     S = cache.n_slots
     L = cfg.n_cache_entries  # a looped stack commits every pass's entry
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = cache.lengths  # [S] — write position for each slot's new token
     x = _embed(params, tokens, cfg, positions)  # [S, D]
+    if cfg.is_hybrid:
+        x, state, (new_k, new_v, new_ck), ck_row, counts = (
+            _hybrid_decode_layers(params, x, cache, active, cfg)
+        )
+        with jax.named_scope("kv_commit"):
+            li = jnp.arange(L)[:, None, None]
+            si = jnp.arange(S)[None, :, None]
+            ki = jnp.arange(KV)[None, None, :]
+            wp = jnp.where(active, positions, cache.max_len - 1)[None, :, None]
+            cache = cache._replace(
+                k=cache.k.at[li, si, ki, wp].set(new_k),
+                v=cache.v.at[li, si, ki, wp].set(new_v),
+                ck=cache.ck.at[li, si, ki, ck_row[None, :, None]].set(new_ck),
+                state=state,
+                lengths=cache.lengths + active.astype(jnp.int32),
+            )
+        return _decode_logits(params, x, cache, counts, cfg, stats)
     cos, sin = rope_frequencies(cfg.rope_dims, cache.max_len, cfg.rope_theta)
 
     # Inactive slots must not write at their stale ``lengths`` position: a
@@ -1903,10 +2615,12 @@ def transformer_decode_step(
 def _decode_logits(params, x, cache, counts, cfg, stats):
     """The decode step's way out: final norm, the head, and each slot's
     held routes where they were asked for."""
-    x = _norm(x[:, None, :], params["final_norm"], cfg, params.get("final_norm_b"))[:, 0]
+    x = _final_norm(x[:, None, :], params, cfg)[:, 0]
     logits = _lm_head("bd,dv->bv", x, params)
     if not stats:
         return logits, cache
+    if cfg.is_hybrid:
+        return logits, cache, counts
     return logits, cache, None if counts is None else route_stats(counts)[0]
 
 

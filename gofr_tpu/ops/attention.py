@@ -237,7 +237,9 @@ def decode_read_plan(
     cache runs, the one whole read where the kernel or a paged pool does
     (the host's counter and the device's program ask the same function).
     A latent cache is always read on the dense bounded path
-    (``latent_decode_attention``), whatever ``max_len``."""
+    (``latent_decode_attention``), whatever ``max_len``, and so are a
+    hybrid cache's sparse layers for the slots under their dense length
+    (``latent`` stands for both: the dense path is forced)."""
     if latent:
         return decode_read_rungs(max_len)
     window = _effective_window(window, max_len, None)
@@ -691,6 +693,278 @@ def cache_chunk_attention(
         (t[None, :] < lens[:, None])[:, :, None, None, None], out, 0.0
     )
     return out.reshape(P, c, n_heads, hd)
+
+
+# ---------------------------------------------------------------------------
+# block-sparse attention selected per query (MiniCPM-SALA's ``minicpm4`` layers)
+# ---------------------------------------------------------------------------
+
+# Positions a step of ``sparse_chunk_attention``'s loop scores at once: the
+# float32 scores of [rows, heads, chunk, positions] are 8 x 32 x 256 x 512 x
+# 4 B = 134 MB, where all 32,768 positions of a slot would be 8.6 GB.
+SPARSE_CHUNK_BLOCK = 512
+
+
+def sparse_block_scores(
+    q: jnp.ndarray,
+    ck: jnp.ndarray,
+    pos: jnp.ndarray,
+    *,
+    kernel: int,
+    stride: int,
+    block: int,
+    init_blocks: int,
+    window: int,
+    scale: float,
+    new: tuple | None = None,
+) -> jnp.ndarray:
+    """The score of every block of keys for every query, one set a kv head:
+    what the choice of blocks is made from.
+
+    q: [P, c, n_heads, hd]; ck: [P, n_kv, M, hd] the compressed keys of each
+    row's slot (row m the mean of keys [m stride, m stride + kernel)); pos:
+    [P, c] the queries' positions. A window counts for a query at position t
+    once it is complete within the context: m stride + kernel <= t + 1. Per
+    query head, a softmax over the windows that count of ``q . ck * scale``;
+    summed over the kv head's query heads; a block's score is the largest
+    of the windows that overlap it; the first ``init_blocks`` blocks and the
+    ``window // block`` blocks that end with the query's own score +inf, a
+    block that no window that counts overlaps -inf. ``new``: (row [P, n_kv,
+    hd], m [P], has [P] bool) a compressed key that completes with this
+    token and is not in ``ck`` yet (the decode step's, which commits after
+    its layers). Returns [P, n_kv, c, M * stride // block] float32.
+    """
+    P, c, n_heads, hd = q.shape
+    n_kv, M = ck.shape[1], ck.shape[2]
+    per_block = block // stride
+    n_blocks = M // per_block
+    m = jnp.arange(M)
+    counts = (m * stride + kernel)[None, None, :] <= (pos + 1)[:, :, None]
+    qg = q.reshape(P, c, n_kv, n_heads // n_kv, hd)
+
+    def shared_by(qg, ck, new_rows):
+        """qg [P, c, G, rep, hd], ck [P, G, M, hd], new_rows [P, G, hd] ->
+        [P, G, c, M]: per query head a softmax over the windows that count,
+        summed over each kv head's query heads."""
+        s = jnp.einsum(
+            "pcgrd,pgmd->pgrcm", qg, ck, preferred_element_type=jnp.float32
+        )
+        if new is not None:
+            s_new = jnp.einsum(
+                "pcgrd,pgd->pgrc", qg, new_rows,
+                preferred_element_type=jnp.float32,
+            )
+            is_new = (m[None, :] == new[1][:, None]) & new[2][:, None]  # [P, M]
+            s = jnp.where(is_new[:, None, None, None, :], s_new[..., None], s)
+        s = jnp.where(counts[:, None, None], s * scale, NEG_INF)
+        p = jnp.where(counts[:, None, None], jax.nn.softmax(s, axis=-1), 0.0)
+        return jnp.sum(p, axis=2)
+
+    new_rows = jnp.zeros((P, n_kv, hd), q.dtype) if new is None else new[0]
+    if c == 1:  # a decode step: every kv head at once, the planes as they lie
+        shared = shared_by(qg, ck, new_rows)
+    else:  # a kv head at a time: [P, rep, c, M] float32 alive, not all heads'
+        shared = jax.lax.map(
+            lambda g: shared_by(
+                jax.lax.dynamic_slice_in_dim(qg, g, 1, 2),
+                jax.lax.dynamic_slice_in_dim(ck, g, 1, 1),
+                jax.lax.dynamic_slice_in_dim(new_rows, g, 1, 1),
+            )[:, 0],
+            jnp.arange(n_kv),
+        ).transpose(1, 0, 2, 3)  # [P, n_kv, c, M]
+    shared = jnp.where(counts[:, None], shared, -jnp.inf)
+    # Block b is overlapped by windows per_block * b - reach .. per_block *
+    # (b + 1) - 1: pad ``reach`` windows before the first, then one window
+    # of the reduction a block.
+    reach = -(-kernel // stride) - 1
+    padded = jnp.pad(
+        shared, ((0, 0), (0, 0), (0, 0), (reach, n_blocks * per_block - M)),
+        constant_values=-jnp.inf,
+    )
+    scores = jax.lax.reduce_window(
+        padded, -jnp.inf, jax.lax.max, (1, 1, 1, per_block + reach),
+        (1, 1, 1, per_block), "VALID",
+    )  # [P, n_kv, c, n_blocks]
+    b = jnp.arange(n_blocks)
+    own = (pos // block)[:, :, None]  # [P, c, 1]
+    forced = (b[None, None, :] < init_blocks) | (
+        (b[None, None, :] <= own) & (b[None, None, :] > own - window // block)
+    )
+    return jnp.where(forced[:, None], jnp.inf, scores)
+
+
+def sparse_chunk_attention(
+    q: jnp.ndarray,
+    k_cache: jnp.ndarray,
+    v_cache: jnp.ndarray,
+    slots: jnp.ndarray,
+    starts: jnp.ndarray,
+    lens: jnp.ndarray,
+    allowed: jnp.ndarray | None,
+    *,
+    sel_block: int,
+    scale: float | None = None,
+    layer: jnp.ndarray | None = None,
+    block: int = SPARSE_CHUNK_BLOCK,
+) -> jnp.ndarray:
+    """Chunked-prefill grouped-query attention over K and V planes in blocks
+    of positions with a running softmax, each query restricted to the blocks
+    of keys it is allowed: causal at global positions, and ``allowed`` [P,
+    n_kv, c, max_len // sel_block] bool (None: every block) says which blocks
+    of ``sel_block`` keys a query of a kv head attends at all. No array of
+    rows x heads x chunk x max_len is ever alive, and the loop ends at the
+    block that holds the longest row's last position. The chunk's keys and
+    values must already be written into the cache.
+
+    q: [P, c, n_heads, hd]; k_cache, v_cache: the stacked ``[entries, S,
+    n_kv, max_len, hd]`` planes with ``layer`` the entry attended, or one
+    entry; slots/starts/lens: [P] as in ``cache_chunk_attention``. ``block``
+    >= ``max_len`` is the unblocked mathematics (one step). Rows with t >=
+    lens[p] return 0. Returns [P, c, n_heads, hd].
+    """
+    P, c, n_heads, hd = q.shape
+    n_kv, max_len = k_cache.shape[-3], k_cache.shape[-2]
+    block = min(block, max_len)
+    if max_len % block or block % sel_block:
+        raise ValueError(
+            f"max_len {max_len}, the loop's block {block} and the selection's "
+            f"block {sel_block} must divide each other in turn"
+        )
+    if scale is None:
+        scale = hd**-0.5
+    rep = n_heads // n_kv
+    t = jnp.arange(c)
+    pos = starts[:, None] + t[None, :]  # [P, c] global query positions
+    live = t[None, :] < lens[:, None]  # [P, c]
+    qg = q.reshape(P, c, n_kv, rep, hd)
+    per_step = block // sel_block
+
+    def rows_at(plane, i):
+        """[P, n_kv, block, hd]: block i of the attended entry, each row's
+        slot."""
+        if layer is None:
+            blk = jax.lax.dynamic_slice_in_dim(plane, i * block, block, 2)
+        else:
+            sizes = list(plane.shape)
+            sizes[0], sizes[3] = 1, block
+            blk = jax.lax.dynamic_slice(
+                plane, [layer, 0, 0, i * block, 0], sizes
+            )[0]
+        return blk[slots]
+
+    def step(i, carry):
+        m, l, acc = carry  # [P, KV, rep, c], same, [P, KV, rep, c, hd]
+        s = jnp.einsum(
+            "pcgrd,pgkd->pgrck", qg, rows_at(k_cache, i),
+            preferred_element_type=jnp.float32,
+        )
+        k_pos = i * block + jnp.arange(block)
+        valid = (k_pos[None, None, :] <= pos[:, :, None]) & live[:, :, None]
+        valid = jnp.broadcast_to(valid[:, None], (P, n_kv, c, block))
+        if allowed is not None:
+            mine = jax.lax.dynamic_slice_in_dim(
+                allowed, i * per_step, per_step, 3
+            )
+            valid &= jnp.repeat(mine, sel_block, axis=3)
+        valid = valid[:, :, None]  # [P, KV, 1, c, block]
+        s = jnp.where(valid, s * scale, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # A row with nothing valid yet keeps m at NEG_INF: exp(s - m) would
+        # be 1 there, so the mask is applied to the weights too.
+        e = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(e, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "pgrck,pgkd->pgrcd", e.astype(q.dtype), rows_at(v_cache, i),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l, acc
+
+    init = (
+        jnp.full((P, n_kv, rep, c), NEG_INF, jnp.float32),
+        jnp.zeros((P, n_kv, rep, c), jnp.float32),
+        jnp.zeros((P, n_kv, rep, c, hd), jnp.float32),
+    )
+    if block == max_len:
+        _, l, acc = step(0, init)
+    else:
+        last = jnp.max(jnp.where(lens > 0, starts + lens, 0))
+        _, l, acc = jax.lax.fori_loop(
+            0, (last + block - 1) // block, step, init
+        )
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    out = out.transpose(0, 3, 1, 2, 4)  # [P, c, KV, rep, hd]
+    out = jnp.where(live[:, :, None, None, None], out, 0.0)
+    return out.reshape(P, c, n_heads, hd).astype(q.dtype)
+
+
+def sparse_decode_attention(
+    q: jnp.ndarray,
+    k_cache: jnp.ndarray,
+    v_cache: jnp.ndarray,
+    chosen: jnp.ndarray,
+    lengths: jnp.ndarray,
+    k_new: jnp.ndarray,
+    v_new: jnp.ndarray,
+    *,
+    sel_block: int,
+    layer: jnp.ndarray,
+    scale: float | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Single-token decode attention over the chosen blocks of keys only: a
+    gather of ``chosen`` blocks a slot a kv head out of the stacked planes,
+    in place of a read of the slot's whole prefix.
+
+    q: [S, n_heads, hd]; k_cache, v_cache: the stacked ``[entries, S, n_kv,
+    max_len, hd]`` planes, ``layer`` the entry; chosen: [S, n_kv, n] int32
+    block indices (a block is ``sel_block`` positions; a chosen block beyond
+    the context holds nothing valid); lengths: [S] cached positions, the
+    current token's key and value (k_new, v_new [S, n_kv, hd]) attended split
+    from the cache as in ``decode_attention``. Returns ([S, n_heads, hd],
+    [S] int32 the positions attended through the choice, the current one
+    among them, for the first kv head).
+    """
+    S, n_heads, hd = q.shape
+    n_kv, max_len = k_cache.shape[2], k_cache.shape[3]
+    n = chosen.shape[-1]
+    if scale is None:
+        scale = hd**-0.5
+    s_idx = jnp.arange(S)[:, None, None]
+    g_idx = jnp.arange(n_kv)[None, :, None]
+
+    def gathered(plane):
+        blocks = plane.reshape(
+            plane.shape[0], S, n_kv, max_len // sel_block, sel_block, hd
+        )
+        return blocks[layer, s_idx, g_idx, chosen].reshape(
+            S, n_kv, n * sel_block, hd
+        )
+
+    k_pos = (
+        chosen[..., None] * sel_block + jnp.arange(sel_block)
+    ).reshape(S, n_kv, n * sel_block)
+    valid = k_pos < lengths[:, None, None]  # [S, KV, n * sel_block]
+    qg = q.reshape(S, n_kv, n_heads // n_kv, hd)
+    scores = jnp.einsum(
+        "bgrd,bgkd->bgrk", qg, gathered(k_cache),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    scores = jnp.where(valid[:, :, None], scores, NEG_INF)
+    s_new = jnp.einsum(
+        "bgrd,bgd->bgr", qg, k_new, preferred_element_type=jnp.float32
+    ) * scale
+    m = jnp.maximum(jnp.max(scores, axis=-1), s_new)
+    e_c = jnp.where(valid[:, :, None], jnp.exp(scores - m[..., None]), 0.0)
+    e_n = jnp.exp(s_new - m)
+    denom = jnp.sum(e_c, axis=-1) + e_n
+    out = jnp.einsum(
+        "bgrk,bgkd->bgrd", e_c.astype(q.dtype), gathered(v_cache),
+        preferred_element_type=jnp.float32,
+    )
+    out = out + e_n[..., None] * v_new[:, :, None, :].astype(jnp.float32)
+    out = (out / denom[..., None]).astype(q.dtype)
+    attended = jnp.sum(valid[:, 0], axis=-1).astype(jnp.int32) + 1
+    return out.reshape(S, n_heads, hd), attended
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -97,6 +97,21 @@ class KVCache(NamedTuple):
             total += self.k_s.size * self.k_s.dtype.itemsize * 2
         return int(total)
 
+    def bytes_per_token(self) -> int:
+        return _bytes_per_position(self, self.hbm_bytes())
+
+    state_bytes_per_slot = 0
+
+
+def _bytes_per_position(cache: Any, token_bytes: int) -> int:
+    """What one token position holds of ``token_bytes``, the bytes of a
+    cache's planes that grow with tokens, from the arrays as allocated: over
+    its slots (or pool blocks) x positions (or a block's). The one place
+    every cache's ``bytes_per_token`` comes from, and through it health's
+    ``kv_bytes_per_token`` and the gauge."""
+    # k: [entries, slots | blocks, kv_heads | 1, max_len | block, width]
+    return token_bytes // (cache.k.shape[1] * cache.k.shape[3])
+
 
 class LatentKVCache(NamedTuple):
     """The contiguous cache of a latent-attention model: ONE row a token a
@@ -154,6 +169,103 @@ class LatentKVCache(NamedTuple):
 
     def hbm_bytes(self) -> int:
         return int(self.k.size * self.k.dtype.itemsize)
+
+    def bytes_per_token(self) -> int:
+        return _bytes_per_position(self, self.hbm_bytes())
+
+    state_bytes_per_slot = 0
+
+
+class HybridCache(NamedTuple):
+    """The contiguous cache of a stack of two kinds of mixer (sparse
+    attention and lightning linear attention layers,
+    ``models/transformer.py`` ``layer_kinds``): two kinds of per-slot state
+    in one object that the engine sizes, commits and hands to both programs.
+
+    * ``k``, ``v``: ``[sparse layers, slots, kv_heads, max_len, head_dim]``,
+      the sparse layers' keys and values, ``KVCache``'s layout and slot
+      discipline (made safe by the slot's length);
+    * ``ck``: ``[sparse layers, slots, kv_heads, max_len // stride,
+      head_dim]``, the compressed keys that the choice of blocks is made
+      from: row m the mean of keys ``[m stride, m stride + kernel)``, written
+      when that window fills (by the prefill step for the windows that end
+      in its chunk, by the decode step for the one its token completes) and
+      never recomputed from the K plane; a row is read only once its window
+      lies within the slot's length, so a former occupant's rows are never;
+    * ``state``: ``[lightning layers, slots, heads, head_dim, head_dim]``
+      float32, fixed in size whatever the slot's length. A length does NOT
+      make it safe: a prompt's first chunk (start 0) reads zeros in its
+      place, which is the reset of a slot admitted again, and a padding row
+      or an inactive slot leaves it as it was.
+
+    The axes of ``k`` keep ``KVCache``'s places, so ``lengths`` and every
+    reader of ``k.shape[1]`` / ``k.shape[3]`` hold. A class of its own
+    because what copies K and V rows (the prefix pool, the paged pool, KV
+    export) would carry a slot without its state: the engine refuses those
+    at boot."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    ck: jnp.ndarray
+    state: jnp.ndarray
+    lengths: jnp.ndarray  # [slots] int32
+
+    @classmethod
+    def create(
+        cls, n_sparse: int, n_lin: int, n_slots: int, max_len: int,
+        n_kv_heads: int, head_dim: int, lin_heads: int, lin_head_dim: int,
+        stride: int, dtype: Any = jnp.bfloat16,
+    ) -> "HybridCache":
+        kv = (n_sparse, n_slots, n_kv_heads, max_len, head_dim)
+        return cls(
+            k=jnp.zeros(kv, dtype=dtype),
+            v=jnp.zeros(kv, dtype=dtype),
+            ck=jnp.zeros(kv[:3] + (max_len // stride, head_dim), dtype=dtype),
+            state=jnp.zeros(
+                (n_lin, n_slots, lin_heads, lin_head_dim, lin_head_dim),
+                dtype=jnp.float32,
+            ),
+            lengths=jnp.zeros((n_slots,), dtype=jnp.int32),
+        )
+
+    @classmethod
+    def for_config(cls, cfg: Any, n_slots: int, max_len: int) -> "HybridCache":
+        """The cache of ``cfg`` (a ``TransformerConfig`` with
+        ``layer_kinds``): its sparse and lightning layers' planes."""
+        return cls.create(
+            cfg.n_sparse_layers, cfg.n_lin_layers, n_slots, max_len,
+            cfg.n_kv_heads, cfg.head_dim, cfg.lin_heads, cfg.lin_head_dim,
+            cfg.sparse_stride, cfg.dtype,
+        )
+
+    # What the engine asks of any contiguous cache.
+    k_s = v_s = None
+    quantized = False
+
+    @property
+    def n_slots(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def max_len(self) -> int:
+        return self.k.shape[3]
+
+    def hbm_bytes(self) -> int:
+        return int(sum(
+            p.size * p.dtype.itemsize
+            for p in (self.k, self.v, self.ck, self.state)
+        ))
+
+    def bytes_per_token(self) -> int:
+        """K, V and the compressed keys: what grows with tokens."""
+        return _bytes_per_position(self, sum(
+            p.size * p.dtype.itemsize for p in (self.k, self.v, self.ck)
+        ))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """What a slot holds whatever its length."""
+        return int(self.state.size * self.state.dtype.itemsize) // self.n_slots
 
 
 class PagedKVCache(NamedTuple):
@@ -257,6 +369,11 @@ class PagedKVCache(NamedTuple):
         if self.k_s is not None:
             total += self.k_s.size * self.k_s.dtype.itemsize * 2
         return int(total)
+
+    def bytes_per_token(self) -> int:
+        return _bytes_per_position(self, self.hbm_bytes())
+
+    state_bytes_per_slot = 0
 
     def block_bytes(self) -> int:
         """Global bytes of ONE pool block across every layer's K/V

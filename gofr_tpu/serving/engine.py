@@ -202,13 +202,13 @@ class InferenceEngine(
                 "has no int8 / int4 operand; a stacked all-held expert "
                 "layer's products take quantised leaves)"
             )
-        if getattr(self.cfg, "is_latent", False):
-            self._refuse_for_latent_cache(
-                mesh=mesh, tp=tp, kv_quant=kv_quant,
-                kv_block=kv_block, auto_prefix=auto_prefix,
-                prefix_slots=prefix_slots, lora_slots=lora_slots,
-                lora_targets=lora_targets,
-            )
+        self._refuse_for_cache(
+            tp=int(tp or 0) > 1 or mesh is not None,
+            kv_block=int(kv_block or 0) > 0, auto_prefix=bool(auto_prefix),
+            prefix_slots=int(prefix_slots or 0) > 0, kv_quant=kv_quant,
+            quant=quant,
+            lora_slots=lora_targets if int(lora_slots or 0) > 0 else "",
+        )
         self.tokenizer = tokenizer
         # GSPMD-sharded serving (TPU_TP): a caller may hand a pre-built
         # mesh (dryruns, tests composing tp×cp), or just a tp degree —
@@ -439,6 +439,11 @@ class InferenceEngine(
                    if getattr(self.cfg, "counts_routes", False) else {}),
                 **({"cache_row": self.cfg.cache_row}
                    if getattr(self.cfg, "is_latent", False) else {}),
+                **({"layer_kinds": " ".join(
+                        f"{n}x{kind}" for kind, n in self.cfg.layer_runs),
+                    "state_bytes": self.cfg.state_bytes_per_slot,
+                    "blocks_chosen": self.cfg.sparse_topk}
+                   if getattr(self.cfg, "is_hybrid", False) else {}),
             },
             recorder=(
                 FlightRecorder(
@@ -1285,47 +1290,109 @@ class InferenceEngine(
             ).start()
         return engine
 
-    def _refuse_for_latent_cache(
-        self, *, mesh: Any, tp: int, kv_quant: str,
-        kv_block: int, auto_prefix: bool, prefix_slots: int,
-        lora_slots: int, lora_targets: str,
-    ) -> None:
-        """What cannot run over a latent cache yet (one row a token a
-        layer, ``ops.kv_cache.LatentKVCache``), refused before anything is
-        initialised, each by the setting that asked for it. Only a
-        latent-attention model's constructor comes here."""
-        name, cfg = self.model_name, self.cfg
-        why = (
-            f"{name}: latent attention keeps one {cfg.cache_row}-value row "
-            "a token a layer in a contiguous cache of its own; "
-        )
-        refused = [
-            (int(tp or 0) > 1 or mesh is not None,
-             "TPU_TP > 1 (or a mesh) is not served: the row has no kv-head "
-             "axis to shard and the grouped expert product has no expert "
-             "axis yet"),
-            (int(kv_block or 0) > 0,
-             "TPU_KV_BLOCK > 0 (the paged pool) is not served: the pool, "
-             "its Pallas kernels and the KV export / import payloads "
-             "(tier transfers, KVB1) move K and V planes"),
-            (bool(auto_prefix),
-             "TPU_AUTO_PREFIX (the radix prefix cache) is not served: it "
-             "aliases blocks of the paged pool"),
-            (int(prefix_slots or 0) > 0,
-             "TPU_PREFIX_SLOTS > 0 (the prefix pool) is not served: it "
-             "copies K and V rows"),
-            (bool(kv_quant),
-             f"TPU_KV_QUANT={kv_quant} is not served: int8 latent rows "
-             "have no scales plane"),
-            (int(lora_slots or 0) > 0,
-             f"TPU_LORA_SLOTS > 0 (targets {lora_targets!r}) is not "
-             "served: there is no wq / wk / wv to adapt, and no LoRA on "
-             "the latent projections (wq_down, wq_up, wkv_down, wk_up, "
-             "wv_up) or beside routed experts"),
-        ]
-        for asked, message in refused:
-            if asked:
-                raise ValueError(why + message)
+    def _cache_kind(self) -> str:
+        """Which contiguous cache the model is served over: "latent"
+        (``LatentKVCache``), "hybrid" (``HybridCache``) or "kv"."""
+        if getattr(self.cfg, "is_latent", False):
+            return "latent"
+        if getattr(self.cfg, "is_hybrid", False):
+            return "hybrid"
+        return "kv"
+
+    # What cannot run over a cache that is not K and V planes alone, by the
+    # cache's kind and the setting that asks for it: the one table of boot
+    # refusals (``_refuse_for_cache``). A kind that a row does not name
+    # serves that setting.
+    _CACHE_WHY = {
+        "latent": (
+            "latent attention keeps one {cfg.cache_row}-value row a token a "
+            "layer in a contiguous cache of its own; "
+        ),
+        "hybrid": (
+            "a stack of sparse and lightning layers keeps K, V and "
+            "compressed keys for its {cfg.n_sparse_layers} sparse layers and "
+            "a fixed-size state a slot for its {cfg.n_lin_layers} lightning "
+            "layers in one contiguous cache of its own; "
+        ),
+    }
+    _CACHE_REFUSALS = {
+        "tp": {
+            "latent": "TPU_TP > 1 (or a mesh) is not served: the row has no "
+                      "kv-head axis to shard and the grouped expert product "
+                      "has no expert axis yet",
+            "hybrid": "TPU_TP > 1 (or a mesh, pipeline stages among them) is "
+                      "not served: the state plane and the two kinds' "
+                      "stacked leaves have no partition specs",
+        },
+        "kv_block": {
+            "latent": "TPU_KV_BLOCK > 0 (the paged pool) is not served: the "
+                      "pool, its Pallas kernels and the KV export / import "
+                      "payloads (tier transfers, KVB1) move K and V planes",
+            "hybrid": "TPU_KV_BLOCK > 0 (the paged pool) is not served: a "
+                      "state is not block-addressable, and the pool's "
+                      "kernels and KV export / import payloads move K and V "
+                      "blocks without it",
+        },
+        "auto_prefix": {
+            "latent": "TPU_AUTO_PREFIX (the radix prefix cache) is not "
+                      "served: it aliases blocks of the paged pool",
+            "hybrid": "TPU_AUTO_PREFIX (the radix prefix cache) is not "
+                      "served: it aliases blocks of the paged pool, and a "
+                      "prefix's state would have to be kept at every block "
+                      "boundary",
+        },
+        "prefix_slots": {
+            "latent": "TPU_PREFIX_SLOTS > 0 (the prefix pool) is not served: "
+                      "it copies K and V rows",
+            "hybrid": "TPU_PREFIX_SLOTS > 0 (the prefix pool) is not served: "
+                      "it copies K and V rows and would leave the state "
+                      "behind",
+        },
+        "kv_quant": {
+            "latent": "TPU_KV_QUANT={value} is not served: int8 latent rows "
+                      "have no scales plane",
+            "hybrid": "TPU_KV_QUANT={value} is not served: the compressed "
+                      "keys and the float32 state have no int8 form",
+        },
+        "quant": {
+            "hybrid": "TPU_QUANT={value} is not served: the lightning and gate "
+                      "projections (lin_layers, wg) and the per-head norms "
+                      "have no quantised path yet",
+        },
+        "lora_slots": {
+            "latent": "TPU_LORA_SLOTS > 0 (targets {value!r}) is not served: "
+                      "there is no wq / wk / wv to adapt, and no LoRA on the "
+                      "latent projections "
+                      "(wq_down, wq_up, wkv_down, wk_up, wv_up) or beside "
+                      "routed experts",
+            "hybrid": "TPU_LORA_SLOTS > 0 (targets {value!r}) is not served: "
+                      "no LoRA on the lightning layers' projections or the "
+                      "output gates",
+        },
+        "tier_export": {
+            "latent": "a prefill-tier role (TPU_REPLICA_ROLES) is not "
+                      "served: KV export / import payloads move K and V "
+                      "blocks of the paged pool, and a latent row has neither",
+            "hybrid": "a prefill-tier role (TPU_REPLICA_ROLES) is not "
+                      "served: KV export / import payloads move K and V "
+                      "blocks of the paged pool and carry no state",
+        },
+    }
+
+    def _refuse_for_cache(self, **asked: Any) -> None:
+        """Refuse, before anything is initialised, each setting in ``asked``
+        (by its name in ``_CACHE_REFUSALS``; a value that is true asks for
+        it) that cannot run over this model's kind of cache, with a message
+        that names the setting."""
+        kind = self._cache_kind()
+        for setting, value in asked.items():
+            message = self._CACHE_REFUSALS[setting].get(kind)
+            if value and message:
+                raise ValueError(
+                    f"{self.model_name}: "
+                    + self._CACHE_WHY[kind].format(cfg=self.cfg)
+                    + message.format(value=value)
+                )
 
     def _placement(self) -> Any:
         """Context in which NEW arrays land on a pinned engine's own
@@ -1437,6 +1504,25 @@ class InferenceEngine(
                 self.cfg.n_cache_entries, n_slots, self.max_len,
                 self.cfg.cache_row, self.cfg.dtype,
             )
+        elif getattr(self.cfg, "is_hybrid", False):
+            from gofr_tpu.ops.attention import SPARSE_CHUNK_BLOCK
+            from gofr_tpu.ops.kv_cache import HybridCache
+
+            cfg = self.cfg
+            unit = (
+                SPARSE_CHUNK_BLOCK if self.max_len > SPARSE_CHUNK_BLOCK
+                else cfg.sparse_block
+            )
+            if self.max_len % unit or unit % cfg.sparse_block:
+                raise ValueError(
+                    f"{self.model_name}: TPU_MAX_LEN={self.max_len} is not "
+                    f"served: a sparse layer's prefill attends a slot in "
+                    f"whole blocks of {unit} positions (and picks among "
+                    f"blocks of {cfg.sparse_block})"
+                )
+            make_cache = lambda: HybridCache.for_config(  # noqa: E731
+                cfg, n_slots, self.max_len
+            )
         else:
             make_cache = lambda: KVCache.create(  # noqa: E731
                 self.cfg.n_cache_entries, n_slots, self.max_len,
@@ -1474,6 +1560,11 @@ class InferenceEngine(
                 "app_tpu_kv_bytes_per_token", self.kv_bytes_per_token(),
                 "model", self.model_name,
             )
+            if self.state_bytes_per_slot():
+                self._metrics.set_gauge(
+                    "app_tpu_state_bytes_per_slot",
+                    self.state_bytes_per_slot(), "model", self.model_name,
+                )
         self._radix = None
         if self.kv_block:
             # Host-side REFCOUNTED block allocator (ops/kv_cache.py):
@@ -1927,13 +2018,7 @@ class InferenceEngine(
         tier, retries exhausted AND no sibling adopted it, transfer cap
         hit) means the scheduler decodes locally — the fused fallback,
         so a collapsed decode tier degrades service, never drops it."""
-        if exporter is not None and getattr(self.cfg, "is_latent", False):
-            raise ValueError(
-                f"{self.model_name}: a prefill-tier role "
-                "(TPU_REPLICA_ROLES) is not served over a latent cache: "
-                "KV export / import payloads move K and V blocks of the "
-                "paged pool, and a latent row has neither"
-            )
+        self._refuse_for_cache(tier_export=exporter is not None)
         self._tier_exporter = exporter
 
     def handoff_prefilled(self, req: _GenRequest, payload: Any) -> Optional[str]:
@@ -3196,10 +3281,16 @@ class InferenceEngine(
     def kv_bytes_per_token(self) -> int:
         """Bytes of KV cache one token position holds, from the arrays as
         allocated: every cache entry's keys and values, and the scales of
-        an int8 cache; for a latent cache every entry's one row."""
-        # k: [entries, slots | blocks, kv_heads | 1, max_len | block, width]
-        k = self.cache.k
-        return self.cache.hbm_bytes() // (k.shape[1] * k.shape[3])
+        an int8 cache; for a latent cache every entry's one row; for a
+        hybrid cache K, V and the compressed keys (what grows with tokens:
+        ``state_bytes_per_slot`` is the rest). Each cache says its own
+        (``ops/kv_cache.py`` ``bytes_per_token``)."""
+        return self.cache.bytes_per_token()
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a slot holds whatever its length, from the arrays as
+        allocated: a hybrid cache's lightning states; 0 for every other."""
+        return int(self.cache.state_bytes_per_slot)
 
     def health_check(self) -> dict:
         details: dict[str, Any] = {
@@ -3244,6 +3335,8 @@ class InferenceEngine(
             }
             details["max_len"] = self.max_len
             details["kv_bytes_per_token"] = self.kv_bytes_per_token()
+            if self.state_bytes_per_slot():
+                details["state_bytes_per_slot"] = self.state_bytes_per_slot()
             details["pending"] = self._pending.qsize()
             details["prefilling"] = len(self._prefilling)
             # Disaggregated-tier role (TPU_REPLICA_ROLES): which serving

@@ -156,7 +156,7 @@ def config_from_hf(path: str):
         head_dim_override=int(hf.get("head_dim", 0)) if mt == "gemma" else 0,
         act="gelu" if mt == "gemma" else "silu",
         norm_offset=(mt == "gemma"),
-        embed_scale=(mt == "gemma"),
+        embed_scale=float(hf["hidden_size"]) ** 0.5 if mt == "gemma" else 0.0,
     )
 
 

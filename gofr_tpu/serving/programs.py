@@ -99,7 +99,7 @@ class LLMProgramsMixin:
         self.decode_read_rungs = decode_read_plan(
             self.max_len, paged=bool(self.kv_block),
             window=cfg.sliding_window, kernel=False if dense_attn else None,
-            latent=cfg.is_latent,
+            latent=cfg.is_latent or cfg.is_hybrid,
         ) if bound_read else (self.max_len,)
         # An expert layer that may hold a share of the experts counts its
         # routes; the steps return the counts beside their tokens (no
@@ -109,6 +109,11 @@ class LLMProgramsMixin:
         # its expert load the same way.
         count_routes = cfg.counts_routes
         sharded = self.mesh is not None
+        # A hybrid stack's steps return what its sparse layers did the same
+        # way: a prefill step each row's queries past the dense length, a
+        # decode step two planes a slot (positions attended through the
+        # choice, context).
+        hybrid = cfg.is_hybrid
 
         def moe_product(rows: int) -> Optional[str]:
             """app_tpu_moe_product_steps_total's ``product`` of a step of
@@ -269,13 +274,15 @@ class LLMProgramsMixin:
             logits, cache, *counts = transformer_prefill_chunk(
                 params, tokens, cache, slots, starts, lens, cfg,
                 dense_attn=dense_attn, aids=aids[slots],
-                row_valid=row_valid if grouped else None,
-                stats=grouped, sharded=sharded,
+                row_valid=row_valid if grouped or hybrid else None,
+                stats=grouped or hybrid, sharded=sharded,
             )
             moe = None
             if grouped:
                 ((held, load_ratio),) = counts
                 moe = rep(jnp.concatenate([held, load_ratio[None]]))
+            elif hybrid:  # [rows]: each row's queries past the dense length
+                moe = rep(counts[0].astype(jnp.float32))
             # Sample at the slot's counter OFFSET (noff): 0 for fresh
             # admissions, the delivered-token count for replayed requests
             # — so a non-greedy stream carried across a restart continues
@@ -343,8 +350,10 @@ class LLMProgramsMixin:
                 logits, cache, *held = transformer_decode_step(
                     params, tokens, cache, active, cfg,
                     dense_attn=dense_attn, aids=aids, bound_read=bound_read,
-                    stats=count_routes, sharded=sharded,
+                    stats=count_routes or hybrid, sharded=sharded,
                 )
+                if hybrid:  # (attended, context): two planes of the block
+                    held = list(held[0])
                 pen = (pcounts, fpen, ppen) if enable_penalties else None
                 sub = row_keys(seeds, nsteps)
                 nxt, nlp, ntopi, ntopl = sample(

@@ -1524,6 +1524,13 @@ class SchedulerMixin:
                 "model", self.model_name, "rows", str(R),
             )
             self._count_moe_product("prefill_chunk", R)
+            if self.cfg.is_hybrid and not starts[: len(rows)].all():
+                # A prompt's first chunk reads zeros for its slot's state.
+                self._metrics.add_counter(
+                    "app_tpu_state_resets_total",
+                    int((starts[: len(rows)] == 0).sum()),
+                    "model", self.model_name,
+                )
             # How much of the [R, c] step that ran was prompt: the rest
             # of its R x c token rows is padding the device computes
             # anyway.
@@ -1705,6 +1712,21 @@ class SchedulerMixin:
                 "model", self.model_name, "where", where,
             )
 
+    def _count_sparse_queries(
+        self, program: str, selected: float, tokens: int
+    ) -> None:
+        """``tokens`` computed tokens of a hybrid stack, ``selected`` of
+        them past the dense length, into
+        ``app_tpu_sparse_attn_queries_total{branch, program}``: a query is
+        a computed token x a sparse layer."""
+        layers = self.cfg.n_sparse_layers
+        for branch, n in (("selected", selected), ("dense", tokens - selected)):
+            self._metrics.add_counter(
+                "app_tpu_sparse_attn_queries_total", n * layers,
+                "model", self.model_name, "branch", branch,
+                "program", program,
+            )
+
     def _count_moe_product(self, program: str, rows: int) -> None:
         """One dispatched step of an expert model, under the product its
         expert layers ran (``programs.moe_products``): the share of steps in
@@ -1733,6 +1755,11 @@ class SchedulerMixin:
             if self._metrics is None:
                 continue
             host = np.asarray(counts)  # graftlint: disable=GL001 — landed (is_ready): a copy, not a sync
+            if self.cfg.is_hybrid:  # [rows]: queries past the dense length
+                self._count_sparse_queries(
+                    "prefill_chunk", float(host[:n_rows].sum()), tokens
+                )
+                continue
             if self.cfg.counts_routes:
                 self._count_routes(float(host[:n_rows].sum()), tokens)
             self._metrics.record_histogram(
@@ -1823,7 +1850,11 @@ class SchedulerMixin:
                     + seq.tokens_in_flight - 1
                 )
                 live_positions += held
-                longest = max(longest, held)
+                # A hybrid cache's dense read serves the slots under the
+                # dense length only; the others gather their chosen blocks.
+                if not (self.cfg.is_hybrid
+                        and held >= self.cfg.sparse_dense_len):
+                    longest = max(longest, held)
                 seq.tokens_in_flight += self.window_k
         # Results land in LOCALS first and commit to self only after a
         # superseded check: a dispatch that BLOCKED here (a hung device
@@ -1980,10 +2011,27 @@ class SchedulerMixin:
                 # on held experts at each step. Every live slot computed
                 # all window_k steps, whatever was emitted of them.
                 live = [i for i, s in enumerate(snapshot) if s is not None]
-                self._count_routes(
-                    float(emitted_host[2][:, live].sum()),
-                    dispatched_live * self.window_k,
-                )
+                if self.cfg.is_hybrid:
+                    # Two planes: the positions each slot's query attended
+                    # through the choice of blocks at each step (0 where it
+                    # took the dense read), and its context there.
+                    attended = emitted_host[2][:, live]
+                    self._count_sparse_queries(
+                        "decode_window", float((attended > 0).sum()),
+                        dispatched_live * self.window_k,
+                    )
+                    if attended.any():
+                        self._metrics.record_histogram(
+                            "app_tpu_sparse_attn_read_ratio",
+                            float(attended.sum())
+                            / float(emitted_host[3][:, live].sum()),
+                            "model", self.model_name,
+                        )
+                else:
+                    self._count_routes(
+                        float(emitted_host[2][:, live].sum()),
+                        dispatched_live * self.window_k,
+                    )
             # How full the batch is now (the gauge), and how full this
             # window ran — the slots live when it was dispatched — as a
             # histogram whose sum over count between two scrapes is the

@@ -36,8 +36,14 @@ VOCABULARY = {
     # PR 33: latent attention's projections, and a grouped expert layer's
     # sort-and-group and shared expert
     "mla_q", "mla_kv", "moe_dispatch", "moe_shared",
+    # PR 35: a lightning layer's projections, its chunk-wise product or
+    # recurrence step with the state's update, its norm, gate and wo; a
+    # sparse layer's compressed-key scores and choice, and the decode step's
+    # gather of the chosen blocks
+    "lin_qkv", "lin_scan", "lin_out", "sparse_index", "sparse_gather",
 }
 LATENT_MOE = {"mla_q", "mla_kv", "moe_dispatch", "moe_shared"}
+HYBRID = {"lin_qkv", "lin_scan", "lin_out", "sparse_index", "sparse_gather"}
 
 
 def engine_of(model: str) -> InferenceEngine:
@@ -96,13 +102,17 @@ def lower_window(e: InferenceEngine):
 
 @pytest.mark.parametrize("model,ffn_scopes,absent", [
     ("llama-tiny", {"ffn"},
-     {"moe_router", "moe_experts", "draft", "verify"} | LATENT_MOE),
+     {"moe_router", "moe_experts", "draft", "verify"} | LATENT_MOE | HYBRID),
     ("moe-tiny", {"moe_router", "moe_experts"},
-     {"ffn", "draft", "verify"} | LATENT_MOE),
+     {"ffn", "draft", "verify"} | LATENT_MOE | HYBRID),
+    # two kinds of mixer: every scope of a lightning layer and of a sparse
+    # one (the gather of chosen blocks is the decode step's alone)
+    ("sala-tiny", {"ffn"} | HYBRID - {"sparse_gather"},
+     {"moe_router", "moe_experts", "draft", "verify"} | LATENT_MOE),
     # a leading dense layer (ffn), then expert layers by the grouped product
     # over a latent cache: every scope of the model's vocabulary
     ("mla-moe-tiny", {"ffn", "moe_router", "moe_experts"} | LATENT_MOE,
-     {"draft", "verify"}),
+     {"draft", "verify"} | HYBRID),
 ])
 def test_lowered_programs_name_their_ops_by_scope(model, ffn_scopes, absent):
     e = engine_of(model)  # built, never started: nothing runs
@@ -113,6 +123,8 @@ def test_lowered_programs_name_their_ops_by_scope(model, ffn_scopes, absent):
     assert everywhere <= set(prefill), sorted(prefill)
     assert everywhere <= set(window), sorted(window)
     assert not absent & (set(prefill) | set(window))
+    if model == "sala-tiny":
+        assert "sparse_gather" in window and "sparse_gather" not in prefill
     # The matrix products carry a name: the attention and FFN einsums are
     # the device's time, and an unnamed one is what this PR is against.
     dots = [n for n in op_names(lowered_window) if n.endswith("dot_general")]
