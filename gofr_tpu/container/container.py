@@ -356,6 +356,14 @@ class Container:
             "live slot) / max_len, one record per processed window; 1.0: "
             "the whole cache", ratio_buckets,
         )
+        m.new_histogram(
+            "app_tpu_prefill_attn_visit_ratio",
+            "block-steps a prefill chunk step's blocked attention ran (each "
+            "row over the blocks of positions up to its own last one) / "
+            "rows x the longest row's blocks, one record per dispatched "
+            "step of a latent or a hybrid cache; 1.0: every row as deep as "
+            "the deepest", ratio_buckets,
+        )
         m.new_gauge(
             "app_tpu_kv_bytes_per_token",
             "KV-cache bytes one token holds (all cache entries, keys and "

@@ -31,6 +31,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from gofr_tpu.ops.attention import (
+    SPARSE_CHUNK_BLOCK,
     attention,
     cache_chunk_attention,
     decode_attention,
@@ -1732,8 +1733,8 @@ def _hybrid_forward(params, x, positions, cfg):
     b, s, _ = x.shape
     unit = max(cfg.sparse_block, cfg.sparse_stride)
     max_len = -(-s // unit) * unit
-    if max_len > 512:  # whole steps of sparse_chunk_attention's loop
-        max_len = -(-max_len // 512) * 512
+    if max_len > SPARSE_CHUNK_BLOCK:  # whole steps of the attention's loop
+        max_len = -(-max_len // SPARSE_CHUNK_BLOCK) * SPARSE_CHUNK_BLOCK
     cache = HybridCache.for_config(cfg, b, max_len)
     x, _, _ = _hybrid_chunk_layers(
         params, x, cache, jnp.arange(b), jnp.zeros((b,), jnp.int32),

@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -1e30
 
@@ -501,10 +502,92 @@ def latent_decode_attention(
     return out[:, 0]
 
 
+# ---------------------------------------------------------------------------
+# the chunk step's attention over blocks of positions, a row at a time
+# ---------------------------------------------------------------------------
+
+
+def chunk_block_counts(starts, lens, block: int):
+    """[P]: how many blocks of ``block`` positions each row of a chunk step
+    attends, those up to the one that holds ITS OWN last position; none for
+    a row that holds no token. numpy in, numpy out (the scheduler's
+    histogram); jnp in, jnp out (the loops' trip counts)."""
+    return (lens > 0) * (-(-(starts + lens) // block))
+
+
+def chunk_visit_ratio(starts, lens, block: int) -> float:
+    """The block-steps a chunk step's blocked attention runs (the sum of its
+    rows' own) over rows x the longest row's: what running every row to the
+    longest row's block would have cost is 1."""
+    counts = chunk_block_counts(np.asarray(starts), np.asarray(lens), block)
+    return float(counts.sum()) / max(int(counts.max()) * len(counts), 1)
+
+
+def _slot_block(plane: jnp.ndarray, layer, slot, i, block: int) -> jnp.ndarray:
+    """[heads, block, width]: block ``i`` of one slot of the attended entry,
+    one ``dynamic_slice`` of the plane where it lies. plane: the stacked
+    ``[entries, S, heads, max_len, width]`` with ``layer`` the entry, or one
+    entry ``[S, heads, max_len, width]`` (``layer`` None)."""
+    at = (slot, 0, i * block, 0)
+    sizes = (1, plane.shape[-3], block, plane.shape[-1])
+    if layer is None:
+        return jax.lax.dynamic_slice(plane, at, sizes)[0]
+    return jax.lax.dynamic_slice(plane, (layer, *at), (1, *sizes))[0, 0]
+
+
+def _row_block_softmax(block_terms, xs, counts, lead: tuple, width: int):
+    """The running softmax of a chunk step's attention over blocks of
+    positions, the step's rows in turn (``lax.map``), each over its own
+    ``counts[p]`` blocks: the block-steps a step runs are the sum of its
+    rows' own, not rows x the longest row's. (A ``vmap`` of the loop would
+    run every row to the longest count under a select.)
+
+    block_terms(x, i) -> (s, valid, weigh): row ``x`` (a slice of ``xs``
+    along its leading axis P) against its block ``i``: the scaled float32
+    scores ``[*lead, block]``, the mask of those that count (broadcastable
+    to them) and ``weigh(e) -> [*lead, width]`` float32, the block's values
+    summed under the weights ``e``. counts None: one step a row, block 0
+    (a block that is the whole slot). Scores, maximum, sum and weighted sum
+    are float32. Returns [P, *lead, width] float32, zeros where nothing
+    counted."""
+
+    def step(x, i, carry):
+        m, l, acc = carry
+        s, valid, weigh = block_terms(x, i)
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # A query with nothing valid yet keeps m at NEG_INF: exp(s - m)
+        # would be 1 there, so the mask is applied to the weights too.
+        e = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(e, axis=-1)
+        return m_new, l, acc * alpha[..., None] + weigh(e)
+
+    def row(args):
+        x, n = args
+        init = (
+            jnp.full(lead, NEG_INF, jnp.float32),
+            jnp.zeros(lead, jnp.float32),
+            jnp.zeros((*lead, width), jnp.float32),
+        )
+        if n is None:
+            _, l, acc = step(x, 0, init)
+        else:
+            _, l, acc = jax.lax.fori_loop(
+                0, n, functools.partial(step, x), init
+            )
+        return acc / jnp.maximum(l, 1e-30)[..., None]
+
+    return jax.lax.map(row, (xs, counts))
+
+
 # Positions a step of ``latent_chunk_attention``'s loop scores at once. The
-# float32 scores of a block are [rows, heads, chunk, block]: 8 x 128 x 256 x
-# 512 x 4 B = 537 MB, where the whole 8,192 positions would be 8.6 GB.
-LATENT_CHUNK_BLOCK = 512
+# float32 scores of a row's block are [heads, chunk, block]: 128 x 256 x 256
+# x 4 B = 34 MB, where the whole 8,192 positions would be 1.07 GB a row. On
+# the v5e (PERF.md section 6, PR 36) 256 beat 128, 512 and 1,024: a block
+# each visited key is expanded in, the size of the serving chunk, so a
+# row's own chunk is one block and nothing beyond the diagonal is scored.
+LATENT_CHUNK_BLOCK = 256
 
 
 def latent_chunk_attention(
@@ -522,9 +605,10 @@ def latent_chunk_attention(
 ) -> jnp.ndarray:
     """Chunked-prefill attention over a latent cache, expanded form, in
     blocks of positions with a running softmax, so that no array of
-    rows x heads x chunk x max_len is ever alive; the loop ends at the
-    block that holds the longest row's last position, whatever ``max_len``.
-    The chunk's rows must already be written into the cache.
+    heads x chunk x max_len is ever alive; a row of the step visits the
+    blocks up to the one that holds its own last position and no more
+    (``_row_block_softmax``), whatever ``max_len`` and whatever the step's
+    other rows. The chunk's rows must already be written into the cache.
 
     q: [P, c, n_heads, nope + rope], ``[q_nope | q_rope]``; plane: the
     stacked ``[entries, S, 1, max_len, width]`` cache (width >= rank + rope,
@@ -533,7 +617,7 @@ def latent_chunk_attention(
     [rank, heads, nope], w_uv [rank, heads, vd]: each block's latents are
     expanded to per-head keys and values before they are scored. Returns
     [P, c, n_heads, vd]. ``block`` >= ``max_len`` is the unblocked
-    mathematics (one step). Rows with t >= lens[p] return 0.
+    mathematics (one step a row). Rows with t >= lens[p] return 0.
     """
     P, c, n_heads, _ = q.shape
     max_len = plane.shape[-2]
@@ -544,64 +628,33 @@ def latent_chunk_attention(
     pos = starts[:, None] + t[None, :]  # [P, c] global query positions
     live = t[None, :] < lens[:, None]  # [P, c]
     rank, nope = w_uk.shape[0], w_uk.shape[-1]
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    rope = q_rope.shape[-1]
+    rope = q.shape[-1] - nope
 
-    def rows_at(i):
-        """[P, block, row]: block i of the attended entry, each row's slot."""
-        if layer is None:
-            blk = jax.lax.dynamic_slice_in_dim(plane, i * block, block, 2)
-        else:
-            sizes = list(plane.shape)
-            sizes[0], sizes[3] = 1, block
-            blk = jax.lax.dynamic_slice(
-                plane, [layer, 0, 0, i * block, 0], sizes
-            )[0]
-        return blk[slots, 0]
-
-    def step(i, carry):
-        m, l, acc = carry  # [P, H, c], [P, H, c], [P, H, c, vd]
-        rows = rows_at(i)
-        latent = rows[..., :rank]
-        k_nope = jnp.einsum("pkr,rhn->pkhn", latent, w_uk)
+    def block_terms(x, i):
+        q_nope, q_rope, slot, pos, live = x  # one row: [c, H, .], [c]
+        rows = _slot_block(plane, layer, slot, i, block)[0]  # [block, row]
+        latent = rows[:, :rank]
+        k_nope = jnp.einsum("kr,rhn->khn", latent, w_uk)
         s = jnp.einsum(
-            "pchn,pkhn->phck", q_nope, k_nope,
+            "chn,khn->hck", q_nope, k_nope,
             preferred_element_type=jnp.float32,
         ) + jnp.einsum(
-            "pchr,pkr->phck", q_rope, rows[..., rank:rank + rope],
+            "chr,kr->hck", q_rope, rows[:, rank:rank + rope],
             preferred_element_type=jnp.float32,
         )
-        values = jnp.einsum("pkr,rhv->pkhv", latent, w_uv)
+        values = jnp.einsum("kr,rhv->khv", latent, w_uv)
         k_pos = i * block + jnp.arange(block)
-        valid = (k_pos[None, None, :] <= pos[:, :, None]) & live[:, :, None]
-        valid = valid[:, None]  # [P, 1, c, block]
-        s = jnp.where(valid, s * scale, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        # A row with nothing valid yet keeps m at NEG_INF: exp(s - m) would
-        # be 1 there, so the mask is applied to the weights too.
-        e = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(e, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "phck,pkhv->phcv", e.astype(q.dtype), values,
+        valid = (k_pos[None, :] <= pos[:, None]) & live[:, None]  # [c, block]
+        return s * scale, valid[None], lambda e: jnp.einsum(
+            "hck,khv->hcv", e.astype(q.dtype), values,
             preferred_element_type=jnp.float32,
         )
-        return m_new, l, acc
 
-    init = (
-        jnp.full((P, n_heads, c), NEG_INF, jnp.float32),
-        jnp.zeros((P, n_heads, c), jnp.float32),
-        jnp.zeros((P, n_heads, c, w_uv.shape[-1]), jnp.float32),
-    )
-    if block == max_len:
-        _, l, acc = step(0, init)
-    else:
-        # Blocks up to the one that holds the longest row's last position.
-        last = jnp.max(jnp.where(lens > 0, starts + lens, 0))
-        _, l, acc = jax.lax.fori_loop(
-            0, (last + block - 1) // block, step, init
-        )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    out = _row_block_softmax(
+        block_terms, (q[..., :nope], q[..., nope:], slots, pos, live),
+        None if block == max_len else chunk_block_counts(starts, lens, block),
+        (n_heads, c), w_uv.shape[-1],
+    )  # [P, H, c, vd]
     out = jnp.where(live[:, None, :, None], out, 0.0)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)  # [P, c, H, vd]
 
@@ -700,8 +753,8 @@ def cache_chunk_attention(
 # ---------------------------------------------------------------------------
 
 # Positions a step of ``sparse_chunk_attention``'s loop scores at once: the
-# float32 scores of [rows, heads, chunk, positions] are 8 x 32 x 256 x 512 x
-# 4 B = 134 MB, where all 32,768 positions of a slot would be 8.6 GB.
+# float32 scores of a row's [heads, chunk, positions] are 32 x 256 x 512 x
+# 4 B = 17 MB, where all 32,768 positions of a slot would be 1.07 GB a row.
 SPARSE_CHUNK_BLOCK = 512
 
 
@@ -812,15 +865,16 @@ def sparse_chunk_attention(
     of keys it is allowed: causal at global positions, and ``allowed`` [P,
     n_kv, c, max_len // sel_block] bool (None: every block) says which blocks
     of ``sel_block`` keys a query of a kv head attends at all. No array of
-    rows x heads x chunk x max_len is ever alive, and the loop ends at the
-    block that holds the longest row's last position. The chunk's keys and
-    values must already be written into the cache.
+    heads x chunk x max_len is ever alive, and a row of the step visits the
+    blocks up to the one that holds its own last position and no more
+    (``_row_block_softmax``). The chunk's keys and values must already be
+    written into the cache.
 
     q: [P, c, n_heads, hd]; k_cache, v_cache: the stacked ``[entries, S,
     n_kv, max_len, hd]`` planes with ``layer`` the entry attended, or one
     entry; slots/starts/lens: [P] as in ``cache_chunk_attention``. ``block``
-    >= ``max_len`` is the unblocked mathematics (one step). Rows with t >=
-    lens[p] return 0. Returns [P, c, n_heads, hd].
+    >= ``max_len`` is the unblocked mathematics (one step a row). Rows with
+    t >= lens[p] return 0. Returns [P, c, n_heads, hd].
     """
     P, c, n_heads, hd = q.shape
     n_kv, max_len = k_cache.shape[-3], k_cache.shape[-2]
@@ -836,63 +890,34 @@ def sparse_chunk_attention(
     t = jnp.arange(c)
     pos = starts[:, None] + t[None, :]  # [P, c] global query positions
     live = t[None, :] < lens[:, None]  # [P, c]
-    qg = q.reshape(P, c, n_kv, rep, hd)
     per_step = block // sel_block
 
-    def rows_at(plane, i):
-        """[P, n_kv, block, hd]: block i of the attended entry, each row's
-        slot."""
-        if layer is None:
-            blk = jax.lax.dynamic_slice_in_dim(plane, i * block, block, 2)
-        else:
-            sizes = list(plane.shape)
-            sizes[0], sizes[3] = 1, block
-            blk = jax.lax.dynamic_slice(
-                plane, [layer, 0, 0, i * block, 0], sizes
-            )[0]
-        return blk[slots]
-
-    def step(i, carry):
-        m, l, acc = carry  # [P, KV, rep, c], same, [P, KV, rep, c, hd]
+    def block_terms(x, i):
+        qg, slot, pos, live, mine = x  # one row: [c, KV, rep, hd], [c]
         s = jnp.einsum(
-            "pcgrd,pgkd->pgrck", qg, rows_at(k_cache, i),
+            "cgrd,gkd->grck", qg, _slot_block(k_cache, layer, slot, i, block),
             preferred_element_type=jnp.float32,
         )
         k_pos = i * block + jnp.arange(block)
-        valid = (k_pos[None, None, :] <= pos[:, :, None]) & live[:, :, None]
-        valid = jnp.broadcast_to(valid[:, None], (P, n_kv, c, block))
-        if allowed is not None:
+        valid = (k_pos[None, :] <= pos[:, None]) & live[:, None]  # [c, block]
+        valid = valid[None]
+        if mine is not None:  # [KV, c, max_len // sel_block]
             mine = jax.lax.dynamic_slice_in_dim(
-                allowed, i * per_step, per_step, 3
+                mine, i * per_step, per_step, 2
             )
-            valid &= jnp.repeat(mine, sel_block, axis=3)
-        valid = valid[:, :, None]  # [P, KV, 1, c, block]
-        s = jnp.where(valid, s * scale, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        # A row with nothing valid yet keeps m at NEG_INF: exp(s - m) would
-        # be 1 there, so the mask is applied to the weights too.
-        e = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(e, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "pgrck,pgkd->pgrcd", e.astype(q.dtype), rows_at(v_cache, i),
+            valid = valid & jnp.repeat(mine, sel_block, axis=2)
+        return s * scale, valid[:, None], lambda e: jnp.einsum(
+            "grck,gkd->grcd", e.astype(q.dtype),
+            _slot_block(v_cache, layer, slot, i, block),
             preferred_element_type=jnp.float32,
         )
-        return m_new, l, acc
 
-    init = (
-        jnp.full((P, n_kv, rep, c), NEG_INF, jnp.float32),
-        jnp.zeros((P, n_kv, rep, c), jnp.float32),
-        jnp.zeros((P, n_kv, rep, c, hd), jnp.float32),
-    )
-    if block == max_len:
-        _, l, acc = step(0, init)
-    else:
-        last = jnp.max(jnp.where(lens > 0, starts + lens, 0))
-        _, l, acc = jax.lax.fori_loop(
-            0, (last + block - 1) // block, step, init
-        )
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    out = _row_block_softmax(
+        block_terms,
+        (q.reshape(P, c, n_kv, rep, hd), slots, pos, live, allowed),
+        None if block == max_len else chunk_block_counts(starts, lens, block),
+        (n_kv, rep, c), hd,
+    )  # [P, KV, rep, c, hd]
     out = out.transpose(0, 3, 1, 2, 4)  # [P, c, KV, rep, hd]
     out = jnp.where(live[:, :, None, None, None], out, 0.0)
     return out.reshape(P, c, n_heads, hd).astype(q.dtype)

@@ -1488,6 +1488,9 @@ class InferenceEngine(
         n_slots = self.n_slots
         from gofr_tpu.ops.kv_cache import KVCache
 
+        # Positions a step of the prefill attention's loop over a latent or
+        # a hybrid cache scores at once (0: that attention is not blocked).
+        self.prefill_attn_block = 0
         if self.kv_block:
             from gofr_tpu.ops.kv_cache import PagedKVCache
 
@@ -1498,8 +1501,10 @@ class InferenceEngine(
                 n_blocks=self.kv_pool_blocks,
             )
         elif getattr(self.cfg, "is_latent", False):
+            from gofr_tpu.ops.attention import LATENT_CHUNK_BLOCK
             from gofr_tpu.ops.kv_cache import LatentKVCache
 
+            self.prefill_attn_block = min(LATENT_CHUNK_BLOCK, self.max_len)
             make_cache = lambda: LatentKVCache.create(  # noqa: E731
                 self.cfg.n_cache_entries, n_slots, self.max_len,
                 self.cfg.cache_row, self.cfg.dtype,
@@ -1520,6 +1525,7 @@ class InferenceEngine(
                     f"whole blocks of {unit} positions (and picks among "
                     f"blocks of {cfg.sparse_block})"
                 )
+            self.prefill_attn_block = min(SPARSE_CHUNK_BLOCK, self.max_len)
             make_cache = lambda: HybridCache.for_config(  # noqa: E731
                 cfg, n_slots, self.max_len
             )

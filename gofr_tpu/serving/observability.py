@@ -192,9 +192,12 @@ class RequestTimeline:
         self.done: Optional[float] = None
         self.outcome = ""
         self.finish_reason = ""
-        # (start, end, tokens, rows) per dispatched prefill chunk step:
-        # this request's tokens in it, and the row count it ran at.
-        self.chunks: list[tuple[float, float, int, int]] = []
+        # (start, end, tokens, rows, attn_visit_ratio) per dispatched
+        # prefill chunk step: this request's tokens in it, the row count
+        # it ran at, and ``note_chunk``'s share of block-steps (or None).
+        self.chunks: list[
+            tuple[float, float, int, int, Optional[float]]
+        ] = []
         # (name, t, attrs) — shed/replay/failover events.
         self.annotations: list[tuple[str, float, dict[str, Any]]] = []
         # (source, target, start, end, result) — disaggregated-tier KV
@@ -224,9 +227,14 @@ class RequestTimeline:
         self.prefix_hit_tokens += tokens
 
     def note_chunk(
-        self, start: float, end: float, tokens: int, rows: int
+        self, start: float, end: float, tokens: int, rows: int,
+        attn_visit_ratio: Optional[float] = None,
     ) -> None:
-        self.chunks.append((start, end, tokens, rows))
+        """One prefill chunk step this request rode: its dispatch times,
+        the request's tokens in it, the step's row count and, where the
+        prefill attention is blocked, the share of rows x the longest
+        row's blocks that the step's rows visited."""
+        self.chunks.append((start, end, tokens, rows, attn_visit_ratio))
 
     def mark_prefill_done(self, now: float) -> None:
         if self.prefill_done is None:
@@ -633,10 +641,11 @@ class RequestObservability:
             )
             if tl.chunks:
                 child("tpu.prefill_wait", tl.admitted, tl.chunks[0][0])
-        for i, (start, end, tokens, rows) in enumerate(tl.chunks):
+        for i, (start, end, tokens, rows, visit) in enumerate(tl.chunks):
             child(
                 "tpu.prefill.chunk", start, end,
                 index=i, tokens=tokens, rows=rows, passes=self.passes,
+                **({} if visit is None else {"attn_visit_ratio": visit}),
                 **self.model_attrs,
             )
         if tl.prefill_done is not None and tl.first_token is not None:

@@ -16,7 +16,7 @@ import numpy as np
 
 from gofr_tpu import faults
 from gofr_tpu.analysis import lockcheck
-from gofr_tpu.ops.attention import decode_read_index
+from gofr_tpu.ops.attention import chunk_visit_ratio, decode_read_index
 from gofr_tpu.serving.loop_profiler import loop_phase
 from gofr_tpu.serving.types import (
     _ActiveSeq,
@@ -77,6 +77,7 @@ class SchedulerMixin:
     pipeline_depth: int
     prefill_batch: int
     prefill_rungs: tuple[int, ...]
+    prefill_attn_block: int
     moe_products: dict[tuple[str, int], str]
     decode_read_rungs: tuple[int, ...]
     prefill_chunk: int
@@ -1479,6 +1480,13 @@ class SchedulerMixin:
             slots[i], starts[i], lens[i] = slots[0], starts[0], lens[0]
             temps[i], greedy[i], topps[i] = temps[0], greedy[0], topps[0]
 
+        # A blocked prefill attention runs each row over its own blocks of
+        # positions: the block-steps this step runs, over what running every
+        # row to the longest row's block would (padding rows cost row 0's).
+        visit_ratio = (
+            chunk_visit_ratio(starts, lens, self.prefill_attn_block)
+            if self.prefill_attn_block else None
+        )
         jnp = self._jnp
         t0 = time.time()
         t0m = self._obs.now()
@@ -1539,6 +1547,11 @@ class SchedulerMixin:
                 float(lens[: len(rows)].sum()) / (R * c),
                 "model", self.model_name,
             )
+            if visit_ratio is not None:
+                self._metrics.record_histogram(
+                    "app_tpu_prefill_attn_visit_ratio", visit_ratio,
+                    "model", self.model_name,
+                )
 
         emits_started = False
         # One clock read per chunk DISPATCH (window granularity); the
@@ -1548,7 +1561,7 @@ class SchedulerMixin:
             st.done += int(lens[i])
             tl = st.request.timeline
             if tl is not None:
-                tl.note_chunk(t0m, t1m, int(lens[i]), R)
+                tl.note_chunk(t0m, t1m, int(lens[i]), R, visit_ratio)
             if finalize[i]:
                 if tl is not None:
                     tl.mark_prefill_done(t1m)

@@ -12,7 +12,12 @@ chunk-wise product alone (``ops/linear_attention.lightning_chunk``) at the
 served precision and with its state products at the default one, a sparse
 layer's block scores with the choice (``sparse_block_scores``, ``top_k``) and
 its masked attention over blocks of positions (``sparse_chunk_attention``) by
-the context it runs to. The decode step's pieces at 16 slots: one recurrence
+the context it runs to, every row at the same depth; then with the step's
+eight rows at eight depths, as the scheduler fills a step (a row a slot, each
+at its own ``done``): eighths of the longest and the ``longctx`` cell's own
+spread (2,400 to 19,000 positions) beside every row at the longest, the loop
+in blocks of 256, 512 and 1,024 positions, with the block-steps it visits (PR
+36: a row visits its own blocks only). The decode step's pieces at 16 slots: one recurrence
 step over a layer's states, and a sparse layer's attention by the gather of
 the chosen 64 blocks (``sparse_decode_attention``) against the bounded dense
 read (``decode_attention``) at each rung of 32,768.
@@ -39,13 +44,14 @@ def main() -> int:
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from gofr_tpu.models.registry import get_model
     from gofr_tpu.models.transformer import _ffn_dense
     from gofr_tpu.ops import linear_attention
     from gofr_tpu.ops.attention import (
-        decode_attention, decode_read_rungs, sparse_block_scores,
-        sparse_chunk_attention, sparse_decode_attention,
+        chunk_block_counts, decode_attention, decode_read_rungs,
+        sparse_block_scores, sparse_chunk_attention, sparse_decode_attention,
     )
 
     cfg = get_model("sala-tiny" if args.tiny else "minicpm-sala").config
@@ -123,6 +129,30 @@ def main() -> int:
             qs, k_pl, v_pl, allowed, starts, start=start,
             blocks_of_512=-(-(start + c) // 512),
         )
+
+    # the step's rows at eight depths (chunk-aligned), every block allowed
+    # but for the causal mask: the loop's cost is its block-steps
+    longest = max_len - (2 if args.tiny else 9) * c  # 30,464 of 32,768
+    apart = {
+        "eighths": np.arange(1, P + 1) * (longest // P),
+        "cell": np.linspace(0.079 * longest, 0.624 * longest, P),
+        "all_at_longest": np.full((P,), 0.624 * longest),
+    }
+    for block in ([16, 32] if args.tiny else [256, 512, 1024]):
+        for name, depths in apart.items():
+            depths = depths.astype(np.int64) // c * c
+            counts = chunk_block_counts(depths, np.full((P,), c), block)
+            timed(
+                "sparse_chunk_attention_rows_apart",
+                lambda qs, k_pl, v_pl, starts, block=block: sparse_chunk_attention(
+                    qs, k_pl, v_pl, slots, starts, lens, None,
+                    sel_block=cfg.sparse_block, layer=jnp.int32(0), block=block,
+                ),
+                qs, k_pl, v_pl, jnp.asarray(depths, jnp.int32), depths=name,
+                block=block, starts=depths.tolist(),
+                block_steps=int(counts.sum()),
+                rows_x_longest=int(counts.max()) * P,
+            )
 
     # -- the decode step's pieces, S slots ----------------------------------
     q1, k1, v1 = (rnd(S, Hl, hl) for _ in range(3))

@@ -2,7 +2,7 @@
 functions, against the plain reference's full forward pass (ISSUE 33, part 6).
 
 The benchmark's probe is 96 + 8 tokens; this model's new paths begin beyond
-it: the prefill attention's loop over blocks of 512 positions, the decode
+it: the prefill attention's loop over blocks of positions (256), the decode
 read's rungs above 2,048. So, outside ``benchmark/``:
 
     chiprun -- python3 scripts/latent_moe_long_compare.py

@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-MODELS = ("llama-tiny", "moe-tiny", "looped-tiny", "mla-moe-tiny")
+MODELS = ("llama-tiny", "moe-tiny", "looped-tiny", "mla-moe-tiny", "sala-tiny")
 
 
 def lowered_texts(model: str) -> dict:

@@ -56,6 +56,7 @@ from gofr_tpu.serving.engine import InferenceEngine
 from gofr_tpu.serving.tokenizer import ByteTokenizer
 
 from benchmark.harness.cells import load_file
+from tests.test_latent_moe import ROWS_APART, beyond_own_blocks_poisoned
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 reference = load_file(
@@ -364,6 +365,51 @@ def test_attention_over_blocks_of_positions_is_the_one_step_mathematics():
             q, k_pl, v_pl, slots, starts, lens, None, sel_block=4, layer=1,
             block=24,
         )
+
+
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "one_entry"])
+@pytest.mark.parametrize("masked", [False, True], ids=["all_allowed", "random_allowed"])
+def test_rows_far_apart_each_visit_their_own_blocks_and_no_more(masked, stacked):
+    """The loop bounded a row (a row at 0, one ending on a block's edge, one
+    a token past it, one in max_len's last block, one with no token, one
+    with a partial chunk, two duplicates of row 0 as the scheduler pads) is
+    the one-step mathematics (``block`` >= max_len) in float32, and a row
+    never touches a block past its own last position: those hold NaN here."""
+    P, c, H, KV, hd, S, max_len = 8, 8, 4, 2, 16, 6, 128
+    key = jax.random.split(jax.random.PRNGKey(36), 4)
+    q = jax.random.normal(key[0], (P, c, H, hd))
+    q = q.at[6:].set(q[0])  # a padding row holds row 0's tokens too
+    k_pl = jax.random.normal(key[1], (2, S, KV, max_len, hd))
+    v_pl = jax.random.normal(key[2], (2, S, KV, max_len, hd))
+    allowed = None
+    if masked:
+        allowed = jax.random.bernoulli(key[3], 0.5, (P, KV, c, max_len // 4))
+        allowed = allowed.at[6:].set(allowed[0])
+    slots, starts, lens = (jnp.asarray(a) for a in ROWS_APART.values())
+
+    def attend(k_pl, v_pl, block):
+        planes = (k_pl, v_pl) if stacked else (k_pl[1], v_pl[1])
+        return sparse_chunk_attention(
+            q, *planes, slots, starts, lens, allowed, sel_block=4,
+            layer=jnp.int32(1) if stacked else None, block=block,
+        )
+
+    one_step = attend(k_pl, v_pl, max_len)
+    bounded = attend(
+        beyond_own_blocks_poisoned(k_pl), beyond_own_blocks_poisoned(v_pl), 16
+    )
+    assert bool(jnp.all(jnp.isfinite(bounded)))
+    np.testing.assert_allclose(bounded, one_step, atol=2e-5)
+    assert float(jnp.abs(bounded[4]).max()) == 0.0  # the row with no token
+    assert float(jnp.abs(bounded[5, 5:]).max()) == 0.0  # the partial chunk
+    np.testing.assert_array_equal(bounded[6], bounded[0])  # the duplicates
+    np.testing.assert_array_equal(bounded[7], bounded[0])
+    if masked:  # the mask is in force: every block allowed reads otherwise
+        everything = sparse_chunk_attention(
+            q, k_pl, v_pl, slots, starts, lens, None, sel_block=4,
+            layer=jnp.int32(1), block=16,
+        )
+        assert float(jnp.max(jnp.abs(everything - one_step))) >= 0.05
 
 
 def test_the_block_scores_are_the_references_choice():

@@ -42,6 +42,9 @@ from gofr_tpu.models.transformer import (
     transformer_prefill_chunk,
 )
 from gofr_tpu.ops.attention import (
+    LATENT_CHUNK_BLOCK,
+    chunk_block_counts,
+    chunk_visit_ratio,
     decode_read_index,
     decode_read_plan,
     decode_read_rungs,
@@ -270,6 +273,84 @@ def test_absorbed_and_expanded_chunk_attention_agree_blocked_or_not():
     )
 
 
+# A step as the scheduler fills one, a row a slot, each at its own depth
+# (max_len 128 in blocks of 16, chunk 8): a row at 0; one that ends exactly
+# on a block's edge; one a token past an edge; one in max_len's last block;
+# one with no token; one with a partial chunk; two that duplicate row 0, as
+# the scheduler pads a step.
+ROWS_APART = dict(
+    slots=np.array([2, 0, 1, 3, 4, 5, 2, 2]),
+    starts=np.array([0, 24, 25, 116, 40, 64, 0, 0]),
+    lens=np.array([8, 8, 8, 8, 0, 5, 8, 8]),
+)
+ROWS_APART_BLOCKS = [1, 2, 3, 8, 0, 5, 1, 1]  # each row's own, of 16
+
+
+def beyond_own_blocks_poisoned(plane, rows=ROWS_APART, block=16):
+    """``plane`` ([..., S, heads, max_len, width]) with NaN at every
+    position past the last block that a row of its slot attends: a loop that
+    ran a row one block beyond its own would return NaN (0 x NaN)."""
+    plane = np.array(plane)
+    reach = np.zeros(plane.shape[-4], np.int64)
+    np.maximum.at(
+        reach, rows["slots"],
+        chunk_block_counts(rows["starts"], rows["lens"], block) * block,
+    )
+    for slot, upto in enumerate(reach):
+        plane[..., slot, :, upto:, :] = np.nan
+    return jnp.asarray(plane)
+
+
+def test_the_block_steps_of_a_step_are_the_sum_of_its_rows_own():
+    """The pure function the loops take their trip counts from, and the
+    ratio the scheduler's histogram records for the same starts and lens."""
+    starts, lens = ROWS_APART["starts"], ROWS_APART["lens"]
+    counts = chunk_block_counts(starts, lens, 16)
+    assert counts.tolist() == ROWS_APART_BLOCKS
+    assert chunk_block_counts(
+        jnp.asarray(starts), jnp.asarray(lens), 16
+    ).tolist() == ROWS_APART_BLOCKS
+    assert chunk_visit_ratio(starts, lens, 16) == sum(ROWS_APART_BLOCKS) / (8 * 8)
+    # every row as deep as the deepest, and a lone row: nothing to skip
+    assert chunk_visit_ratio(np.full(8, 512), np.full(8, 256), 512) == 1.0
+    assert chunk_visit_ratio(np.array([4096]), np.array([256]), 512) == 1.0
+    # a block that is the whole slot: one step a row
+    assert chunk_visit_ratio(starts, lens, 128) == 7 / 8
+    assert chunk_visit_ratio(np.zeros(2, int), np.zeros(2, int), 16) == 0.0
+
+
+@pytest.mark.parametrize("stacked", [True, False], ids=["layer", "one_entry"])
+def test_rows_far_apart_each_visit_their_own_blocks_and_no_more(stacked):
+    """The loop bounded a row is the one-step mathematics (``block`` >=
+    max_len) in float32, and a row never touches a block past its own last
+    position: those hold NaN here."""
+    ks = jax.random.split(jax.random.PRNGKey(36), 4)
+    plane = random_plane(ks[0], entries=2, slots=6, max_len=128)
+    slots, starts, lens = (jnp.asarray(a) for a in ROWS_APART.values())
+    q = jax.random.normal(ks[1], (8, 8, HEADS, NOPE + ROPE))
+    q = q.at[6:].set(q[0])  # a padding row holds row 0's tokens too
+    w_uk = jax.random.normal(ks[2], (RANK, HEADS, NOPE)) * RANK**-0.5
+    w_uv = jax.random.normal(ks[3], (RANK, HEADS, VD)) * RANK**-0.5
+    scale = (NOPE + ROPE) ** -0.5
+    entry = lambda pl: (pl, jnp.int32(1)) if stacked else (pl[1], None)  # noqa: E731
+
+    def attend(plane, block):
+        plane, layer = entry(plane)
+        return latent_chunk_attention(
+            q, plane, slots, starts, lens, w_uk, w_uv, scale=scale,
+            layer=layer, block=block,
+        )
+
+    one_step = attend(plane, 128)
+    bounded = attend(beyond_own_blocks_poisoned(plane), 16)
+    assert bool(jnp.all(jnp.isfinite(bounded)))
+    np.testing.assert_allclose(bounded, one_step, atol=2e-5)
+    assert float(jnp.abs(bounded[4]).max()) == 0.0  # the row with no token
+    assert float(jnp.abs(bounded[5, 5:]).max()) == 0.0  # the partial chunk
+    np.testing.assert_array_equal(bounded[6], bounded[0])  # the duplicates
+    np.testing.assert_array_equal(bounded[7], bounded[0])
+
+
 def test_the_bounded_decode_read_equals_the_whole_read_at_every_rung():
     """At 512 positions the rungs are 128 / 256 / 384 / 512: a slot that
     fits its rung reads the same attention as over the whole cache."""
@@ -451,7 +532,7 @@ def test_no_product_over_held_x_rows_and_no_whole_score_array(params):
             ), (name, shape)
             # never scores of heads x chunk x every position at once
             assert not {cfg.n_heads, c, 2048} <= set(shape), (name, shape)
-    # at 2,048 positions the prefill scores are alive 512 positions at a time
+    # at 2,048 positions the prefill scores are alive a block at a time,
     longer = LatentKVCache.create(
         cfg.n_cache_entries, slots, 2048, cfg.cache_row, cfg.dtype
     )
@@ -461,11 +542,13 @@ def test_no_product_over_held_x_rows_and_no_whole_score_array(params):
             jnp.full((rows,), c, jnp.int32), cfg,
         )
     )(held, longer.k)
+    # a row's own: no float32 array of the loop keeps the step's rows axis
     scores = [
         shape for _, shape, dtype, _ in shapes_in(blocked.jaxpr)
-        if dtype == jnp.float32 and len(shape) == 4 and shape[:3] == (rows, cfg.n_heads, c)
+        if dtype == jnp.float32 and shape[-3:-1] == (cfg.n_heads, c)
     ]
-    assert (rows, cfg.n_heads, c, 512) in scores
+    assert (cfg.n_heads, c, LATENT_CHUNK_BLOCK) in scores
+    assert (rows, cfg.n_heads, c, LATENT_CHUNK_BLOCK) not in scores
     assert not any(2048 in shape for shape in scores)
     for name, shape, _, _ in shapes_in(blocked.jaxpr):
         assert not {cfg.n_heads, c, 2048} <= set(shape), (name, shape)

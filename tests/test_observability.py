@@ -210,7 +210,7 @@ def _timeline_up_to(hub, t, last_mark):
     if "admitted" in reached:
         tl.mark_admitted(MARKS["admitted"])
     if "chunk0" in reached:
-        tl.note_chunk(MARKS["chunk0"], MARKS["chunk0"] + 0.0625, 256, 2)
+        tl.note_chunk(MARKS["chunk0"], MARKS["chunk0"] + 0.0625, 256, 2, 0.75)
     if "prefill_done" in reached:
         tl.note_chunk(MARKS["chunk1"], MARKS["prefill_done"], 44, 1)
         tl.mark_prefill_done(MARKS["prefill_done"])
@@ -330,6 +330,9 @@ def test_ttft_split_records_one_histogram_sample_and_one_span_per_phase(
     # Each carries the row count of the step it rode in.
     assert [(c.attributes["tokens"], c.attributes["rows"]) for c in chunks] \
         == [(256, 2), (44, 1)]
+    # ... and, where the step's attention ran in blocks a row, the share of
+    # rows x the longest row's blocks it visited; absent where none was given.
+    assert [c.attributes.get("attn_visit_ratio") for c in chunks] == [0.75, None]
 
 
 def test_flight_recorder_evicts_ring_but_pins_survive_burst():
