@@ -608,6 +608,38 @@ class Container:
             "scheduler-loop stall anomalies (pass over TPU_LOOP_STALL_S "
             "or TPU_LOOP_STALL_FACTOR x rolling p95; kind=absolute|p95)",
         )
+        # The device's own timeline (the loop profiler's watcher: each
+        # dispatched program stamped as the device finishes it) and the
+        # entry layer's hand-off of each window's tokens.
+        device_buckets = (
+            0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1,
+            2.5, 5,
+        )
+        m.new_histogram(
+            "app_tpu_program_device_seconds",
+            "a dispatched program's device time: its ready stamp minus "
+            "its start (its dispatch, or the previous program's ready)",
+            device_buckets,
+        )
+        m.new_histogram(
+            "app_tpu_program_queued_seconds",
+            "a dispatched program's wait behind the programs ahead of it "
+            "on the device: its start minus its dispatch", device_buckets,
+        )
+        m.new_counter(
+            "app_tpu_device_seconds_total",
+            "device seconds by state: busy (cause=<program>) or idle, "
+            "dry with nothing queued (cause=<the loop's phase when it "
+            "ran dry>; idle = the loop waited for work)",
+        )
+        m.new_histogram(
+            "app_tpu_token_handoff_seconds",
+            "a window's tokens in the scheduler's hand to the stream's "
+            "next SSE chunk written (the read, the decode, the write), "
+            "one record a window a stream",
+            (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+             0.5, 1),
+        )
         # Control plane (serving/control_plane.py; docs/advanced-guide/
         # resilience.md "Control plane"): per-signal guard health, the
         # per-tenant brownout ladder (label set bounded by the ladder

@@ -69,10 +69,21 @@ know when to look. This module is that answer, always on:
   accumulated into ``self_overhead_s`` and reported on ``/debug/loop``
   — the profiler's cost is a number, not a hope. The bench A/B
   (``TPU_LOOP_PROFILE=0``) pins the whole layer's cost.
+* **The device's own timeline** (:class:`DeviceTimeline`, PR 37). The
+  scheduler registers every program it dispatches (its name, the
+  dispatch stamp, one small output no later program donates); one
+  daemon watcher thread waits on them in dispatch order, as the device
+  runs them, and stamps each ``ready`` on this profiler's clock. From
+  the stamps alone: ``start = max(dispatch, previous ready)``,
+  ``queued = start - dispatch``, ``device = ready - start``, and the gap
+  ``dispatch - previous ready`` in which the device had nothing, put
+  down to the loop's phase at that previous ready. A stalled pass's
+  record says what the device did inside it.
 
 Off is off: ``TPU_LOOP_PROFILE=0`` builds no profiler — every scheduler
-phase runs in one shared no-op context (:func:`loop_phase`) and the loop
-is byte-identical to the pre-profiler scheduler.
+phase runs in one shared no-op context (:func:`loop_phase`), no watcher
+thread starts, and the loop is byte-identical to the pre-profiler
+scheduler.
 
 Determinism: every mutation takes the timestamp as an argument (the
 caller reads the clock once per boundary), so tests drive exact phase
@@ -84,14 +95,18 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import queue
+import threading
 import time
 from collections import deque
 from itertools import islice
 from typing import Any, Callable, ContextManager, Iterator, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from gofr_tpu.analysis import lockcheck
+from gofr_tpu.serving import profiler_capture
 
 #: The bounded phase vocabulary (it appears in metric labels — GL016
 #: discipline): the scheduler loop's boundaries, in pass order, plus
@@ -205,6 +220,261 @@ def _pctl(sorted_vals: list[float], q: float) -> float:
     return sorted_vals[idx]
 
 
+# -- the device's own timeline -------------------------------------------
+
+#: How long the watcher waits on an empty queue before it looks whether
+#: the scheduler thread it serves is still alive.
+WATCH_POLL_S = 0.5
+
+#: Device spans (busy or dry) kept for the stall records: thousands of
+#: programs, minutes of serving.
+DEVICE_SPANS = 4096
+
+
+class _Watch:
+    """One scheduler thread's watcher: its queue, and the timeline's state
+    since that thread started (the previous program's ready stamp, the
+    loop's phase and idle-wait count then, and the earliest dispatch of
+    the programs folded into the next one)."""
+
+    __slots__ = ("queue", "owner", "thread", "superseded", "ready",
+                 "phase", "idle_waits", "fold")
+
+    def __init__(self, owner: threading.Thread) -> None:
+        self.queue: "queue.SimpleQueue[Any]" = queue.SimpleQueue()
+        self.owner = owner
+        self.thread: Optional[threading.Thread] = None  # the watcher
+        self.superseded = False
+        self.ready: Optional[float] = None
+        self.phase = "other"
+        self.idle_waits = 0
+        self.fold: Optional[float] = None
+
+
+class DeviceTimeline:
+    """Each dispatched program's queue and device time, and the device's
+    dry spells put down to the loop's phase (PR 37).
+
+    The scheduler thread registers a program right after dispatching it
+    (:meth:`register`: a queue put). One daemon thread a scheduler thread
+    takes them in dispatch order, which is the order one device runs
+    them, waits on each one's output (``block_until_ready`` releases the
+    GIL) inside ``TraceAnnotation("device_wait/<program>")``, and stamps
+    ``ready`` on the profiler's clock. :meth:`settle` derives the rest
+    from the stamps alone, so busy plus dry time over a stretch is
+    ``ready_last - ready_first`` exactly.
+
+    A program whose outputs are all planes that the next program donates
+    has nothing to wait on: it is registered with ``out=None`` and folded
+    into the next program the watcher sees, whose interval then starts at
+    the folded one's dispatch (the device is not dry while it runs); that
+    record counts as busy and feeds no per-program histogram, nor does a
+    program whose wait a profiler capture overlapped.
+
+    The watcher lives with the scheduler thread that started it: a newer
+    :meth:`start` (the supervisor's restart) supersedes it and it drops
+    its queue, and it leaves on its own once its thread is gone. It takes
+    no lock the restart takes; its own lock guards the sums it shares
+    with ``/debug/loop``."""
+
+    def __init__(
+        self,
+        model_name: str,
+        *,
+        metrics: Any = None,
+        clock: Callable[[], float] = time.monotonic,
+        loop: "Optional[LoopProfiler]" = None,
+    ) -> None:
+        self.model_name = model_name
+        self._metrics = metrics
+        self._clock = clock
+        self._loop = loop
+        self._watch: Optional[_Watch] = None
+        self._lock = lockcheck.make_lock("DeviceTimeline._lock")
+        # name -> [count, device seconds, queued seconds]
+        self._programs: dict[str, list] = {}
+        self._idle: dict[str, float] = {}   # dry seconds by loop phase
+        self._folded: dict[str, int] = {}
+        # (t0, t1, state, cause), oldest first; a stall record reads them.
+        self._spans: deque[tuple[float, float, str, str]] = deque(
+            maxlen=DEVICE_SPANS
+        )
+        # (record, start, end): stall records waiting for the device's side.
+        self._stalls: list[tuple[dict[str, Any], float, float]] = []
+
+    # -- the scheduler thread's side --------------------------------------
+
+    def start(self) -> _Watch:
+        """Start the calling scheduler thread's watcher, superseding any
+        earlier one (whose queue is dropped)."""
+        old = self._watch
+        if old is not None:
+            old.superseded = True
+            old.queue.put(None)
+        w = _Watch(threading.current_thread())
+        self._watch = w
+        w.thread = threading.Thread(
+            target=self._run, args=(w,), name="tpu-device-watch", daemon=True
+        )
+        w.thread.start()
+        return w
+
+    def register(self, name: str, dispatch: float, out: Any) -> None:
+        """A program just dispatched: its name, its dispatch stamp, and one
+        small output no later program donates (``None``: fold it)."""
+        w = self._watch
+        if w is not None:
+            w.queue.put((name, dispatch, out))
+
+    # -- the watcher ---------------------------------------------------
+
+    def _run(self, w: _Watch) -> None:
+        loop = self._loop
+        while True:
+            try:
+                item = w.queue.get(timeout=WATCH_POLL_S)
+            except queue.Empty:
+                if w.superseded or not w.owner.is_alive():
+                    return
+                continue
+            if item is None or w.superseded:
+                return
+            name, dispatch, out = item
+            # Did the loop wait for work since the previous ready? Then
+            # a dry spell before this program was the loop's own idle.
+            idled = loop is not None and loop.idle_waits != w.idle_waits
+            # A capture's start and stop hold the runtime's completions
+            # back (the stop of a 2 s capture froze the engine for 1.0 to
+            # 3.2 s on the v5e, PR 37): a program it overlaps is not timed.
+            traced = profiler_capture.capturing()
+            if out is not None:
+                with TraceAnnotation("device_wait/" + name):
+                    try:
+                        jax.block_until_ready(out)
+                    except Exception:  # graftlint: disable=GL006 — a failed or abandoned program has no ready stamp; the scheduler reports the failure
+                        out = None
+            if out is None:
+                self.fold(w, name, dispatch)
+                continue
+            ready = self._clock()
+            if w.superseded:
+                return
+            phase = "other" if loop is None else loop.current_phase
+            self.settle(w, name, dispatch, ready, phase, idled,
+                        timed=not (traced or profiler_capture.capturing()))
+            if loop is not None:
+                w.idle_waits = loop.idle_waits
+
+    def fold(self, w: _Watch, name: str, dispatch: float) -> None:
+        """A program with nothing to wait on: the next one seen carries it."""
+        if w.fold is None:
+            w.fold = dispatch
+        with self._lock:
+            self._folded[name] = self._folded.get(name, 0) + 1
+
+    def settle(
+        self, w: _Watch, name: str, dispatch: float, ready: float,
+        phase: str, idled: bool = False, timed: bool = True,
+    ) -> None:
+        """Program ``name`` dispatched at ``dispatch`` finished at
+        ``ready``; the loop was in ``phase`` then, and ``idled`` says
+        whether it waited for work since the previous program's ready.
+        ``timed`` False (a capture overlapped the wait) keeps the record
+        out of the per-program histograms."""
+        prev = w.ready
+        folded = w.fold is not None
+        timed = timed and not folded
+        first = w.fold if folded else dispatch
+        start = first if prev is None else max(first, prev)
+        gap = 0.0 if prev is None else max(0.0, first - prev)
+        device = ready - start
+        queued = start - dispatch
+        cause = "idle" if idled else w.phase
+        w.ready, w.phase, w.fold = ready, phase, None
+        filled: list = []
+        spans: list = []
+        with self._lock:
+            if timed:
+                rec = self._programs.setdefault(name, [0, 0.0, 0.0])
+                rec[0] += 1
+                rec[1] += device
+                rec[2] += queued
+            if gap > 0.0:
+                self._idle[cause] = self._idle.get(cause, 0.0) + gap
+                self._spans.append((prev, start, "idle", cause))
+            self._spans.append((start, ready, "busy", name))
+            if self._stalls:
+                filled = [s for s in self._stalls if s[2] <= ready]
+                if filled:
+                    self._stalls = [s for s in self._stalls if s[2] > ready]
+                    spans = list(self._spans)
+        for record, s0, s1 in filled:
+            _fill_stall(record, spans, s0, s1)
+        m = self._metrics
+        if m is None:
+            return
+        model = self.model_name
+        if timed:
+            m.record_histogram(
+                "app_tpu_program_device_seconds", device,
+                "model", model, "program", name,
+            )
+            m.record_histogram(
+                "app_tpu_program_queued_seconds", queued,
+                "model", model, "program", name,
+            )
+        if device > 0.0:
+            m.add_counter(
+                "app_tpu_device_seconds_total", device,
+                "model", model, "state", "busy", "cause", name,
+            )
+        if gap > 0.0:
+            m.add_counter(
+                "app_tpu_device_seconds_total", gap,
+                "model", model, "state", "idle", "cause", cause,
+            )
+
+    # -- readers -------------------------------------------------------
+
+    def note_stall(self, record: dict[str, Any], start: float,
+                   end: float) -> None:
+        """A stalled pass ``[start, end]``: its record's ``device_busy_s``,
+        ``device_idle_s`` and ``device_idle_cause`` are filled once the
+        device's timeline has passed ``end``."""
+        with self._lock:
+            self._stalls.append((record, start, end))
+
+    def snapshot(self) -> dict[str, Any]:
+        with self._lock:
+            return {
+                "programs": {
+                    name: {"count": n, "device_s": round(dev, 6),
+                           "queued_s": round(q, 6)}
+                    for name, (n, dev, q) in self._programs.items()
+                },
+                "idle_s": {k: round(v, 6) for k, v in self._idle.items()},
+                "folded": dict(self._folded),
+            }
+
+
+def _fill_stall(record: dict[str, Any], spans: list, start: float,
+                end: float) -> None:
+    """What the device did inside ``[start, end]``, from its spans."""
+    busy = 0.0
+    idle: dict[str, float] = {}
+    for t0, t1, state, cause in spans:
+        overlap = min(t1, end) - max(t0, start)
+        if overlap <= 0.0:
+            continue
+        if state == "busy":
+            busy += overlap
+        else:
+            idle[cause] = idle.get(cause, 0.0) + overlap
+    record["device_idle_cause"] = {k: round(v, 6) for k, v in idle.items()}
+    record["device_idle_s"] = round(sum(idle.values()), 6)
+    record["device_busy_s"] = round(busy, 6)
+
+
 class LoopProfiler:
     """Per-phase time attribution + stall detection for one engine's
     scheduler loop. Written by the scheduler thread only (``begin_pass``
@@ -263,6 +533,16 @@ class LoopProfiler:
         self.compiles: Optional[Callable[[], int]] = None
         self._last_compiles = 0
         self._lock = lockcheck.make_lock("LoopProfiler._lock")
+        #: The phase the scheduler thread is in (``other`` between
+        #: phases) and how many idle waits it began: the device
+        #: timeline's watcher reads both when the device runs dry.
+        self.current_phase = "other"
+        self.idle_waits = 0
+        #: The device's own timeline (its watcher starts with the
+        #: scheduler thread: :meth:`DeviceTimeline.start`).
+        self.device = DeviceTimeline(
+            model_name, metrics=metrics, clock=clock, loop=self
+        )
         # Current-pass accumulation (scheduler thread only — no lock).
         self._pass_start: Optional[float] = None
         self._last_stamp = 0.0
@@ -348,11 +628,29 @@ class LoopProfiler:
         on the calling thread and lap it on exit: one clock read per
         boundary, and the profiler's capture shows the phase on a host
         line beside the device's ops."""
-        with TraceAnnotation("loop/" + name):
-            yield
-            # (Not reached when the body raises: a thread the supervisor
-            # abandoned must not stamp the pass of the one after it.)
-            self.lap(name, self._clock())
+        outer = self.current_phase
+        self.current_phase = name
+        if name == "idle":
+            self.idle_waits += 1
+        try:
+            with TraceAnnotation("loop/" + name):
+                yield
+                # (Not reached when the body raises: a thread the
+                # supervisor abandoned must not stamp the pass of the one
+                # after it.)
+                self.lap(name, self._clock())
+        finally:
+            self.current_phase = outer
+
+    def dispatched(self, name: str, out: Any,
+                   at: Optional[float] = None) -> None:
+        """The scheduler dispatched program ``name`` (``out``: one small
+        output no later program donates, or ``None`` to fold it into the
+        next). ``at`` is its dispatch stamp; by default the last phase
+        boundary's, which the ``dispatch`` phase stamps as the window's
+        call returns."""
+        self.device.register(name, self._last_stamp if at is None else at,
+                             out)
 
     # -- pass summarization --------------------------------------------
 
@@ -464,6 +762,12 @@ class LoopProfiler:
                     "proc_cpu_s": round(max(0.0, proc_cpu_s), 6),
                     "gc_s": round(max(0.0, gc_s), 6),
                     "next_pass": None,  # until the next pass closes
+                    # What the device did inside the pass: busy and dry
+                    # seconds, the dry ones by the loop's phase, filled
+                    # in once the device's timeline has passed its end.
+                    "device_busy_s": None,
+                    "device_idle_s": None,
+                    "device_idle_cause": None,
                 }
                 self._awaits_next = anomaly
             elif not kind:
@@ -471,6 +775,7 @@ class LoopProfiler:
             util = self._utilization_locked()
             host = self._host_overhead_locked()
         if anomaly is not None:
+            self.device.note_stall(anomaly, start, now)
             self._record_anomaly(anomaly)
         if self._metrics is not None:
             self._publish(acc, util, host)
@@ -626,6 +931,7 @@ class LoopProfiler:
                 "anomalies": list(self._anomalies),
                 "pinned_anomalies": list(self._pinned),
             }
+        out["device"] = self.device.snapshot()
         if self._capture is not None and self.trace_ms > 0:
             out["trace"] = dict(self._capture.snapshot())
             out["trace_ms"] = self.trace_ms

@@ -509,6 +509,17 @@ class RequestObservability:
     def now(self) -> float:
         return self._clock()
 
+    def note_handoff(self, handed: float) -> None:
+        """A stream's chunk went out; ``handed`` is when the scheduler
+        had that window's tokens in hand (``TokenStream.handed``): the
+        executor or loop read, the tokenizer's decode and the write, one
+        record a window a stream."""
+        if self._metrics is not None:
+            self._metrics.record_histogram(
+                "app_tpu_token_handoff_seconds", self._clock() - handed,
+                "model", self.model_name,
+            )
+
     def begin(
         self,
         prompt_tokens: int,

@@ -255,6 +255,7 @@ def add_openai_routes(
             printed = ""
             reason = "stop"
             timeline = getattr(req, "timeline", None)
+            handed = 0.0  # the newest window stamp whose hand-off is timed
 
             def payload_of(text: str) -> dict:
                 nonlocal sent_tokens
@@ -287,6 +288,9 @@ def add_openai_routes(
                     tok = await next_token(req.stream)
                     if tok is None:
                         break
+                    # Stamped before the window's puts: this token's
+                    # window, or a newer one if the handler fell behind.
+                    stamp = getattr(req.stream, "handed", 0.0)
                     emitted_ids.append(tok)
                     # What this token sends: None is nothing, "" a chunk
                     # that carries only token ids.
@@ -315,9 +319,13 @@ def add_openai_routes(
                         yield _sse(rid, object_name, model, created,
                                    payload_of(text))
                         # Back from the yield: the chunk is written. The
-                        # first one closes the timeline's delivery phase.
+                        # first one closes the timeline's delivery phase;
+                        # the first of each window times its hand-off.
                         if timeline is not None:
                             timeline.mark_first_written()
+                            if stamp > handed:
+                                handed = stamp
+                                timeline.hub.note_handoff(stamp)
                 brownout_flag = False
                 if stopped:
                     reason = "stop"

@@ -198,6 +198,12 @@ _capture: Optional[ProfilerCapture] = None
 _capture_lock = lockcheck.make_lock("profiler_capture._capture_lock")
 
 
+def capturing() -> bool:
+    """A capture holds the slot now (without minting the singleton)."""
+    cap = _capture
+    return cap is not None and cap.busy
+
+
 def get_capture(cooldown_s: Optional[float] = None) -> ProfilerCapture:
     """The process-wide singleton, constructed exactly once under a
     module lock (closing the lazy-``hasattr`` race the old endpoint

@@ -202,6 +202,11 @@ class SchedulerMixin:
         # GL011's discipline). Off (TPU_LOOP_PROFILE=0) = one shared
         # no-op context per boundary.
         prof = self._loop_prof
+        if prof is not None:
+            # The device's own timeline: a watcher thread that stamps
+            # each program this thread dispatches as the device finishes
+            # it (serving/loop_profiler.py DeviceTimeline).
+            prof.device.start()
         try:
             while self._running and self._epoch == epoch:
                 # begin_pass also CLOSES the previous pass: residual
@@ -325,6 +330,9 @@ class SchedulerMixin:
                 if wants_more:
                     with loop_phase(prof, "dispatch"):
                         inflight.append(self._dispatch_window())
+                    if prof is not None:
+                        # Dispatched as the phase's closing stamp read.
+                        prof.dispatched("decode_window", inflight[-1][0])
                 keep = self.pipeline_depth if wants_more else 0
                 if len(inflight) > keep:
                     # The designated device-wait seam: the fetch block
@@ -783,6 +791,7 @@ class SchedulerMixin:
                     self._up(np.int32(src)),
                     self._up(np.int32(dst)),
                 )
+                self._cache_program("paged_copy_block")
                 row[-1] = dst
                 self._table_host[slot, len(row) - 1] = dst
                 self._allocator.decref(src)
@@ -806,6 +815,14 @@ class SchedulerMixin:
                 "model", self.model_name,
             )
             self._publish_prefix_gauge()
+
+    def _cache_program(self, name: str, out: Any = None) -> None:
+        """A program on the cache just dispatched, for the device's
+        timeline: ``out`` an output no later program donates, or ``None``
+        (every output is a plane the next program donates) to fold it into
+        the next one the timeline sees."""
+        if self._loop_prof is not None:
+            self._loop_prof.dispatched(name, out, self._obs.now())
 
     def _push_table(self) -> None:
         """Upload the block-table mirror if admission/top-up dirtied it."""
@@ -977,6 +994,7 @@ class SchedulerMixin:
                         self._up(payload.v_s[:, j]),
                     ]
                 self.cache = self._paged_insert_block(*args)
+                self._cache_program("paged_insert_block")
             chain.append(bid)
             imported += 1
         n = start + imported
@@ -1032,6 +1050,7 @@ class SchedulerMixin:
                 v_s_blk = jax.device_put(v_s_blk, self._block_sharding)
             args += [k_s_blk, v_s_blk]
         self.cache = self._paged_move_block(*args)
+        self._cache_program("paged_move_block")
 
     def _export_payload_device_leg(
         self, block_ids: "list[int]", token_ids: "list[int]"
@@ -1054,6 +1073,7 @@ class SchedulerMixin:
             k_blk, v_blk, k_s_blk, v_s_blk = self._paged_extract_block(
                 self.cache, self._up(np.int32(bid))
             )
+            self._cache_program("paged_extract_block", k_blk)
             ks.append(k_blk)
             vs.append(v_blk)
             if k_s_blk is not None:
@@ -1366,6 +1386,7 @@ class SchedulerMixin:
                     self.cache = self._prefix_pool.load(
                         self.cache, idx, slot, plen
                     )
+                    self._cache_program("prefix_load")
                     state.done = min(plen, len(pids) - 1)
                     if self._metrics is not None:
                         self._metrics.increment_counter(
@@ -1488,7 +1509,6 @@ class SchedulerMixin:
             if self.prefill_attn_block else None
         )
         jnp = self._jnp
-        t0 = time.time()
         t0m = self._obs.now()
         self._push_table()
         args = self._prefill_operands(
@@ -1522,9 +1542,6 @@ class SchedulerMixin:
             self._jax.block_until_ready(first_dev)  # graftlint: disable=GL019 — multi-process CPU lockstep barrier (gloo collective ordering), a deliberate device wait
         if self._metrics is not None:
             self._metrics.record_histogram(
-                "app_tpu_infer_latency", time.time() - t0, "kind", "prefill"
-            )
-            self._metrics.record_histogram(
                 "app_tpu_batch_size", len(rows), "batcher", "prefill"
             )
             self._metrics.increment_counter(
@@ -1557,6 +1574,8 @@ class SchedulerMixin:
         # One clock read per chunk DISPATCH (window granularity); the
         # per-row loop below only copies it into timelines.
         t1m = self._obs.now()
+        if self._loop_prof is not None:
+            self._loop_prof.dispatched("prefill_chunk", first_dev, t1m)
         for i, (slot, st) in enumerate(rows):
             st.done += int(lens[i])
             tl = st.request.timeline
@@ -1583,6 +1602,7 @@ class SchedulerMixin:
                             st.request.prompt_ids, self.cache, slot,
                             r_aid,
                         )
+                        self._cache_program("prefix_store")
                         if not st.request.future.done():
                             st.request.future.set_result(idx)
                     st.request.stream.put(None)
@@ -1669,6 +1689,7 @@ class SchedulerMixin:
         # landed together, so a shared stamp loses nothing).
         now = time.time()
         now_m = self._obs.now()
+        handoff = self._loop_prof is not None
         for entry in self._prefill_emits:
             first_dev, lp_dev, ftopi_dev, ftopl_dev, row, slot, seq = entry
             req = seq.request
@@ -1704,6 +1725,8 @@ class SchedulerMixin:
             seq.first_emitted = True
             if req.timeline is not None:
                 req.timeline.mark_first_token(now_m)
+            if handoff:
+                req.stream.handed = now_m
             seq.last_token = tok
             seq.n_generated += 1
             self._emit_token(seq, tok, lp, top)
@@ -1914,7 +1937,6 @@ class SchedulerMixin:
         live_positions: int,
         longest: int,
     ) -> None:
-        t_fetch = time.time()
         # Interruptible wait: while this window's block is in flight, flush
         # any prefill first-token fetches that land first (unloaded TTFT
         # would otherwise be gated on the window fetch).
@@ -1939,15 +1961,12 @@ class SchedulerMixin:
         # restarted scheduler's allocator.
         self._check_superseded()
         etops_host = np.asarray(etops) if etops is not None else None
-        if self._metrics is not None:
-            # decode_fetch = host-blocking time (what pipelining hides).
-            self._metrics.record_histogram(
-                "app_tpu_infer_latency", time.time() - t_fetch,
-                "kind", "decode_fetch",
-            )
 
         now = time.time()
         mono_now = self._obs.now()  # shared by every row in this window
+        # The entry layer times each stream's hand-off from this stamp:
+        # one attribute write a row, before the row's puts.
+        handoff = self._loop_prof is not None
         for i, seq in enumerate(snapshot):
             if seq is None:
                 continue
@@ -1990,6 +2009,8 @@ class SchedulerMixin:
             want_top = (
                 etops_host is not None and seq.request.top_logprobs
             )
+            if handoff:
+                seq.request.stream.handed = mono_now
             for step in range(self.window_k):
                 if seq.first_emitted and not seq.first_skip_done:
                     # This position repeats the prefill-sampled token

@@ -51,6 +51,11 @@ class TokenStream(queue.Queue):
         # A wake-up is on its way to the loop: one call a burst of puts,
         # not one a token (a decode window puts window_k tokens at once).
         self._signalled = False
+        #: When the scheduler had the newest window's tokens for this
+        #: stream in hand (its loop profiler's clock; written once a
+        #: window, before the puts, and only while the profiler is on).
+        #: The SSE handler times the hand-off from it.
+        self.handed = 0.0
 
     def put(self, item: Any, block: bool = True,
             timeout: Optional[float] = None) -> None:

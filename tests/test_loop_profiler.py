@@ -38,12 +38,20 @@ Covered:
 * layer-off (``TPU_LOOP_PROFILE=0``): no profiler object, no hooks, a
   byte-identical greedy stream;
 * advertisement: health details / capacity_report / flight_records
-  headline / pool ``loop_report`` all carry the loop stats.
+  headline / pool ``loop_report`` all carry the loop stats;
+* the device's own timeline (PR 37): queued, device and dry seconds from
+  stated dispatch and ready stamps, a dry spell put down to the loop's
+  phase at the previous ready (or to its own wait for work), busy plus dry
+  equal to the stretch between the first and the last ready exactly, a
+  folded program, a stalled pass's device seconds, the watcher's thread
+  bound to its scheduler thread (an abandoned one blocks no restart), and
+  none of it with the layer off.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 
 import pytest
 
@@ -54,7 +62,9 @@ from gofr_tpu.serving.loop_profiler import (
     PHASES,
     REL_STALL_FLOOR_S,
     REL_STALL_MIN_SAMPLES,
+    DeviceTimeline,
     LoopProfiler,
+    _Watch,
     loop_phase,
 )
 from gofr_tpu.serving.profiler_capture import ProfilerCapture
@@ -74,9 +84,32 @@ def loop_metrics() -> Manager:
         "app_tpu_loop_stalls_total",
         "app_tpu_loop_phase_seconds_total",
         "app_tpu_gc_pause_seconds_total",
+        "app_tpu_device_seconds_total",
     ):
         m.new_counter(name)
+    for name in TIMELINE_HISTOGRAMS:
+        m.new_histogram(name, "", (0.01, 0.1, 1.0))
     return m
+
+
+#: The timeline's histograms; with ``app_tpu_device_seconds_total`` the
+#: four series the layer mints.
+TIMELINE_HISTOGRAMS = (
+    "app_tpu_program_device_seconds",
+    "app_tpu_program_queued_seconds",
+    "app_tpu_token_handoff_seconds",
+)
+
+
+def hist(m: Manager, name: str, **labels: str) -> tuple[float, int]:
+    """(sum, count) of a histogram over the series that carry ``labels``."""
+    inst = [i for i in m.instruments() if i.name == name]
+    total, count = 0.0, 0
+    want = set(labels.items())
+    for key, (_, (s, c)) in (inst[0].collect().items() if inst else ()):
+        if want <= set(key):
+            total, count = total + s, count + c
+    return total, count
 
 
 def gauge_values(m: Manager, name: str) -> dict:
@@ -563,6 +596,203 @@ def test_get_capture_is_a_race_free_singleton():
 
 
 # ----------------------------------------------------------------------
+# the device's own timeline
+# ----------------------------------------------------------------------
+
+
+def test_device_timeline_arithmetic_on_stated_clocks():
+    m = loop_metrics()
+    tl = DeviceTimeline("m", metrics=m)
+    w = _Watch(threading.current_thread())
+    total = "app_tpu_device_seconds_total"
+    # The thread's first program: it starts at its dispatch, no gap before.
+    tl.settle(w, "decode_window", 1.0, 1.5, "dispatch")
+    first = counter_value(m, total)
+    # A prefill step dispatched behind it waits for it: starts at 1.5.
+    tl.settle(w, "prefill_chunk", 1.25, 2.0, "prefill")
+    # Registered while the device's queue is empty: it starts at its own
+    # dispatch, and the dry half second goes to the phase the loop was in
+    # when the device finished the program before it.
+    tl.settle(w, "decode_window", 2.5, 3.0, "device_window")
+    # The loop waited for work since that ready: the dry spell is its own.
+    tl.settle(w, "prefill_chunk", 3.25, 3.5, "emit_flush", idled=True)
+    assert hist(m, "app_tpu_program_device_seconds",
+                program="prefill_chunk") == (0.75, 2)
+    assert hist(m, "app_tpu_program_queued_seconds",
+                program="prefill_chunk") == (0.25, 2)
+    assert hist(m, "app_tpu_program_device_seconds",
+                program="decode_window") == (1.0, 2)
+    assert hist(m, "app_tpu_program_queued_seconds",
+                program="decode_window") == (0.0, 2)
+    assert counter_value(m, total, state="idle", cause="prefill") == 0.5
+    assert counter_value(m, total, state="idle", cause="idle") == 0.25
+    assert counter_value(m, total, state="busy",
+                         cause="decode_window") == 1.0
+    # Busy plus dry from the first ready on is the stretch between the
+    # first and the last ready, exactly.
+    assert counter_value(m, total) - first == 3.5 - 1.5
+    snap = tl.snapshot()
+    assert snap["programs"]["prefill_chunk"] == {
+        "count": 2, "device_s": 0.75, "queued_s": 0.25,
+    }
+    assert snap["idle_s"] == {"prefill": 0.5, "idle": 0.25}
+
+
+def test_a_program_with_nothing_to_wait_on_is_folded_into_the_next():
+    m = loop_metrics()
+    tl = DeviceTimeline("m", metrics=m)
+    w = _Watch(threading.current_thread())
+    total = "app_tpu_device_seconds_total"
+    tl.settle(w, "decode_window", 0.0, 1.0, "prefill")
+    # A block copy whose only output the prefill step donates: the device
+    # runs it from its dispatch, so it is dry until then and busy after.
+    tl.fold(w, "paged_copy_block", 1.5)
+    tl.settle(w, "prefill_chunk", 1.75, 2.5, "prefill")
+    assert counter_value(m, total, state="idle") == 0.5
+    assert counter_value(m, total, state="busy",
+                         cause="prefill_chunk") == 1.0
+    # ... and no per-program record carries the copy's time.
+    assert hist(m, "app_tpu_program_device_seconds",
+                program="prefill_chunk") == (0.0, 0)
+    assert tl.snapshot()["folded"] == {"paged_copy_block": 1}
+
+
+def test_phase_context_names_the_current_phase_and_counts_idle_waits():
+    prof = make_prof()
+    prof.begin_pass(prof._clock())
+    assert prof.current_phase == "other"
+    with prof.phase("prefill"):
+        with prof.phase("tier_import"):
+            assert prof.current_phase == "tier_import"
+        assert prof.current_phase == "prefill"
+    assert prof.current_phase == "other"
+    with prof.phase("idle"):
+        assert prof.current_phase == "idle"
+    with pytest.raises(RuntimeError):
+        with prof.phase("idle"):
+            raise RuntimeError("the body raised")
+    assert prof.current_phase == "other" and prof.idle_waits == 2
+
+
+def test_stalled_pass_says_what_the_device_did():
+    prof = make_prof(stall_s=1.0)
+    w = _Watch(threading.current_thread())
+    drive_pass(prof, 0.0, [("device_window", 0.5)], 0.5)
+    # The stalled pass, 0.5 to 4.5.
+    drive_pass(prof, 0.5, [("prefill", 4.0)], 4.5)
+    rec = prof.snapshot()["pinned_anomalies"][0]
+    assert rec["device_busy_s"] is None  # the device's side is not seen yet
+    prof.device.settle(w, "decode_window", 0.25, 1.0, "prefill")
+    prof.device.settle(w, "prefill_chunk", 3.5, 4.25, "prefill")
+    assert prof.snapshot()["pinned_anomalies"][0]["device_busy_s"] is None
+    # Once the device's timeline passes the pass's end the record fills:
+    # busy 0.5 to 1.0, then dry while the loop prefilled, busy from 3.5.
+    prof.device.settle(w, "decode_window", 4.0, 5.0, "device_window")
+    rec = prof.snapshot()["pinned_anomalies"][0]
+    assert rec["device_busy_s"] == 1.5
+    assert rec["device_idle_s"] == 2.5
+    assert rec["device_idle_cause"] == {"prefill": 2.5}
+    assert rec["total_s"] == 4.0
+
+
+class _Blocked:
+    """An output whose program does not finish until it is released."""
+
+    def __init__(self) -> None:
+        self.waiting = threading.Event()
+        self.release = threading.Event()
+
+    def block_until_ready(self):
+        self.waiting.set()
+        self.release.wait(30)
+        return self
+
+
+class _Done:
+    def block_until_ready(self):
+        return self
+
+
+def _count(tl: DeviceTimeline, program: str) -> int:
+    return tl.snapshot()["programs"].get(program, {}).get("count", 0)
+
+
+def test_a_program_a_profiler_capture_overlaps_is_not_timed(monkeypatch):
+    """A capture's start and stop hold the runtime's completions back: the
+    device's timeline counts such a program busy and times it in no
+    per-program histogram."""
+    from gofr_tpu.serving import profiler_capture
+
+    class Capture:
+        busy = True
+
+    monkeypatch.setattr(profiler_capture, "_capture", Capture())
+    m = loop_metrics()
+    prof = make_prof(metrics=m)
+    tl = prof.device
+    watch = tl.start()
+    tl.register("decode_window", prof._clock(), _Done())
+    deadline = time.monotonic() + 10
+    while (counter_value(m, "app_tpu_device_seconds_total") == 0
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+    assert counter_value(m, "app_tpu_device_seconds_total") > 0
+    assert _count(tl, "decode_window") == 0
+    Capture.busy = False
+    tl.register("decode_window", prof._clock(), _Done())
+    deadline = time.monotonic() + 10
+    while _count(tl, "decode_window") == 0 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert _count(tl, "decode_window") == 1
+    assert hist(m, "app_tpu_program_device_seconds")[1] == 1
+    tl.start()  # supersede the test's watcher
+    watch.thread.join(5)
+    assert not watch.thread.is_alive()
+
+
+def test_an_abandoned_schedulers_watcher_does_not_block_a_restart(
+    monkeypatch,
+):
+    monkeypatch.setattr(loop_profiler, "WATCH_POLL_S", 0.05)
+    tl = make_prof().device
+    wedged, hold, done = _Blocked(), threading.Event(), threading.Event()
+    watches = {}
+
+    def scheduler(name, out, until):
+        watches[name] = tl.start()
+        tl.register("decode_window", 0.0, out)
+        until.wait(30)
+
+    old = threading.Thread(target=scheduler, args=("old", wedged, hold),
+                           daemon=True)
+    old.start()
+    assert wedged.waiting.wait(10)  # the device hangs on the old program
+    # The supervisor abandons the old thread (it stays alive, wedged) and
+    # starts a new one: its watcher starts at once and stamps programs.
+    new = threading.Thread(target=scheduler, args=("new", _Done(), done),
+                           daemon=True)
+    new.start()
+    new.join(0.5)
+    deadline = time.monotonic() + 10
+    while _count(tl, "decode_window") < 1 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert _count(tl, "decode_window") == 1
+    # Released at last, the old watcher drops what it held and leaves.
+    tl.register("decode_window", 1.0, _Done())  # the new thread's queue
+    wedged.release.set()
+    watches["old"].thread.join(5)
+    assert not watches["old"].thread.is_alive()
+    # A watcher leaves once the scheduler thread it serves has ended.
+    done.set()
+    new.join(5)
+    watches["new"].thread.join(5)
+    assert not watches["new"].thread.is_alive()
+    assert _count(tl, "decode_window") == 2  # the old program never counted
+    hold.set()
+    old.join(5)
+
+
+# ----------------------------------------------------------------------
 # engine integration: hooks, layer-off, advertisement
 # ----------------------------------------------------------------------
 
@@ -654,11 +884,17 @@ def test_pool_aggregates_loop_reports(eng):
             replica.set_tier_exporter(None)
 
 
+def _watchers() -> int:
+    return sum(t.name == "tpu-device-watch" for t in threading.enumerate())
+
+
 def test_layer_off_mints_nothing_and_streams_identically(eng):
-    e, _ = eng
+    e, m_on = eng
+    m_off = loop_metrics()
+    watchers = _watchers()
     off = InferenceEngine(
         "llama-tiny", tokenizer=ByteTokenizer(), loop_profile=False,
-        **ENG_KW,
+        metrics=m_off, **ENG_KW,
     )
     off.start_sync()
     try:
@@ -667,16 +903,35 @@ def test_layer_off_mints_nothing_and_streams_identically(eng):
         assert "loop" not in off.health_check()["details"]
         assert "loop" not in off.capacity_report()
         assert "loop" not in off.flight_records()
-        r_off = off.generate_sync(
+        req_off = off.submit_generate(
             "loop ab prompt", max_new_tokens=8, temperature=0.0,
-            stop_on_eos=False, timeout=120,
+            stop_on_eos=False,
         )
-        r_on = e.generate_sync(
+        req_on = e.submit_generate(
             "loop ab prompt", max_new_tokens=8, temperature=0.0,
-            stop_on_eos=False, timeout=120,
+            stop_on_eos=False,
         )
+        r_off = req_off.future.result(timeout=120)
+        r_on = req_on.future.result(timeout=120)
         # TPU_LOOP_PROFILE=0 is byte-identical: same greedy stream.
         assert r_off.token_ids == r_on.token_ids
+        # ... starts no watcher thread, stamps no stream's hand-off, and
+        # mints none of the timeline's four series; the profiled engine
+        # beside it does.
+        assert _watchers() <= watchers
+        assert req_off.stream.handed == 0.0 < req_on.stream.handed
+        for name in TIMELINE_HISTOGRAMS:
+            assert hist(m_off, name) == (0.0, 0), name
+        assert counter_value(m_off, "app_tpu_device_seconds_total") == 0.0
+        deadline = time.monotonic() + 10
+        while (hist(m_on, "app_tpu_program_device_seconds",
+                    program="decode_window")[1] == 0
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        assert hist(m_on, "app_tpu_program_device_seconds",
+                    program="decode_window")[1] > 0
+        assert counter_value(m_on, "app_tpu_device_seconds_total",
+                             state="busy", cause="prefill_chunk") > 0
     finally:
         off.stop_sync()
 

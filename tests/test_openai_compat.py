@@ -625,3 +625,36 @@ def test_unstreamed_request_has_an_entry_phase_and_no_delivery(oai_app):
     c.close()
     phases = _newest_flight_record(oai_app)["phases"]
     assert [k for k in TTFT_SPLIT if k in phases] == list(TTFT_SPLIT[:5])
+
+
+def test_streamed_request_times_its_hand_off_once_a_window(oai_app):
+    """The scheduler stamps a stream once a window (the tokens in hand) and
+    the SSE handler, back from a chunk's write, records the time since the
+    newest stamp it has not recorded: one record a window, not a token."""
+    hist = {i.name: i for i in oai_app.container.metrics.instruments()}[
+        "app_tpu_token_handoff_seconds"
+    ]
+
+    def count() -> int:
+        return sum(c for _, (_, c) in hist.collect().values())
+
+    before = count()
+    c = _conn(oai_app)
+    c.request("POST", "/v1/completions", body=json.dumps({
+        "prompt": "time my hand-off", "max_tokens": 40, "temperature": 0,
+        "stream": True, "stream_options": {"include_tokens": True},
+    }))
+    r = c.getresponse()
+    body = r.read().decode()
+    c.close()
+    tokens = sum(
+        len(json.loads(line[6:])["choices"][0].get("token_ids", []))
+        for line in body.splitlines()
+        if line.startswith("data: {") and '"choices"' in line
+    )
+    k = oai_app.container.tpu.window_k
+    records = count() - before
+    assert tokens >= 2 * k
+    # The first token comes from the prefill step's flush, the rest a
+    # window at a time (the first window repeats that first token).
+    assert 2 <= records <= 2 + (tokens - 1 + k - 1) // k < tokens

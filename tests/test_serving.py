@@ -610,7 +610,7 @@ def test_scheduler_death_fails_futures_fast():
         "llama-tiny", n_slots=2, max_len=64, tokenizer=ByteTokenizer()
     )
     eng._dispatch_prefill_chunk = (
-        lambda: (_ for _ in ()).throw(RuntimeError("boom"))
+        lambda **_: (_ for _ in ()).throw(RuntimeError("boom"))
     )
     eng.start_sync()
     try:
