@@ -369,6 +369,12 @@ class Container:
             "KV-cache bytes one token holds (all cache entries, keys and "
             "values, scales included): what an operator sizes slots by",
         )
+        m.new_gauge(
+            "app_tpu_prefill_temp_bytes",
+            "temporary bytes the prefill chunk step's largest rung holds "
+            "beside its operands while it runs, from its compiled program "
+            "at boot: with the cache, what a prefill step needs of HBM",
+        )
         m.new_histogram(
             "app_tpu_prefill_fill_ratio",
             "prompt tokens in a prefill chunk step / the token rows of "

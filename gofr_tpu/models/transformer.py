@@ -22,6 +22,7 @@ the collectives.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -1044,33 +1045,34 @@ def _post_norm(out, lp, name, cfg):
     return _norm(out, lp[name], cfg) if cfg.post_norm else out
 
 
-def _scan_stack(body, x, params, cfg, cache_xs=()):
-    """Run the layer stack: ``body(x, (lp, *cache_slices)) -> (x, ys)``
+def _scan_stack(body, x, params, cfg, xs=()):
+    """Run the layer stack: ``body(x, (lp, *xs_slices)) -> (x, ys)``
     over the stacked layers, ``cfg.n_passes`` times over the one set of
-    weights.
+    weights. ``xs`` is what a step is handed beside its layer's weights:
+    the cache entries' indices (``jnp.arange(cfg.n_cache_entries)``) where
+    the body asks for them. No cache plane rides xs → ys: a step that
+    writes the cache carries the stacked planes in ``x`` (a tuple that
+    leads with the stream) and writes its own entry in place, and one that only
+    reads them closes over them; either way the attention reads the entry
+    where it lies by its index, and ys are small.
 
     One pass over one kind of layer — every model but a looped one or one
     that leads with dense layers — is the plain ``lax.scan`` over
-    ``(layers, *cache_xs)``, the program it always was. A looped
-    stack stays ONE scan, over the n_passes * n_layers cache entries that
-    ``cache_xs`` lead with (entry ``t * L + l`` is pass t's layer l, so a
-    pass reads and writes only its own keys and values): step i takes
-    layer ``i % L``'s weights out of the stacked leaves, which is what a
-    scan does with its xs anyway, and the shared final norm ends every
-    pass but the last (the caller's own final norm ends that one). The
-    cache rides xs → ys exactly as it does for one pass, so a looped
-    model costs the prefill program no further copy of it. (The decode
-    step, which only reads the cache, scans the entries' indices instead
-    and closes over the planes.)
+    ``(layers, *xs)``, the program it always was. A looped stack stays
+    ONE scan, over the n_passes * n_layers cache entries that ``xs`` index
+    (entry ``t * L + l`` is pass t's layer l, so a pass reads and writes
+    only its own keys and values): step i takes layer ``i % L``'s weights
+    out of the stacked leaves, which is what a scan does with its xs
+    anyway, and the shared final norm ends every pass but the last (the
+    caller's own final norm ends that one).
 
     Two kinds of layer (``params["dense_layers"]``, the leading layers with
     a dense FFN, then ``params["layers"]``): leaves of two shapes cannot
     share one stack, so the two stacked groups run in order, one scan
     each, over one run of cache entries: each takes its own stretch of
-    ``cache_xs``, and their ys are joined along the entry axis. ``body``
-    tells the kinds apart by the leaves it is handed (``_ffn_any``). ``x``
-    may be any pytree a body carries (the latent prefill body carries the
-    cache plane beside the stream, and its ys are small).
+    ``xs``, and their (small) ys are joined along the entry axis.
+    ``body`` tells the kinds apart by the leaves it is handed
+    (``_ffn_any``).
     """
     if cfg.is_hybrid:
         return _scan_runs(body, x, params, cfg)
@@ -1080,12 +1082,12 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
         # index among the expert layers (``moe_grouped_experts``).
         groups[-1] = {**groups[-1], "expert_layer": jnp.arange(cfg.n_moe_layers)}
     if cfg.n_passes == 1 and len(groups) == 1:
-        return jax.lax.scan(body, x, (groups[0], *cache_xs))
+        return jax.lax.scan(body, x, (groups[0], *xs))
     if cfg.n_passes == 1:
         joined, lo = [], 0
         for layers in groups:
             hi = lo + jax.tree.leaves(layers)[0].shape[0]
-            stretch = jax.tree.map(lambda a: a[lo:hi], tuple(cache_xs))  # noqa: B023
+            stretch = jax.tree.map(lambda a: a[lo:hi], tuple(xs))  # noqa: B023
             x, ys = jax.lax.scan(body, x, (layers, *stretch))
             joined.append(ys)
             lo = hi
@@ -1102,21 +1104,25 @@ def _scan_stack(body, x, params, cfg, cache_xs=()):
         return _norm(x, params["final_norm"], cfg, params.get("final_norm_b"))
 
     def step(x, scanned):
-        i, cache_slices = scanned
+        i, xs_slices = scanned
         l = i % L
         lp = jax.tree.map(
             lambda w: jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False),
             layers,
         )
         with jax.named_scope("pass"):
-            x, ys = body(x, (lp, *cache_slices))
+            x, ys = body(x, (lp, *xs_slices))
             with jax.named_scope("pass_norm"):
-                x = jax.lax.cond(
-                    (l == L - 1) & (i < n - 1), pass_norm, lambda x: x, x
+                # A carry that holds the cache planes leads with the stream:
+                # only the stream goes through the branch.
+                stream = x[0] if isinstance(x, tuple) else x
+                stream = jax.lax.cond(
+                    (l == L - 1) & (i < n - 1), pass_norm, lambda x: x, stream
                 )
+                x = (stream, *x[1:]) if isinstance(x, tuple) else stream
         return x, ys
 
-    return jax.lax.scan(step, x, (jnp.arange(n), tuple(cache_xs)))
+    return jax.lax.scan(step, x, (jnp.arange(n), tuple(xs)))
 
 
 def _scan_runs(bodies, carry, params, cfg):
@@ -1810,6 +1816,32 @@ def transformer_prefill(
     return logits, cache
 
 
+# A prefill step writes its chunk's K and V into its entry of the stacked
+# planes in place, one 256-byte row of ``head_dim`` at a time: ~72 ns a row
+# on the v5e, whatever the step. Where the step writes a large share of a
+# small entry, the same scatter into the entry's own slice (sliced out of the
+# stack, scattered, written back) is faster: XLA keeps such a slice in VMEM,
+# where the rows cost a fraction of that (PERF.md section 6: the write
+# alone at ouro-2.6b's 8 x 384 slots 927 -> 80 ms an [8, 256] step, at
+# mistral-7b's 14 x 2,048 78 -> 61 ms, but 11 -> 18 at one row, and at
+# mixtral-8x7b-d4's 64 x 2,048 11 -> 23).
+KV_WRITE_SLICE_SHARE = 1 / 32  # rows a step writes / the entry's rows, at least
+KV_WRITE_SLICE_BYTES = 64 << 20  # the entry's plane, at most (VMEM: 128 MiB)
+
+
+def kv_write_through_slice(plane_shape, itemsize: int, rows: int) -> bool:
+    """Whether a step that writes ``rows`` rows of ``head_dim`` into one entry
+    of a stacked plane of ``plane_shape`` writes them through the entry's
+    slice (``KV_WRITE_SLICE_SHARE``): from the shapes alone, so each
+    compiled rung has one form."""
+    entry_rows = math.prod(plane_shape[1:-1])
+    entry_bytes = entry_rows * plane_shape[-1] * itemsize
+    return (
+        rows >= KV_WRITE_SLICE_SHARE * entry_rows
+        and entry_bytes <= KV_WRITE_SLICE_BYTES
+    )
+
+
 def transformer_prefill_chunk(
     params: dict,
     tokens: jnp.ndarray,
@@ -1916,12 +1948,29 @@ def transformer_prefill_chunk(
     else:
         idx_row = slots[:, None, None]
         idx_pos = write_pos[:, None, :]  # [P, 1, c]
-        # Scale-write indices (int8 mode): [S, KV, 8, max_len] layer slice.
+        # Scale-write indices (int8 mode): [S, KV, 8, max_len] of an entry.
         s_row = slots[:, None, None, None]
         s_pos = write_pos[:, None, None, :]  # [P, 1, 1, c]
+    through_slice = kv_write_through_slice(
+        cache.k.shape, cache.k.dtype.itemsize, P * KV * c
+    )
 
-    def body(x, scanned):
-        lp, ck, cv, cks, cvs, *entry = scanned  # ck/cv: [S, KV, max_len, hd]
+    def commit(plane, entry, idx, rows):
+        """``rows`` scattered at ``idx`` into entry ``entry`` of ``plane``."""
+        if not through_slice:
+            return plane.at[(entry, *idx)].set(rows)
+        one = jax.lax.dynamic_index_in_dim(plane, entry, 0, keepdims=False)
+        return jax.lax.dynamic_update_index_in_dim(
+            plane, one.at[idx].set(rows), entry, 0
+        )
+
+    def body(carry, scanned):
+        # The stacked planes ride the CARRY and each layer writes its chunk
+        # into its own entry (``commit``); as xs and ys they would be a
+        # second whole copy of the cache while the step runs (and the
+        # attention reads the entry where it lies, ``layer=entry``).
+        x, ck, cv, cks, cvs = carry  # ck/cv: [E, S, KV, max_len, hd]
+        lp, entry = scanned
         with jax.named_scope("attn"):
             h = _norm(x, lp["attn_norm"], cfg, lp.get("attn_norm_b"))
             q, k, v = _qkv(h, lp, "pcd,dh->pch", H, KV, hd, P, c, aids=aids)
@@ -1934,18 +1983,20 @@ def transformer_prefill_chunk(
             if cks is not None:
                 k, k_sc = quantize_kv(k)  # scales [P, c, KV]
                 v, v_sc = quantize_kv(v)
-                cks = cks.at[s_row, s_kv, s_sub, s_pos].set(
-                    k_sc.transpose(0, 2, 1)[:, :, None, :]
+                s_idx = (s_row, s_kv, s_sub, s_pos)
+                cks = commit(
+                    cks, entry, s_idx, k_sc.transpose(0, 2, 1)[:, :, None, :]
                 )
-                cvs = cvs.at[s_row, s_kv, s_sub, s_pos].set(
-                    v_sc.transpose(0, 2, 1)[:, :, None, :]
+                cvs = commit(
+                    cvs, entry, s_idx, v_sc.transpose(0, 2, 1)[:, :, None, :]
                 )
-            ck = ck.at[idx_row, idx_kv, idx_pos].set(k.transpose(0, 2, 1, 3))
-            cv = cv.at[idx_row, idx_kv, idx_pos].set(v.transpose(0, 2, 1, 3))
+            idx = (idx_row, idx_kv, idx_pos)
+            ck = commit(ck, entry, idx, k.transpose(0, 2, 1, 3))
+            cv = commit(cv, entry, idx, v.transpose(0, 2, 1, 3))
         with jax.named_scope("attn"):
             attn = cache_chunk_attention(
-                q, ck, cv, slots, starts, lens, k_scale=cks, v_scale=cvs,
-                block_table=cache.block_table if paged else None,
+                q, ck, cv, slots, starts, lens, layer=entry, k_scale=cks,
+                v_scale=cvs, block_table=cache.block_table if paged else None,
                 kernel=False if dense_attn else None,
                 window=cfg.sliding_window,
             )
@@ -1960,18 +2011,15 @@ def transformer_prefill_chunk(
         h = _norm(mlp_in, lp["mlp_norm"], cfg, lp.get("mlp_norm_b"))
         ffn, counts = _ffn_any(
             h, lp, cfg, aids, counted, params.get("experts"),
-            _expert_stack(params, cfg, entry[0] if tiled else None),
+            _expert_stack(params, cfg, entry if tiled else None),
         )
         ffn = _post_norm(ffn, lp, "mlp_post_norm", cfg)
         x = x + attn_out + ffn if cfg.parallel_residual else mlp_in + ffn
-        return x, (ck, cv, cks, cvs, counts)
+        return (x, ck, cv, cks, cvs), counts
 
-    # A grouped stacked layer slices its experts out of the stack itself,
-    # so its body is also handed its cache entry's index.
-    x, (new_k, new_v, new_ks, new_vs, counts) = _scan_stack(
-        body, x, params, cfg,
-        (cache.k, cache.v, cache.k_s, cache.v_s,
-         *((jnp.arange(cfg.n_cache_entries),) if tiled else ())),
+    (x, new_k, new_v, new_ks, new_vs), counts = _scan_stack(
+        body, (x, cache.k, cache.v, cache.k_s, cache.v_s), params, cfg,
+        (jnp.arange(cfg.n_cache_entries),),
     )
     cache = cache._replace(k=new_k, v=new_v, k_s=new_ks, v_s=new_vs)
     return _chunk_logits(params, x, lens, cache, counts, cfg, stats)
@@ -2011,9 +2059,9 @@ def _latent_chunk_layers(params, x, cache, slots, starts, lens, positions,
                          cos, sin, counted, cfg, aids, tiled, write_pos):
     """The chunk step's layer stack over a latent cache. The stacked plane
     rides the scan's CARRY and each layer writes its chunk's rows into its
-    own entry in place: the plane as xs and ys, as the K and V planes ride,
-    would be a second whole copy of the cache while the step runs, and
-    joining two layer groups' ys a third.
+    own entry in place, as the dense body's K and V planes do: the plane as
+    xs and ys would be a second whole copy of the cache while the step
+    runs, and joining two layer groups' ys a third.
 
     Prefill runs the EXPANDED form (each block's latents expanded to
     per-head keys and values); the absorbed form, decode's, lost here at

@@ -667,6 +667,7 @@ def cache_chunk_attention(
     starts: jnp.ndarray,
     lens: jnp.ndarray,
     *,
+    layer: jnp.ndarray,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     block_table: jnp.ndarray | None = None,
@@ -678,45 +679,47 @@ def cache_chunk_attention(
     to its slot's cache prefix [0, starts[p]+t] (causal at global
     positions). The chunk's K/V must already be written into the cache.
 
-    q: [P, c, n_heads, hd]; caches: [S, n_kv, max_len, hd] (heads-major);
+    q: [P, c, n_heads, hd]; caches: the STACKED [E, S, n_kv, max_len, hd]
+    (heads-major) with ``layer`` (a traced index) the entry attended: the
+    prefill step's layer scan carries the planes and hands in its index;
     slots/starts/lens: [P] int32 (lens = valid tokens in this chunk);
-    k_scale/v_scale: int8-cache scales [S, n_kv, 8, max_len].
+    k_scale/v_scale: int8-cache scales [E, S, n_kv, 8, max_len].
     block_table ([S, max_blocks] int32, paged): the caches are a pool
-    [n_blocks, n_kv, block, hd]; the kernel indexes it through the table
-    in place, while the dense path gathers each row's contiguous view
-    (the CPU/tests fallback). Rows with t >= lens[p] return 0.
+    [E, n_blocks, n_kv, block, hd]; the kernel indexes it through the
+    table in place, while the dense path gathers each row's contiguous
+    view (the CPU/tests fallback). Rows with t >= lens[p] return 0.
     kernel: None → auto (pallas on TPU).
     """
-    window = _effective_window(window, k_cache.shape[2], block_table)
+    window = _effective_window(window, k_cache.shape[3], block_table)
     if kernel is None:
         kernel = _flash_enabled()
     if kernel:
         from gofr_tpu.ops.pallas import flash_cache_attention
 
         return flash_cache_attention(
-            q, k_cache, v_cache, slots, starts, lens, k_scale=k_scale,
-            v_scale=v_scale, block_table=block_table, scale=scale,
-            window=window, interpret=_interpret(),
+            q, k_cache, v_cache, slots, starts, lens, layer=layer,
+            k_scale=k_scale, v_scale=v_scale, block_table=block_table,
+            scale=scale, window=window, interpret=_interpret(),
         )
-    pre_gathered = False
     if block_table is not None:
         from gofr_tpu.ops.kv_cache import paged_view
 
-        k_cache, v_cache, k_scale, v_scale = paged_view(
-            block_table, k_cache, v_cache, slots, k_scale, v_scale
+        # Views already per row: [P, KV, max_len, hd].
+        ck, cv, ksl, vsl = paged_view(
+            block_table, k_cache, v_cache, slots, k_scale, v_scale,
+            layer=layer,
         )
-        pre_gathered = True  # views are already per-row: skip the gather
+    else:
+        ck, cv = k_cache[layer, slots], v_cache[layer, slots]
+        ksl, vsl = (
+            None if p is None else p[layer, slots] for p in (k_scale, v_scale)
+        )
     P, c, n_heads, hd = q.shape
-    n_kv, max_len = k_cache.shape[1], k_cache.shape[2]
+    n_kv, max_len = ck.shape[1], ck.shape[2]
     rep = n_heads // n_kv
     if scale is None:
         scale = hd**-0.5
-    quant = k_scale is not None
-    if pre_gathered:
-        ck, cv = k_cache, v_cache
-    else:
-        ck = k_cache[slots]  # [P, KV, max_len, hd]
-        cv = v_cache[slots]
+    quant = ksl is not None
     if quant:  # int8 cache: dequant via score/prob scaling, not the cache
         ck = ck.astype(q.dtype)
         cv = cv.astype(q.dtype)
@@ -725,7 +728,6 @@ def cache_chunk_attention(
         "pcgrd,pgkd->pgrck", qg, ck, preferred_element_type=jnp.float32
     ) * scale  # [P, KV, rep, c, max_len]
     if quant:
-        ksl = k_scale if pre_gathered else k_scale[slots]
         scores = scores * ksl[:, :, 0, :][:, :, None, None, :]
     t = jnp.arange(c)
     pos = starts[:, None] + t[None, :]  # [P, c] global query positions
@@ -739,7 +741,6 @@ def cache_chunk_attention(
     scores = jnp.where(valid[:, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     if quant:
-        vsl = v_scale if pre_gathered else v_scale[slots]
         probs = probs * vsl[:, :, 0, :][:, :, None, None, :]
     out = jnp.einsum("pgrck,pgkd->pcgrd", probs.astype(q.dtype), cv)
     out = jnp.where(

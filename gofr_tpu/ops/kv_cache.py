@@ -486,25 +486,30 @@ def paged_view(
     rows: Any,
     layer_ks: Any = None,
     layer_vs: Any = None,
+    layer: Any = None,
 ) -> tuple:
     """Dense-fallback view: gather ``rows``' blocks into contiguous
     per-row caches ``[R, KV, max_len, hd]`` (+ scale planes). Materializes
-    a copy — the paged flash-decode kernel indexes the pool in place
-    instead; this exists for the CPU/dense path and tests.
+    a copy — the paged flash kernels index the pool in place instead;
+    this exists for the CPU/dense path and tests.
 
-    layer_k/layer_v: one layer's pool ``[n_blocks, KV, block, hd]``.
+    layer_k/layer_v: one layer's pool ``[n_blocks, KV, block, hd]``, or,
+    with ``layer`` (a traced index) the entry gathered from, the stacked
+    pools ``[E, n_blocks, KV, block, hd]``.
     """
     bt = block_table[rows]  # [R, max_blocks]
     R, MB = bt.shape
-    KV, B, hd = layer_k.shape[1], layer_k.shape[2], layer_k.shape[3]
-    k = layer_k[bt]  # [R, MB, KV, block, hd]
-    v = layer_v[bt]
-    k = k.transpose(0, 2, 1, 3, 4).reshape(R, KV, MB * B, hd)
-    v = v.transpose(0, 2, 1, 3, 4).reshape(R, KV, MB * B, hd)
+    KV, B, hd = layer_k.shape[-3], layer_k.shape[-2], layer_k.shape[-1]
+
+    def blocks(pool: Any) -> Any:  # [R, MB, ...] of the entry's pool
+        return pool[bt] if layer is None else pool[layer, bt]
+
+    k = blocks(layer_k).transpose(0, 2, 1, 3, 4).reshape(R, KV, MB * B, hd)
+    v = blocks(layer_v).transpose(0, 2, 1, 3, 4).reshape(R, KV, MB * B, hd)
     if layer_ks is None:
         return k, v, None, None
-    ks = layer_ks[bt].transpose(0, 2, 3, 1, 4).reshape(R, KV, 8, MB * B)
-    vs = layer_vs[bt].transpose(0, 2, 3, 1, 4).reshape(R, KV, 8, MB * B)
+    ks = blocks(layer_ks).transpose(0, 2, 3, 1, 4).reshape(R, KV, 8, MB * B)
+    vs = blocks(layer_vs).transpose(0, 2, 3, 1, 4).reshape(R, KV, 8, MB * B)
     return k, v, ks, vs
 
 

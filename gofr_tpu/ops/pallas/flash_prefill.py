@@ -9,13 +9,19 @@ lengths are handled by the loop count, not the program; the row count is
 one of the engine's two rungs (1 and ``prefill_batch``), so this kernel
 is compiled once a rung, before the engine serves.
 
-Contract (heads-major cache, ``ops/kv_cache.py``): the chunk's K/V must
-already be written into the cache at positions ``starts[p] ..
-starts[p]+lens[p]-1`` before the call. Queries are grouped kv-head-major
-and token-major within the group: row ``r`` of the ``[c*rep, hd]`` q block
-is token ``r // rep``, query-head ``(r % rep)`` of that kv head — so one
-MXU matmul per (row-batch × kv block) serves all rep query heads of a kv
-head, and the causal mask is computable from the row index alone.
+Contract (heads-major cache, ``ops/kv_cache.py``): the kernel takes the
+STACKED planes, every cache entry's ``[E, S, KV, max_len, hd]`` (a paged
+pool ``[E, n_blocks, KV, block, hd]``), and the entry it attends, ``layer``,
+as one more prefetched scalar: its index maps pick the entry's blocks where
+they lie, so the prefill step's layer scan carries the planes and writes
+each entry in place, and no entry is copied out for a custom call that
+cannot take a slice. The chunk's K/V must already be written into the
+entry at positions ``starts[p] .. starts[p]+lens[p]-1`` before the call.
+Queries are grouped kv-head-major and token-major within the group: row
+``r`` of the ``[c*rep, hd]`` q block is token ``r // rep``, query-head
+``(r % rep)`` of that kv head — so one MXU matmul per (row-batch × kv
+block) serves all rep query heads of a kv head, and the causal mask is
+computable from the row index alone.
 
 Per-row scalars (slots, starts, lens) ride in SMEM via scalar prefetch;
 kv blocks beyond ``starts[p]+lens[p]`` are skipped (clamped index maps →
@@ -53,14 +59,13 @@ def _kernel(*refs, scale, rep, block_k, quant, paged, window):
 
     quant (static): int8 cache mode — k/v scale refs follow v_ref
     ([8, block_k] sublane-replicated); see ``flash_decode._kernel``.
-    paged (static): a 4th prefetched scalar (the block table) follows
-    lens; it acts only through the index_maps — the body is unchanged.
+    The entry's index (a 4th prefetched scalar) and, paged (static), the
+    block table (a 5th) follow lens; they act only through the index_maps
+    — the body is the same for every layout.
     """
-    refs = list(refs)
     slot_ref, start_ref, len_ref = refs[:3]
-    refs = refs[3:]
-    if paged:
-        refs.pop(0)  # block table: consumed by the index_maps only
+    # The entry (and the block table): consumed by the index_maps only.
+    refs = refs[5 if paged else 4:]
     q_ref, k_ref, v_ref = refs[:3]
     rest = refs[3:]
     if quant:
@@ -152,6 +157,7 @@ def flash_cache_attention(
     starts: jnp.ndarray,
     lens: jnp.ndarray,
     *,
+    layer: jnp.ndarray,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     block_table: jnp.ndarray | None = None,
@@ -168,28 +174,29 @@ def flash_cache_attention(
     and their DMA.
 
     q: [P, c, n_heads, hd] — chunk queries (RoPE'd at positions
-    starts[p]+t); k_cache, v_cache: [S, n_kv, max_len, hd] with the chunk's
-    K/V already written; slots/starts/lens: [P] int32; k_scale/v_scale:
-    int8-cache scales [S, n_kv, 8, max_len]. Rows with ``t >= lens[p]``
+    starts[p]+t); k_cache, v_cache: the stacked [E, S, n_kv, max_len, hd]
+    with the chunk's K/V already written into entry ``layer`` (a traced
+    int32 scalar); slots/starts/lens: [P] int32; k_scale/v_scale:
+    int8-cache scales [E, S, n_kv, 8, max_len]. Rows with ``t >= lens[p]``
     return 0. block_table ([S, max_blocks] int32, paged mode): the caches
-    are then a POOL [n_blocks, n_kv, block, hd] (scales
-    [n_blocks, n_kv, 8, block]); logical kv block ``ik`` of row ``p``
+    are then a POOL [E, n_blocks, n_kv, block, hd] (scales
+    [E, n_blocks, n_kv, 8, block]); logical kv block ``ik`` of row ``p``
     resolves to pool block ``block_table[slots[p], ik]`` inside the
     BlockSpec index_maps — no per-chunk gather of the whole view.
     Returns [P, c, n_heads, hd].
     """
     P, c, n_heads, hd = q.shape
     paged = block_table is not None
-    n_kv = k_cache.shape[1]
+    n_kv = k_cache.shape[2]
     rep = n_heads // n_kv
     quant = k_scale is not None
     if scale is None:
         scale = hd**-0.5
     if paged:
-        block_k = k_cache.shape[2]  # pool block size
+        block_k = k_cache.shape[3]  # pool block size
         n_grid_blocks = block_table.shape[1]
     else:
-        max_len = k_cache.shape[2]
+        max_len = k_cache.shape[3]
         block_k = min(block_k, max_len)
         if max_len % block_k:
             # Persistent cache can't be padded per call; shrink to a
@@ -205,52 +212,53 @@ def flash_cache_attention(
     )
 
     if paged:
-        def kv_idx(ip, ig, ik, slots, starts, lens, bt, bk=block_k):
+        def kv_idx(ip, ig, ik, slots, starts, lens, layer, bt, bk=block_k):
             blk = _clamp_blk(
                 ik, starts[ip] + lens[ip], bk, starts[ip], window
             )
-            return (bt[slots[ip], blk], ig, 0, 0)
+            return (layer[0], bt[slots[ip], blk], ig, 0, 0)
 
         # Paged scale planes index exactly like K/V (pool block, head).
         scale_idx = kv_idx
 
-        def row_idx(ip, ig, ik, slots, starts, lens, bt):
+        def row_idx(ip, ig, ik, slots, starts, lens, layer, bt):
             return (ip, ig, 0, 0)
     else:
-        def kv_idx(ip, ig, ik, slots, starts, lens, bk=block_k):
+        def kv_idx(ip, ig, ik, slots, starts, lens, layer, bk=block_k):
             blk = _clamp_blk(
                 ik, starts[ip] + lens[ip], bk, starts[ip], window
             )
-            return (slots[ip], ig, blk, 0)
+            return (layer[0], slots[ip], ig, blk, 0)
 
-        def scale_idx(ip, ig, ik, slots, starts, lens, bk=block_k):
+        def scale_idx(ip, ig, ik, slots, starts, lens, layer, bk=block_k):
             blk = _clamp_blk(
                 ik, starts[ip] + lens[ip], bk, starts[ip], window
             )
-            return (slots[ip], ig, 0, blk)
+            return (layer[0], slots[ip], ig, 0, blk)
 
-        def row_idx(ip, ig, ik, slots, starts, lens):
+        def row_idx(ip, ig, ik, slots, starts, lens, layer):
             return (ip, ig, 0, 0)
 
+    # The entry's axis is squeezed (None): the body sees one entry's block.
     in_specs = [
         pl.BlockSpec((1, 1, c * rep, hd), row_idx),
-        pl.BlockSpec((1, 1, block_k, hd), kv_idx),
-        pl.BlockSpec((1, 1, block_k, hd), kv_idx),
+        pl.BlockSpec((None, 1, 1, block_k, hd), kv_idx),
+        pl.BlockSpec((None, 1, 1, block_k, hd), kv_idx),
     ]
     inputs = [
         slots.astype(jnp.int32), starts.astype(jnp.int32),
-        lens.astype(jnp.int32),
+        lens.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
     ]
     if paged:
         inputs.append(block_table.astype(jnp.int32))
     inputs += [qg, k_cache, v_cache]
     if quant:
-        scale_spec = pl.BlockSpec((1, 1, 8, block_k), scale_idx)
+        scale_spec = pl.BlockSpec((None, 1, 1, 8, block_k), scale_idx)
         in_specs += [scale_spec, scale_spec]
         inputs += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if paged else 3,
+        num_scalar_prefetch=5 if paged else 4,
         grid=(P, n_kv, n_grid_blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, c * rep, hd), row_idx),

@@ -3341,6 +3341,7 @@ class InferenceEngine(
             }
             details["max_len"] = self.max_len
             details["kv_bytes_per_token"] = self.kv_bytes_per_token()
+            details["prefill_temp_bytes"] = self.prefill_temp_bytes
             if self.state_bytes_per_slot():
                 details["state_bytes_per_slot"] = self.state_bytes_per_slot()
             details["pending"] = self._pending.qsize()

@@ -77,6 +77,8 @@ class LLMProgramsMixin:
     # Compiled-program callables (built below, compile-tracked).
     _prefill_chunk_step: Any
     _decode_window: Any
+    _metrics: Any
+    model_name: str
 
     def _build_llm_steps(self) -> None:
         jax, jnp = self._jax, self._jnp
@@ -422,6 +424,16 @@ class LLMProgramsMixin:
         self._prefill_steps: dict[tuple[int, bool], Any] = {}
         for rows in self.prefill_rungs:
             self._prefill_step(rows, False)
+        # What the largest rung holds beside its operands while it runs,
+        # from its compiled program (the cache's second copy, were the
+        # layer scan to pass the planes through its xs and ys, shows here).
+        mem = self._prefill_steps[(self.prefill_rungs[-1], False)].memory_analysis()
+        self.prefill_temp_bytes = 0 if mem is None else int(mem.temp_size_in_bytes)
+        if self._metrics is not None:
+            self._metrics.set_gauge(
+                "app_tpu_prefill_temp_bytes", self.prefill_temp_bytes,
+                "model", self.model_name,
+            )
 
     def _prefill_operands(self, *per_row: np.ndarray) -> tuple[Any, ...]:
         """The prefill step's operands in the program's order: weights

@@ -356,7 +356,7 @@ def test_attention_over_blocks_of_positions_is_the_one_step_mathematics():
         q, k_pl, v_pl, slots, starts, lens, None, sel_block=4, layer=1, block=16,
     )
     dense = cache_chunk_attention(
-        q, k_pl[1], v_pl[1], slots, starts, lens, kernel=False
+        q, k_pl, v_pl, slots, starts, lens, layer=1, kernel=False
     )
     assert float(jnp.max(jnp.abs(everything - dense))) <= 1e-5
     assert float(jnp.max(jnp.abs(everything - looped))) >= 0.05

@@ -83,9 +83,14 @@ def test_int8_chunk_attention_matches_bf16(kernel):
     slots = jnp.asarray([0, 2], jnp.int32)
     starts = jnp.asarray([8, 32], jnp.int32)
     lens = jnp.asarray([16, 9], jnp.int32)
-    want = cache_chunk_attention(q, k, v, slots, starts, lens, kernel=kernel)
+    # One entry of the stacked planes the prefill step hands in.
+    layer = jnp.int32(0)
+    want = cache_chunk_attention(
+        q, k[None], v[None], slots, starts, lens, layer=layer, kernel=kernel
+    )
     got = cache_chunk_attention(
-        q, kq, vq, slots, starts, lens, k_scale=ks, v_scale=vs, kernel=kernel
+        q, kq[None], vq[None], slots, starts, lens, layer=layer,
+        k_scale=ks[None], v_scale=vs[None], kernel=kernel,
     )
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),

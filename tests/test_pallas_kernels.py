@@ -428,19 +428,21 @@ def serving_kernel_cases() -> list[KernelCase]:
 
 
 def _rep8(scale, width):
-    """[rows, KV, width] scales → the sublane-replicated
-    [rows, KV, 8, width] f32 plane the caches store."""
+    """[..., KV, width] scales → the sublane-replicated
+    [..., KV, 8, width] f32 plane the caches store."""
     return jnp.broadcast_to(
-        scale[:, :, None, :], scale.shape[:2] + (8, width)
+        scale[..., None, :], scale.shape[:-1] + (8, width)
     ).astype(jnp.float32)
 
 
-def _case_cache(key, g: Geometry, case: KernelCase):
-    """A slot cache for ``case``: contiguous ``[S, KV, max_len, hd]`` or
-    a pool ``[1 + S*mb, KV, block, hd]`` behind a scrambled table (block
-    0 parks, as in the engine), bf16 or int8 with scale planes. Returns
-    ``(k, v, kwargs)`` twice: what the kernel takes, and the contiguous
-    per-slot view the dense path takes."""
+def _case_cache(key, g: Geometry, case: KernelCase, entries: int = 1):
+    """The stacked cache of ``entries`` entries (layers) for ``case``, each
+    of its own random content: contiguous ``[E, S, KV, max_len, hd]`` or
+    pools ``[E, 1 + S*mb, KV, block, hd]`` behind one scrambled table
+    (block 0 parks, as in the engine), bf16 or int8 with scale planes.
+    Returns ``(k, v, kwargs)`` twice: what the kernel takes, and each
+    entry's contiguous per-slot view, stacked, which the dense path
+    takes."""
     kk, kv_, kt = jax.random.split(key, 3)
     if case.paged:
         mb = g.max_len // g.block
@@ -450,7 +452,7 @@ def _case_cache(key, g: Geometry, case: KernelCase):
         ).reshape(g.slots, mb).astype(jnp.int32)
     else:
         rows, width, table = g.slots, g.max_len, None
-    shape = (rows, g.n_kv, width, g.hd)
+    shape = (entries, rows, g.n_kv, width, g.hd)
     k = jax.random.normal(kk, shape, jnp.float32)
     v = jax.random.normal(kv_, shape, jnp.float32)
     ks = vs = None
@@ -462,10 +464,25 @@ def _case_cache(key, g: Geometry, case: KernelCase):
         k, v = k.astype(g.dtype), v.astype(g.dtype)
     kern = (k, v, dict(k_scale=ks, v_scale=vs, block_table=table))
     if case.paged:
-        k, v, ks, vs = paged_view(
-            table, k, v, jnp.arange(g.slots), ks, vs
+        views = [
+            paged_view(
+                table, k[e], v[e], jnp.arange(g.slots),
+                None if ks is None else ks[e], None if vs is None else vs[e],
+            ) for e in range(entries)
+        ]
+        k, v, ks, vs = (
+            None if parts[0] is None else jnp.stack(parts)
+            for parts in zip(*views)
         )
     return kern, (k, v, dict(k_scale=ks, v_scale=vs))
+
+
+def _entry(kw: dict, layer: int) -> dict:
+    """A case cache's kwargs with every scale plane cut to one entry."""
+    return {
+        name: (p[layer] if name.endswith("scale") and p is not None else p)
+        for name, p in kw.items()
+    }
 
 
 def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
@@ -489,8 +506,15 @@ def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
         return (4 * jax.random.normal(kq, shape, jnp.float32)).astype(bf)
 
     if case.kernel != "attention":
-        (k_c, v_c, kern), (k_d, v_d, dense) = _case_cache(kc, g, case)
+        # The chunk kernel reads the last of two stacked entries.
+        entries = 2 if case.kernel == "prefill_chunk" else 1
+        (k_c, v_c, kern), (k_d, v_d, dense) = _case_cache(
+            kc, g, case, entries
+        )
     if case.kernel == "decode":
+        # The decode kernel takes one entry.
+        k_c, v_c, kern = k_c[0], v_c[0], _entry(kern, 0)
+        k_d, v_d, dense = k_d[0], v_d[0], _entry(dense, 0)
         q = queries((g.slots, g.n_heads, g.hd))
         # Empty, short, just past the window, and full slots.
         lens = jnp.array(
@@ -526,15 +550,16 @@ def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
             [0, g.window - c // 2, g.max_len - 2 * c][-P:], jnp.int32
         )
         lens = jnp.array([c, c, max(1, c // 2 + 1)][-P:], jnp.int32)
+        layer = jnp.int32(entries - 1)
         got = flash_cache_attention(
-            q, k_c, v_c, slots, starts, lens, window=w,
+            q, k_c, v_c, slots, starts, lens, layer=layer, window=w,
             interpret=INTERPRET, **kern,
         )
 
         def dense_chunk(window):
             return cache_chunk_attention(
-                q, k_d, v_d, slots, starts, lens, window=window,
-                kernel=False, **dense,
+                q, k_d, v_d, slots, starts, lens, layer=layer,
+                window=window, kernel=False, **dense,
             )
 
         want = dense_chunk(w)
@@ -580,3 +605,54 @@ def run_serving_kernel_case(case: KernelCase, g: Geometry) -> float:
 @pytest.mark.parametrize("case", serving_kernel_cases(), ids=str)
 def test_serving_kernel_matrix(case):
     run_serving_kernel_case(case, TINY)
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize(
+    "case",
+    [
+        KernelCase("prefill_chunk", False, False, False, False),
+        KernelCase("prefill_chunk", False, True, False, False),
+        KernelCase("prefill_chunk", True, False, False, False),
+        KernelCase("prefill_chunk", False, False, True, False),
+    ],
+    ids=["contiguous", "paged", "int8_kv", "window"],
+)
+def test_chunk_kernel_reads_its_layer_of_the_stacked_plane(case, layer):
+    """The chunk kernel on the stacked planes of five entries, each of its
+    own content, reads entry ``layer`` where it lies (its index maps pick
+    the entry's blocks): it equals the XLA path on the same stacked planes
+    and layer, and that equals the XLA path on the entry alone; the other
+    entries give another answer, so a kernel that read the wrong one
+    would fail."""
+    g = TINY
+    (k_c, v_c, kern), (k_d, v_d, dense) = _case_cache(
+        jax.random.PRNGKey(5), g, case, entries=5
+    )
+    P, c = 3, g.chunk
+    q = 4 * jax.random.normal(jax.random.PRNGKey(6), (P, c, g.n_heads, g.hd))
+    slots = jnp.array([0, 3, 1], jnp.int32)
+    starts = jnp.array([0, g.window - c // 2, g.max_len - 2 * c], jnp.int32)
+    lens = jnp.array([c, c, c // 2 + 1], jnp.int32)
+    w = g.window if case.windowed else 0
+
+    def chunk(k, v, kw, at, kernel):
+        extra = dict(interpret=INTERPRET) if kernel else dict(kernel=False)
+        run = flash_cache_attention if kernel else cache_chunk_attention
+        return np.asarray(run(
+            q, k, v, slots, starts, lens, layer=jnp.int32(at), window=w,
+            **kw, **extra,
+        ))
+
+    got = chunk(k_c, v_c, kern, layer, kernel=True)
+    stacked = chunk(k_c, v_c, kern, layer, kernel=False)
+    alone = chunk(
+        k_d[layer][None], v_d[layer][None],
+        {name: None if p is None else p[layer][None] for name, p in dense.items()},
+        0, kernel=False,
+    )
+    tol = serving_tolerance(case, g)
+    np.testing.assert_allclose(got, stacked, atol=tol, rtol=tol)
+    np.testing.assert_allclose(stacked, alone, atol=2e-5, rtol=2e-5)
+    other = chunk(k_c, v_c, kern, (layer + 1) % 5, kernel=True)
+    assert np.abs(other - got).max() > 0.1
