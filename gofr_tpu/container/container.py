@@ -351,10 +351,11 @@ class Container:
         )
         m.new_histogram(
             "app_tpu_decode_read_ratio",
-            "positions of every slot the dense decode attention read at a "
-            "decode window's last step (the rung that holds the longest "
-            "live slot) / max_len, one record per processed window; 1.0: "
-            "the whole cache", ratio_buckets,
+            "positions of the cache the decode attention read at a decode "
+            "window's last step / slots x max_len, one record per processed "
+            "window: the dense read, every slot to the rung that holds the "
+            "longest live slot; a latent cache's kernel, each live slot's "
+            "own blocks; 1.0: the whole cache", ratio_buckets,
         )
         m.new_histogram(
             "app_tpu_prefill_attn_visit_ratio",

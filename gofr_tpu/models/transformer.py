@@ -40,6 +40,7 @@ from gofr_tpu.ops.attention import (
     decode_read_rungs,
     latent_chunk_attention,
     latent_decode_attention,
+    latent_decode_block,
     pad_last,
     sparse_block_scores,
     sparse_chunk_attention,
@@ -2464,7 +2465,10 @@ def transformer_decode_step(
     ``decode_read_rungs(max_len)[i]`` positions of every slot, the
     smallest rung that holds the longest ACTIVE slot, chosen here on the
     device at every step (so it follows the lengths as they grow inside a
-    window). An inactive slot's stale length does not count.
+    window). An inactive slot's stale length does not count. A latent
+    cache on a TPU is read by ``latent_flash_decode`` instead, each active
+    slot to its own length and an inactive one not at all
+    (``ops/attention.latent_decode_block``).
     bound_read: False keeps the whole read — for a cache whose position
     axis is sharded (context parallel), where a prefix lives on the first
     chips only.
@@ -2527,8 +2531,13 @@ def transformer_decode_step(
     # The slots whose routes count (a counting model's only).
     tiled = cfg.expert_product(S, sharded) == "tiles"
     counted = active[:, None] if cfg.counts_routes or tiled else None
+    # A latent cache on the TPU: ``latent_flash_decode`` reads each slot to
+    # its own length in blocks of this many positions, and no rung is chosen.
+    block = latent_decode_block(
+        cache.max_len, latent=cfg.is_latent, dense=dense_attn
+    )
     read = None
-    if bound_read and not paged:
+    if bound_read and not paged and not block:
         read = decode_read_index(
             decode_read_rungs(cache.max_len),
             jnp.max(jnp.where(active, cache.lengths, 0)),
@@ -2536,7 +2545,8 @@ def transformer_decode_step(
 
     def latent_body(x, scanned):
         """The absorbed form: the queries are projected into the latent
-        space and the cache is read as it lies, never expanded."""
+        space and the cache is read as it lies, never expanded. A slot that
+        does not decode reads nothing of it (its output is discarded)."""
         lp, entry = scanned
         pos2 = positions[:, None]  # [S, 1]
         h = _norm(x[:, None, :], lp["attn_norm"], cfg, lp.get("attn_norm_b"))
@@ -2544,8 +2554,9 @@ def transformer_decode_step(
         row = _mla_rows(h, lp, cfg, cos, sin, pos2)[:, 0]  # [S, row]
         with jax.named_scope("attn"):
             o = latent_decode_attention(
-                q, cache.k, positions, row, rank=cfg.kv_lora_rank,
-                scale=cfg.head_dim**-0.5, layer=entry, read=read,
+                q, cache.k, jnp.where(active, positions, 0), row,
+                rank=cfg.kv_lora_rank, scale=cfg.head_dim**-0.5,
+                layer=entry, read=read, block=block,
             )
             attn_out = _mla_out(o[:, None], lp, cfg, absorbed=True)
             attn_out = _post_norm(attn_out, lp, "attn_post_norm", cfg)[:, 0]

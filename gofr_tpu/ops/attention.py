@@ -64,6 +64,10 @@ def _flash_decode_enabled() -> bool:
     return _flash_enabled()
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
     """Pallas interpret mode, for every kernel call in this module: never
     on a TPU backend, always off it. Off-TPU the kernel path is only
@@ -229,19 +233,53 @@ def _decode_takes_kernel(
     return kernel
 
 
+# Positions a grid step of ``latent_flash_decode`` reads of a slot. Measured
+# on the v5e (PERF.md section 6), a layer of the latent share, 32
+# slots of 8,192 positions: blocks of 256 / 512 / 1,024 read every slot at
+# 8,092 positions in 1.041 / 0.745 / 0.597 ms and the ``longdoc`` cell's 7
+# live slots in 0.272 / 0.185 / 0.152 ms (the dense read at its rung 2.52).
+LATENT_DECODE_BLOCK = 1024
+
+
+def latent_decode_block(max_len: int, *, latent: bool, dense: bool) -> int:
+    """The block of positions in which a decode step's attention reads each
+    slot through ``latent_flash_decode``, or 0 where the dense bounded path
+    (``latent_decode_attention``'s rungs) runs. The kernel runs for a latent
+    cache (``latent``; a hybrid cache's sparse layers keep their own dense
+    read and gather), on a TPU backend, unless the caller asks for the dense
+    form (``dense``: under a mesh, which XLA partitions and a Pallas kernel
+    does not). No switch reaches it. The block is ``LATENT_DECODE_BLOCK``, or
+    the largest of 512, 256 and 128 that divides ``max_len`` (the plane is
+    the cache, so it cannot be padded), or the whole ``max_len``.
+    The host's counter and the device's program ask this same function."""
+    if not latent or dense or not _on_tpu():
+        return 0
+    return next(
+        (b for b in (LATENT_DECODE_BLOCK, 512, 256, 128)
+         if b <= max_len and max_len % b == 0),
+        max_len,
+    )
+
+
 def decode_read_plan(
     max_len: int, *, paged: bool = False, window: int = 0,
-    kernel: bool | None = None, latent: bool = False,
+    kernel: bool | None = None, latent: bool = False, hybrid: bool = False,
 ) -> tuple[int, ...]:
     """The prefixes ``decode_attention``'s ``read`` selects among for such
     a cache: ``decode_read_rungs`` where the dense path over a contiguous
     cache runs, the one whole read where the kernel or a paged pool does
     (the host's counter and the device's program ask the same function).
-    A latent cache is always read on the dense bounded path
-    (``latent_decode_attention``), whatever ``max_len``, and so are a
-    hybrid cache's sparse layers for the slots under their dense length
-    (``latent`` stands for both: the dense path is forced)."""
-    if latent:
+    A latent cache is read by ``latent_flash_decode`` where
+    ``latent_decode_block`` says so (``kernel`` False: the dense form is
+    asked for), each slot to its own length: the one whole read here; else
+    on the dense bounded path at every ``max_len``. A hybrid cache's sparse
+    layers are always read on the dense bounded path for the slots under
+    their dense length."""
+    if latent and latent_decode_block(
+        max_len, latent=True, dense=kernel is False,
+    ):
+        return (max_len,)
+    if latent or hybrid:
         return decode_read_rungs(max_len)
     window = _effective_window(window, max_len, None)
     if paged or _decode_takes_kernel(kernel, max_len, paged, window):
@@ -457,16 +495,19 @@ def latent_decode_attention(
     scale: float,
     layer: jnp.ndarray | None = None,
     read: jnp.ndarray | None = None,
+    block: int = 0,
 ) -> jnp.ndarray:
     """Absorbed-form decode attention over a latent cache, read as it lies.
 
     Every head attends the SAME row of a token: ``[latent | rotary key]``,
     ``rank`` + rope values, with the values the first ``rank`` of it. That
     is multi-query attention with one kv head whose value is a prefix of
-    its key, so this is the dense split-softmax of ``_dense_decode`` with
-    one group of ``n_heads`` queries, bounded by PR 31's rungs at every
-    ``max_len`` (neither Pallas decode kernel takes a key wider than its
-    value, so no auto rule sends this geometry to one).
+    its key. ``block`` > 0 (``latent_decode_block``): the Pallas kernel
+    ``latent_flash_decode`` reads each slot to its own length in blocks of
+    that many positions, from the stacked plane (``layer`` required).
+    Otherwise this is the dense split-softmax of ``_dense_decode`` with one
+    group of ``n_heads`` queries, bounded by the read's rungs: the CPU's path,
+    and the kernel's reference.
 
     q: [b, n_heads, rank + rope], the queries projected into the latent
     space beside their rotary part; plane: the stacked
@@ -480,9 +521,17 @@ def latent_decode_attention(
     caller's value up-projection.
     """
     max_len = plane.shape[-2]
-    rungs = decode_read_rungs(max_len) if read is not None else (max_len,)
     # The plane is allocated in whole lane tiles: zeros beyond a row's
     # content, so zeros on the query there leave every score as it was.
+    if block:
+        from gofr_tpu.ops.pallas.latent_decode import latent_flash_decode
+
+        return latent_flash_decode(
+            pad_last(q, plane.shape[-1]), plane, lengths,
+            pad_last(row_new, plane.shape[-1]), layer=layer, rank=rank,
+            scale=scale, block=block, interpret=_interpret(),
+        )
+    rungs = decode_read_rungs(max_len) if read is not None else (max_len,)
     qg = pad_last(q, plane.shape[-1])[:, None]  # [b, 1, heads, width]
     k_new = pad_last(row_new, plane.shape[-1])[:, None]  # [b, 1, width]
 

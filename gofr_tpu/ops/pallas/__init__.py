@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the attention hot path.
 
-Two kernels, both written grid-sequential in the canonical TPU style (the
+Four kernels, all written grid-sequential in the canonical TPU style (the
 kv axis is the innermost grid dimension; online-softmax state carries in
 VMEM scratch across kv iterations):
 
@@ -10,7 +10,9 @@ VMEM scratch across kv iterations):
   KV cache with per-slot lengths prefetched to SMEM so fully-invalid KV
   blocks are skipped before their DMA cost is paid;
 * :func:`flash_cache_attention` — chunked-prefill queries against the slot
-  cache in place (one compile a row count serves every prompt length).
+  cache in place (one compile a row count serves every prompt length);
+* :func:`latent_flash_decode` — a latent cache's absorbed decode attention
+  over the stacked plane in place, each slot read to its own length.
 
 All run under ``interpret=True`` on CPU, which is how the unit tests
 exercise them without hardware.
@@ -19,5 +21,9 @@ exercise them without hardware.
 from gofr_tpu.ops.pallas.flash_attention import flash_attention
 from gofr_tpu.ops.pallas.flash_decode import flash_decode
 from gofr_tpu.ops.pallas.flash_prefill import flash_cache_attention
+from gofr_tpu.ops.pallas.latent_decode import latent_flash_decode
 
-__all__ = ["flash_attention", "flash_cache_attention", "flash_decode"]
+__all__ = [
+    "flash_attention", "flash_cache_attention", "flash_decode",
+    "latent_flash_decode",
+]

@@ -55,6 +55,7 @@ class LLMProgramsMixin:
     prefill_rungs: tuple[int, ...]
     moe_products: dict[tuple[str, int], str]
     decode_read_rungs: tuple[int, ...]
+    decode_read_block: int
     max_len: int
     kv_block: int
     _slot_state_dirty: bool
@@ -86,7 +87,10 @@ class LLMProgramsMixin:
             transformer_decode_step,
             transformer_prefill_chunk,
         )
-        from gofr_tpu.ops.attention import decode_read_plan
+        from gofr_tpu.ops.attention import (
+            decode_read_plan,
+            latent_decode_block,
+        )
         cfg, top_k = self.cfg, self._top_k
         # pallas kernels don't auto-partition under GSPMD: mesh-sharded
         # serving takes the dense attention formulations, which XLA
@@ -101,8 +105,13 @@ class LLMProgramsMixin:
         self.decode_read_rungs = decode_read_plan(
             self.max_len, paged=bool(self.kv_block),
             window=cfg.sliding_window, kernel=False if dense_attn else None,
-            latent=cfg.is_latent or cfg.is_hybrid,
+            latent=cfg.is_latent, hybrid=cfg.is_hybrid,
         ) if bound_read else (self.max_len,)
+        # A latent cache's kernel reads each live slot to its own length in
+        # blocks of this many positions (0: the rungs' rule holds).
+        self.decode_read_block = latent_decode_block(
+            self.max_len, latent=cfg.is_latent, dense=dense_attn
+        )
         # An expert layer that may hold a share of the experts counts its
         # routes; the steps return the counts beside their tokens (no
         # program of another model changes). A stacked expert layer picks

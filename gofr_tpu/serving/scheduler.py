@@ -80,6 +80,7 @@ class SchedulerMixin:
     prefill_attn_block: int
     moe_products: dict[tuple[str, int], str]
     decode_read_rungs: tuple[int, ...]
+    decode_read_block: int
     prefill_chunk: int
     top_logprobs: int
     window_k: int
@@ -1807,7 +1808,7 @@ class SchedulerMixin:
         """Dispatch one k-step device window (non-blocking) and start the
         async device→host copy of its emitted [2, k, S] block. Returns
         ``(emitted_dev, slots_snapshot, etops_dev_or_None,
-        live_positions, longest)`` for _process_window — the snapshot matters
+        held, longest)`` for _process_window — the snapshot matters
         because by processing time a retired slot may already hold a NEW
         request admitted in between."""
         # Fault seam: a raise models the device failing a decode window;
@@ -1873,24 +1874,24 @@ class SchedulerMixin:
                 self._release_slot(i)
             self._push_table()
 
-        # Cache positions that are context when this window starts: each
-        # live slot's prompt plus what earlier windows already cover; and
+        # Cache positions that are context when this window starts, a live
+        # slot each: its prompt plus what earlier windows already cover; and
         # the longest of them, which decides how much of every slot the
         # window's dense attention reads.
-        live_positions = longest = 0
+        held: list[int] = []
+        longest = 0
         for i, seq in enumerate(self._slots):
             if seq is not None:
                 req = seq.request
-                held = (
+                held.append(
                     (req.effective_prompt_len or len(req.prompt_ids))
                     + seq.tokens_in_flight - 1
                 )
-                live_positions += held
                 # A hybrid cache's dense read serves the slots under the
                 # dense length only; the others gather their chosen blocks.
                 if not (self.cfg.is_hybrid
-                        and held >= self.cfg.sparse_dense_len):
-                    longest = max(longest, held)
+                        and held[-1] >= self.cfg.sparse_dense_len):
+                    longest = max(longest, held[-1])
                 seq.tokens_in_flight += self.window_k
         # Results land in LOCALS first and commit to self only after a
         # superseded check: a dispatch that BLOCKED here (a hung device
@@ -1927,14 +1928,14 @@ class SchedulerMixin:
         if self._lockstep:
             lockcheck.note_device_sync("lockstep_block_until_ready")
             self._jax.block_until_ready(emitted)
-        return emitted, list(self._slots), etops, live_positions, longest
+        return emitted, list(self._slots), etops, held, longest
 
     def _process_window(
         self,
         emitted: Any,
         snapshot: "list[Optional[_ActiveSeq]]",
         etops: Any,
-        live_positions: int,
+        held: "list[int]",
         longest: int,
     ) -> None:
         # Interruptible wait: while this window's block is in flight, flush
@@ -2086,19 +2087,29 @@ class SchedulerMixin:
             # not reserve when this window was dispatched.
             self._metrics.record_histogram(
                 "app_tpu_kv_live_ratio",
-                live_positions / max(1, self.n_slots * self.max_len),
+                sum(held) / max(1, self.n_slots * self.max_len),
                 "model", self.model_name,
             )
-            # The share of every slot that the window's last step read:
-            # the device's own rule (ops/attention.decode_read_index)
-            # over the longest live slot, which that step finds
-            # window_k - 1 positions further on. 1.0: the whole cache.
-            rungs = self.decode_read_rungs
+            # The share of the cache that the window's last step read,
+            # where each slot stands window_k - 1 positions further on. A
+            # latent cache's kernel: each live slot's own blocks
+            # (ops/attention.latent_decode_block), an inactive slot none.
+            # Otherwise the device's own rule
+            # (ops/attention.decode_read_index) over the longest live
+            # slot, the same rung of every slot. 1.0: the whole cache.
+            block = self.decode_read_block
+            if block:
+                read = sum(
+                    -(-(n + self.window_k - 1) // block) * block
+                    for n in held
+                ) / (self.n_slots * self.max_len)
+            else:
+                rungs = self.decode_read_rungs
+                read = rungs[
+                    decode_read_index(rungs, longest + self.window_k - 1)
+                ] / rungs[-1]
             self._metrics.record_histogram(
-                "app_tpu_decode_read_ratio",
-                rungs[decode_read_index(rungs, longest + self.window_k - 1)]
-                / rungs[-1],
-                "model", self.model_name,
+                "app_tpu_decode_read_ratio", read, "model", self.model_name,
             )
         self._update_slot_gauges()
 

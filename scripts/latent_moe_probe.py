@@ -14,8 +14,13 @@ at the sizes of ``openpangu-ultra-moe-718b-ep16`` (seeded random weights,
   visits its own blocks only);
 * ``latent_chunk_attention`` alone at those depths, its loop in blocks of
   256, 512 and 1,024 positions;
-* one decode step with every slot live at several lengths: the bounded
-  read's rungs at 8,192 positions;
+* one decode step with every slot live at several lengths, and with the
+  ``longdoc`` cell's 7 live slots of 32: through the kernel
+  (``latent_flash_decode``, each slot to its own length) and through
+  the dense bounded read (the rung of the longest live slot);
+* the latent decode attention alone, a layer: the kernel in blocks of 256,
+  512 and 1,024 positions against the dense read at its rung, at those
+  lengths (``--only decode_attention``; no weights drawn);
 * one expert layer's held part by the sorted, grouped product
   (``jax.lax.ragged_dot``) against the dense einsum over the held experts,
   at a prefill step's 2,048 rows and a decode step's 32: ROADMAP S5's
@@ -51,7 +56,7 @@ def main() -> int:
     parser.add_argument("--slots", type=int, default=32)
     parser.add_argument("--max-len", type=int, default=8192)
     parser.add_argument("--chunk", type=int, default=256)
-    parser.add_argument("--only", default="", help="of prefill, attention, decode, experts: one, or several with commas")
+    parser.add_argument("--only", default="", help="of prefill, attention, decode, decode_attention, experts: one, or several with commas")
     args = parser.parse_args()
     only = {name for name in args.only.split(",") if name}
     asked = lambda name: not only or name in only  # noqa: E731
@@ -64,7 +69,9 @@ def main() -> int:
     from gofr_tpu.models import transformer as T
     from gofr_tpu.models.registry import get_model
     from gofr_tpu.ops.attention import (
-        LATENT_CHUNK_BLOCK, chunk_block_counts, latent_chunk_attention,
+        LATENT_CHUNK_BLOCK, chunk_block_counts, decode_read_index,
+        decode_read_rungs, latent_chunk_attention, latent_decode_attention,
+        latent_decode_block,
     )
     from gofr_tpu.ops.kv_cache import LatentKVCache
 
@@ -83,7 +90,8 @@ def main() -> int:
     # the attention alone needs no weights but its own two (a boot draws
     # 9.84 GB in a minute or two)
     params = (T.init_transformer(jax.random.PRNGKey(0), cfg)
-              if only != {"attention"} else None)
+              if only - {"attention", "decode_attention"} or not only
+              else None)
     cache = LatentKVCache.create(
         cfg.n_cache_entries, S, ML, cfg.cache_row, cfg.dtype
     )
@@ -175,20 +183,70 @@ def main() -> int:
                     ms=best(attend, q, plane[0],
                             jnp.asarray(depths, jnp.int32), w_uk, w_uv))
 
-    # 2. one decode step, every slot live
-    def dec(p, tok, k, lengths):
+    # 2. one decode step: every slot live at one length, then the cell's
+    # live slots (7 of 32, 1,100 to 7,700 positions; the others idle),
+    # through the kernel where this backend takes it and through the dense
+    # bounded read
+    def dec(p, tok, k, lengths, active, dense):
         logits, cache = T.transformer_decode_step(
-            p, tok, LatentKVCache(k, lengths), jnp.ones((S,), bool), cfg
+            p, tok, LatentKVCache(k, lengths), active, cfg, dense_attn=dense
         )
         return logits, cache.k
 
-    dec = jax.jit(dec, donate_argnums=(2,))
+    dec = jax.jit(dec, donate_argnums=(2,), static_argnums=(5,))
     tok = jax.random.randint(key, (S,), 3, cfg.vocab_size)
-    lengths = sorted({ML // 8, 3 * ML // 8, 5 * ML // 8, ML - 100})
-    for length in lengths if asked("decode") else ():
-        out(what="decode_step", length=length,
-            ms=best_on_cache(dec, params, tok,
-                             jnp.full((S,), length, jnp.int32)))
+    cell = np.zeros((S,), np.int64)
+    live = np.array([1100, 2300, 3400, 4100, 4900, 6200, 7700])[:S]
+    cell[:live.size] = live * ML // 8192
+    cases = {f"all_at_{n}": np.full((S,), n)
+             for n in sorted({ML // 8, 3 * ML // 8, 5 * ML // 8, ML - 100})}
+    cases["cell"] = cell
+    block = latent_decode_block(ML, latent=True, dense=False)
+    for name, lens in cases.items() if asked("decode") else ():
+        active = jnp.asarray(lens > 0)
+        lens = jnp.asarray(lens, jnp.int32)
+        for dense in (False, True) if block else (True,):
+            out(what="decode_step", lengths=name,
+                read="dense" if dense else f"kernel_{block}",
+                ms=best_on_cache(
+                    lambda p, t, k, dense=dense: dec(
+                        p, t, k, lens, active, dense),
+                    params, tok))
+
+    # 2b. the latent decode attention alone, a layer (the mean over 40
+    # calls inside one program, the entry cycling through the plane's 5)
+    if asked("decode_attention"):
+        H, W = cfg.n_heads, cache.k.shape[-1]
+        ks = jax.random.split(key, 3)
+        q = jax.random.normal(ks[0], (S, H, cfg.cache_row), cfg.dtype)
+        row = jax.random.normal(ks[1], (S, cfg.cache_row), cfg.dtype)
+        entries = jnp.arange(40, dtype=jnp.int32) % cfg.n_cache_entries
+        rungs = decode_read_rungs(ML)
+
+        def per_call(attend):
+            def run(q, k, lens, row):
+                def one(total, entry):
+                    o = attend(q, k, lens, row, entry)
+                    return total + jnp.sum(o.astype(jnp.float32)), None
+                return jax.lax.scan(one, jnp.float32(0), entries)[0]
+            run = jax.jit(run)
+            return lambda lens: best(run, q, plane[0], lens, row) / 40
+
+        kw = dict(rank=cfg.kv_lora_rank, scale=cfg.head_dim**-0.5)
+        forms = {"dense_rung": per_call(
+            lambda q, k, lens, row, e: latent_decode_attention(
+                q, k, lens, row, layer=e, read=decode_read_index(
+                    rungs, jnp.max(lens)), **kw))}
+        for blk in (256, 512, 1024):
+            forms[f"kernel_{blk}"] = per_call(
+                lambda q, k, lens, row, e, blk=blk: latent_decode_attention(
+                    q, k, lens, row, layer=e, block=min(blk, ML), **kw))
+        for name, lens in cases.items():
+            live = lens[lens > 0]
+            out(what="latent_decode_attention", lengths=name,
+                live_slots=int(live.size), live_positions=int(live.sum()),
+                **{form: ms(jnp.asarray(lens, jnp.int32))
+                   for form, ms in forms.items()})
 
     # 3. the held experts' part of one expert layer (the weights are
     # operands: closed over, they would be compiled in as constants)
